@@ -95,6 +95,22 @@ def build_pipeline(spec: str, batch_size: int, int8: bool = False,
     return pipe
 
 
+def _demo_census(broker, args) -> dict:
+    """What a ``--demo N`` run left on the in-process broker, against what
+    it fed (keys ``0..N-1``): record counts on the output and dead-letter
+    topics, and whether every fed key came out exactly once — zero loss,
+    zero duplication. The demo's own accounting, so a caller of ``main()``
+    need not reach into the broker."""
+    out_keys = sorted(m.key for m in broker.messages(args.output_topic)
+                      if m.key is not None)
+    dlq_topic = ((args.dlq_topic or f"{args.output_topic}-dlq")
+                 if args.dlq else None)
+    return {"fed": args.demo, "out": len(out_keys),
+            "dlq": broker.topic_size(dlq_topic) if dlq_topic else 0,
+            "keys_exact": out_keys == sorted(
+                str(i).encode() for i in range(args.demo))}
+
+
 def _judge_scenario(scenario, events, feeder, broker, args, out,
                     tracers) -> dict:
     """Evaluate a --scenario run's SLO gates from the serve-side evidence
@@ -213,10 +229,8 @@ def main(argv=None) -> int:
                          "py): ship raw UTF-8 bytes and run tokenize/"
                          "murmur-hash/TF counting inside the scoring "
                          "program — the host featurize leg disappears. "
-                         "Requires a TPU backend; elsewhere the probe "
-                         "falls back to host featurization honestly and "
-                         "health()['device']['featurize_path'] says which "
-                         "path ran (docs/serving.md)")
+                         "Requires a TPU; without one the flag is an "
+                         "error, not a host fallback (docs/serving.md)")
     ap.add_argument("--featurize-width", type=int, default=None,
                     metavar="BYTES",
                     help="fixed byte width of the --featurize-device "
@@ -743,6 +757,11 @@ def main(argv=None) -> int:
 
     from fraud_detection_tpu.stream import InProcessBroker, StreamingClassifier
     from fraud_detection_tpu.stream.kafka import kafka_available
+    from fraud_detection_tpu.utils.device import device_stamp
+    from fraud_detection_tpu.utils.jax_cache import (
+        enable_persistent_compile_cache)
+
+    compile_cache = enable_persistent_compile_cache()
 
     explain_hook = None
     breaker = None
@@ -857,9 +876,8 @@ def main(argv=None) -> int:
     lifecycle = None
     model_desc = args.model
     # Device-side featurization: True asks for the compiled Pallas path
-    # (refused off-TPU with an honest host fallback recorded in health);
-    # FRAUD_TPU_FEATURIZE_INTERPRET=1 forces interpreter mode so CLI e2e
-    # tests and parity demos can exercise the kernel on CPU containers.
+    # (an error without a TPU); FRAUD_TPU_FEATURIZE_INTERPRET=1 forces
+    # interpreter mode so CLI e2e tests can exercise the kernel on the CPU.
     featurize_device = False
     if args.featurize_device:
         featurize_device = ("interpret" if os.environ.get(
@@ -883,9 +901,15 @@ def main(argv=None) -> int:
             shadow = ShadowScorer(max_queue=args.shadow_queue,
                                   sample=args.shadow_sample)
     else:
-        pipe = build_pipeline(args.model, args.batch_size, int8=args.int8,
-                              featurize_device=featurize_device,
-                              featurize_width=args.featurize_width)
+        from fraud_detection_tpu.featurize.device import (
+            DeviceFeaturizeUnavailable)
+
+        try:
+            pipe = build_pipeline(args.model, args.batch_size, int8=args.int8,
+                                  featurize_device=featurize_device,
+                                  featurize_width=args.featurize_width)
+        except DeviceFeaturizeUnavailable as e:
+            raise SystemExit(f"--featurize-device: {e}")
 
     if args.mesh:
         # Mesh data-parallel scoring: shard micro-batches over every local
@@ -901,16 +925,19 @@ def main(argv=None) -> int:
             pipe, per_chip_batch=max(1, args.batch_size // max(1, dp)))
         model_desc = f"{model_desc} (mesh x{pipe.data_parallel or 1})"
 
+    # Say which featurizer runs and where: the Pallas kernel, the C++
+    # library, or — when g++/dlopen failed — the pure-Python tokenizer.
     if featurize_device:
-        # Say which featurize path actually runs — silent fallback would
-        # defeat the flag's point (health carries the same field).
-        reason = getattr(pipe, "featurize_unavailable_reason", None)
-        path = getattr(pipe, "device_stats", None)
-        path = path.featurize_path if path is not None else "host"
-        model_desc = f"{model_desc} (featurize={path})"
-        if reason is not None:
-            print(f"--featurize-device unavailable, serving host featurize: "
-                  f"{reason}", file=sys.stderr)
+        featurizer_desc = pipe.device_stats.featurize_path
+    else:
+        from fraud_detection_tpu.featurize import native as native_mod
+
+        featurizer_desc = ("host-native" if native_mod.available()
+                           else "host-python")
+    stamp = device_stamp()
+    model_desc = (f"{model_desc} featurizer={featurizer_desc} "
+                  f"device={stamp['platform']}:{stamp['device_kind']}"
+                  f"x{stamp['device_count']} compile_cache={compile_cache}")
 
     sched_ladder_costs = None
     if sched_config is not None:
@@ -1180,6 +1207,7 @@ def main(argv=None) -> int:
             out = fleet.run(idle_timeout=1.0)
         finally:
             finish_metrics()
+        out["device"] = stamp
         print(json.dumps(out))
         n_out = broker.topic_size(args.output_topic)
         print(f"classified messages on {args.output_topic}: {n_out}")
@@ -1459,7 +1487,8 @@ def main(argv=None) -> int:
         # supervisor increments it outside _merge_stats).
         total.elapsed = max((r.elapsed for r in done), default=0.0)
         total.restarts = sum(r.restarts for r in done)
-        merged = {**total.as_dict(), "workers": args.workers,
+        merged = {**total.as_dict(), "device": stamp,
+                  "workers": args.workers,
                   "per_worker_processed": [r.processed if r else None
                                            for r in results],
                   "health": [e.health() if e is not None else None
@@ -1542,6 +1571,14 @@ def main(argv=None) -> int:
         finally:
             engine.consumer.close()
     out = stats.as_dict()
+    out["device"] = stamp
+    out["featurizer"] = featurizer_desc
+    if engines_built:
+        # The run is over and the dispatch lane has stopped: whether the
+        # last engine's raw-JSON encode and C++ frame assembly stayed on
+        # (None = never asked — e.g. a device-featurized pipeline).
+        out["fast_paths"] = {"native_json": engines_built[-1]._json_fast,
+                             "native_frames": engines_built[-1]._frames_ok}
     out["health"] = engines_built[-1].health() if engines_built else None
     if fault_plan is not None:
         out["chaos"] = fault_plan.report()
@@ -1585,6 +1622,8 @@ def main(argv=None) -> int:
                   f"(exit 4): "
                   f"{[v['name'] for v in out['scenario']['verdicts'] if not v['ok'] and not v['skipped']]}",
                   file=sys.stderr, flush=True)
+    if args.demo and scenario is None:
+        out["demo"] = _demo_census(broker, args)
     print(json.dumps(out))
     if args.demo:
         n_out = broker.topic_size(args.output_topic)

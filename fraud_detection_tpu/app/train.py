@@ -136,6 +136,12 @@ def main(argv=None) -> int:
 
     import jax.numpy as jnp
 
+    from fraud_detection_tpu.utils.device import device_stamp
+    from fraud_detection_tpu.utils.jax_cache import (
+        enable_persistent_compile_cache)
+
+    enable_persistent_compile_cache()
+
     from fraud_detection_tpu.data import train_val_test_split
     from fraud_detection_tpu.eval import evaluate_classification
     from fraud_detection_tpu.featurize.tfidf import HashingTfIdfFeaturizer
@@ -238,11 +244,9 @@ def main(argv=None) -> int:
                     print(f"  {k}: {v:.4f}")
                 print(f"  confusion: {rep.confusion.tolist()}")
     if args.json:
-        print(json.dumps(all_metrics, indent=2))
+        print(json.dumps({"device": device_stamp(), **all_metrics}, indent=2))
     if args.metrics_out:
         import math as math_mod
-
-        import jax
 
         from fraud_detection_tpu.models.train_trees import resolve_config
 
@@ -258,7 +262,7 @@ def main(argv=None) -> int:
             "n_rounds": args.n_rounds,
             "splits": {"train": len(train), "val": len(val),
                        "test": len(test)},
-            "backend": jax.default_backend(),
+            **device_stamp(),
             "mesh": dict(mesh.shape) if mesh is not None else None,
             "train_seconds": timings,
         }

@@ -12,12 +12,11 @@ XLA count/pack). This module owns everything around it:
   device equals running the host featurizer on the truncated text.
 * :class:`DeviceFeaturizer` — validates that a host featurizer's exact
   semantics are expressible on device (hashing featurizer, representable
-  stop list, int16-range feature space), builds the stop table and static
-  spec, and answers the capability probe: ``path()`` is ``"pallas"`` on a
-  TPU backend, ``"interpret"`` when explicitly requested off-TPU (tests,
-  parity benches), else the build refuses and callers keep the host path —
-  CPU containers fall back honestly and ``DeviceStats.featurize_path``
-  says which path actually ran.
+  stop list, int16-range feature space) and builds the stop table and
+  static spec. ``path`` is ``"pallas"`` on a TPU, ``"interpret"`` when a
+  test asks for the interpreter by argument; anything else raises
+  :class:`DeviceFeaturizeUnavailable` — asking for the device featurizer
+  where it cannot run is an error, never a quiet host fallback.
 
 The serving integration lives in models/pipeline.py
 (``ServingPipeline(featurize_device=...)``): the byte tensor becomes the
@@ -34,14 +33,15 @@ import numpy as np
 
 from fraud_detection_tpu.featurize.hashing import spark_hash_bucket
 from fraud_detection_tpu.featurize.tfidf import HashingTfIdfFeaturizer
+from fraud_detection_tpu.utils.device import device_stamp
 
 DEFAULT_WIDTH = 2048
 DEFAULT_TOKENS = 256
 
 
 class DeviceFeaturizeUnavailable(RuntimeError):
-    """The device featurize path cannot represent this configuration (or
-    this backend); the caller must keep host featurization."""
+    """The device featurize path cannot represent this configuration, or
+    this process has no TPU to compile it for."""
 
 
 def truncation_cut(data: bytes, width: int) -> int:
@@ -105,12 +105,11 @@ class DeviceFeaturizer:
     the reason (vocabulary featurizers, stop words longer than the identity
     pack, feature spaces past int16) — and resolves the execution path:
 
-    * ``interpret=False`` — compiled Pallas; requires a TPU backend.
-    * ``interpret=True``  — interpreter mode (CPU test mesh / parity
-      benches); requires the interpreter canary to pass.
-    * ``interpret=None``  — auto: compiled on TPU, otherwise refuse (an
+    * ``interpret=False`` — compiled Pallas; requires a TPU.
+    * ``interpret=True``  — interpreter mode (the CPU test mesh).
+    * ``interpret=None``  — compiled on a TPU, otherwise refuse: an
       interpreted kernel on the serving path would be slower than the host
-      leg it replaces — falling back is the honest default).
+      leg it replaces.
     """
 
     def __init__(self, featurizer: HashingTfIdfFeaturizer, *,
@@ -140,16 +139,14 @@ class DeviceFeaturizer:
         table, empty_is_stop = built
         legacy = bool(getattr(featurizer.hashing_tf, "legacy", False))
         if interpret is None:
-            if fk.auto_interpret():
+            stamp = device_stamp()
+            if stamp["platform"] != "tpu":
                 raise DeviceFeaturizeUnavailable(
-                    "no TPU backend (interpreted featurize would be slower "
-                    "than the host leg it replaces; pass interpret=True to "
-                    "force it for parity testing)")
+                    f"device featurization needs a TPU, this process runs on "
+                    f"{stamp['platform']} ({stamp['device_kind']}); pass "
+                    "interpret=True to run the kernel interpreted for parity "
+                    "testing")
             interpret = False
-        if interpret and not fk.interpreter_can_run():
-            raise DeviceFeaturizeUnavailable(
-                "this jax's Pallas interpreter cannot run the scan kernel "
-                "(capability canary failed)")
         self.featurizer = featurizer
         self.width = int(width)
         self.tokens = int(tokens)

@@ -1,8 +1,9 @@
 """ctypes loader for the native featurizer (native/fast_featurize.cpp).
 
 Builds the shared library on demand with g++ (no pip/pybind dependency —
-plain C ABI + ctypes), caches it next to the source, and degrades to None
-when no toolchain is available so the pure-Python path keeps working.
+plain C ABI + ctypes), caches it next to the source keyed on the source's
+content, and degrades to None when no toolchain is available so the
+pure-Python path keeps working (``serve`` prints which featurizer it runs).
 The Python featurizer (featurize/tfidf.py) auto-uses this when loadable;
 parity is enforced by tests/test_native_featurize.py comparing both paths
 byte-for-byte.
@@ -11,6 +12,7 @@ byte-for-byte.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -44,18 +46,38 @@ _SAN_VARIANTS = {
 _SAN_RUNTIMES = {"asan": "libasan.so", "tsan": "libtsan.so"}
 
 
+def _build_key(opt_flags) -> str:
+    """What a built library is a function of: the source's CONTENT and the
+    compiler flags. A library is reused only when the key recorded beside it
+    matches — never on file times, which a copied tree does not keep."""
+    digest = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        digest.update(f.read())
+    digest.update("\0".join([*opt_flags, *_BASE_FLAGS]).encode())
+    return digest.hexdigest()
+
+
 def _compile(out: str, opt_flags) -> Optional[str]:
-    if os.path.isfile(out) and os.path.getmtime(out) >= os.path.getmtime(_SRC):
-        return out
+    key, key_path = _build_key(opt_flags), out + ".key"
+    try:
+        with open(key_path) as f:
+            if f.read() == key and os.path.isfile(out):
+                return out
+    except OSError:
+        pass
     tmp = None
     try:
-        # build to a temp name then atomic-rename: concurrent processes race safely
+        # build to a temp name then atomic-rename: concurrent processes race
+        # safely; the key lands after the library it describes.
         fd, tmp = tempfile.mkstemp(suffix=".so", dir=os.path.dirname(out))
         os.close(fd)
         subprocess.run(
             ["g++", *opt_flags, *_BASE_FLAGS, _SRC, "-o", tmp],
             check=True, capture_output=True, timeout=240)
         os.replace(tmp, out)
+        with open(tmp, "w") as f:
+            f.write(key)
+        os.replace(tmp, key_path)
         return out
     except (OSError, subprocess.SubprocessError):
         if tmp is not None:

@@ -250,28 +250,23 @@ class ServingPipeline:
         # device.py): the host ships a fixed-width raw-byte tensor and ONE
         # jitted program runs tokenize/murmur-hash/count/pack + scoring —
         # the featurize leg leaves the host CPU entirely. ``featurize_device``
-        # accepts False, True (compiled Pallas; on a non-TPU backend the
-        # build REFUSES and the pipeline honestly keeps the host path —
-        # ``DeviceStats.featurize_path`` says which ran) or "interpret"
-        # (force interpreter mode: parity tests and benches off-TPU).
+        # accepts False, True (compiled Pallas; raises
+        # DeviceFeaturizeUnavailable where there is no TPU or the
+        # featurizer cannot be represented) or "interpret" (interpreter
+        # mode, for parity tests on the CPU mesh).
         self._dev_feat = None
-        self.featurize_unavailable_reason: Optional[str] = None
         if featurize_device:
-            from fraud_detection_tpu.featurize.device import (
-                DeviceFeaturizeUnavailable, DeviceFeaturizer)
+            from fraud_detection_tpu.featurize.device import DeviceFeaturizer
 
-            try:
-                self._dev_feat = DeviceFeaturizer(
-                    featurizer,
-                    **({"width": featurize_width}
-                       if featurize_width is not None else {}),
-                    **({"tokens": featurize_tokens}
-                       if featurize_tokens is not None else {}),
-                    interpret=(True if featurize_device == "interpret"
-                               else None))
-                self.device_stats.featurize_path = self._dev_feat.path
-            except DeviceFeaturizeUnavailable as e:
-                self.featurize_unavailable_reason = str(e)
+            self._dev_feat = DeviceFeaturizer(
+                featurizer,
+                **({"width": featurize_width}
+                   if featurize_width is not None else {}),
+                **({"tokens": featurize_tokens}
+                   if featurize_tokens is not None else {}),
+                interpret=(True if featurize_device == "interpret"
+                           else None))
+            self.device_stats.featurize_path = self._dev_feat.path
         # Donate per-batch staging buffers into the scoring program when the
         # platform consumes them (probed once; False on CPU).
         self._donate = donation_effective()

@@ -62,9 +62,7 @@ def _fit_lbfgs_impl(X, y, mask, l2, tol, max_iter: int):
         params, state, prev_val, it = carry
         val = optax.tree_utils.tree_get(state, "value")
         grad = optax.tree_utils.tree_get(state, "grad")
-        tree_norm = getattr(optax.tree_utils, "tree_norm",
-                            getattr(optax.tree_utils, "tree_l2_norm", None))
-        gnorm = tree_norm(grad)
+        gnorm = optax.tree_utils.tree_norm(grad)
         rel_impr = jnp.abs(prev_val - val) / jnp.maximum(jnp.abs(prev_val), 1e-12)
         not_converged = jnp.logical_or(it < 2, jnp.logical_and(gnorm > tol, rel_impr > tol))
         return jnp.logical_and(it < max_iter, not_converged)

@@ -4,8 +4,8 @@ Histogram tree building reformulated as MXU matmuls (ops/histogram.py) —
 the kernels BASELINE.json calls for — and device-side featurization
 (ops/featurize_kernel.py): a byte-scan kernel that moves the serving
 path's tokenize/murmur-hash/TF-count leg off the host entirely. XLA
-fallback paths live next to every kernel; off-TPU the kernels run in
-interpreter mode so the CPU test mesh exercises them.
+reference paths live next to every kernel; every kernel takes
+``interpret=`` so the CPU test mesh exercises them.
 """
 
 from fraud_detection_tpu.ops.featurize_kernel import (
@@ -15,7 +15,6 @@ from fraud_detection_tpu.ops.featurize_kernel import (
     featurize_bytes_jit,
 )
 from fraud_detection_tpu.ops.histogram import (
-    auto_interpret,
     best_splits,
     histogram_reference,
     node_feature_bin_histogram,
@@ -24,7 +23,6 @@ from fraud_detection_tpu.ops.histogram import (
 
 __all__ = [
     "FeaturizeSpec",
-    "auto_interpret",
     "best_splits",
     "build_stop_table",
     "featurize_bytes",

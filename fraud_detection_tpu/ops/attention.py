@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fraud_detection_tpu.ops.histogram import _round_up, auto_interpret  # noqa: F401
+from fraud_detection_tpu.ops.histogram import _round_up
 
 _NEG = -1e30  # mask value: exp(s - m) underflows to exactly 0, no inf-inf NaNs
 

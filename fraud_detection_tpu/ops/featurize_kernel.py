@@ -32,7 +32,7 @@ ONE jitted device program reproduces the exact Spark-parity pipeline:
     construction). Stop words that cannot match any cleaned token (non
     ``[a-z]`` chars) are dropped from the table host-side; a pure-alpha
     stop word longer than the pack width makes the device path refuse
-    (honest fallback) rather than silently diverge.
+    rather than silently diverge.
   * **count + pack** — bucket = nonNegativeMod(signed hash, F), per-row
     unique-bucket counting via sort + segment-sum, host truncation rule
     (keep top counts, ties toward the LOWER bucket id) when a row has more
@@ -45,22 +45,20 @@ IDF scaling already lives on device (folded into LR weights /
 and upstream of it, the raw byte tensor — is the only host artifact on the
 scoring path.
 
-Like ``ops/histogram.py``, the kernel runs under ``interpret=True``
-off-TPU so the CPU test mesh pins parity; ``interpreter_can_run()`` is the
-environment-only capability canary (PR 9 style) the tests and the serving
-probe share.
+Tests run the kernel under ``interpret=True`` on the CPU mesh (parity with
+the host featurizer, byte for byte); on a TPU it compiles through Mosaic.
 """
 
 from __future__ import annotations
 
-from functools import lru_cache, partial
+from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401 — VMEM specs
+from jax.experimental.pallas import tpu as pltpu
 
 from fraud_detection_tpu.featurize.hashing import SPARK_HASHING_TF_SEED
 
@@ -82,7 +80,16 @@ SPECIAL_LOWER = ((b"\xc4\xb0", ord("i")), (b"\xe2\x84\xaa", ord("k")))
 _STOP_PACK_CHARS = 12
 _STOP_TABLE_MAX = 1 << 16
 
-ROW_TILE = 128
+# Kernel tile geometry. Mosaic indexes a ref dynamically only on its leading
+# (untiled) dims, so the scan runs POSITION-MAJOR: streams are
+# (positions, row-groups, 128 lanes), one step of the scan reads/writes
+# ``ref[j]`` — a whole (row-groups, 128) slab — and every batch row owns one
+# lane of it. SUBLANES row groups make each state vector a full (8, 128)
+# vreg; COL_TILE positions per grid step bound VMEM at 5 streams x 2 buffers
+# x COL_TILE x 4 KiB = 5 MiB, under the 16 MiB scoped default.
+LANES = 128
+SUBLANES = 8
+COL_TILE = 128
 
 _MASK32 = 0xFFFFFFFF
 
@@ -97,7 +104,6 @@ class FeaturizeSpec(NamedTuple):
     legacy: bool            # murmur legacy sign-extended-tail variant
     empty_bucket: int       # spark_hash_bucket("") — the "" token's bucket
     empty_is_stop: bool     # "" present in the stop list
-    row_tile: int = ROW_TILE
     interpret: bool = False
 
 
@@ -106,30 +112,41 @@ def _round_up(n: int, m: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# murmur3 x86_32 primitives (uint32 vector ops — usable inside the kernel)
+# murmur3 x86_32 primitives (int32 vector ops — usable inside the kernel)
 # ---------------------------------------------------------------------------
+#
+# Everything is int32: two's-complement multiply/add/xor/shift-left are the
+# same bits as the uint32 reference, and the unsigned right shift is spelled
+# ``shift_right_logical`` — the kernel needs no unsigned vector type.
+
+def _i32(c: int):
+    """A 32-bit constant given as unsigned, as the int32 with the same bits."""
+    return jnp.int32(c - (1 << 32) if c >= (1 << 31) else c)
+
+
+def _srl(x, n: int):
+    return jax.lax.shift_right_logical(x, jnp.int32(n))
+
+
+def _rotl(x, r: int):
+    return (x << r) | _srl(x, 32 - r)
+
 
 def _mix_k1(k1):
-    # Constants are built at trace time INSIDE the kernel: Pallas refuses
-    # closure-captured device arrays (jax 0.4.x), inline scalars are fine.
-    k1 = k1 * jnp.uint32(0xCC9E2D51)
-    k1 = (k1 << 15) | (k1 >> 17)
-    return k1 * jnp.uint32(0x1B873593)
+    return _rotl(k1 * _i32(0xCC9E2D51), 15) * _i32(0x1B873593)
 
 
 def _mix_h1(h1, k1):
-    h1 = h1 ^ k1
-    h1 = (h1 << 13) | (h1 >> 19)
-    return h1 * jnp.uint32(5) + jnp.uint32(0xE6546B64)
+    return _rotl(h1 ^ k1, 13) * jnp.int32(5) + _i32(0xE6546B64)
 
 
-def _fmix(h1, length_u32):
-    h1 = h1 ^ length_u32
-    h1 = h1 ^ (h1 >> 16)
-    h1 = h1 * jnp.uint32(0x85EBCA6B)
-    h1 = h1 ^ (h1 >> 13)
-    h1 = h1 * jnp.uint32(0xC2B2AE35)
-    return h1 ^ (h1 >> 16)
+def _fmix(h1, length):
+    h1 = h1 ^ length
+    h1 = h1 ^ _srl(h1, 16)
+    h1 = h1 * _i32(0x85EBCA6B)
+    h1 = h1 ^ _srl(h1, 13)
+    h1 = h1 * _i32(0xC2B2AE35)
+    return h1 ^ _srl(h1, 16)
 
 
 # ---------------------------------------------------------------------------
@@ -167,43 +184,56 @@ def byte_classes(byts: jax.Array, lengths: jax.Array) -> jax.Array:
 # the scan kernel: tokenize + murmur + stop-key pack, one pass over bytes
 # ---------------------------------------------------------------------------
 
-def _scan_kernel(cls_ref, h_ref, w0_ref, w1_ref, tl_ref, emp_ref, *,
+# Scan state carried across byte positions (and, in VMEM scratch, across
+# the column tiles of one row tile), in this order.
+_STATE = ("h1", "k1", "nb", "w0", "w1", "pend", "emp", "kept")
+_EMP = _STATE.index("emp")
+
+
+def _scan_kernel(cls_ref, h_ref, w0_ref, w1_ref, tl_ref, emp_ref, st_ref, *,
                  legacy: bool):
-    """One row tile: sequential scan over byte positions, rows vectorized.
+    """One (row tile, column tile) cell: sequential scan over the tile's
+    byte positions, batch rows vectorized across (row-group, lane).
 
     Per step, every row advances its token state by one char class: letters
     stream into the murmur word accumulator and the 5-bit identity pack;
     a space or the end flush the current field. Emissions land at the
-    CURRENT column (each position closes at most one field), so the output
-    streams are (R, W+1) with no data-dependent scatter: ``tl`` >= 0 marks
-    a real token (its byte length), -1 an empty slot.
+    CURRENT position (each position closes at most one field), so the output
+    streams have the input's shape with no data-dependent scatter: ``tl``
+    >= 0 marks a real token (its byte length), -1 an empty slot.
 
     Java-split semantics ride two per-row counters: ``pend`` accumulates
     empty fields whose interior-ness is unknown until a later non-empty
     field confirms it (trailing empties die in ``pend``), and ``emp`` is
     the confirmed empty-token count — plus the ``"" -> [""]`` rule when the
     cleaned row kept no chars at all.
+
+    The grid runs column tiles innermost, so ``st_ref`` hands the state from
+    one column tile of a row tile to the next.
     """
-    nrows, ncols = cls_ref.shape
-    seed_v = jnp.full((nrows, 1), SPARK_HASHING_TF_SEED, jnp.uint32)
-    zero_u = jnp.zeros((nrows, 1), jnp.uint32)
-    zero_i = jnp.zeros((nrows, 1), jnp.int32)
+    ncols = cls_ref.shape[0]
+    zero = jnp.zeros(cls_ref.shape[1:], jnp.int32)
+    seed_v = jnp.full_like(zero, SPARK_HASHING_TF_SEED)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        for i, name in enumerate(_STATE):
+            st_ref[i] = seed_v if name == "h1" else zero
 
     def step(j, st):
         h1, k1, nb, w0, w1, pend, emp, kept = st
-        c = cls_ref[:, pl.dslice(j, 1)]
+        c = cls_ref[j]
         is_let = (c >= 1) & (c <= 26)
         is_space = c == CLS_SPACE
         is_end = c == CLS_END
 
         # letter: stream the byte into murmur (body words complete every
         # 4th byte) and the identity pack (first _STOP_PACK_CHARS chars).
-        vb = jnp.where(is_let, c + 96, 0).astype(jnp.uint32)
-        k1n = jnp.where(is_let, k1 | (vb << ((nb & 3) * 8).astype(jnp.uint32)),
-                        k1)
+        vb = jnp.where(is_let, c + 96, 0)
+        k1n = jnp.where(is_let, k1 | (vb << ((nb & 3) * 8)), k1)
         word_full = is_let & ((nb & 3) == 3)
         h1n = jnp.where(word_full, _mix_h1(h1, _mix_k1(k1n)), h1)
-        k1n = jnp.where(word_full, zero_u, k1n)
+        k1n = jnp.where(word_full, zero, k1n)
         cw = jnp.where(is_let, c, 0)
         w0n = jnp.where(is_let & (nb < 6),
                         w0 | (cw << (5 * jnp.minimum(nb, 6))), w0)
@@ -211,103 +241,96 @@ def _scan_kernel(cls_ref, h_ref, w0_ref, w1_ref, tl_ref, emp_ref, *,
                         w1 | (cw << (5 * jnp.clip(nb - 6, 0, 6))), w1)
         nbn = jnp.where(is_let, nb + 1, nb)
 
-        # boundary: this column closes a field. Non-empty -> finalize the
+        # boundary: this position closes a field. Non-empty -> finalize the
         # hash and emit; empty at a space -> one more pending empty field;
         # empty at the end -> trailing, dropped.
         emit = (is_space | is_end) & (nbn > 0)
-        tail_n = (nbn & 3).astype(jnp.uint32)
+        tail_n = nbn & 3
         if legacy:
             # hashUnsafeBytes: each tail byte gets a FULL mix round. Token
             # bytes are 'a'..'z' (< 0x80), so Java's sign extension is the
             # identity here.
             hfin = h1n
             for t in range(3):
-                byte_t = (k1n >> jnp.uint32(8 * t)) & jnp.uint32(0xFF)
+                byte_t = _srl(k1n, 8 * t) & 0xFF
                 hfin = jnp.where(tail_n > t, _mix_h1(hfin, _mix_k1(byte_t)),
                                  hfin)
         else:
             # hashUnsafeBytes2: the pending tail word mixes in once
             # (mix_k1(0) == 0, so the aligned case is the same expression).
             hfin = h1n ^ _mix_k1(k1n)
-        hfin = _fmix(hfin, nbn.astype(jnp.uint32))
-        hout = jax.lax.bitcast_convert_type(hfin, jnp.int32)
+        hout = _fmix(hfin, nbn)
 
-        pl.store(h_ref, (slice(None), pl.dslice(j, 1)),
-                 jnp.where(emit, hout, 0))
-        pl.store(w0_ref, (slice(None), pl.dslice(j, 1)),
-                 jnp.where(emit, w0n, 0))
-        pl.store(w1_ref, (slice(None), pl.dslice(j, 1)),
-                 jnp.where(emit, w1n, 0))
-        pl.store(tl_ref, (slice(None), pl.dslice(j, 1)),
-                 jnp.where(emit, nbn, -1))
+        h_ref[j] = jnp.where(emit, hout, 0)
+        w0_ref[j] = jnp.where(emit, w0n, 0)
+        w1_ref[j] = jnp.where(emit, w1n, 0)
+        tl_ref[j] = jnp.where(emit, nbn, -1)
 
         empn = jnp.where(emit, emp + pend, emp)
-        pendn = jnp.where(emit, zero_i, pend)
+        pendn = jnp.where(emit, zero, pend)
         pendn = jnp.where(is_space & (nbn == 0), pendn + 1, pendn)
-        keptn = kept | is_let | is_space
+        keptn = kept | (is_let | is_space).astype(jnp.int32)
         # cleaned row kept NOTHING: Java split("") returns [""] — exactly
         # one empty token, regardless of pending state.
-        empn = jnp.where(is_end & ~keptn, jnp.ones_like(empn), empn)
+        empn = jnp.where(is_end & (keptn == 0), jnp.ones_like(empn), empn)
 
         boundary = is_space | is_end
         return (jnp.where(boundary, seed_v, h1n),
-                jnp.where(boundary, zero_u, k1n),
-                jnp.where(boundary, zero_i, nbn),
-                jnp.where(boundary, zero_i, w0n),
-                jnp.where(boundary, zero_i, w1n),
+                jnp.where(boundary, zero, k1n),
+                jnp.where(boundary, zero, nbn),
+                jnp.where(boundary, zero, w0n),
+                jnp.where(boundary, zero, w1n),
                 pendn, empn, keptn)
 
-    init = (seed_v, zero_u, zero_i, zero_i, zero_i, zero_i, zero_i,
-            jnp.zeros((nrows, 1), jnp.bool_))
-    final = jax.lax.fori_loop(0, ncols, step, init)
-    emp_ref[:, :] = final[6]
+    final = jax.lax.fori_loop(
+        0, ncols, step, tuple(st_ref[i] for i in range(len(_STATE))))
+    for i, v in enumerate(final):
+        st_ref[i] = v
+    # The block is resident across the row tile's column steps; the last
+    # step's write is the one that reaches HBM.
+    emp_ref[...] = final[_EMP]
 
 
 def tokenize_hash(classes: jax.Array, *, legacy: bool = False,
-                  row_tile: int = ROW_TILE, interpret: bool = False
+                  interpret: bool = False
                   ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                              jax.Array]:
     """Run the scan kernel over a (B, C) class tensor.
 
     Returns per-position streams ``(h_raw, w0, w1, tok_len)`` — each
     (B, C) int32, ``tok_len`` < 0 where no token ends — plus the per-row
-    confirmed empty-token count (B, 1). Rows pad to the tile; columns pad
-    to a lane multiple with CLS_NOP (a no-op for the scan).
+    confirmed empty-token count (B, 1). The kernel sees the tensor
+    position-major, (C, B/128, 128): rows pad to whole tiles of up to
+    SUBLANES lane groups, positions pad to COL_TILE with CLS_NOP (a no-op
+    for the scan).
     """
     b, c = classes.shape
-    rt = min(row_tile, _round_up(max(b, 1), 8))
-    b_pad = _round_up(max(b, 1), rt)
-    c_pad = _round_up(c, 128)
+    groups = min(SUBLANES, _round_up(max(b, 1), LANES) // LANES)
+    b_pad = _round_up(max(b, 1), groups * LANES)
+    c_pad = _round_up(c, COL_TILE)
     cls = jnp.zeros((b_pad, c_pad), jnp.int32).at[:b, :c].set(
         classes.astype(jnp.int32))
+    cls = cls.T.reshape(c_pad, b_pad // LANES, LANES)
+    stream_spec = pl.BlockSpec((COL_TILE, groups, LANES),
+                               lambda i, j: (j, i, 0),
+                               memory_space=pltpu.VMEM)
+    stream_shape = jax.ShapeDtypeStruct(cls.shape, jnp.int32)
     outs = pl.pallas_call(
         partial(_scan_kernel, legacy=legacy),
-        grid=(b_pad // rt,),
-        in_specs=[pl.BlockSpec((rt, c_pad), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=[
-            pl.BlockSpec((rt, c_pad), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rt, c_pad), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rt, c_pad), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rt, c_pad), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((rt, 1), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b_pad, c_pad), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad, c_pad), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad, c_pad), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad, c_pad), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad, 1), jnp.int32),
-        ],
+        grid=(b_pad // (groups * LANES), c_pad // COL_TILE),
+        in_specs=[stream_spec],
+        out_specs=[stream_spec] * 4 + [
+            pl.BlockSpec((groups, LANES), lambda i, j: (i, 0),
+                         memory_space=pltpu.VMEM)],
+        out_shape=[stream_shape] * 4 + [
+            jax.ShapeDtypeStruct((b_pad // LANES, LANES), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((len(_STATE), groups, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cls)
-    h, w0, w1, tl, emp = outs
-    return h[:b, :c], w0[:b, :c], w1[:b, :c], tl[:b, :c], emp[:b]
+    h, w0, w1, tl = (x.reshape(c_pad, b_pad).T[:b, :c] for x in outs[:4])
+    return h, w0, w1, tl, outs[4].reshape(b_pad, 1)[:b]
 
 
 # ---------------------------------------------------------------------------
@@ -354,8 +377,8 @@ def pack_token(word: str) -> Optional[Tuple[int, int, int]]:
 def build_stop_table(words) -> Optional[Tuple[np.ndarray, bool]]:
     """Direct-mapped (size, 3) int32 stop table [w0, w1, len] + the
     empty-token flag, or None when the list cannot be represented exactly
-    (a pure-[a-z] word longer than the pack width — the caller must fall
-    back to host featurization rather than diverge silently).
+    (a pure-[a-z] word longer than the pack width — the device path refuses
+    such a list rather than diverge silently).
 
     Size doubles until every eligible word lands in its own slot (the probe
     is just a hash; collisions are resolved by growing, so the table is
@@ -494,52 +517,8 @@ def featurize_bytes(staged: jax.Array, stop_table: jax.Array, *,
     byts, lengths = split_staged(staged)
     classes = byte_classes(byts, lengths)
     h, w0, w1, tl, emp = tokenize_hash(
-        classes, legacy=spec.legacy, row_tile=spec.row_tile,
-        interpret=spec.interpret)
+        classes, legacy=spec.legacy, interpret=spec.interpret)
     return assemble_packed(h, w0, w1, tl, emp, stop_table, spec=spec)
 
 
 featurize_bytes_jit = jax.jit(featurize_bytes, static_argnames=("spec",))
-
-
-# ---------------------------------------------------------------------------
-# capability probes
-# ---------------------------------------------------------------------------
-
-def auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@lru_cache(maxsize=None)
-def interpreter_can_run() -> bool:
-    """Environment-only canary (PR 9 style): can this jax's Pallas
-    interpreter run the scan kernel's feature set — ``fori_loop`` carrying
-    state, predicated ``pl.store`` to a dynamic column, uint32 wrap-around
-    arithmetic? Probes a miniature kernel against a host-computed
-    expectation; any exception or mismatch means the kernel tests skip and
-    the serving probe falls back to host featurization with an honest
-    ``featurize_path``."""
-    try:
-        def kern(x_ref, o_ref):
-            def step(j, acc):
-                v = x_ref[:, pl.dslice(j, 1)].astype(jnp.uint32)
-                acc = acc * jnp.uint32(0x9E3779B1) + v
-                pl.store(o_ref, (slice(None), pl.dslice(j, 1)),
-                         jax.lax.bitcast_convert_type(acc, jnp.int32))
-                return acc
-            jax.lax.fori_loop(0, x_ref.shape[1], step,
-                              jnp.zeros((x_ref.shape[0], 1), jnp.uint32))
-
-        x = np.arange(8, dtype=np.int32).reshape(2, 4)
-        out = pl.pallas_call(
-            kern, out_shape=jax.ShapeDtypeStruct((2, 4), jnp.int32),
-            interpret=True)(jnp.asarray(x))
-        want = np.zeros((2, 4), np.uint32)
-        for r in range(2):
-            acc = 0
-            for j in range(4):
-                acc = (acc * 0x9E3779B1 + int(x[r, j])) & _MASK32
-                want[r, j] = acc
-        return bool(np.array_equal(np.asarray(out).view(np.uint32), want))
-    except Exception:  # noqa: BLE001 — any refusal means "no"
-        return False

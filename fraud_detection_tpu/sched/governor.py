@@ -2,7 +2,7 @@
 
 Two degradation modes the bare engine had no answer for:
 
-* **Batch wall blowup.** A slow device (contended TPU, tunnel latency spike)
+* **Batch wall blowup.** A slow device (a contended TPU, a host stall)
   makes each full-size batch take seconds; every row polled into such a
   batch inherits that wall as queue time, and — on a real broker — a poll
   interval that outgrows ``max.poll.interval.ms`` gets the consumer evicted,

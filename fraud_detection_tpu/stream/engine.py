@@ -39,6 +39,7 @@ from fraud_detection_tpu.sched.sketch import LatencySketch
 from fraud_detection_tpu.stream.broker import (CommitFailedError, Consumer,
                                                Message, Producer)
 from fraud_detection_tpu.utils import get_logger
+from fraud_detection_tpu.utils.device import device_stamp
 from fraud_detection_tpu.utils.racecheck import ExclusiveRegion
 from fraud_detection_tpu.utils.tracing import Tracer
 
@@ -979,7 +980,12 @@ class StreamingClassifier:
         ls = lane.stats() if lane is not None else (self._lane_stats or {})
         ds = getattr(self.pipeline, "device_stats", None)
         snap = ds.snapshot() if ds is not None else {}
+        stamp = device_stamp()
         return {
+            # Where this engine's pipeline runs, as JAX reports it.
+            "platform": stamp["platform"],
+            "device_kind": stamp["device_kind"],
+            "device_count": stamp["device_count"],
             "async_dispatch": self.async_dispatch,
             "dispatch_depth": self.pipeline_depth,
             "max_inflight": ls.get("max_inflight", self._max_inflight),
@@ -999,9 +1005,8 @@ class StreamingClassifier:
             "mesh_devices": snap.get("mesh_devices"),
             "per_chip_rungs": snap.get("per_chip_rungs"),
             # Device-side featurization (ops/featurize_kernel.py): which
-            # path featurize ran ("host" / "pallas" / "interpret" — the
-            # probe falls back honestly on CPU containers), raw bytes
-            # shipped per row, and rows truncated at the byte width.
+            # path featurize ran ("host" / "pallas" / "interpret"), raw
+            # bytes shipped per row, and rows truncated at the byte width.
             "featurize_path": snap.get("featurize_path"),
             "bytes_in_per_row": snap.get("bytes_in_per_row"),
             "truncated_rows": snap.get("truncated_rows"),
@@ -1221,9 +1226,8 @@ class StreamingClassifier:
         device scoring is in flight while the host polls, decodes, and
         featurizes the next batch. Batches finish strictly FIFO, so offsets
         commit in order. Depth 1 recovers serial dispatch->finish; depth >= 2
-        hides the full device round-trip behind host work — on a remote
-        (tunneled) TPU the round-trip latency exceeds one batch of host work,
-        so deeper pipelining is what makes the stream host-bound."""
+        hides the device round-trip (dispatch, execute, fetch) behind the
+        host work of the following batches."""
         with self._drive_region:
             if self._stopped:
                 return self.stats          # stop() latched: stay stopped

@@ -1,28 +1,45 @@
-"""Shared persistent-XLA-compilation-cache setup.
+"""The persistent XLA compilation cache — one rule, every entry point.
 
-The tree trainers unroll depth-wise programs and the 18-layer LLM compiles
-cost far more than they run; both the test suite (tests/conftest.py) and the
-benchmark (bench.py) want the same on-disk cache so they share compiled
-programs. ONE definition here keeps the directory and knobs from drifting
-apart. Tracing and Pallas lowering still run per process — the cache roughly
-halves a cold program's cost, it does not zero it.
+``serve``, ``train``, ``bench.py``, the test suite and ``chip_smoke.py`` all
+call :func:`enable_persistent_compile_cache` before their first compile, so
+a second process start finds the programs the first one built (the 18-layer
+LLM programs and the depth-unrolled tree builders cost far more to compile
+than to run).
+
+Where the cache lives is decided from outside: if ``JAX_COMPILATION_CACHE_DIR``
+is set, JAX reads it itself and this module sets no directory in code.
+Otherwise the cache is ``.jax_cache/`` at the root of the checkout
+(git-ignored) — a fixed path, because the path is part of what makes a
+cache findable by the next process.
 """
 
 from __future__ import annotations
 
 import os
 
+from fraud_detection_tpu.utils.device import on_tpu
 
-def enable_persistent_compile_cache(min_compile_secs: float = 1.0) -> None:
-    """Best-effort: the cache is an optimization, never a failure source."""
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def enable_persistent_compile_cache() -> str:
+    """Turn the persistent cache on; returns the directory in effect."""
     import jax
 
-    path = os.environ.get("JAX_TEST_COMPILATION_CACHE",
-                          os.path.expanduser("~/.cache/fraud_tpu_jax_tests"))
-    try:
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = DEFAULT_CACHE_DIR
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_secs)
-    except Exception:
-        pass
+    if on_tpu():
+        # JAX's default threshold (1.0 s) would leave the serving pad
+        # ladder's many sub-second programs out of the cache, and a warm
+        # start on the chip would compile every rung again. The CPU keeps
+        # the default: there the cache serves the test suite, whose
+        # thousands of sub-second programs would each pay a serialize and a
+        # write on a cold run.
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

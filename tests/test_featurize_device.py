@@ -4,12 +4,10 @@ clean/tokenize/stop-filter/murmur-hash/count, packed layout included — and
 the serving integration must keep every scoring path's outputs exact while
 shipping raw bytes as the only host->device crossing.
 
-Kernel tests run in interpret mode on the CPU mesh, gated by a pure-
-environment capability canary (PR 9 style): old interpreters that cannot
-run the kernel's feature set skip with an honest reason instead of failing.
+Kernel tests run in interpret mode on the CPU mesh, unconditionally: a
+kernel the installed JAX cannot run is a failure, not a skip.
 """
 
-import functools
 import json
 
 import jax
@@ -33,46 +31,6 @@ from fraud_detection_tpu.models.pipeline import (
     synthetic_demo_pipeline,
     unpack_packed_host,
 )
-
-
-@functools.lru_cache(maxsize=1)
-def _interpreter_runs_scan_kernels() -> bool:
-    """Capability probe (environment-only, no repo code): the featurize
-    kernel needs ``fori_loop``-carried state, predicated ``pl.store`` to a
-    dynamic column, and uint32 wrap-around arithmetic in this jax's Pallas
-    interpreter. Probe a miniature kernel against a hand-computed result."""
-    try:
-        from jax.experimental import pallas as pl
-
-        def kern(x_ref, o_ref):
-            def step(j, acc):
-                v = x_ref[:, pl.dslice(j, 1)].astype(jnp.uint32)
-                acc = acc * jnp.uint32(0x9E3779B1) + v
-                pl.store(o_ref, (slice(None), pl.dslice(j, 1)),
-                         jax.lax.bitcast_convert_type(acc, jnp.int32))
-                return acc
-            jax.lax.fori_loop(0, x_ref.shape[1], step,
-                              jnp.zeros((x_ref.shape[0], 1), jnp.uint32))
-
-        x = np.arange(8, dtype=np.int32).reshape(2, 4)
-        out = pl.pallas_call(
-            kern, out_shape=jax.ShapeDtypeStruct((2, 4), jnp.int32),
-            interpret=True)(jnp.asarray(x))
-        want = np.zeros((2, 4), np.uint32)
-        for r in range(2):
-            acc = 0
-            for j in range(4):
-                acc = (acc * 0x9E3779B1 + int(x[r, j])) & 0xFFFFFFFF
-                want[r, j] = acc
-        return bool(np.array_equal(np.asarray(out).view(np.uint32), want))
-    except Exception:  # noqa: BLE001 — no pallas at all: same skip
-        return False
-
-
-_needs_scan_kernel = pytest.mark.skipif(
-    not _interpreter_runs_scan_kernels(),
-    reason="this jax's Pallas interpreter cannot run the byte-scan kernel's "
-           "feature set (capability probe)")
 
 
 def _python_twin(feat: HashingTfIdfFeaturizer,
@@ -153,14 +111,12 @@ ADVERSARIAL = [
 ]
 
 
-@_needs_scan_kernel
 def test_kernel_matches_host_on_adversarial_corpus():
     feat = HashingTfIdfFeaturizer(num_features=1000)
     dev = DeviceFeaturizer(feat, width=128, tokens=16, interpret=True)
     _assert_device_matches_host(dev, _python_twin(feat), ADVERSARIAL)
 
 
-@_needs_scan_kernel
 @pytest.mark.parametrize("legacy", [False, True])
 @pytest.mark.parametrize("binary", [False, True])
 def test_kernel_fuzz_parity_all_hash_modes(legacy, binary):
@@ -185,7 +141,6 @@ def test_kernel_fuzz_parity_all_hash_modes(legacy, binary):
         _assert_device_matches_host(dev, twin, texts, batch_size=8)
 
 
-@_needs_scan_kernel
 def test_empty_text_vs_padding_row():
     """A real "" tokenizes to [""] and counts one empty-token bucket (Java
     split semantics) on BOTH paths; padding rows beyond len(texts) must
@@ -201,7 +156,6 @@ def test_empty_text_vs_padding_row():
     np.testing.assert_array_equal(cnt, np.asarray(host.counts))
 
 
-@_needs_scan_kernel
 def test_high_count_rows():
     feat = HashingTfIdfFeaturizer(num_features=1000)
     dev = DeviceFeaturizer(feat, width=2048, tokens=8, interpret=True)
@@ -209,7 +163,6 @@ def test_high_count_rows():
     _assert_device_matches_host(dev, _python_twin(feat), texts)
 
 
-@_needs_scan_kernel
 def test_overflow_truncation_matches_host_rule():
     """More unique buckets than token slots: the device applies the HOST
     truncation rule (keep top counts, ties toward the lower bucket id) —
@@ -230,7 +183,6 @@ def test_overflow_truncation_matches_host_rule():
     np.testing.assert_array_equal(cnt_d, np.asarray(want.counts))
 
 
-@_needs_scan_kernel
 def test_truncation_honesty():
     """Byte-width truncation cuts at a CODEPOINT boundary, is counted, and
     the device result equals the host featurizer run on the truncated
@@ -282,7 +234,6 @@ def test_stop_table_build_and_refusal():
     assert build_stop_table(list("abc")) is not None
 
 
-@_needs_scan_kernel
 def test_stopword_removal_exact_on_device():
     """Every default stop word must vanish on device exactly as on host —
     including 'i' reached via İ and one-char words."""
@@ -332,7 +283,6 @@ def demo():
     return pipe, texts
 
 
-@_needs_scan_kernel
 def test_pipeline_parity_lr(demo):
     host, texts = demo
     dev = ServingPipeline(host.featurizer, host.model, batch_size=32,
@@ -348,7 +298,6 @@ def test_pipeline_parity_lr(demo):
     assert snap["bytes_in_per_row"] is not None
 
 
-@_needs_scan_kernel
 def test_pipeline_parity_int8(demo):
     host, texts = demo
     q8h = ServingPipeline(host.featurizer, host.model, batch_size=32,
@@ -360,7 +309,6 @@ def test_pipeline_parity_int8(demo):
     assert float(np.abs(ph.probabilities - pd.probabilities).max()) < 1e-6
 
 
-@_needs_scan_kernel
 def test_pipeline_parity_tree(demo):
     _, texts = demo
     host = synthetic_demo_pipeline(batch_size=32, n=200, seed=7, model="dt")
@@ -371,22 +319,15 @@ def test_pipeline_parity_tree(demo):
     assert float(np.abs(ph.probabilities - pd.probabilities).max()) < 1e-6
 
 
-def test_pipeline_honest_fallback_off_tpu(demo):
-    """featurize_device=True (compiled) on a CPU backend: the pipeline must
-    SERVE — through host featurization — and say so."""
-    host, texts = demo
-    pipe = ServingPipeline(host.featurizer, host.model, batch_size=32,
-                           featurize_device=True)
-    if jax.default_backend() == "tpu":       # honest either way
-        assert pipe.device_stats.featurize_path == "pallas"
-        return
-    assert pipe.device_stats.featurize_path == "host"
-    assert "TPU" in pipe.featurize_unavailable_reason
-    ph, pd = host.predict(texts[:8]), pipe.predict(texts[:8])
-    np.testing.assert_array_equal(ph.labels, pd.labels)
+def test_pipeline_refuses_device_featurize_off_tpu(demo):
+    """featurize_device=True (compiled) without a TPU is an error naming the
+    platform — never a quiet host fallback."""
+    host, _ = demo
+    with pytest.raises(DeviceFeaturizeUnavailable, match="needs a TPU.*cpu"):
+        ServingPipeline(host.featurizer, host.model, batch_size=32,
+                        featurize_device=True)
 
 
-@_needs_scan_kernel
 def test_pin_device_includes_stop_table(demo):
     host, _ = demo
     plain = ServingPipeline(host.featurizer, host.model, batch_size=32)
@@ -397,7 +338,6 @@ def test_pin_device_includes_stop_table(demo):
             + dev._dev_feat.stop_table_np.nbytes)
 
 
-@_needs_scan_kernel
 def test_mesh_pipeline_parity(demo):
     from fraud_detection_tpu.parallel.serving import MeshServingPipeline
 
@@ -414,7 +354,6 @@ def test_mesh_pipeline_parity(demo):
     assert snap["featurize_path"] == "interpret"
 
 
-@_needs_scan_kernel
 def test_mesh_from_pipeline_carries_featurize_config(demo):
     from fraud_detection_tpu.parallel.serving import MeshServingPipeline
 
@@ -448,7 +387,6 @@ def _run_engine(pipe, texts, topic, **kw):
     return sorted((m.key, m.value) for m in out), engine
 
 
-@_needs_scan_kernel
 def test_engine_wire_parity_and_health(demo):
     host, texts = demo
     dev_pipe = ServingPipeline(host.featurizer, host.model, batch_size=32,
@@ -464,7 +402,6 @@ def test_engine_wire_parity_and_health(demo):
     assert block["uploads_per_batch"] == 1.0
 
 
-@_needs_scan_kernel
 def test_serve_cli_featurize_device(monkeypatch, capsys):
     """serve --featurize-device e2e (interpret forced via env on CPU): exit
     0, every demo message classified, and the final health's device block
@@ -477,7 +414,7 @@ def test_serve_cli_featurize_device(monkeypatch, capsys):
                      "--featurize-device", "--featurize-width", "512"])
     assert rc == 0
     out = capsys.readouterr().out
-    assert "(featurize=interpret)" in out
+    assert "featurizer=interpret" in out
     stats = json.loads([l for l in out.splitlines() if l.startswith("{")][0])
     assert stats["processed"] == 48
     block = stats["health"]["device"]
@@ -495,7 +432,6 @@ def test_serve_cli_featurize_width_requires_flag():
                     "--featurize-width", "512"])
 
 
-@_needs_scan_kernel
 def test_engine_async_dispatch_lane_ships_bytes(demo):
     """The dispatch lane's _launch leg with device featurization: byte-
     identical output, strict FIFO, and the lane's upload accounting shows

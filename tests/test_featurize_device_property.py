@@ -16,15 +16,7 @@ hypothesis = pytest.importorskip(
 from hypothesis import given, settings  # noqa: E402
 from hypothesis import strategies as st  # noqa: E402
 
-from tests.test_featurize_device import (  # noqa: E402
-    _interpreter_runs_scan_kernels,
-    _python_twin,
-)
-
-pytestmark = pytest.mark.skipif(
-    not _interpreter_runs_scan_kernels(),
-    reason="this jax's Pallas interpreter cannot run the byte-scan kernel's "
-           "feature set (capability probe)")
+from tests.test_featurize_device import _python_twin  # noqa: E402
 
 from fraud_detection_tpu.featurize.device import DeviceFeaturizer  # noqa: E402
 from fraud_detection_tpu.featurize.hashing import HashingTF  # noqa: E402
@@ -66,14 +58,9 @@ def _scoring_pair():
 
 
 # One device featurizer per mode, built once (jit caches per spec+shape).
-# Guarded: on an interpreter that fails the canary every test above skips,
-# but module import must not raise from the eager builds.
-if _interpreter_runs_scan_kernels():
-    _MODES = {(lg, bn): _build(lg, bn)
-              for lg in (False, True) for bn in (False, True)}
-    _SCORING = _scoring_pair()
-else:
-    _MODES, _SCORING = {}, None
+_MODES = {(lg, bn): _build(lg, bn)
+          for lg in (False, True) for bn in (False, True)}
+_SCORING = _scoring_pair()
 
 
 @settings(max_examples=60, deadline=None)

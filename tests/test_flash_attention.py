@@ -2,7 +2,7 @@
 
 The kernel's contract is numerical equivalence with the materialized-score
 path — same inputs, same causal mask — to f32 round-off. Runs in interpret
-mode on the CPU test mesh (auto_interpret), compiled on a real TPU.
+mode on the CPU test mesh; chip_smoke.py runs the compiled kernel on a TPU.
 """
 
 import jax
@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from fraud_detection_tpu.models import llm
-from fraud_detection_tpu.ops.attention import auto_interpret, flash_attention
+from fraud_detection_tpu.ops.attention import flash_attention
 
 
 def _ref(q, k, v):
@@ -30,7 +30,7 @@ def test_flash_matches_attend(shape):
     q = jnp.asarray(rng.normal(size=shape).astype(np.float32))
     k = jnp.asarray(rng.normal(size=shape).astype(np.float32))
     v = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-    got = flash_attention(q, k, v, interpret=auto_interpret())
+    got = flash_attention(q, k, v, interpret=True)
     want = _ref(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -42,7 +42,7 @@ def test_flash_matches_attend_bf16():
     q = jnp.asarray(rng.normal(size=shape)).astype(jnp.bfloat16)
     k = jnp.asarray(rng.normal(size=shape)).astype(jnp.bfloat16)
     v = jnp.asarray(rng.normal(size=shape)).astype(jnp.bfloat16)
-    got = flash_attention(q, k, v, interpret=auto_interpret())
+    got = flash_attention(q, k, v, interpret=True)
     want = _ref(q, k, v)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -77,7 +77,7 @@ def test_flash_gqa_native_kv_matches_expanded():
     import numpy as np
 
     from fraud_detection_tpu.models.llm import _attend
-    from fraud_detection_tpu.ops.attention import auto_interpret, flash_attention
+    from fraud_detection_tpu.ops.attention import flash_attention
 
     B, T, H, Hkv, d = 2, 192, 4, 1, 32
     rng = jax.random.PRNGKey(5)
@@ -86,9 +86,8 @@ def test_flash_gqa_native_kv_matches_expanded():
     v = jax.random.normal(jax.random.fold_in(rng, 2), (B, T, Hkv, d), jnp.float32)
     ke, ve = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
 
-    interp = auto_interpret()
-    native = flash_attention(q, k, v, interpret=interp)
-    expanded = flash_attention(q, ke, ve, interpret=interp)
+    native = flash_attention(q, k, v, interpret=True)
+    expanded = flash_attention(q, ke, ve, interpret=True)
     np.testing.assert_array_equal(np.asarray(native), np.asarray(expanded))
 
     tril = jnp.tril(jnp.ones((T, T), bool))
@@ -101,5 +100,5 @@ def test_flash_gqa_native_kv_matches_expanded():
     v2 = jax.random.normal(jax.random.fold_in(rng, 4), (B, T, 2, d), jnp.float32)
     ke2, ve2 = (jnp.repeat(t, 2, axis=2) for t in (k2, v2))
     np.testing.assert_array_equal(
-        np.asarray(flash_attention(q, k2, v2, interpret=interp)),
-        np.asarray(flash_attention(q, ke2, ve2, interpret=interp)))
+        np.asarray(flash_attention(q, k2, v2, interpret=True)),
+        np.asarray(flash_attention(q, ke2, ve2, interpret=True)))

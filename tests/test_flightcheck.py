@@ -643,7 +643,6 @@ _EXPECTED_PRAGMAS = {
     ("fleet/worker.py", "FC102"): 1,          # lock-free stop latch
     ("stream/engine.py", "FC102"): 2,         # lock-free stop latches
     ("stream/annotations.py", "FC102"): 5,    # worker-only counters
-    ("ops/histogram.py", "FC201"): 1,         # one-shot capability probe
     ("models/pipeline.py", "FC201"): 1,       # one-shot donation probe
     ("models/train_llm.py", "FC201"): 1,      # once-per-run opt-state init
 }

@@ -390,6 +390,9 @@ ENGINE_HEALTH_SCHEMA = {
 }
 
 DEVICE_BLOCK_SCHEMA = {
+    "platform": (str,),                      # utils/device.py device_stamp
+    "device_kind": (str,),
+    "device_count": (int,),
     "async_dispatch": (bool,),
     "dispatch_depth": (int,),
     "max_inflight": (int,),

@@ -10,12 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-_needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="jax.shard_map unavailable on this jax (0.4.x capability "
-           "probe) — ring/ulysses attention shards the sequence axis "
-           "through it")
-
 from fraud_detection_tpu.models.llm import (
     ByteTokenizer,
     LanguageModel,
@@ -51,7 +45,6 @@ def model_mesh(n=8):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("T", [32, 64])
-@_needs_shard_map
 def test_ring_attention_matches_dense(T):
     B, H, d = 2, 4, 16
     rng = np.random.default_rng(0)
@@ -67,7 +60,6 @@ def test_ring_attention_matches_dense(T):
                                rtol=2e-5, atol=2e-5)
 
 
-@_needs_shard_map
 def test_ring_attention_under_jit_with_sharded_inputs():
     mesh = seq_mesh(8)
     B, T, H, d = 1, 64, 4, 16
@@ -79,7 +71,6 @@ def test_ring_attention_under_jit_with_sharded_inputs():
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense), rtol=2e-5, atol=2e-5)
 
 
-@_needs_shard_map
 def test_forward_ring_mode_matches_plain(params):
     tokens = jnp.asarray(np.random.default_rng(2).integers(0, 256, (2, 64)), jnp.int32)
     plain, _ = forward(params, tokens, CFG)
@@ -181,7 +172,6 @@ def test_tp_generation_runs():
     assert toks.shape == (4,)
 
 
-@_needs_shard_map
 def test_ring_attention_key_chunked_matches_dense():
     """Force the within-step key-chunk loop (key_chunk < T_loc) — the
     memory-bounded path long shards take — and require exact agreement
@@ -235,7 +225,6 @@ def test_generation_freezes_after_eos(params):
         raise AssertionError("no early EOS drawn in 40 seeds at temp 3.0")
 
 
-@_needs_shard_map
 def test_ulysses_attention_matches_dense():
     """All-to-all sequence parallelism: heads re-shard across the seq axis,
     full local attention per head group, re-shard back — must equal dense
@@ -255,7 +244,6 @@ def test_ulysses_attention_matches_dense():
         ulysses_attention(q[:, :, :6], k[:, :, :6], v[:, :, :6], mesh)
 
 
-@_needs_shard_map
 def test_forward_ulysses_mode_matches_plain(params):
     tokens = jnp.asarray(np.random.default_rng(6).integers(0, 256, (2, 64)),
                          jnp.int32)
@@ -527,7 +515,6 @@ def test_flash_gqa_narrow_kv_gradients_match_expanded():
 
 
 @pytest.mark.parametrize("hkv", [1, 2])
-@_needs_shard_map
 def test_ring_attention_narrow_kv_matches_dense(hkv):
     """GQA/MQA kv ride the ring at NARROW width (1/rep of the ICI bytes per
     rotation) and expand per arrival — must equal dense attention over the
@@ -551,7 +538,6 @@ def test_ring_attention_narrow_kv_matches_dense(hkv):
                                rtol=2e-5, atol=2e-5)
 
 
-@_needs_shard_map
 def test_ulysses_narrow_kv_matches_dense():
     """Ulysses expands narrow kv at entry (its all-to-all splits the head
     axis) — same result as pre-expanded kv."""

@@ -4,9 +4,6 @@ designed precision (the histogram accumulates f32 stats split into hi/lo
 bf16 MXU passes — ~16 mantissa bits per term), and trees built through the
 Pallas path must match trees built through the XLA path."""
 
-import functools
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,39 +13,6 @@ from fraud_detection_tpu.ops import (
     histogram_reference,
     node_feature_bin_histogram,
 )
-
-
-@functools.lru_cache(maxsize=1)
-def _pltpu_repeat_tile_concats() -> bool:
-    """Capability probe (environment-only, no repo code): the histogram
-    kernel builds its (bin, feature) layout with ``pltpu.repeat`` as a
-    TILE-CONCAT (``[x, x]`` along the axis). Old jax releases (0.4.37 on
-    this container) instead implement it as an ELEMENT-WISE repeat in
-    interpret mode, which silently mis-bins every histogram cell — so the
-    kernels that depend on it skip with an honest reason rather than fail
-    on a known-broken interpreter."""
-    try:
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-
-        def kern(x_ref, o_ref):
-            o_ref[...] = pltpu.repeat(x_ref[...], 2, axis=1)
-
-        x = np.arange(8, dtype=np.float32).reshape(2, 4)
-        out = pl.pallas_call(
-            kern, out_shape=jax.ShapeDtypeStruct((2, 8), jnp.float32),
-            interpret=True)(x)
-        return bool(np.array_equal(np.asarray(out),
-                                   np.concatenate([x, x], axis=1)))
-    except Exception:  # noqa: BLE001 — no pallas at all: same skip
-        return False
-
-
-_needs_tile_repeat = pytest.mark.skipif(
-    not _pltpu_repeat_tile_concats(),
-    reason="pltpu.repeat is element-wise (not tile-concat) in this jax's "
-           "interpret mode — the histogram kernel's layout is miscomputed "
-           "by the interpreter itself (capability probe)")
 
 
 @pytest.fixture(scope="module")
@@ -61,7 +25,6 @@ def hist_case():
     return bins, local, stats, L, nb
 
 
-@_needs_tile_repeat
 def test_histogram_kernel_matches_reference(hist_case):
     bins, local, stats, L, nb = hist_case
     got = node_feature_bin_histogram(bins, local, stats, n_nodes=L, n_bins=nb,
@@ -75,7 +38,6 @@ def test_histogram_kernel_matches_reference(hist_case):
                                rtol=1e-3, atol=1e-3 * scale)
 
 
-@_needs_tile_repeat
 def test_histogram_kernel_ragged_sizes():
     """N and F not multiples of the tiles: padding must not leak into bins."""
     rng = np.random.default_rng(1)
@@ -150,7 +112,6 @@ def test_gain_scan_tiled_features_matches_flat():
                                rtol=1e-4, atol=1e-5)
 
 
-@_needs_tile_repeat
 def test_tree_built_with_pallas_matches_xla_path():
     from fraud_detection_tpu.models import trees as trees_mod
     from fraud_detection_tpu.models.train_trees import TreeTrainConfig, fit_decision_tree
@@ -171,7 +132,6 @@ def test_tree_built_with_pallas_matches_xla_path():
     np.testing.assert_allclose(np.asarray(p_base), np.asarray(p_pall), rtol=1e-6)
 
 
-@_needs_tile_repeat
 def test_boosting_with_pallas_matches_xla_path():
     from fraud_detection_tpu.models import trees as trees_mod
     from fraud_detection_tpu.models.train_trees import (
@@ -215,7 +175,6 @@ def test_multi_tree_histogram_matches_single():
                                       err_msg=f"tree {t}")
 
 
-@_needs_tile_repeat
 def test_forest_chunk_pallas_matches_per_tree_loop():
     """fit_random_forest through the fused Pallas chunk builder must produce
     the same forest as the XLA per-tree loop (same PRNG stream; argmaxes on

@@ -155,8 +155,6 @@ def test_window_sampling_reaches_stream_tail():
     pytest.fail("no sampled window ever ended on the stream's last token")
 
 
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="jax.shard_map unavailable on this jax (0.4.x capability probe) — the sp axis rides it")
 def test_dp_sp_mesh_training_step():
     """Sequence-parallel fine-tuning: one step over a (data=2, seq=4) mesh —
     ring attention inside the jitted train step, gradients flowing back
