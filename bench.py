@@ -685,12 +685,11 @@ def _warm(pipe, texts, batch_size: int) -> None:
 
 
 def _stream_run(pipe, texts, batch_size: int, depth: int, n_msgs: int,
-                tracer=None, async_dispatch=None, rowtrace=None,
+                async_dispatch=None, rowtrace=None,
                 sentinel_setup=None):
     """One timed streaming run: fresh broker, n_msgs produced, engine drains.
     The ONE definition of the measured loop — the headline and tree-family
-    sections must not drift apart. ``tracer`` (utils.tracing.Tracer) records
-    the engine's per-batch dispatch/finish spans for phase attribution.
+    sections must not drift apart.
 
     ``async_dispatch`` defaults to ON (``BENCH_ASYNC=0`` reverts): the
     headline measures the double-buffered serving configuration — featurize+
@@ -713,7 +712,7 @@ def _stream_run(pipe, texts, batch_size: int, depth: int, n_msgs: int,
     engine = StreamingClassifier(
         pipe, consumer, broker.producer(), "dialogues-classified",
         batch_size=batch_size, max_wait=0.01, pipeline_depth=depth,
-        tracer=tracer, async_dispatch=async_dispatch, rowtrace=rowtrace)
+        async_dispatch=async_dispatch, rowtrace=rowtrace)
     # ``sentinel_setup(engine)`` -> finish(): the alerts section arms a
     # live sentinel over this engine's health for the paired
     # evaluation-overhead measurement (obs/sentinel/).
@@ -726,25 +725,6 @@ def _stream_run(pipe, texts, batch_size: int, depth: int, n_msgs: int,
     assert stats.processed == n_msgs, stats.as_dict()
     stats.device_health = engine.health()["device"]
     return stats
-
-
-def _attribution(tracer) -> dict:
-    """Engine-span phase attribution for one streaming run: ``dispatch`` =
-    host JSON+featurize+device launch (the engine's pre-device leg),
-    ``finish`` = device wait + frame assembly + produce + commit. Mean
-    seconds per batch plus each phase's share of their sum — the committed
-    answer to "where does the time go" (round-4 verdict item 4)."""
-    spans = tracer.as_dict()
-    d = spans.get("dispatch", {}).get("mean_sec", 0.0)
-    f = spans.get("finish", {}).get("mean_sec", 0.0)
-    total = d + f
-    return {
-        "batches": spans.get("dispatch", {}).get("count", 0),
-        "dispatch_mean_ms": round(1e3 * d, 2),
-        "finish_mean_ms": round(1e3 * f, 2),
-        "dispatch_share": round(d / total, 3) if total else None,
-        "finish_share": round(f / total, 3) if total else None,
-    }
 
 
 def featurize_bench(texts) -> dict:
@@ -1449,25 +1429,17 @@ def tree_streaming_bench(texts, batch_size: int, depth: int,
     control run per model: same minute, same host regime — the committed
     answer to whether a tree-vs-LR gap in this artifact is traversal cost
     or contention (same-session probes measure them at parity)."""
-    from fraud_detection_tpu.utils.tracing import Tracer
-
     out = {}
     for model in ("dt", "xgb"):
         pipe = build_pipeline(batch_size, model=model)
         tw = time.time()
         _warm(pipe, texts, batch_size)
         compile_s = time.time() - tw
-        rates = []
-        best_attr = None
-        for _ in range(3):
-            tracer = Tracer()
-            rate = round(_stream_run(pipe, texts, batch_size, depth, n_msgs,
-                                     tracer=tracer).msgs_per_sec, 1)
-            rates.append(rate)
-            if rate == max(rates):
-                best_attr = _attribution(tracer)
+        rates = [round(_stream_run(pipe, texts, batch_size, depth,
+                                   n_msgs).msgs_per_sec, 1)
+                 for _ in range(3)]
         out[model] = {"msgs_per_s": max(rates), "compile_s": round(compile_s, 1),
-                      "runs": rates, "attribution": best_attr}
+                      "runs": rates}
         if lr_pipe is not None:
             # Best-of-3 like the tree runs (a single control run would be
             # exposed to exactly the contention it exists to rule out);
@@ -2349,11 +2321,9 @@ def main() -> int:
     harness.line.update({"metric": metric, "unit": "dialogues/sec",
                          **device_stamp()})
 
-    from fraud_detection_tpu.utils.tracing import Tracer
-
     # Shared across sections: the warm headline pipeline and the best-of
     # accounting the final resample section extends.
-    state = {"pipe": None, "best": 0.0, "best_stats": None, "best_attr": None,
+    state = {"pipe": None, "best": 0.0, "best_stats": None,
              "flops_peak": None, "L_pad": None}
     run_rates: list = []
 
@@ -2370,7 +2340,6 @@ def main() -> int:
                 "p50": round(best_stats.latency_percentile(50) * 1e3, 2),
                 "p99": round(best_stats.latency_percentile(99) * 1e3, 2),
             },
-            "attribution": state["best_attr"],
             # Device-residency evidence for the best run (engine
             # health()['device']): host->device crossings per micro-batch,
             # dispatch-lane depth/overlap, donation hits, pinned bytes.
@@ -2385,14 +2354,12 @@ def main() -> int:
 
     def _sample_runs(n: int, scratch) -> None:
         for _ in range(n):
-            tracer = Tracer()
             stats = _stream_run(pipe_or_raise(), texts, batch_size, depth,
-                                n_msgs, tracer=tracer)
+                                n_msgs)
             run_rates.append(round(stats.msgs_per_sec, 1))
             if state["best_stats"] is None or stats.msgs_per_sec > state["best"]:
                 state["best"] = stats.msgs_per_sec
                 state["best_stats"] = stats
-                state["best_attr"] = _attribution(tracer)
             # Partial headline after EVERY run: a budget/TERM cut mid-best-of
             # still commits whatever was measured.
             scratch.update(_headline_fields())

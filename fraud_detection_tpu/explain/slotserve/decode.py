@@ -27,11 +27,16 @@ thin device seam so the policy layer never touches jax directly.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+import contextlib
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 from fraud_detection_tpu.models import llm
+
+
+def _no_span(_stage: str):
+    return contextlib.nullcontext()
 
 
 class PagePoolExhausted(RuntimeError):
@@ -212,27 +217,34 @@ class SlotDecoder:
 
     def step(self, tokens: np.ndarray, lens: np.ndarray, active: np.ndarray,
              remaining: np.ndarray, temperatures: np.ndarray, seed: int,
-             steps: int):
+             steps: int, span: Callable = _no_span):
         """One fused decode window (up to ``steps`` iterations) over the
         whole pool; returns ``(out (B, steps) EOS-padded, new_lens,
         steps_run, active_row_steps)``. ONE host sync per window — the
         per-token dispatch amortized ``steps``-wide is what makes
-        iteration-level scheduling pay on dispatch-bound hosts too."""
+        iteration-level scheduling pay on dispatch-bound hosts too.
+        ``span(stage)`` opens the caller's span around each half:
+        ``slot_launch`` (arguments placed, program enqueued) and
+        ``slot_fetch`` (blocked until the window's tokens are on the
+        host)."""
         import jax
         import jax.numpy as jnp
 
-        out, new_lens, steps_run, n_act, self.cache = llm.slot_decode_window(
-            self.lm.params, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(lens, jnp.int32), jnp.asarray(active),
-            jnp.asarray(remaining, jnp.int32),
-            self.cfg, self.cache,
-            jnp.asarray(temperatures, jnp.float32),
-            jax.random.PRNGKey(seed & 0x7FFFFFFF), int(steps))
+        with span("slot_launch"):
+            out, new_lens, steps_run, n_act, self.cache = \
+                llm.slot_decode_window(
+                    self.lm.params, jnp.asarray(tokens, jnp.int32),
+                    jnp.asarray(lens, jnp.int32), jnp.asarray(active),
+                    jnp.asarray(remaining, jnp.int32),
+                    self.cfg, self.cache,
+                    jnp.asarray(temperatures, jnp.float32),
+                    jax.random.PRNGKey(seed & 0x7FFFFFFF), int(steps))
         self.steps += 1
-        # np.array, not asarray: the lens copy must be writable (the
-        # service mutates it per-slot on prefill/release).
-        return (np.asarray(out), np.array(new_lens), int(steps_run),
-                int(n_act))
+        with span("slot_fetch"):
+            # np.array, not asarray: the lens copy must be writable (the
+            # service mutates it per-slot on prefill/release).
+            return (np.asarray(out), np.array(new_lens), int(steps_run),
+                    int(n_act))
 
     def warm(self, steps: int, prompt: Optional[str] = None) -> None:
         """Compile the decode window + the smallest prefill bucket off the
@@ -556,24 +568,26 @@ class PagedSlotDecoder:
 
     def step(self, tokens: np.ndarray, lens: np.ndarray, active: np.ndarray,
              remaining: np.ndarray, temperatures: np.ndarray, seed: int,
-             steps: int):
+             steps: int, span: Callable = _no_span):
         """One fused decode window over the paged pool — identical contract
         (and bit-identical output) to :meth:`SlotDecoder.step`."""
         import jax
         import jax.numpy as jnp
 
-        out, new_lens, steps_run, n_act, self.pages = \
-            llm.paged_decode_window(
-                self.lm.params, jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(lens, jnp.int32), jnp.asarray(active),
-                jnp.asarray(remaining, jnp.int32),
-                self.cfg, self.pages, jnp.asarray(self._tables),
-                jnp.asarray(temperatures, jnp.float32),
-                jax.random.PRNGKey(seed & 0x7FFFFFFF), int(steps),
-                self.max_len)
+        with span("slot_launch"):
+            out, new_lens, steps_run, n_act, self.pages = \
+                llm.paged_decode_window(
+                    self.lm.params, jnp.asarray(tokens, jnp.int32),
+                    jnp.asarray(lens, jnp.int32), jnp.asarray(active),
+                    jnp.asarray(remaining, jnp.int32),
+                    self.cfg, self.pages, jnp.asarray(self._tables),
+                    jnp.asarray(temperatures, jnp.float32),
+                    jax.random.PRNGKey(seed & 0x7FFFFFFF), int(steps),
+                    self.max_len)
         self.steps += 1
-        return (np.asarray(out), np.array(new_lens), int(steps_run),
-                int(n_act))
+        with span("slot_fetch"):
+            return (np.asarray(out), np.array(new_lens), int(steps_run),
+                    int(n_act))
 
     def warm(self, steps: int, prompt: Optional[str] = None) -> None:
         """Compile the decode window + the smallest suffix bucket off the

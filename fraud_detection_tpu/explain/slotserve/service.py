@@ -50,6 +50,7 @@ FC301-checked).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Callable, List, Optional, Sequence
@@ -86,16 +87,20 @@ class _SlotRequest:
     latch every waiter blocks on."""
 
     __slots__ = ("tokens", "max_new", "temperature", "cid", "submitted_at",
-                 "first_token_at", "out", "text", "dropped", "error", "done",
-                 "slot")
+                 "submitted_wall", "first_token_at", "out", "text", "dropped",
+                 "error", "done", "slot")
 
     def __init__(self, tokens, max_new: int, temperature: float,
-                 cid: Optional[str], submitted_at: float):
+                 cid: Optional[str], submitted_at: float,
+                 submitted_wall: Optional[float] = None):
         self.tokens = tokens
         self.max_new = max_new
         self.temperature = temperature
         self.cid = cid
         self.submitted_at = submitted_at
+        # The same moment on the tracer's clock (traced rows only): where
+        # the row's ``slot_wait`` span starts.
+        self.submitted_wall = submitted_wall
         self.first_token_at: Optional[float] = None
         self.out: List[int] = []
         self.text: Optional[str] = None
@@ -221,6 +226,12 @@ class SlotServeService:
         self._decode_steps = 0
         self._tokens_out = 0
         self._occ_sum = 0
+        # Free slot-steps of every decode window, put down to the queue's
+        # state at the window's boundary: nothing queued (starved from
+        # upstream) or requests waiting (admission-limited). With
+        # ``_occ_sum`` they partition ``decode_steps * slots``.
+        self._steps_starved = 0
+        self._steps_backlogged = 0
         self._started_at: Optional[float] = None
         self._lat = LatencySketch()         # submit -> complete (sec)
         self._first = LatencySketch()       # submit -> first token (sec)
@@ -243,10 +254,13 @@ class SlotServeService:
         lane serves a sliding recent sample, like the annotation lane."""
         toks, truncated = self._decoder.encode_prompt(prompt)
         max_new = min(max_tokens or self.max_new_tokens, self.max_new_tokens)
+        tr = self._rowtrace
         req = _SlotRequest(toks, max(1, max_new),
                            self.temperature if temperature is None
                            else temperature,
-                           cid, self._clock())
+                           cid, self._clock(),
+                           tr.wall() if tr is not None and cid is not None
+                           else None)
         evicted: List[_SlotRequest] = []
         with self._cv:
             self._admitted += 1
@@ -344,11 +358,23 @@ class SlotServeService:
         (free slots fill before the pool advances), then one decode step
         moves every busy slot, then finished rows retire and free their
         slots for the next boundary."""
-        self._admit_pending()
-        self._decode_step()
-        self._retire_done()
+        with self._span("slot_iter"):
+            with self._span("slot_admit"):
+                self._admit_pending()
+            self._decode_step()
+            with self._span("slot_retire"):
+                self._retire_done()
         with self._cv:
             self._iterations += 1
+
+    def _span(self, stage: str, cid: Optional[str] = None):
+        """One span of the loop's own chain (``slot-<iteration>``, or a
+        row's ``cid``) when a tracer is attached: in the ring, and as
+        ``fraud/<stage>`` on this thread's line of a profiler capture."""
+        tr = self._rowtrace
+        if tr is None:
+            return contextlib.nullcontext()
+        return tr.span(cid or f"slot-{self._iterations:x}", stage)
 
     def _admit_pending(self) -> None:
         """free → prefill: pop queued requests into free slots (bounded
@@ -379,10 +405,20 @@ class SlotServeService:
                 self._admits += 1
                 self._admit_seq[slot] = self._admits
                 grabbed.append((slot, req))
+        tr = self._rowtrace
         for slot, req in grabbed:
             self._seq += 1
-            first = self._decoder.prefill(slot, req.tokens, req.temperature,
-                                          self._seed + self._seq)
+            if tr is not None and req.submitted_wall is not None:
+                # Queued for a slot: submit -> this row's own prefill call
+                # (not the grant: rows granted together prefill in turn).
+                tr.record_span(req.cid, "slot_wait",
+                               max(0.0, tr.wall() - req.submitted_wall),
+                               start=req.submitted_wall,
+                               detail=f"slot={slot}")
+            with self._span("prefill", req.cid):
+                first = self._decoder.prefill(
+                    slot, req.tokens, req.temperature,
+                    self._seed + self._seq)
             now = self._clock()
             req.first_token_at = now
             self._first.add(max(0.0, now - req.submitted_at))
@@ -406,7 +442,8 @@ class SlotServeService:
         # Host side of the iteration boundary: every busy row's page table
         # must cover this window's writes BEFORE the compiled program runs
         # (paged decoder; the contiguous one grows trivially).
-        self._ensure_window_pages(busy_rows)
+        with self._span("slot_grow"):
+            self._ensure_window_pages(busy_rows)
         busy_rows = np.flatnonzero(self._active_arr).tolist()
         if not busy_rows:
             return
@@ -417,22 +454,31 @@ class SlotServeService:
         self._seq += 1
         out, new_lens, steps_run, n_act = self._decoder.step(
             self._last_tok, self._lens, self._active_arr, remaining,
-            self._temps, self._seed + self._seq, self.decode_window)
+            self._temps, self._seed + self._seq, self.decode_window,
+            span=self._span)
         self._lens = new_lens
+        free = steps_run * self.slots - n_act
         with self._cv:
             self._decode_steps += steps_run
             self._occ_sum += n_act
+            # The steps a row leaves over when it finishes mid-window go
+            # with the boundary's class too.
+            if self._q:
+                self._steps_backlogged += free
+            else:
+                self._steps_starved += free
         eos = self._decoder.cfg.EOS
-        for slot in busy_rows:
-            req = self._slot_req[slot]
-            for j in range(out.shape[1]):
-                tok = int(out[slot, j])
-                req.out.append(tok)
-                self._last_tok[slot] = tok
-                if tok == eos or len(req.out) >= req.max_new:
-                    self._active_arr[slot] = False
-                    self._retired.append(slot)
-                    break
+        with self._span("slot_emit"):
+            for slot in busy_rows:
+                req = self._slot_req[slot]
+                for j in range(out.shape[1]):
+                    tok = int(out[slot, j])
+                    req.out.append(tok)
+                    self._last_tok[slot] = tok
+                    if tok == eos or len(req.out) >= req.max_new:
+                        self._active_arr[slot] = False
+                        self._retired.append(slot)
+                        break
 
     def _ensure_window_pages(self, busy_rows: List[int]) -> None:
         """Grow each busy slot's page table to cover ``lens +
@@ -498,8 +544,10 @@ class SlotServeService:
         if self._rowtrace is not None and req.cid is not None:
             wait_ms = round(1e3 * max(0.0, (req.first_token_at or dt)
                                       - req.submitted_at), 2)
+            # The one END-stamped span (docs/observability.md): the
+            # benchmark reads this span's ``start`` as the row's done time.
             self._rowtrace.record_span(
-                req.cid, "explain", dt,
+                req.cid, "explain", dt, start=self._rowtrace.wall(),
                 detail=f"slot={slot} tokens={len(req.out)} "
                        f"admit_ms={wait_ms}")
         req.done.set()
@@ -603,6 +651,8 @@ class SlotServeService:
             iterations, prefills = self._iterations, self._prefills
             decode_steps, tokens_out = self._decode_steps, self._tokens_out
             occ_sum, started = self._occ_sum, self._started_at
+            starved, backlogged = (self._steps_starved,
+                                   self._steps_backlogged)
             lat_p50 = self._lat.quantile(0.50)
             lat_p99 = self._lat.quantile(0.99)
             adm_p50 = self._first.quantile(0.50)
@@ -632,6 +682,11 @@ class SlotServeService:
             "iterations": iterations,
             "prefills": prefills,
             "decode_steps": decode_steps,
+            # decode_steps * slots, partitioned: a row decoded / the slot
+            # was free with nothing queued / free with requests waiting.
+            "slot_steps_occupied": occ_sum,
+            "slot_steps_starved": starved,
+            "slot_steps_backlogged": backlogged,
             "tokens_out": tokens_out,
             "kv_bytes": self._decoder.kv_bytes,
             # Paged-pool block (all-zero when the contiguous decoder runs

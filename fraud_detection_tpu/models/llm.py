@@ -321,12 +321,14 @@ def _attend(q, k, v, mask) -> jax.Array:
     """Plain masked attention. q: (B,T,H,d), k/v: (B,S,H,d), mask (T,S)
     shared across the batch or (B,T,S) per-row (batched decode with uneven
     prompt lengths)."""
-    scores = jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32)
-    scores = scores / math.sqrt(q.shape[-1])
-    mask_b = mask[None] if mask.ndim == 2 else mask      # -> (B|1, T, S)
-    scores = jnp.where(mask_b[:, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bshd->bthd", probs, v)
+    with jax.named_scope("attn.scores"):
+        scores = jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32)
+        scores = scores / math.sqrt(q.shape[-1])
+        mask_b = mask[None] if mask.ndim == 2 else mask  # -> (B|1, T, S)
+        scores = jnp.where(mask_b[:, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    with jax.named_scope("attn.values"):
+        return jnp.einsum("bhts,bshd->bthd", probs, v)
 
 
 # Below this the materialized-score path is cheaper to compile and its
@@ -408,7 +410,10 @@ def _expand_kv_heads(t: jax.Array, rep: int) -> jax.Array:
     ONE expansion idiom — the flash kernel never calls it (its index map
     reads narrow kv directly); the XLA attention paths and the flash
     backward do."""
-    return t if rep == 1 else jnp.repeat(t, rep, axis=2)
+    if rep == 1:
+        return t
+    with jax.named_scope("attn.expand_kv"):
+        return jnp.repeat(t, rep, axis=2)
 
 
 @jax.custom_vjp
@@ -787,14 +792,47 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict[str, ja
 # ---------------------------------------------------------------------------
 
 
+# The slot programs name their parts for the profiler (``jax.named_scope``:
+# op metadata only, no op or number changes): a device op of a capture then
+# says which part of the layer it belongs to, whatever its shape. The names
+# are listed in docs/observability.md.
+
+
 def _logits_head(x: jax.Array, params: Params, cfg: TransformerConfig) -> jax.Array:
     """Output-head logits for (N, D) features — the Q8 per-row-scale move
     `forward` applies, shared by the slot prefill/decode entries."""
     head = params["lm_head"] if not cfg.tie_embeddings else params["embed"]
-    if isinstance(head, Q8):
-        return (jnp.einsum("nD,VD->nV", x, head.q.astype(cfg.dtype))
-                .astype(jnp.float32) * head.scale[:, 0])
-    return jnp.einsum("nD,VD->nV", x, head).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        if isinstance(head, Q8):
+            return (jnp.einsum("nD,VD->nV", x, head.q.astype(cfg.dtype))
+                    .astype(jnp.float32) * head.scale[:, 0])
+        return jnp.einsum("nD,VD->nV", x, head).astype(jnp.float32)
+
+
+def _qkv(params: Params, cfg: TransformerConfig, l: int, h: jax.Array,
+         positions: jax.Array):
+    """Layer ``l``'s q/k/v projections with rotary positions applied."""
+    with jax.named_scope("attn.qkv"):
+        q = _mm("btD,Dhd->bthd", h, params[f"l{l}.wq"], cfg.dtype)
+        k = _mm("btD,Dhd->bthd", h, params[f"l{l}.wk"], cfg.dtype)
+        v = _mm("btD,Dhd->bthd", h, params[f"l{l}.wv"], cfg.dtype)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def _attn_out_mlp(params: Params, cfg: TransformerConfig, l: int,
+                  x: jax.Array, attn: jax.Array, act) -> jax.Array:
+    """Layer ``l`` after attention: output projection, then the gated MLP,
+    each on its residual."""
+    with jax.named_scope("attn.out"):
+        x = x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
+    with jax.named_scope("mlp"):
+        h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps)
+        gate = act(_mm("btD,DF->btF", h2, params[f"l{l}.w_gate"], cfg.dtype))
+        up = _mm("btD,DF->btF", h2, params[f"l{l}.w_up"], cfg.dtype)
+        return x + _mm("btF,FD->btD", gate * up, params[f"l{l}.w_down"],
+                       cfg.dtype)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -824,11 +862,7 @@ def slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     new_cache: Dict[str, jax.Array] = {}
     for l in range(cfg.n_layers):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
-        q = _mm("btD,Dhd->bthd", h, params[f"l{l}.wq"], cfg.dtype)
-        k = _mm("btD,Dhd->bthd", h, params[f"l{l}.wk"], cfg.dtype)
-        v = _mm("btD,Dhd->bthd", h, params[f"l{l}.wv"], cfg.dtype)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q, k, v = _qkv(params, cfg, l, h, positions)
         # Write this prompt's k/v into the slot's cache rows. Right-padded
         # overhang is masked by length everywhere downstream.
         new_cache[f"l{l}.k"] = jax.lax.dynamic_update_slice(
@@ -839,11 +873,7 @@ def slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
         # real+pad keys at or below their position — garbage-but-finite,
         # and only the length-1 position is ever read).
         attn = causal_attention(q, k, v, use_flash=False)
-        x = x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
-        h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps)
-        gate = act(_mm("btD,DF->btF", h2, params[f"l{l}.w_gate"], cfg.dtype))
-        up = _mm("btD,DF->btF", h2, params[f"l{l}.w_up"], cfg.dtype)
-        x = x + _mm("btF,FD->btD", gate * up, params[f"l{l}.w_down"], cfg.dtype)
+        x = _attn_out_mlp(params, cfg, l, x, attn, act)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)
     # Logits at the LAST REAL position only (length-1; right padding means
     # it is not at Tp-1) — full (Tp, V) logits would pay T times the head.
@@ -874,15 +904,12 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
     new_cache: Dict[str, jax.Array] = {}
     for l in range(cfg.n_layers):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
-        q = _mm("btD,Dhd->bthd", h, params[f"l{l}.wq"], cfg.dtype)
-        k = _mm("btD,Dhd->bthd", h, params[f"l{l}.wk"], cfg.dtype)
-        v = _mm("btD,Dhd->bthd", h, params[f"l{l}.wv"], cfg.dtype)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q, k, v = _qkv(params, cfg, l, h, positions)
         # Per-slot append: row b writes at its own lens[b] (a scatter —
         # the whole point of slots is rows sitting at different lengths).
-        ck = kv_cache[f"l{l}.k"].at[rows, lens].set(k[:, 0])
-        cv = kv_cache[f"l{l}.v"].at[rows, lens].set(v[:, 0])
+        with jax.named_scope("kv.append"):
+            ck = kv_cache[f"l{l}.k"].at[rows, lens].set(k[:, 0])
+            cv = kv_cache[f"l{l}.v"].at[rows, lens].set(v[:, 0])
         new_cache[f"l{l}.k"], new_cache[f"l{l}.v"] = ck, cv
         S = ck.shape[1]
         # Row b attends its own prefix [0, lens[b]] (the appended token's
@@ -891,19 +918,17 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
                  <= lens[:, None, None])                        # (B, 1, S)
         attn = _attend(q, _expand_kv_heads(ck, rep),
                        _expand_kv_heads(cv, rep), valid)
-        x = x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
-        h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps)
-        gate = act(_mm("btD,DF->btF", h2, params[f"l{l}.w_gate"], cfg.dtype))
-        up = _mm("btD,DF->btF", h2, params[f"l{l}.w_up"], cfg.dtype)
-        x = x + _mm("btF,FD->btD", gate * up, params[f"l{l}.w_down"], cfg.dtype)
+        x = _attn_out_mlp(params, cfg, l, x, attn, act)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)[:, 0]          # (B, D)
     logits = _logits_head(x, params, cfg)                       # (B, V)
-    greedy = jnp.argmax(logits, -1)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    row_keys = jax.vmap(partial(jax.random.fold_in, step_key))(rows)
-    drawn = jax.vmap(lambda k_, lg: jax.random.categorical(k_, lg, -1))(
-        row_keys, scaled)
-    tok = jnp.where(temperature <= 1e-6, greedy, drawn).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, -1)
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        row_keys = jax.vmap(partial(jax.random.fold_in, step_key))(rows)
+        drawn = jax.vmap(lambda k_, lg: jax.random.categorical(k_, lg, -1))(
+            row_keys, scaled)
+        tok = jnp.where(temperature <= 1e-6, greedy,
+                        drawn).astype(jnp.int32)
     return tok, new_cache
 
 
@@ -1021,10 +1046,12 @@ def _gather_view(kv_pages: Dict[str, jax.Array],
     decode/prefill masks (never attended) and the scatter-back never
     targets (write positions are always table-covered by the allocator)."""
     out = {}
-    for name, arr in kv_pages.items():
-        num_pages, page, hkv, d = arr.shape
-        g = arr[tables]                                  # (B, n_view, P, ...)
-        out[name] = g.reshape(tables.shape[0], tables.shape[1] * page, hkv, d)
+    with jax.named_scope("kv.gather_pages"):
+        for name, arr in kv_pages.items():
+            num_pages, page, hkv, d = arr.shape
+            g = arr[tables]                              # (B, n_view, P, ...)
+            out[name] = g.reshape(tables.shape[0], tables.shape[1] * page,
+                                  hkv, d)
     return out
 
 
@@ -1075,27 +1102,20 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     new_pages: Dict[str, jax.Array] = dict(kv_pages)
     for l in range(cfg.n_layers):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
-        q = _mm("btD,Dhd->bthd", h, params[f"l{l}.wq"], cfg.dtype)
-        k = _mm("btD,Dhd->bthd", h, params[f"l{l}.wk"], cfg.dtype)
-        v = _mm("btD,Dhd->bthd", h, params[f"l{l}.wv"], cfg.dtype)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q, k, v = _qkv(params, cfg, l, h, positions)
         # Scatter the suffix k/v into the row's own pages (pad-region
         # overhang included — garbage-but-private, masked downstream and
         # overwritten in order by decode, same as the contiguous path).
-        pk = new_pages[f"l{l}.k"].at[pids, offs].set(k[0])
-        pv = new_pages[f"l{l}.v"].at[pids, offs].set(v[0])
+        with jax.named_scope("kv.scatter_pages"):
+            pk = new_pages[f"l{l}.k"].at[pids, offs].set(k[0])
+            pv = new_pages[f"l{l}.v"].at[pids, offs].set(v[0])
         new_pages[f"l{l}.k"], new_pages[f"l{l}.v"] = pk, pv
         # Gather the row's resident view: prefix pages + the suffix just
         # written. (B=1: table_row[None] is the one-row table.)
         view = _gather_view({"k": pk, "v": pv}, table_row[None])
         attn = _attend(q, _expand_kv_heads(view["k"], rep),
                        _expand_kv_heads(view["v"], rep), kv_mask)
-        x = x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
-        h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps)
-        gate = act(_mm("btD,DF->btF", h2, params[f"l{l}.w_gate"], cfg.dtype))
-        up = _mm("btD,DF->btF", h2, params[f"l{l}.w_up"], cfg.dtype)
-        x = x + _mm("btF,FD->btD", gate * up, params[f"l{l}.w_down"], cfg.dtype)
+        x = _attn_out_mlp(params, cfg, l, x, attn, act)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)
     # Logits at the last REAL position, suffix-local index length-1-prefix.
     x_last = jax.lax.dynamic_slice_in_dim(
@@ -1153,9 +1173,10 @@ def paged_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
     offs = pos % page
     pos_c = jnp.minimum(pos, view_len - 1)
     new_pages: Dict[str, jax.Array] = {}
-    for name, arr in kv_pages.items():
-        vals = new_view[name][rows[:, None], pos_c]        # (B, W, Hkv, d)
-        new_pages[name] = arr.at[pids, offs].set(vals)
+    with jax.named_scope("kv.scatter_pages"):
+        for name, arr in kv_pages.items():
+            vals = new_view[name][rows[:, None], pos_c]    # (B, W, Hkv, d)
+            new_pages[name] = arr.at[pids, offs].set(vals)
     return out, new_lens, i, n_act, new_pages
 
 
@@ -1169,13 +1190,15 @@ def _sample_token(temperature, logits_1, step_key):
     ``fold_in(step_key, row)``, so a row's sample depends only on
     (seed, step, row) — NOT on how many prompts are co-batched (batch-size
     bucketing pads B; a (B, V)-shaped draw would change with the padding)."""
-    greedy = jnp.argmax(logits_1, -1)
-    scaled = logits_1 / jnp.maximum(temperature, 1e-6)
-    row_keys = jax.vmap(partial(jax.random.fold_in, step_key))(
-        jnp.arange(logits_1.shape[0]))
-    drawn = jax.vmap(lambda k, lg: jax.random.categorical(k, lg, -1))(
-        row_keys, scaled)
-    return jnp.where(temperature <= 1e-6, greedy, drawn).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits_1, -1)
+        scaled = logits_1 / jnp.maximum(temperature, 1e-6)
+        row_keys = jax.vmap(partial(jax.random.fold_in, step_key))(
+            jnp.arange(logits_1.shape[0]))
+        drawn = jax.vmap(lambda k, lg: jax.random.categorical(k, lg, -1))(
+            row_keys, scaled)
+        return jnp.where(temperature <= 1e-6, greedy,
+                         drawn).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("cfg", "max_new"))

@@ -10,9 +10,10 @@ materialized (gather/segment-sum fast path, models/linear.py).
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from functools import partial
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -165,6 +166,21 @@ class DeviceStats:
         }
 
 
+class Phase(NamedTuple):
+    """One host phase of one chunk's dispatch, timed where it happens:
+    ``featurize`` (decode + tokenize + hash + count) or ``upload`` (pack +
+    host->device placement). A traced engine records these as the children
+    of its ``launch`` span (stream/engine.py); the jit call is what is left
+    of ``launch``."""
+
+    stage: str
+    started: float          # time.perf_counter() when the phase began
+    seconds: float
+    rows: int               # real rows of the chunk
+    padded: int = 0         # rows the device program runs (upload only)
+    nbytes: int = 0         # bytes placed on the device (upload only)
+
+
 class PendingPrediction:
     """Unresolved device results from ``ServingPipeline.predict_async``.
 
@@ -176,10 +192,12 @@ class PendingPrediction:
     proba reduces to the same comparison)."""
 
     def __init__(self, parts: List[Tuple[object, int]], threshold: float = 0.5,
-                 argmax: bool = False):
+                 argmax: bool = False,
+                 phases: Optional[List[Phase]] = None):
         self._parts = parts
         self.threshold = threshold
         self.argmax = argmax  # parts hold full (B, C) probas (multiclass trees)
+        self.phases: List[Phase] = phases or []
 
     def resolve(self) -> PredictionBatch:
         if not self._parts:
@@ -380,30 +398,36 @@ class ServingPipeline:
         is_tree = self._fused_model is None
         tree_binary = is_tree and self._tree_is_binary()
         parts: List[Tuple[object, int]] = []
+        phases: List[Phase] = []
         stats: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = []
         ctxs: Optional[List[Tuple[object, int]]] = []
         for start in range(0, len(values), self.batch_size):
             chunk = values[start : start + self.batch_size]
+            t0 = time.perf_counter()
             out = encode_json(chunk, text_field,
                               batch_size=self._pad_rows(len(chunk)),
                               keep_splice_ctx=True)
             if out is None:
                 return None
+            phases.append(Phase("featurize", t0, time.perf_counter() - t0,
+                                len(chunk)))
             enc, status, span_start, span_len = out
             ctx = pop_ctx()
             if ctx is None:
                 ctxs = None
             elif ctxs is not None:
                 ctxs.append((ctx, len(chunk)))
+            placed = self._upload(enc, len(chunk), phases)
             if is_tree:
-                parts.append((self._dispatch_tree(enc, tree_binary), len(chunk)))
+                parts.append((self._dispatch_tree(placed, tree_binary),
+                              len(chunk)))
             else:
-                parts.append((self._dispatch_fused(enc), len(chunk)))
+                parts.append((self._dispatch_fused(placed), len(chunk)))
             stats.append((status, span_start, span_len))
         pending = PendingPrediction(
             parts,
             threshold=0.5 if is_tree else self._fused_model.threshold,
-            argmax=is_tree and not tree_binary)
+            argmax=is_tree and not tree_binary, phases=phases)
         if not stats:
             empty = np.empty(0, np.int32)
             return pending, empty, empty, empty, ctxs
@@ -482,46 +506,64 @@ class ServingPipeline:
 
         return shard_rows(packed, self.mesh)
 
-    def _dispatch_fused(self, enc) -> object:
-        """Launch fused sparse LR scoring for one encoded chunk and start the
-        async device->host fetch; shared by both predict paths. The chunk
-        rides the packed single-buffer upload, donated into the scoring
-        program where the platform consumes donations; int8 pipelines score
-        through the quantized program on the same staging buffer."""
+    def _upload(self, enc, rows: int,
+                phases: Optional[List[Phase]] = None) -> tuple:
+        """Pack and place one encoded chunk (``rows`` real rows), timed as
+        the chunk's ``upload`` phase. Returns ``(device buffer(s), packed)``:
+        the one packed staging buffer, or the ``(ids, counts)`` pair where
+        the packed layout does not apply."""
+        t0 = time.perf_counter()
         packed = _pack_encoded(enc)
         if packed is None:
-            ids, counts = self._device_rows(enc.ids, enc.counts)
-            p = linear_mod.prob_encoded_arrays(self._fused_model, ids, counts)
+            dev = self._device_rows(enc.ids, enc.counts)
+            padded = int(dev[0].shape[0])
+            nbytes = sum(int(a.size * a.dtype.itemsize) for a in dev)
         else:
             dev = self._device_packed(packed)
-            if self._q8 is not None:
-                p = linear_mod.prob_packed_q8(
-                    self._q8[0], self._q8[1], self._fused_model.intercept,
-                    dev, donate=self._donate)
-            else:
-                p = linear_mod.prob_packed(self._fused_model, dev,
-                                           donate=self._donate)
-            if self._donate:
-                self.device_stats.donated += 1
+            padded, nbytes = packed.shape[0], packed.nbytes
+        if phases is not None:
+            phases.append(Phase("upload", t0, time.perf_counter() - t0, rows,
+                                padded, nbytes))
+        return dev, packed is not None
+
+    def _dispatch_fused(self, placed: tuple) -> object:
+        """Launch fused sparse LR scoring for one placed chunk
+        (``_upload``) and start the async device->host fetch; shared by
+        both predict paths. The chunk rides the packed single-buffer
+        upload, donated into the scoring program where the platform
+        consumes donations; int8 pipelines score through the quantized
+        program on the same staging buffer."""
+        dev, packed = placed
+        if not packed:
+            ids, counts = dev
+            p = linear_mod.prob_encoded_arrays(self._fused_model, ids, counts)
+        elif self._q8 is not None:
+            p = linear_mod.prob_packed_q8(
+                self._q8[0], self._q8[1], self._fused_model.intercept,
+                dev, donate=self._donate)
+        else:
+            p = linear_mod.prob_packed(self._fused_model, dev,
+                                       donate=self._donate)
+        if packed and self._donate:
+            self.device_stats.donated += 1
         copy_async = getattr(p, "copy_to_host_async", None)
         if copy_async is not None:
             copy_async()  # start the device->host fetch behind the dispatch
         return p
 
-    def _dispatch_tree(self, enc, binary: bool) -> object:
-        """Launch the scatter-free ensemble traversal for one encoded chunk
-        and start the async device->host fetch."""
+    def _dispatch_tree(self, placed: tuple, binary: bool) -> object:
+        """Launch the scatter-free ensemble traversal for one placed chunk
+        (``_upload``) and start the async device->host fetch."""
         if self._tree_idf is None:
             # One upload, reused every chunk (pin_device does this off the
             # hot path; this is the fallback for unpinned pipelines).
             self._tree_idf = self.featurizer.idf_array()
-        packed = _pack_encoded(enc)
-        if packed is None:
-            ids, counts = self._device_rows(enc.ids, enc.counts)
+        dev, packed = placed
+        if not packed:
+            ids, counts = dev
             p = _tree_prob_encoded(self.model, ids, counts, self._tree_idf,
                                    binary)
         else:
-            dev = self._device_packed(packed)
             p = _tree_prob_packed(self.model, dev, self._tree_idf, binary,
                                   donate=self._donate)
             if self._donate:
@@ -585,6 +627,7 @@ class ServingPipeline:
         overlaps a PARALLEL featurize with the in-flight batches' device
         wait instead of a single-threaded one."""
         parts: List[Tuple[object, int]] = []
+        phases: List[Phase] = []
         threshold = 0.5
         argmax = False
         # Multiclass trees need the full (B, C) proba + host argmax — still
@@ -603,18 +646,22 @@ class ServingPipeline:
                 else:
                     argmax = not tree_binary
                 continue
+            t0 = time.perf_counter()
             enc = self.featurizer.encode(chunk, batch_size=self._pad_rows(n))
+            phases.append(Phase("featurize", t0, time.perf_counter() - t0, n))
+            placed = self._upload(enc, n, phases)
             if self._fused_model is not None:
-                parts.append((self._dispatch_fused(enc), n))
+                parts.append((self._dispatch_fused(placed), n))
                 threshold = self._fused_model.threshold
                 continue
             # Trees ride the same scatter-free encoded traversal (and packed
             # upload) as the raw-JSON path — the old densify-then-traverse
             # formulation paid a (B, F) XLA scatter plus a second upload
             # per chunk for bit-identical probabilities.
-            parts.append((self._dispatch_tree(enc, tree_binary), n))
+            parts.append((self._dispatch_tree(placed, tree_binary), n))
             argmax = not tree_binary
-        return PendingPrediction(parts, threshold=threshold, argmax=argmax)
+        return PendingPrediction(parts, threshold=threshold, argmax=argmax,
+                                 phases=phases)
 
     def predict(self, texts: Sequence[str]) -> PredictionBatch:
         """Score texts in fixed-size micro-batches (pads the tail batch)."""
@@ -661,12 +708,13 @@ class ServingPipeline:
                 chunk_counts = np.concatenate(
                     [chunk_counts, np.zeros((rows - n, counts.shape[1]),
                                             counts.dtype)])
-            enc = EncodedBatch(ids=chunk_ids, counts=chunk_counts)
+            placed = self._upload(
+                EncodedBatch(ids=chunk_ids, counts=chunk_counts), n)
             if self._fused_model is not None:
-                parts.append((self._dispatch_fused(enc), n))
+                parts.append((self._dispatch_fused(placed), n))
                 threshold = self._fused_model.threshold
             else:
-                parts.append((self._dispatch_tree(enc, tree_binary), n))
+                parts.append((self._dispatch_tree(placed, tree_binary), n))
                 argmax = not tree_binary
         return PendingPrediction(parts, threshold=threshold,
                                  argmax=argmax).resolve()
@@ -685,9 +733,12 @@ def _tree_prob_encoded(ensemble: TreeEnsemble, ids, counts, idf, binary: bool):
 
 
 def _tree_prob_packed_impl(ensemble: TreeEnsemble, packed, idf, binary: bool):
-    ids, counts = linear_mod.unpack_rows(packed)
-    proba = trees_mod.predict_proba_encoded(ensemble, ids, counts, idf)
-    return proba[:, 1] if binary else proba
+    # Scopes name the two parts in a profiler capture (op metadata only).
+    with jax.named_scope("score.unpack"):
+        ids, counts = linear_mod.unpack_rows(packed)
+    with jax.named_scope("score.traverse"):
+        proba = trees_mod.predict_proba_encoded(ensemble, ids, counts, idf)
+        return proba[:, 1] if binary else proba
 
 
 _tree_prob_packed_plain = jax.jit(_tree_prob_packed_impl,

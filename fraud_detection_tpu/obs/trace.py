@@ -11,12 +11,23 @@ module adds the missing attribution layer, Dapper-style but sized for a
   every row derives a stable id from it (``<batch>:<partition>:<offset>``)
   — the same coordinates DLQ/shed records already carry, so a dead-lettered
   row joins back to its spans by construction.
-* **Spans are batch-granular** ("poll", "admit", "launch", "device",
-  "deliver") with **row-granular events** for the interesting minority
-  (shed, dlq, flag, annotate): per-row spans for every clean row would cost
-  more than the work they measure; per-batch spans plus row events keep the
-  overhead under the bench's 5%% tracing budget while still giving every
-  flagged/shed/DLQ'd row a complete poll->terminal chain by id.
+* **Spans are batch-granular** ("poll", "admit", "launch" with its
+  children "featurize" and "upload", "device", "deliver") with
+  **row-granular events** for the interesting minority (shed, dlq, flag,
+  annotate): per-row spans for every clean row would cost more than the
+  work they measure; per-batch spans plus row events keep the overhead
+  under the tracing budget while still giving every flagged/shed/DLQ'd row
+  a complete poll->terminal chain by id. A FLAGGED row's chain goes on
+  past the batch's terminal, under its row id: "lane_wait" (annotation
+  lane), "slot_wait" and "prefill" (slot lane), the per-row "explain" and
+  the "annotate" event, tiling flag -> annotation
+  (docs/observability.md). The slot lane's loop writes one chain per
+  iteration (``slot-<iteration>``).
+* **A span's ``start`` is when it began**, whether it was timed by a
+  context manager or recorded after the fact from a measured duration.
+  Spans opened as context managers are also written into the profiler's
+  trace as ``fraud/<stage>`` (``jax.profiler.TraceAnnotation``), so a
+  capture holds the program's spans beside the device's ops, on one clock.
 * Spans buffer **batch-locally** (no shared state while the batch is in
   flight) and commit into a fixed-size ring in ONE append per batch at the
   terminal (deliver/abort). The ring drops OLDEST on overflow and counts
@@ -60,6 +71,20 @@ STAGE_LAUNCH = "launch"      # featurize + upload + device launch
 STAGE_DEVICE = "device"      # blocking on device results
 STAGE_DELIVER = "deliver"    # produce + flush + commit
 STAGE_EXPLAIN = "explain"    # one LLM explain call (annotation lane)
+STAGE_FEATURIZE = "featurize"  # child of launch: host decode + featurize
+STAGE_UPLOAD = "upload"      # child of launch: pack + host->device placement
+STAGE_LANE_WAIT = "lane_wait"  # flagged row queued on the annotation lane
+STAGE_SLOT_WAIT = "slot_wait"  # request queued for a decode slot
+STAGE_PREFILL = "prefill"    # one prompt's prefill, first token on the host
+# The slot lane's loop, one chain per iteration (cid ``slot-<iteration>``):
+# ``slot_iter`` contains the others, in this order.
+STAGE_SLOT_ITER = "slot_iter"
+STAGE_SLOT_ADMIT = "slot_admit"    # queue -> free slots, prefills inside
+STAGE_SLOT_GROW = "slot_grow"      # page tables grown to cover the window
+STAGE_SLOT_LAUNCH = "slot_launch"  # arguments placed, program enqueued
+STAGE_SLOT_FETCH = "slot_fetch"    # blocked until the tokens are on the host
+STAGE_SLOT_EMIT = "slot_emit"      # host replay of the window's tokens
+STAGE_SLOT_RETIRE = "slot_retire"  # finished rows resolved, slots freed
 EVENT_SHED = "shed"          # row diverted by admission control
 EVENT_DLQ = "dlq"            # row dead-lettered (malformed/poison)
 EVENT_FLAG = "flag"          # row classified non-benign
@@ -80,7 +105,9 @@ class Span(NamedTuple):
 
     cid: str
     stage: str
-    start: float            # wall-clock seconds (time.time domain)
+    start: float            # wall-clock seconds (time.time domain): when
+                            # the span BEGAN (docs/observability.md names
+                            # the one end-stamped exception)
     duration_ms: float
     ok: bool = True
     detail: Optional[str] = None
@@ -197,17 +224,20 @@ class BatchTrace:
     def span(self, stage: str, *, detail: Optional[str] = None):
         """Context manager timing one batch stage; exception-safe (the
         span ends, ok=False, and re-raises)."""
-        return _SpanCtx(self, stage, detail)
+        return _SpanCtx(self.tracer, self.spans.append, self.cid, stage,
+                        detail)
 
     def add(self, stage: str, duration_sec: float, *, ok: bool = True,
             detail: Optional[str] = None,
             start: Optional[float] = None) -> None:
         """Record an already-measured batch stage (the engine's existing
-        ``dispatch_time`` style timings)."""
+        ``dispatch_time`` style timings). ``start`` is when the stage
+        began; left out, the stage is taken to have ended now."""
         t = self.tracer
         t._count_begin_end()
         self.spans.append(Span(self.cid, stage,
-                               t._wall() if start is None else start,
+                               t._wall() - duration_sec if start is None
+                               else start,
                                duration_sec * 1e3, ok, detail))
         t._observe_stage(stage, duration_sec)
 
@@ -257,25 +287,49 @@ class BatchTrace:
         return cid
 
 
-class _SpanCtx:
-    __slots__ = ("bt", "stage", "detail", "_t0", "_w0")
+def _annotation(stage: str, cid: str):
+    """The span's twin in the profiler's own trace (``fraud/<stage>`` on
+    the calling thread's line, on the device's clock). Outside a capture
+    entering one is a flag test."""
+    from jax.profiler import TraceAnnotation
 
-    def __init__(self, bt: BatchTrace, stage: str, detail: Optional[str]):
-        self.bt = bt
+    return TraceAnnotation("fraud/" + stage, cid=cid)
+
+
+class _SpanCtx:
+    """A span open as a context manager: true start, exception-safe end,
+    and a ``fraud/<stage>`` annotation in the profiler's trace while it is
+    open. ``sink`` is the batch-local buffer's ``append`` (a batch leg) or
+    the ring's (a leg after the batch's terminal)."""
+
+    __slots__ = ("tracer", "sink", "cid", "stage", "detail", "_t0", "_w0",
+                 "_ann")
+
+    def __init__(self, tracer: "RowTracer", sink, cid: str, stage: str,
+                 detail: Optional[str]):
+        self.tracer = tracer
+        self.sink = sink
+        self.cid = cid
         self.stage = stage
         self.detail = detail
 
     def __enter__(self):
-        self._w0 = self.bt.tracer._wall()
+        self._ann = _annotation(self.stage, self.cid)
+        self._ann.__enter__()
+        self._w0 = self.tracer._wall()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dt = time.perf_counter() - self._t0
-        bt, t = self.bt, self.bt.tracer
+        self._ann.__exit__(exc_type, exc, tb)
+        t = self.tracer
         t._count_begin_end()
-        bt.spans.append(Span(bt.cid, self.stage, self._w0, dt * 1e3,
-                             exc_type is None, self.detail))
+        # A span that an exception closes names it, as the lane's failed
+        # explain span always has.
+        self.sink(Span(self.cid, self.stage, self._w0, dt * 1e3,
+                       exc_type is None,
+                       self.detail if exc_type is None else exc_type.__name__))
         t._observe_stage(self.stage, dt)
         return False
 
@@ -353,6 +407,9 @@ class RowTracer:
             self.batches_traced += 1
             sampled = self._rng.random() < self.sample
         bt = BatchTrace(self, f"{self.worker}-{seq:x}", sampled)
+        # ``poll_wait_sec``: how long the batch's oldest row had been on
+        # the broker when the poll returned it, so the span starts at that
+        # row's broker stamp and ends now.
         bt.add(STAGE_POLL, poll_wait_sec, detail=f"rows={n_rows}")
         return bt
 
@@ -383,15 +440,32 @@ class RowTracer:
 
     # -- direct records (post-terminal legs: annotation lane) ------------
 
+    def wall(self) -> float:
+        """Now, on the clock every span's ``start`` is read from."""
+        return self._wall()
+
+    def span(self, cid: str, stage: str, *, detail: Optional[str] = None):
+        """Context manager for a leg AFTER a batch's terminal (annotation
+        lane, slot lane): like :meth:`BatchTrace.span`, committed straight
+        to the ring when it closes."""
+        return _SpanCtx(self, self._ring_append, cid, stage, detail)
+
+    def _ring_append(self, span: Span) -> None:
+        self.ring.extend((span,))
+
     def record_span(self, cid: str, stage: str, duration_sec: float, *,
-                    ok: bool = True, detail: Optional[str] = None) -> None:
+                    ok: bool = True, detail: Optional[str] = None,
+                    start: Optional[float] = None) -> None:
         """Record a span straight into the ring — for legs that run AFTER
         a batch's terminal commit (the annotation lane's explain calls).
         Only call for rows/legs that are always-kept (flagged rows are);
-        head sampling does not apply here."""
+        head sampling does not apply here. ``start`` as in
+        :meth:`BatchTrace.add`."""
         self._count_begin_end()
-        self.ring.extend([Span(cid, stage, self._wall(),
-                               duration_sec * 1e3, ok, detail)])
+        self.ring.extend((Span(cid, stage,
+                               self._wall() - duration_sec if start is None
+                               else start,
+                               duration_sec * 1e3, ok, detail),))
         self._observe_stage(stage, duration_sec)
 
     def record_event(self, cid: str, stage: str, *, ok: bool = True,

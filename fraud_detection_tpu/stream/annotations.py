@@ -29,6 +29,7 @@ logged and dropped, classification untouched.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
@@ -66,18 +67,20 @@ class AsyncAnnotationLane:
                 f"max_queue/max_batch must be >= 1, got {max_queue}/{max_batch}")
         self._clock = clock   # injectable: drain/close deadlines in tests
         # Optional obs.trace.RowTracer: items may carry a 5th element (the
-        # row's correlation id), and the lane then records an "explain"
-        # span per backend call plus an "annotate" event per row — ok=False
-        # on backend errors AND breaker fast-fails, so a flagged row's
-        # chain shows exactly where its explanation died. Flagged rows are
-        # always-kept by the tracer, so these record directly to the ring.
+        # row's correlation id), and the lane then records a "lane_wait"
+        # span per row (enqueued here -> taken into a micro-batch), an
+        # "explain" span per backend call plus an "annotate" event per row
+        # — ok=False on backend errors AND breaker fast-fails, so a flagged
+        # row's chain shows exactly where its explanation died. Flagged
+        # rows are always-kept by the tracer, so these record directly to
+        # the ring.
         self._rowtrace = rowtrace
         self._fn = explain_batch_fn
         self._producer = producer
         self.topic = topic
         self.max_queue = max_queue
         self.max_batch = max_batch
-        self._q: deque = deque()
+        self._q: deque = deque()     # (item, enqueue stamp | None)
         self._cv = threading.Condition()
         self._closed = False
         # Structured drop records pending emission (built at the drop
@@ -126,16 +129,18 @@ class AsyncAnnotationLane:
         """
         if not items:
             return
+        tr = self._rowtrace
+        at = tr.wall() if tr is not None else None
         with self._cv:
             if self._closed:
                 return
             for it in items:
                 if len(self._q) >= self.max_queue:
-                    old = self._q.popleft()
+                    old, _at = self._q.popleft()
                     self.dropped += 1
                     self._drop_backlog.append(
                         self._drop_record(old, "queue_overflow"))
-                self._q.append(it)
+                self._q.append((it, at))
             self.submitted += len(items)
             self._idle.clear()
             self._cv.notify()
@@ -182,6 +187,8 @@ class AsyncAnnotationLane:
                                   len(drops))
             if not batch:
                 continue
+            self._trace_waits(batch)
+            batch = [it for it, _at in batch]
             try:
                 self._annotate(batch)
             except Exception:  # noqa: BLE001 — lane must survive anything
@@ -189,6 +196,20 @@ class AsyncAnnotationLane:
                 self.backend_errors += 1
                 log.exception("annotation batch failed (%d rows dropped); "
                               "classification unaffected", len(batch))
+
+    def _trace_waits(self, batch: List[tuple]) -> None:
+        """A ``lane_wait`` span per traced row of the micro-batch just
+        taken: enqueued on the lane -> taken. While one micro-batch
+        decodes the next waits here, so this is the wait the lane's
+        one-batch-at-a-time barrier costs a row."""
+        tr = self._rowtrace
+        if tr is None:
+            return
+        taken = tr.wall()
+        for it, at in batch:
+            if at is not None and len(it) == 5 and it[4] is not None:
+                tr.record_span(it[4], "lane_wait", max(0.0, taken - at),
+                               start=at)
 
     def _emit_drops(self, drops: List[tuple]) -> None:
         """Produce + flush the pending structured drop records (worker
@@ -217,32 +238,30 @@ class AsyncAnnotationLane:
         batch = [it if len(it) == 5 else (*it, None) for it in batch]
         keys, texts, labels, confs, cids = map(list, zip(*batch))
         tr = self._rowtrace
-        t0 = time.perf_counter()
         try:
-            if getattr(self._fn, "accepts_cids", False):
-                # Slotserve hooks (explain/slotserve/make_slot_explain_hook)
-                # take the rows' trace cids so each explanation's slot +
-                # latency lands on the row's own chain(cid).
-                analyses = self._fn(texts, labels, confs, cids=cids)
-            else:
-                analyses = self._fn(texts, labels, confs)
+            # The micro-batch's own span ("lane" chain), open around the
+            # backend call so a profiler capture shows it on this thread.
+            with (tr.span("lane", "explain", detail=f"rows={len(batch)}")
+                  if tr is not None else contextlib.nullcontext()):
+                if getattr(self._fn, "accepts_cids", False):
+                    # Slotserve hooks (explain/slotserve/
+                    # make_slot_explain_hook) take the rows' trace cids so
+                    # each explanation's slot + latency lands on the row's
+                    # own chain(cid).
+                    analyses = self._fn(texts, labels, confs, cids=cids)
+                else:
+                    analyses = self._fn(texts, labels, confs)
         except Exception as e:
             if tr is not None:
-                # One failed explain span for the batch + a failed
-                # annotate event per traced row: breaker fast-fails
-                # (BreakerOpenError) land here too, so breaker-tripped
-                # rows keep a complete chain by id.
-                tr.record_span("lane", "explain",
-                               time.perf_counter() - t0, ok=False,
-                               detail=type(e).__name__)
+                # The span above closed ok=False naming the exception; a
+                # failed annotate event per traced row on top: breaker
+                # fast-fails (BreakerOpenError) land here too, so
+                # breaker-tripped rows keep a complete chain by id.
                 for cid in cids:
                     if cid is not None:
                         tr.record_event(cid, "annotate", ok=False,
                                         detail=type(e).__name__)
             raise
-        if tr is not None:
-            tr.record_span("lane", "explain", time.perf_counter() - t0,
-                           detail=f"rows={len(batch)}")
         if len(analyses) != len(batch):  # mirrors the engine's inline check
             raise ValueError(f"explain_batch_fn returned {len(analyses)} "
                              f"analyses for {len(batch)} rows")

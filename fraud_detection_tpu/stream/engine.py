@@ -41,7 +41,6 @@ from fraud_detection_tpu.stream.broker import (CommitFailedError, Consumer,
 from fraud_detection_tpu.utils import get_logger
 from fraud_detection_tpu.utils.device import device_stamp
 from fraud_detection_tpu.utils.racecheck import ExclusiveRegion
-from fraud_detection_tpu.utils.tracing import Tracer
 
 log = get_logger("stream.engine")
 
@@ -235,7 +234,6 @@ class StreamingClassifier:
         annotations_topic: Optional[str] = None,
         annotations_producer: Optional[Producer] = None,
         annotations_queue: int = 1024,
-        tracer: Optional[Tracer] = None,
         dlq_topic: Optional[str] = None,
         dlq_max_attempts: int = 3,
         dlq_attempts: Optional[dict] = None,
@@ -306,11 +304,6 @@ class StreamingClassifier:
                 max_queue=annotations_queue, rowtrace=rowtrace)
             self.explain_fn = explain_fn = None
             self.explain_batch_fn = explain_batch_fn = None
-        # Optional utils.tracing.Tracer: per-batch "dispatch" / "finish"
-        # spans (host featurize+launch vs device-wait+produce+commit legs)
-        # for profiling beyond StreamStats' aggregate latencies. None = the
-        # hot loop pays nothing.
-        self.tracer = tracer
         # Optional obs.trace.RowTracer (docs/observability.md): a
         # correlation id is minted per polled batch and rides every row to
         # its terminal — batch stage spans (poll/admit/launch/device/
@@ -454,8 +447,15 @@ class StreamingClassifier:
         # Correlation id minted at poll (docs/observability.md): this
         # batch's trace context, handed through _Prep/_InFlight to every
         # later leg — admission below records shed row events into it.
-        bt = (self._rowtrace.batch_begin(len(msgs))
-              if self._rowtrace is not None else None)
+        bt = None
+        if self._rowtrace is not None:
+            # The poll span runs from the batch's oldest broker stamp to
+            # now: what a row waits for before the engine has it.
+            now = self._rowtrace.wall()
+            oldest = min((m.timestamp for m in msgs if m.timestamp > 0.0),
+                         default=now)
+            bt = self._rowtrace.batch_begin(
+                len(msgs), poll_wait_sec=max(0.0, now - oldest))
         # Offsets cover the ORIGINAL batch — rows screened out below are
         # handled (their DLQ record ships with this batch) and must commit.
         offsets: dict = {}
@@ -513,23 +513,17 @@ class StreamingClassifier:
         tokenize/hash/count run inside the scoring program, so the only
         host work left here is JSON decode + byte packing."""
         t0 = time.perf_counter()
-        msgs, offsets = prep.msgs, prep.offsets
-        inflight = None
-        if msgs and self._json_fast is not False:
-            inflight = self._dispatch_raw_json(msgs, offsets, t0)
-        if inflight is None:
-            texts: List[Optional[str]] = [self._decode(m) for m in msgs]
-            valid_idx = [i for i, t in enumerate(texts) if t is not None]
-            pending = (self.pipeline.predict_async([texts[i] for i in valid_idx])
-                       if valid_idx else None)
-            inflight = _InFlight(msgs, texts, valid_idx, pending, offsets,
-                                 time.perf_counter() - t0)
-        inflight.trace = prep.trace
-        if prep.trace is not None:
+        bt = prep.trace
+        if bt is None:
+            inflight = self._score_async(prep.msgs, prep.offsets, t0)
+        else:
             # The featurize+upload+launch leg, measured before prep time
             # folds in (this may run on the lane thread — the trace is
             # handed off with the batch, strictly FIFO, never shared).
-            prep.trace.add("launch", inflight.dispatch_time)
+            with bt.span("launch"):
+                inflight = self._score_async(prep.msgs, prep.offsets, t0)
+            self._trace_phases(bt, inflight.pending)
+        inflight.trace = bt
         inflight.dispatch_time += prep.prep_time
         if prep.dead:
             inflight.dead = prep.dead
@@ -544,6 +538,38 @@ class StreamingClassifier:
         # transports whose messages carry no producer timestamp.
         inflight.recv_wall = time.time()
         return inflight
+
+    def _score_async(self, msgs: List[Message], offsets: dict,
+                     t0: float) -> "_InFlight":
+        """Decode + featurize + upload + launch, raw-JSON path first."""
+        inflight = None
+        if msgs and self._json_fast is not False:
+            inflight = self._dispatch_raw_json(msgs, offsets, t0)
+        if inflight is None:
+            texts: List[Optional[str]] = [self._decode(m) for m in msgs]
+            valid_idx = [i for i, t in enumerate(texts) if t is not None]
+            pending = (self.pipeline.predict_async([texts[i] for i in valid_idx])
+                       if valid_idx else None)
+            inflight = _InFlight(msgs, texts, valid_idx, pending, offsets,
+                                 time.perf_counter() - t0)
+        return inflight
+
+    @staticmethod
+    def _trace_phases(bt, pending) -> None:
+        """The pipeline's own phase timings (models/pipeline.py ``Phase``)
+        as child spans of the ``launch`` that just closed: ``featurize`` and
+        ``upload`` with their true starts; what they leave of ``launch`` is
+        its self time, the jit call."""
+        phases = getattr(pending, "phases", None)
+        if not phases:
+            return
+        shift = bt.tracer.wall() - time.perf_counter()
+        for ph in phases:
+            detail = f"rows={ph.rows}"
+            if ph.stage == "upload":
+                detail += f" padded={ph.padded} bytes={ph.nbytes}"
+            bt.add(ph.stage, ph.seconds, start=ph.started + shift,
+                   detail=detail)
 
     def _screen_poison(self, msgs: List[Message], dead: List[tuple],
                        dead_reasons: dict,
@@ -1181,9 +1207,6 @@ class StreamingClassifier:
             if self._sched is not None:
                 self._sched.observe_batch(len(msgs), dt, lats)
         self._last_batch_at = self._clock()
-        if self.tracer is not None:
-            self.tracer.record("dispatch", inflight.dispatch_time)
-            self.tracer.record("finish", finish_dt)
         if bt is not None:
             if msgs and getattr(self._rowtrace, "record_rows", False):
                 # Record mode (scenarios/record.py): one compact block per

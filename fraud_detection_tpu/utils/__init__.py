@@ -1,4 +1,4 @@
-"""Cross-cutting utilities: config, structured logging, tracing/profiling.
+"""Cross-cutting utilities: config, structured logging, the profiler hook.
 
 Replaces the reference's import-time dotenv reads + print() observability
 (SURVEY.md §5) with typed config dataclasses, logfmt logging, and real
@@ -14,7 +14,7 @@ from fraud_detection_tpu.utils.config import (
     parse_env_file,
 )
 from fraud_detection_tpu.utils.logging import configure, get_logger, kv
-from fraud_detection_tpu.utils.tracing import RateCounter, Tracer, device_trace
+from fraud_detection_tpu.utils.tracing import device_trace
 
 __all__ = [
     "AppConfig",
@@ -26,7 +26,5 @@ __all__ = [
     "configure",
     "get_logger",
     "kv",
-    "RateCounter",
-    "Tracer",
     "device_trace",
 ]

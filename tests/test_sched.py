@@ -124,6 +124,28 @@ def test_sketch_quantiles_track_numpy_within_bucket_error():
         assert want <= got <= want * 1.12, (q, got, want)
 
 
+def test_sketch_scalar_add_picks_add_manys_buckets():
+    """``add`` (one sample, no array) and ``add_many`` are one sketch: the
+    same bucket, count, sum and max for samples below, on and between the
+    edges, past the last one, at zero and negative."""
+    from fraud_detection_tpu.sched import sketch as sketch_mod
+
+    rng = np.random.default_rng(5)
+    samples = np.concatenate([
+        10.0 ** rng.uniform(-6, 3.5, 4000), sketch_mod._EDGES[::7],
+        np.nextafter(sketch_mod._EDGES[::11], np.inf),
+        [0.0, -1e-6, 1e-5, 1e9]])
+    one, many = LatencySketch(), LatencySketch()
+    for sec in samples.tolist():
+        one.add(sec)
+    many.add_many(samples)
+    assert np.array_equal(one._counts, many._counts)
+    assert one.count == many.count == len(samples)
+    assert one.max == many.max
+    assert one.sum == pytest.approx(many.sum, rel=1e-12)
+    assert one.to_wire()["idx"] == many.to_wire()["idx"]
+
+
 def test_sketch_empty_and_merge():
     a, b = LatencySketch(), LatencySketch()
     assert a.quantile(0.99) is None

@@ -362,6 +362,11 @@ SLOTSERVE_BLOCK_SCHEMA = {
     "iterations": (int,),
     "prefills": (int,),
     "decode_steps": (int,),
+    # decode_steps * slots, partitioned (ISSUE 26): a row decoded / the
+    # slot was free with nothing queued / free with requests waiting.
+    "slot_steps_occupied": (int,),
+    "slot_steps_starved": (int,),
+    "slot_steps_backlogged": (int,),
     "tokens_out": (int,),
     "kv_bytes": (int,),
     # Paged-pool block (PR 19): zeros in contiguous mode so the schema is
@@ -396,6 +401,30 @@ def test_snapshot_schema_contract(lm):
         json.dumps(snap)
     finally:
         svc.close()
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_slot_steps_partition_into_occupied_starved_backlogged(lm, paged):
+    """Every slot-step of every decode window is counted once: a row
+    decoded in it, or the slot was free with the queue empty (starved), or
+    free with requests waiting (backlogged). A mixed run has all three:
+    12 rows over 4 slots admit 2 an iteration (free slots, rows queued),
+    then one row decodes alone (free slots, nothing queued)."""
+    svc = make_service(lm, slots=4, max_new_tokens=16, paged=paged)
+    try:
+        svc.generate_batch(prompts_varied(12), temperature=0.0, max_tokens=16)
+        svc.generate_batch(prompts_varied(1, base=90), temperature=0.0,
+                           max_tokens=16)
+        snap = svc.snapshot()
+        occupied, starved, backlogged = (snap["slot_steps_" + k] for k in
+                                         ("occupied", "starved", "backlogged"))
+        assert occupied + starved + backlogged == snap["decode_steps"] * 4
+        assert occupied > 0 and backlogged > 0
+        assert starved > 0
+        assert snap["occupancy"] == pytest.approx(
+            occupied / (snap["decode_steps"] * 4), abs=1e-4)
+    finally:
+        assert svc.close()
 
 
 def test_engine_health_explain_block(lm, pipeline):

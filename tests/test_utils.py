@@ -1,15 +1,12 @@
-"""Tests for config / logging / tracing utilities."""
+"""Tests for config / logging utilities and the profiler hook."""
 
 import io
 import logging as stdlib_logging
-import time
 
 from fraud_detection_tpu.utils import (
     AppConfig,
     KafkaConfig,
     LLMConfig,
-    RateCounter,
-    Tracer,
     load_dotenv,
     parse_env_file,
 )
@@ -135,29 +132,6 @@ def test_get_logger_emits_to_configured_stream():
 # ---------------------------------------------------------------------------
 # tracing
 # ---------------------------------------------------------------------------
-
-def test_tracer_aggregates_spans():
-    tr = Tracer()
-    for _ in range(3):
-        with tr.span("op"):
-            pass
-    tr.record("op", 0.5)
-    s = tr.stats()["op"]
-    assert s.count == 4
-    assert s.max >= 0.5
-    d = tr.as_dict()["op"]
-    assert d["count"] == 4 and d["max_sec"] >= 0.5
-
-
-def test_rate_counter_sliding_window():
-    rc = RateCounter(window=10.0)
-    t0 = 1000.0
-    for i in range(10):
-        rc.add(5, now=t0 + i)  # 50 events over 9 seconds
-    assert abs(rc.rate(now=t0 + 9) - 50 / 9) < 0.01
-    # events age out of the window
-    assert rc.rate(now=t0 + 100) == 0.0
-
 
 def test_device_trace_noop_without_dir(monkeypatch):
     from fraud_detection_tpu.utils import device_trace
