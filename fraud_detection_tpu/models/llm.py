@@ -318,17 +318,26 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 def _attend(q, k, v, mask) -> jax.Array:
-    """Plain masked attention. q: (B,T,H,d), k/v: (B,S,H,d), mask (T,S)
-    shared across the batch or (B,T,S) per-row (batched decode with uneven
-    prompt lengths)."""
+    """Plain masked attention. q: (B,T,H,d), k/v: (B,S,Hkv,d), H % Hkv == 0:
+    query head h reads kv head h // rep (``jnp.repeat(kv, rep, axis=2)``'s
+    order) by contracting against the NARROW k/v — no (B,S,H,d) copy is
+    built; MHA is rep == 1, MQA one group. mask (T,S) shared across the
+    batch or (B,T,S) per-row (batched decode with uneven prompt lengths)."""
+    B, T, H, d = q.shape
+    g = k.shape[2]
     with jax.named_scope("attn.scores"):
-        scores = jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32)
-        scores = scores / math.sqrt(q.shape[-1])
+        # heads first: the scores leave the contraction as (B, g, rep, T, S),
+        # which IS (B, H, T, S) — the small q is transposed, never they
+        qh = q.reshape(B, T, g, H // g, d).transpose(0, 2, 3, 1, 4)
+        scores = jnp.einsum("bgrtd,bsgd->bgrts", qh, k).reshape(B, H, T, -1)
+        scores = scores.astype(jnp.float32) / math.sqrt(d)
         mask_b = mask[None] if mask.ndim == 2 else mask  # -> (B|1, T, S)
         scores = jnp.where(mask_b[:, None], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     with jax.named_scope("attn.values"):
-        return jnp.einsum("bhts,bshd->bthd", probs, v)
+        out = jnp.einsum("bgrts,bsgd->bgrtd",
+                         probs.reshape(B, g, H // g, T, -1), v)
+        return out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, d)
 
 
 # Below this the materialized-score path is cheaper to compile and its
@@ -407,9 +416,9 @@ def chunked_causal_attention(q, k, v, q_chunk: int = 512,
 
 def _expand_kv_heads(t: jax.Array, rep: int) -> jax.Array:
     """GQA/MQA kv -> full query-head width (HF repeat_kv semantics). The
-    ONE expansion idiom — the flash kernel never calls it (its index map
-    reads narrow kv directly); the XLA attention paths and the flash
-    backward do."""
+    ONE expansion idiom, for the paths that need full width: ulysses (its
+    all-to-all splits heads), the ring step, the chunked XLA path (flash
+    backward included). ``_attend`` and the flash kernel read kv narrow."""
     if rep == 1:
         return t
     with jax.named_scope("attn.expand_kv"):
@@ -469,18 +478,17 @@ def causal_attention(q, k, v, use_flash: Optional[bool] = None) -> jax.Array:
     pass False.
 
     k/v may arrive at their narrow GQA/MQA width (fewer heads than q):
-    the flash path consumes them natively — no 8x K/V expansion is
-    materialized or streamed on MQA — and the XLA paths expand here, so
-    every branch sees identical math."""
+    the flash path and the short path (``_attend``) consume them natively;
+    only the chunked path expands here. Every branch sees identical math."""
     long_seq = q.shape[1] >= _FLASH_MIN_T
     if use_flash is None:
         use_flash = long_seq
     if use_flash:
         return _flash_attention_diff(q, k, v)
-    rep = q.shape[2] // k.shape[2]
-    k, v = _expand_kv_heads(k, rep), _expand_kv_heads(v, rep)
     if long_seq:
-        return chunked_causal_attention(q, k, v)
+        rep = q.shape[2] // k.shape[2]
+        return chunked_causal_attention(q, _expand_kv_heads(k, rep),
+                                        _expand_kv_heads(v, rep))
     causal = jnp.tril(jnp.ones((q.shape[1], q.shape[1]), bool))
     return _attend(q, k, v, causal)
 
@@ -701,8 +709,6 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     new_cache: Optional[Dict[str, jax.Array]] = {} if kv_cache is not None else None
     act = jax.nn.silu if cfg.activation == "silu" else partial(
         jax.nn.gelu, approximate=True)
-    rep = cfg.n_heads // cfg.kv_heads  # GQA: queries per kv head
-    expand_kv = partial(_expand_kv_heads, rep=rep)
 
     for l in range(cfg.n_layers):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
@@ -714,8 +720,7 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
 
         if kv_cache is not None:
             # decode: append this step's k/v at cache_len, attend over prefix
-            # (cache stays at Hkv width — the GQA memory win — and expands
-            # only for the score einsum)
+            # (cache stays at Hkv width — _attend reads it as stored)
             ck = jax.lax.dynamic_update_slice(
                 kv_cache[f"l{l}.k"], k, (0, cache_len, 0, 0))
             cv = jax.lax.dynamic_update_slice(
@@ -735,7 +740,7 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
                           & (jnp.arange(S)[None, None, :]
                              >= valid_from[:, None, None]))
                          | own[None])
-            attn = _attend(q, expand_kv(ck), expand_kv(cv), valid)
+            attn = _attend(q, ck, cv, valid)
         elif seq_mesh is not None:
             # On a (data, seq) training mesh the batch dim rides the data
             # axis through the SP body; a pure-seq serving mesh has none.
@@ -747,8 +752,7 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
                   else ring_attention)
             attn = sp(q, k, v, seq_mesh, batch_axis=b_axis)
         else:
-            # kv at native GQA width: causal_attention expands only on the
-            # XLA branches; the flash kernel maps heads to groups directly.
+            # kv at native GQA width: only the chunked branch expands it
             attn = causal_attention(q, k, v, use_flash)
 
         x = x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
@@ -899,7 +903,6 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
         x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
     act = jax.nn.silu if cfg.activation == "silu" else partial(
         jax.nn.gelu, approximate=True)
-    rep = cfg.n_heads // cfg.kv_heads
     rows = jnp.arange(B)
     new_cache: Dict[str, jax.Array] = {}
     for l in range(cfg.n_layers):
@@ -916,8 +919,7 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
         # own slot included — never a fully-masked row, so no NaN).
         valid = (jnp.arange(S)[None, None, :]
                  <= lens[:, None, None])                        # (B, 1, S)
-        attn = _attend(q, _expand_kv_heads(ck, rep),
-                       _expand_kv_heads(cv, rep), valid)
+        attn = _attend(q, ck, cv, valid)
         x = _attn_out_mlp(params, cfg, l, x, attn, act)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)[:, 0]          # (B, D)
     logits = _logits_head(x, params, cfg)                       # (B, V)
@@ -1090,7 +1092,6 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
         x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
     act = jax.nn.silu if cfg.activation == "silu" else partial(
         jax.nn.gelu, approximate=True)
-    rep = cfg.n_heads // cfg.kv_heads
     # Static per-suffix-position page/offset mapping: position prefix_len+j
     # lives at (table_row[(prefix_len+j)//page], (prefix_len+j)%page).
     pos = prefix_len + jnp.arange(Ts)
@@ -1113,8 +1114,7 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
         # Gather the row's resident view: prefix pages + the suffix just
         # written. (B=1: table_row[None] is the one-row table.)
         view = _gather_view({"k": pk, "v": pv}, table_row[None])
-        attn = _attend(q, _expand_kv_heads(view["k"], rep),
-                       _expand_kv_heads(view["v"], rep), kv_mask)
+        attn = _attend(q, view["k"], view["v"], kv_mask)
         x = _attn_out_mlp(params, cfg, l, x, attn, act)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)
     # Logits at the last REAL position, suffix-local index length-1-prefix.

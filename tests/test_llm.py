@@ -553,3 +553,38 @@ def test_ulysses_narrow_kv_matches_dense():
     out = ulysses_attention(q, k, v, seq_mesh(8))
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("T", [1, 5], ids=["decode", "prefill"])
+@pytest.mark.parametrize("per_row_mask", [False, True],
+                         ids=["shared_mask", "row_mask"])
+@pytest.mark.parametrize("rep", [1, 2, 4, 8])
+def test_attend_narrow_kv_matches_expanded(rep, per_row_mask, T, dtype, tol):
+    """``_attend`` contracts grouped query heads against the kv as stored:
+    query head h reads kv head h // rep, which is ``_expand_kv_heads``'s
+    order, so narrow kv must give what the expanded copy gives — MHA
+    (rep 1) and MQA (rep == H) included, under the slot decode's per-row
+    (B, T, S) mask and the prefill's shared (T, S) mask alike."""
+    from fraud_detection_tpu.models.llm import _expand_kv_heads
+
+    B, S, H, d = 3, 24, 8, 16
+    rng = np.random.default_rng(100 + rep)
+    q = jnp.asarray(rng.normal(size=(B, T, H, d)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(B, S, H // rep, d)), dtype)
+            for _ in range(2))
+    # row t sees keys [0, S - T + t]: never a fully-masked row
+    mask = jnp.arange(S)[None, :] <= (S - T + jnp.arange(T))[:, None]
+    if per_row_mask:  # each batch row holds its own prefix length
+        held = jnp.asarray([S, S - 7, T])
+        mask = mask[None] & (jnp.arange(S)[None, None, :]
+                             < held[:, None, None])
+    got = _attend(q, k, v, mask)
+    want = _attend(q, _expand_kv_heads(k, rep), _expand_kv_heads(v, rep),
+                   mask)
+    assert got.shape == (B, T, H, d) and got.dtype == want.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)

@@ -13,9 +13,8 @@ import pytest
 
 from fraud_detection_tpu.models import linear, llm, pipeline, trees
 
-LAYER_SCOPES = {"attn.qkv", "attn.expand_kv", "attn.scores", "attn.values",
-                "attn.out", "mlp", "lm_head", "sample", "kv.gather_pages",
-                "kv.scatter_pages"}
+LAYER_SCOPES = {"attn.qkv", "attn.scores", "attn.values", "attn.out", "mlp",
+                "lm_head", "sample", "kv.gather_pages", "kv.scatter_pages"}
 
 CFG = llm.TransformerConfig(vocab_size=300, d_model=64, n_heads=4, n_layers=2,
                             d_ff=128, max_seq=256, n_kv_heads=2)
@@ -44,6 +43,14 @@ def _prefill_case():
     args = (params, tokens, jnp.int32(40), CFG, pages, tables[0],
             jnp.float32(0.0), key, 16)
     return llm.paged_slot_prefill, (3, 8), args, LAYER_SCOPES
+
+
+def _contiguous_decode_case():
+    _, _, a, _ = _decode_case()       # the same window over a contiguous pool
+    args = a[:6] + (llm.init_cache(CFG, 3, a[11]),) + a[8:11]
+    return (llm.slot_decode_window, (5, 9), args,
+            LAYER_SCOPES - {"kv.gather_pages", "kv.scatter_pages"}
+            | {"kv.append"})
 
 
 def _packed_rows():
@@ -81,8 +88,8 @@ def _tree_case():
             {"score.unpack", "score.traverse"})
 
 
-@pytest.mark.parametrize("case", [_decode_case, _prefill_case, _lr_case,
-                                  _tree_case])
+@pytest.mark.parametrize("case", [_decode_case, _contiguous_decode_case,
+                                  _prefill_case, _lr_case, _tree_case])
 def test_scopes_are_named_and_change_no_number(case, monkeypatch):
     fn, static, args, scopes = case()
     named = fn.lower(*args)
@@ -108,3 +115,21 @@ def test_scopes_are_named_and_change_no_number(case, monkeypatch):
                     jax.tree_util.tree_leaves(want)):
         assert a.dtype == b.dtype
         assert np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)
+
+
+@pytest.mark.parametrize("case,view", [
+    (_decode_case, (3, 60)),              # 3 slots, view_len 60
+    (_contiguous_decode_case, (3, 60)),
+    (_prefill_case, (1, 64)),             # one row, 4 pages of 16
+])
+def test_slot_programs_attend_the_narrow_kv(case, view):
+    """CFG is grouped (4 query heads over 2 kv heads): the slot programs hand
+    ``_attend`` the cache as stored, so the lowered program carries no
+    ``attn.expand_kv`` and builds nothing of shape (B, S, n_heads, head_dim)
+    — the copy the padded view once paid for in every layer of every step."""
+    fn, _, args, _ = case()
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert "attn.expand_kv" not in text
+    B, S = view
+    assert f"tensor<{B}x{S}x{CFG.kv_heads}x{CFG.head_dim}x" in text
+    assert f"tensor<{B}x{S}x{CFG.n_heads}x{CFG.head_dim}x" not in text
