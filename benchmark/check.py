@@ -1,9 +1,12 @@
 """The comparison that decides ``correct``. The yardstick: later PRs may not
 edit this file. It compares what the timed path produced, at the timed
-sizes, with the plain references of ``benchmark/reference.py``; each number
-compared stands beside its limit in the result.
+sizes, with the plain references of ``benchmark/reference.py`` and of the
+configuration's explainer family (``explainers/<model_type>.py``); each
+number compared stands beside its limit in the result.
 
-Numbers (limits and the readings they were set from are in PERF.md):
+Numbers (limits and the readings they were set from are in PERF.md; a
+configuration file may state ``token_gap_sq``'s for its own family, with its
+readings: ``stated_limits``):
 
 ``rows_unaccounted``    rows due in the window with no frame on the output
                         topic, a frame twice, or a dead-letter record (0).
@@ -76,6 +79,40 @@ LIMITS = {
     # least 1.106e-3 (0.0333) on 8 (PERF.md section 6).
     "token_gap_sq": 6.5e-4,
 }
+
+
+# The one limit a configuration file may state for itself, with the keys
+# it has to bring: an explainer family's logit noise is its own (a routed
+# model's steps at every flipped expert choice), so its limit is set between
+# its own sound runs and its own control, and says so where it is stated.
+STATABLE = ("token_gap_sq",)
+STATED_KEYS = ("limit", "sound", "control", "why")
+
+
+def stated_limits(cfg: dict) -> Dict[str, float]:
+    """``LIMITS``, with the configuration's ``"check": {"token_gap_sq":
+    {"limit": x, "sound": [...], "control": [...], "why": "..."}}`` merged
+    over it. ``sound`` and ``control`` are the readings the limit was set
+    from (the program as stated; the family's lower precision), and the limit
+    has to stand between them. Any other name, a missing key or a limit
+    outside its own readings is an error."""
+    limits = dict(LIMITS)
+    for name, entry in (cfg.get("check") or {}).items():
+        if name not in STATABLE:
+            raise ValueError(f"a configuration may state a limit for "
+                             f"{list(STATABLE)} only, not {name!r}")
+        missing = [k for k in STATED_KEYS if not entry.get(k)]
+        if missing:
+            raise ValueError(f"the stated limit of {name!r} lacks {missing}: "
+                             f"it needs {list(STATED_KEYS)}")
+        limit = float(entry["limit"])
+        if not max(entry["sound"]) < limit < min(entry["control"]):
+            raise ValueError(
+                f"the stated limit {limit!r} of {name!r} does not stand "
+                f"between its sound readings (largest {max(entry['sound'])!r})"
+                f" and its control's (smallest {min(entry['control'])!r})")
+        limits[name] = limit
+    return limits
 
 
 def sample_indices(n: int, k: int, seed: int, always: Sequence[int] = ()) -> List[int]:
@@ -162,14 +199,16 @@ def notes_numbers(flagged: Sequence[int], must_be_real: Sequence[int],
 
 
 def explainer_numbers(seed: int, cfg: dict, requests: Sequence[dict],
-                      pad_to: int) -> Dict[str, float]:
+                      pad_to: int, token_gaps) -> Dict[str, float]:
     """``requests``: ``{"prompt", "served", "text"}`` of sampled finished
     rows; ``text`` is the sent transcript the prompt carries, None if it
-    carries none. Returns ``token_gap_sq`` (compared), and for the record
-    the mean gap it squares, the mean gap over all tokens, the share of
-    served tokens that are not the reference's first choice, the widest
-    single gap and how many tokens were compared."""
-    gap = np.concatenate(reference.llm_token_gaps(
+    carries none. ``token_gaps`` is the plain reference of the
+    configuration's explainer family (``run.load_family``). Returns
+    ``token_gap_sq`` (compared), and for the record the mean gap it squares,
+    the mean gap over all tokens, the share of served tokens that are not
+    the reference's first choice, the widest single gap and how many tokens
+    were compared."""
+    gap = np.concatenate(token_gaps(
         seed, cfg, cfg["torch_dtype"], requests, pad_to))
     off = gap[gap > 0]
     off_mean = float(np.mean(off)) if len(off) else 0.0
@@ -187,8 +226,9 @@ def control_verdict(numbers: Dict[str, float],
     """The verdict with every ``control_<name>`` reading (the classifier's
     reference in bfloat16) put in the place of the program's ``<name>``:
     what ``correct`` says of the lower precision. The explainer's control
-    is served by the program itself (benchmark/control.py), so its numbers
-    stand in the program's place already."""
+    is served by the program itself, through the lower precision of its
+    family file's ``build`` (benchmark/control.py), so its numbers stand in
+    the program's place already."""
     swapped = dict(numbers)
     for k, v in numbers.items():
         if k.startswith("control_"):
@@ -199,8 +239,9 @@ def control_verdict(numbers: Dict[str, float],
 def verdict(numbers: Dict[str, float],
             limits: Optional[Dict[str, float]] = None) -> Dict[str, object]:
     """``{"correct": bool, "compared": {name: [value, limit]}}`` over the
-    numbers that have a limit; a NaN never passes. ``limits`` is for tests
-    at sizes whose readings differ from the cells'."""
+    numbers that have a limit; a NaN never passes. ``limits`` is
+    ``stated_limits`` of the cell's configuration, or a test's at sizes
+    whose readings differ from the cells'."""
     limits = LIMITS if limits is None else limits
     compared = {k: [numbers[k], limits[k]] for k in limits if k in numbers}
     ok = bool(compared) and all(

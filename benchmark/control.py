@@ -5,17 +5,20 @@
 
 It is ``run.py``'s own ``run_cell``, for each seed in turn in one process
 (set-up is long and the programs stay loaded). The explainer is served
-through the program's own lower precision (``LanguageModel.quantized()``,
-weight-only int8, where the configuration states bfloat16), so the tokens
+through the lower precision of its family (``build`` of
+``explainers/<model_type>.py`` given ``weights`` "int8": the program's own
+weight-only int8 path where the configuration states bfloat16), so the tokens
 compared are the control's; the classifier's reference, computed in bfloat16
 where the configuration states float32, is put in the program's place. All
-of it goes through the same ``check.verdict`` with the same limits, and every
-result line carries ``"control": {"correct": false, ..}``.
+of it goes through the same ``check.verdict`` with the same limits, the one
+a configuration states for itself among them, and every result line carries
+``"control": {"correct": false, ..}``.
 
 ``--sound`` runs the program as the configuration states instead: several
 seeds' readings of a sound run for the price of one set-up. The benchmark's
-runs never come here; the limits in ``check.py`` were set between the two
-kinds of reading (PERF.md section 6).
+runs never come here; the limits in ``check.py``, and the one a
+configuration states, were set between the two kinds of reading (PERF.md
+section 6).
 """
 
 import argparse

@@ -2,13 +2,15 @@
 ``serve --explain-slots N --explain-paged`` wires it (app/serve.py) — one
 ``StreamingClassifier`` over an in-process broker, scoring with the trained
 classifier and explaining flagged rows through the paged slot lane via
-``make_slot_explain_hook`` and the asynchronous annotation lane. This is the
-only module of the benchmark that imports the program.
+``make_slot_explain_hook`` and the asynchronous annotation lane. This module
+and the explainer family files (``explainers/<model_type>.py``, whose
+``build`` makes the model the slot lane serves) are the only ones of the
+benchmark that import the program.
 
 Everything here is set-up: the classifier is trained through the normal
 ``train`` entry on the benchmark's own corpus, the explainer's weights are
-made by ``reference.make_llm_params`` and handed to the program, and
-``warm`` drives every shape the cell's traffic will touch.
+made from the seed by the family's ``make_params`` and handed to its
+``build``, and ``warm`` drives every shape the cell's traffic will touch.
 """
 
 from __future__ import annotations
@@ -22,30 +24,10 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from benchmark import reference
 from benchmark.corpus import generate_corpus
 
 IN_TOPIC, OUT_TOPIC, DLQ_TOPIC = "calls", "scored", "scored-dlq"
 NOTES_TOPIC = OUT_TOPIC + "-annotations"
-
-
-def llm_config(cfg: dict):
-    """The program's ``TransformerConfig`` for an HF-style config dict."""
-    import jax.numpy as jnp
-
-    from fraud_detection_tpu.models.llm import TransformerConfig
-
-    return TransformerConfig(
-        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"], n_layers=cfg["num_hidden_layers"],
-        d_ff=cfg["intermediate_size"], max_seq=cfg["max_position_embeddings"],
-        rope_theta=float(cfg["rope_theta"]),
-        dtype=jnp.dtype(cfg["torch_dtype"]).type,
-        n_kv_heads=cfg.get("num_key_value_heads"),
-        head_dim_override=cfg.get("head_dim"),
-        activation=cfg.get("hidden_act", "silu"),
-        tie_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        rms_eps=float(cfg["rms_norm_eps"]))
 
 
 def train_classifier(spec: dict, seed: int, workdir: str) -> str:
@@ -82,13 +64,15 @@ def train_classifier(spec: dict, seed: int, workdir: str) -> str:
 
 class Desk:
     """One serve process's worth of objects, and the thread its engine
-    runs on."""
+    runs on. ``family`` is the configuration's explainer family
+    (``run.load_family``)."""
 
-    def __init__(self, cfg: dict, seed: int, workdir: str, *,
+    def __init__(self, cfg: dict, seed: int, workdir: str, family, *,
                  traced: bool = False):
+        import jax.numpy as jnp
+
         from fraud_detection_tpu.explain.slotserve import (
             SlotServeService, make_slot_explain_hook)
-        from fraud_detection_tpu.models.llm import LanguageModel
         from fraud_detection_tpu.models.pipeline import ServingPipeline
         from fraud_detection_tpu.stream import (InProcessBroker,
                                                 StreamingClassifier)
@@ -101,13 +85,12 @@ class Desk:
         self.checkpoint = train_classifier(desk["classifier"], seed, workdir)
         self.pipe = ServingPipeline.from_checkpoint(
             self.checkpoint, batch_size=eng["batch_size"])
-        tcfg = llm_config(cfg)
-        params = reference.make_llm_params(seed, cfg, tcfg.dtype)
-        self.lm = LanguageModel(tcfg, params)
-        if exp.get("weights", cfg["torch_dtype"]) == "int8":
-            # The program's own lower precision (benchmark/control.py, or a
-            # configuration that states it).
-            self.lm = self.lm.quantized()
+        # ``weights`` other than the stated dtype is the family's own lower
+        # precision (benchmark/control.py, or a configuration that states it).
+        self.lm = family.build(
+            cfg, family.make_params(seed, cfg,
+                                    jnp.dtype(cfg["torch_dtype"]).type),
+            exp.get("weights", cfg["torch_dtype"]))
         self.svc = SlotServeService(
             self.lm, slots=exp["slots"], max_queue=exp["max_queue"],
             max_new_tokens=exp["max_new_tokens"],
@@ -245,9 +228,11 @@ class Desk:
         self.engine.close_annotations(timeout=30.0)   # ... and note them
         if self._error is not None:
             raise RuntimeError("the engine died") from self._error
+        # At quiescence every page is back on the free list, the shared
+        # preamble's too (both 0 where the lane is not paged).
+        after = self.svc.snapshot()
         return {"snapshot": snap, "closed": bool(closed),
-                "leaked_pages": int(getattr(self.svc._decoder,
-                                            "leaked_pages", 0)),
+                "leaked_pages": int(after["kv_pages"] - after["pages_free"]),
                 "lane": self.engine.annotation_stats(),
                 "stats": self.engine.stats.as_dict(),
                 "health_device": self.engine.health().get("device", {})}

@@ -3,6 +3,8 @@
 ``ctx`` is the dict ``benchmark/run.py`` builds after a traced run:
 
 ``cfg`` ``mix`` ``device_kind`` ``seconds``   the cell and the chip
+``family``        the configuration's explainer family (``explainers/<model_type>.py``):
+                  ``decode_cost`` and ``prefill_cost`` here take its counts
 ``window``        (open, close) of the measured window, ``time.time()`` clock
 ``trace_window``  (start, stop) of the profiler's window, same clock
 ``late_ms``       feeder lateness of every row due in the window
@@ -106,7 +108,7 @@ def decode_cost(ctx, a: str, b: str, which: str):
     ctxlen = mean_context(ctx, which)
     if not steps or not rows or ctxlen is None:
         return None
-    return counts.decode_aggregate_cost(ctx["cfg"], steps, rows, ctxlen)
+    return ctx["family"].decode_cost(ctx["cfg"], steps, rows, ctxlen)
 
 
 def prefill_cost(ctx, which: str):
@@ -116,8 +118,8 @@ def prefill_cost(ctx, which: str):
     n = 0
     for t in ctx["tickets"]:
         if t["first_token"] is not None and lo <= t["first_token"] < hi:
-            f, b = counts.prefill_cost(ctx["cfg"], prefix,
-                                       t["prompt_len"] - prefix)
+            f, b = ctx["family"].prefill_cost(ctx["cfg"], prefix,
+                                              t["prompt_len"] - prefix)
             flops, nbytes, n = flops + f, nbytes + b, n + 1
     return (flops, nbytes, n) if n else None
 

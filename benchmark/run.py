@@ -89,6 +89,14 @@ def cell_metrics(spec: dict, cell: str):
     return e2e, layer
 
 
+def _load_file(path: str, module_name: str):
+    mod_spec = importlib.util.spec_from_file_location(
+        module_name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(spec: dict, name: str, root: str = ROOT):
     """``read`` of ``metrics/<name>.py``. A quantity split by the end-to-end
     metric it moves (``gen.late_p99_ms.stream``, ``gen.late_p99_ms.explain``)
@@ -97,13 +105,32 @@ def load_reader(spec: dict, name: str, root: str = ROOT):
         for p in spec["paths"]:
             path = os.path.join(root, p, "metrics", stem + ".py")
             if stem and os.path.exists(path):
-                mod_spec = importlib.util.spec_from_file_location(
-                    "bench_metric_" + stem.replace(".", "_").replace("-", "_"),
-                    path)
-                mod = importlib.util.module_from_spec(mod_spec)
-                mod_spec.loader.exec_module(mod)
-                return mod.read
+                return _load_file(path, "bench_metric_" + stem).read
     raise SystemExit(f"no reader file metrics/{name}.py under {spec['paths']}")
+
+
+FAMILY_FUNCTIONS = ("build", "make_params", "token_gaps", "decode_cost",
+                    "prefill_cost", "param_count")
+
+
+def load_family(spec: dict, cfg: dict, root: str = ROOT):
+    """The explainer family a configuration names: the module
+    ``explainers/<model_type>.py`` under the first directory of ``paths``
+    that holds one. It brings the model the desk serves, its weights from
+    the seed, its plain reference and its counts (the contract is the
+    header of ``benchmark/explainers/internlm2.py``)."""
+    model_type = cfg.get("model_type")
+    dirs = [os.path.join(p, "explainers") for p in spec["paths"]]
+    for d in dirs:
+        path = os.path.join(root, d, f"{model_type}.py")
+        if model_type and os.path.exists(path):
+            family = _load_file(path, f"bench_explainer_{model_type}")
+            missing = [f for f in FAMILY_FUNCTIONS if not hasattr(family, f)]
+            if missing:
+                raise SystemExit(f"explainer family file {path} lacks {missing}")
+            return family
+    raise SystemExit(f"no explainer family file for model_type {model_type!r}: "
+                     f"searched {dirs} for {model_type}.py")
 
 
 # ---------------------------------------------------------------------------
@@ -220,19 +247,21 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
     ``device_kind`` lets it name a chip for the readers' arithmetic.
     ``control`` (benchmark/control.py) judges the lower precision too: the
     classifier's reference in bfloat16 put in the program's place, beside
-    whatever lower precision ``cfg`` has the explainer served in."""
+    whatever lower precision ``cfg`` has the explainer's family serve."""
     import jax
 
     from benchmark import (check, corpus, desk as desk_mod, reference,
                            trace_reduce, traffic)
 
     e2e_spec, layer_spec = cell_metrics(spec, cell["name"])
+    family = load_family(spec, cfg, root)
+    limits = check.stated_limits(cfg)
     workdir = tempfile.mkdtemp(prefix="bench-", dir=scratch)
     cap = None
     try:
         # ---- set-up -----------------------------------------------------
         log(f"cell {cell['name']} seed {seed} seconds {seconds} trace {int(trace)}")
-        desk = desk_mod.Desk(cfg, seed, workdir, traced=trace)
+        desk = desk_mod.Desk(cfg, seed, workdir, family, traced=trace)
         log("desk built (classifier trained, explainer resident)")
         plan = traffic.build_plan(mix, seconds, cfg)
         texts = traffic.build_texts(plan, seed, desk.flags)
@@ -335,7 +364,8 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
         numbers.update(check.classifier_numbers(art, frame_of, sent))
         ctx = None
         if trace:
-            ctx = {"cfg": cfg, "mix": mix, "seconds": seconds,
+            ctx = {"cfg": cfg, "family": family, "mix": mix,
+                   "seconds": seconds,
                    "device_kind": device_kind or jax.devices()[0].device_kind,
                    "window": (t0 + plan.open_s, t0 + plan.close_s),
                    "trace_window": (marks.stamps.get("trace_start", 0.0),
@@ -363,9 +393,10 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
             t_ref = time.time()
             log(f"reference over {len(picked)} requests; device memory in "
                 f"use {desk_mod.memory_in_use() / 1e9:.2f} GB")
-            numbers.update(check.explainer_numbers(seed, cfg, picked, pad_to))
+            numbers.update(check.explainer_numbers(seed, cfg, picked, pad_to,
+                                                   family.token_gaps))
             log(f"reference took {time.time() - t_ref:.1f} s")
-        result = check.verdict(numbers)
+        result = check.verdict(numbers, limits)
         # ---- the line ---------------------------------------------------
         device = desk_mod.device_stamp()
         device["memory_peak_bytes"] = peak
@@ -403,7 +434,7 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
                         "numbers": {k: v for k, v in numbers.items()
                                     if k not in check.LIMITS}}
         if control:
-            line["control"] = check.control_verdict(numbers)
+            line["control"] = check.control_verdict(numbers, limits)
         line["compared"] = result["compared"]
         return line
     finally:

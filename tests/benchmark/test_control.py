@@ -61,7 +61,8 @@ def test_explainer_numbers_on_random_tokens_are_not_correct():
     # second one short.
     requests = [{"prompt": rng.integers(0, 258, 40 - i), "text": "x",
                  "served": rng.integers(0, 4096, 24 + i)} for i in range(10)]
-    got = check.explainer_numbers(3, cfg, requests, pad_to=128)
+    got = check.explainer_numbers(3, cfg, requests, 128,
+                                  reference.llm_token_gaps)
     assert got["tokens_compared"] == sum(24 + i for i in range(10))
     assert got["prompt_mismatch"] == 0
     assert got["token_gap_max"] >= got["token_gap_off_best"] >= got["token_gap_mean"] > 0
@@ -137,3 +138,53 @@ def test_the_explainer_limit_stands_between_the_recorded_readings():
     # The mean over all tokens, which the share of near ties moves as much
     # as the precision does, no longer separates the two by three times.
     assert min(m for m, _ in PROGRAM_INT8) < 3.0 * max(m for m, _ in SOUND_BF16)
+
+
+def test_the_internlm2_family_binds_these_weights_and_this_reference(spec):
+    """Through ``explainers/internlm2.py`` the weights of a seed are bitwise
+    ``reference.make_llm_params``'s and the gaps ``reference.llm_token_gaps``'s
+    (the cases of the two tests above)."""
+    import jax.numpy as jnp
+
+    from benchmark import run
+
+    cfg = {"model_type": "internlm2", "hidden_size": 32, "intermediate_size": 64,
+           "num_attention_heads": 4, "num_key_value_heads": 2,
+           "num_hidden_layers": 3, "vocab_size": 300, "rms_norm_eps": 1e-5,
+           "rope_theta": 1e6, "hidden_act": "silu", "tie_word_embeddings": False}
+    family = run.load_family(spec, cfg)
+    seed = 2**31 + 12345
+    mine = family.make_params(seed, cfg, jnp.bfloat16)
+    theirs = reference.make_llm_params(seed, cfg, jnp.bfloat16)
+    assert set(mine) == set(theirs)
+    for name, w in theirs.items():
+        assert mine[name].dtype == w.dtype and jnp.array_equal(mine[name], w)
+    rng = np.random.default_rng(1)
+    requests = [{"prompt": rng.integers(0, 258, 30 + 3 * i),
+                 "served": rng.integers(0, 300, 6)} for i in range(10)]
+    for got, want in zip(family.token_gaps(9, cfg, "float32", requests, 64),
+                         reference.llm_token_gaps(9, cfg, "float32", requests, 64)):
+        np.testing.assert_array_equal(got, want)
+    # The limit it is held to is check.LIMITS': its files state none.
+    assert check.stated_limits(cfg) == check.LIMITS
+    assert check.stated_limits(cfg) is not check.LIMITS
+
+
+def test_the_internlm2_family_builds_what_the_desk_built(spec):
+    """``build`` hands the slot lane the program's model at the stated
+    dtype, its own int8 path as the lower precision, a tokenizer on both."""
+    import jax.numpy as jnp
+
+    from benchmark import desk, run
+
+    cfg = desk.load_config(os.path.join(FIXTURES, "configs", "tiny-desk.json"))
+    family = run.load_family(spec, cfg)
+    params = family.make_params(4, cfg, jnp.float32)
+    stated = family.build(cfg, params, cfg["torch_dtype"])
+    lower = family.build(cfg, params, "int8")
+    assert stated.params is params and stated.cfg.dtype == jnp.float32
+    assert (stated.cfg.n_layers, stated.cfg.kv_heads, stated.cfg.d_ff) == (2, 2, 64)
+    assert not stated.cfg.tie_embeddings and stated.cfg.activation == "silu"
+    assert type(lower.params["l0.wq"]).__name__ == "Q8"
+    assert list(stated.tokenizer.encode("a")) == [256, 97] \
+        == list(lower.tokenizer.encode("a"))

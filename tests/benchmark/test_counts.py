@@ -58,3 +58,60 @@ def test_score_costs_and_peaks(cfg):
     assert counts.roofline(1.0, 819e9, "TPU v5 lite") == (1.0, "memory")
     with pytest.raises(KeyError, match="no peaks recorded"):
         counts.peaks("cpu")
+
+
+# ---------------------------------------------------------------------------
+# the same counts through the configuration's family file
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def family(spec, cfg):
+    from benchmark import run
+
+    return run.load_family(spec, cfg)
+
+
+def test_the_family_file_is_found_by_model_type(family, cfg):
+    assert cfg["model_type"] == "internlm2"
+    assert family.__file__ == os.path.join(REPO, "benchmark", "explainers",
+                                           "internlm2.py")
+
+
+@pytest.mark.parametrize("name,args,through", [
+    ("decode_cost", (2, 3, (1000 + 1500 + 1001) / 3), "decode_aggregate_cost"),
+    ("decode_cost", (211.0, 2505.0, 1735.5, 1), "decode_aggregate_cost"),
+    ("prefill_cost", (293, 1000), "prefill_cost"),
+    ("prefill_cost", (0, 1991, 1), "prefill_cost"),
+    ("param_count", (), "llm_param_count"),
+])
+def test_family_counts_are_those_of_counts_py(family, cfg, name, args, through):
+    assert getattr(family, name)(cfg, *args) == getattr(counts, through)(cfg, *args)
+    if name == "param_count":
+        assert family.param_count(cfg) == 1889110016
+
+
+def test_readers_take_their_counts_from_the_family():
+    """``_lib.decode_cost`` and ``_lib.prefill_cost`` hand the family what
+    the marks and the tickets say, and return what it says."""
+    from types import SimpleNamespace
+
+    from benchmark.metrics import _lib
+
+    asked = []
+    fam = SimpleNamespace(
+        decode_cost=lambda *a: asked.append(("decode",) + a) or (1.0, 2.0),
+        prefill_cost=lambda *a: asked.append(("prefill",) + a) or (10.0, 20.0))
+    mark = {"slots": 2, "prefix_pages": 5, "occupancy": 0.0, "decode_steps": 0}
+    ctx = {"cfg": {"name": "x"}, "family": fam, "prefix_len": 293,
+           "window": (100.0, 110.0),
+           "marks": {"open": mark,
+                     "close": dict(mark, occupancy=0.75, decode_steps=40)},
+           "tickets": [
+               {"prompt_len": 1400, "n_out": 100, "first_token": 101.0, "done": 105.0},
+               {"prompt_len": 1600, "n_out": 100, "first_token": 104.0, "done": None},
+               {"prompt_len": 1500, "n_out": 0, "first_token": None, "done": None}]}
+    assert _lib.decode_cost(ctx, "open", "close", "window") == (1.0, 2.0)
+    assert _lib.prefill_cost(ctx, "window") == (20.0, 40.0, 2)
+    assert asked == [("decode", ctx["cfg"], 40.0, 60.0, 1550.0),
+                     ("prefill", ctx["cfg"], 293, 1107),
+                     ("prefill", ctx["cfg"], 293, 1307)]
