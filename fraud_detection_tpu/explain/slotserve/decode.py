@@ -116,7 +116,36 @@ class PageAllocator:
                 "in_use": refd, "refs": sum(self._refs)}
 
 
-class SlotDecoder:
+class _ModelCounters:
+    """What the device programs count about the model's own routing and
+    state, summed on the host as the results come back (no sync of their
+    own: they ride with the first token and with the window's tokens). All
+    stay 0 for a model with no expert layer and no recurrent state."""
+
+    def _init_counters(self) -> None:
+        self.moe_picks = 0              # expert choices made by real tokens
+        self.moe_picks_held = 0         # ... that landed on a held expert
+        self.moe_experts_touched = 0    # distinct held experts read, summed
+        #                                 over decode steps and expert layers
+        self.moe_prefill_load_max = 0   # busiest held expert's tokens, summed
+        #                                 over prefills and expert layers
+        self.moe_prefill_load_mean = 0.0    # the mean expert's, likewise
+        self.state_restores = 0         # snapshots copied into a slot's block
+
+    def _count(self, stats, *, prefill: bool) -> None:
+        if stats is None:
+            return
+        picks, held, touched, load_max = (int(v) for v in np.asarray(stats))
+        self.moe_picks += picks
+        self.moe_picks_held += held
+        if prefill:
+            self.moe_prefill_load_max += load_max
+            self.moe_prefill_load_mean += held / self.cfg.moe.held
+        else:
+            self.moe_experts_touched += touched
+
+
+class SlotDecoder(_ModelCounters):
     """One slot pool + its device programs. NOT thread-safe — owned by the
     slot lane's single worker thread (the service's contract)."""
 
@@ -149,6 +178,7 @@ class SlotDecoder:
             for a in self.cache.values()))
         self.prefills = 0
         self.steps = 0
+        self._init_counters()
         # Paged-pool stats surface (zero here: the whole region is a single
         # worst-case reservation). The service snapshot reads these
         # unconditionally so the health schema is mode-independent.
@@ -198,7 +228,8 @@ class SlotDecoder:
         return self.lm.tokenizer.decode(np.asarray(tokens, np.int32))
 
     def prefill(self, slot: int, prompt_tokens: np.ndarray,
-                temperature: float, seed: int) -> int:
+                temperature: float, seed: int,
+                span: Callable = _no_span) -> int:
         """Admit one prompt into ``slot``; returns the FIRST sampled token
         (already part of the row's output)."""
         import jax
@@ -208,12 +239,14 @@ class SlotDecoder:
         bucket = self.prompt_bucket * (-(-n // self.prompt_bucket))
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = prompt_tokens
-        tok, self.cache = llm.slot_prefill(
+        tok, self.cache, stats = llm.slot_prefill(
             self.lm.params, jnp.asarray(padded), jnp.int32(n), self.cfg,
             self.cache, jnp.int32(slot), jnp.float32(temperature),
             jax.random.PRNGKey(seed & 0x7FFFFFFF))
         self.prefills += 1
-        return int(tok)
+        tok = int(tok)
+        self._count(stats, prefill=True)
+        return tok
 
     def step(self, tokens: np.ndarray, lens: np.ndarray, active: np.ndarray,
              remaining: np.ndarray, temperatures: np.ndarray, seed: int,
@@ -231,7 +264,7 @@ class SlotDecoder:
         import jax.numpy as jnp
 
         with span("slot_launch"):
-            out, new_lens, steps_run, n_act, self.cache = \
+            out, new_lens, steps_run, n_act, self.cache, stats = \
                 llm.slot_decode_window(
                     self.lm.params, jnp.asarray(tokens, jnp.int32),
                     jnp.asarray(lens, jnp.int32), jnp.asarray(active),
@@ -243,8 +276,10 @@ class SlotDecoder:
         with span("slot_fetch"):
             # np.array, not asarray: the lens copy must be writable (the
             # service mutates it per-slot on prefill/release).
-            return (np.asarray(out), np.array(new_lens), int(steps_run),
-                    int(n_act))
+            fetched = (np.asarray(out), np.array(new_lens), int(steps_run),
+                       int(n_act))
+            self._count(stats, prefill=False)
+            return fetched
 
     def warm(self, steps: int, prompt: Optional[str] = None) -> None:
         """Compile the decode window + the smallest prefill bucket off the
@@ -261,10 +296,19 @@ class SlotDecoder:
         self.release_slot(0)
 
 
-class PagedSlotDecoder:
+class PagedSlotDecoder(_ModelCounters):
     """The paged twin of :class:`SlotDecoder`: same serving surface, but the
     KV region is a flat pool of ``total_pages`` fixed-size pages indexed by
     a per-slot page table (PagedAttention applied to the slot pool).
+
+    Two kinds of per-slot state live under this one manager, by the model's
+    layer kinds: what grows with the tokens held (an ``attention`` layer's
+    k/v, an ``mla`` layer's latents) is paged; a layer that keeps a
+    recurrent state (``kda``) gets a fixed block per slot, allocated once
+    (``self.state``). Pages are counted for the paging layers only; the
+    blocks need no allocator. The shared preamble is both: pages mapped
+    copy-on-write, and a snapshot of every recurrent layer's state at its
+    last token, copied into the slot's block on admission.
 
     * **Admission** builds the slot's table — shared full prefix pages are
       retained, a partially-filled shared page is copied-on-write, the
@@ -322,6 +366,12 @@ class PagedSlotDecoder:
                 f"({self.n_view} pages of {page_size})")
         self.total_pages = total
         self.pages = llm.init_kv_pages(cfg, total, page_size)
+        # One block per slot for every layer that keeps a recurrent state
+        # ({} for a model without one), and the one-row state a fresh prompt
+        # starts from: zeros, or the shared preamble's once it is set.
+        self.state = llm.init_state(cfg, slots)
+        self._zero_state = llm.init_state(cfg, 1)
+        self._prefix_state = self._zero_state
         self.allocator = PageAllocator(total)
         self._tables = np.zeros((slots, self.n_view), np.int32)
         self._cover = [0] * slots        # table entries resident per slot
@@ -335,15 +385,18 @@ class PagedSlotDecoder:
         self.cow_copies = 0
         self.prefix_tokens_saved = 0
         self.leaked_pages = 0
+        self._init_counters()
         # One page's bytes across every layer/tensor array; the pool's
-        # total; and the reservation the contiguous layout would have made
-        # for the same slot count (the headline saving).
+        # total (pages, plus the slots' state blocks); and the reservation
+        # the contiguous layout would have made for the same slot count
+        # (the headline saving).
         per_pos = int(sum(a.dtype.itemsize * a.shape[2] * a.shape[3]
                           for a in self.pages.values()))
         self.page_bytes = per_pos * page_size
-        self.kv_bytes = self.page_bytes * total
+        self.kv_bytes = self.page_bytes * total + int(sum(
+            a.size * a.dtype.itemsize for a in self.state.values()))
         self.kv_bytes_saved_vs_contiguous = (
-            per_pos * max_len * slots - self.kv_bytes)
+            per_pos * max_len * slots - self.page_bytes * total)
         if prefix_text:
             self.set_prefix(prefix_text)
 
@@ -381,7 +434,9 @@ class PagedSlotDecoder:
         The byte tokenizer is concatenation-safe (``encode(a + b)`` =
         ``[BOS] + bytes(a) + bytes(b)``), so a prompt shares the prefix
         iff its text starts with ``prefix_text`` — checked per admit at
-        the token level. The prefix k/v are computed by the CONTIGUOUS
+        the token level. A layer that keeps a recurrent state leaves a
+        snapshot of it at the preamble's last token instead of pages
+        (``_prefix_state``). The prefix k/v are computed by the CONTIGUOUS
         prefill program at a bucket-aligned width (ragged widths are not
         bit-stable; bucket-aligned ones are — pinned by the parity tests),
         which makes them bit-identical to the same positions inside any
@@ -407,19 +462,19 @@ class PagedSlotDecoder:
         tmp = llm.init_cache(self.cfg, 1, wp)
         padded = np.zeros((1, wp), np.int32)
         padded[0, :lp] = toks
-        _, tmp = llm.slot_prefill(
+        _, tmp, _ = llm.slot_prefill(
             self.lm.params, jnp.asarray(padded), jnp.int32(lp), self.cfg,
             tmp, jnp.int32(0), jnp.float32(0.0), jax.random.PRNGKey(0))
         pids = [self.allocator.alloc() for _ in range(n_prefix)]
         for j, pid in enumerate(pids):
             take = min(self.page_size, lp - j * self.page_size)
-            for l in range(self.cfg.n_layers):
-                for t in ("k", "v"):
-                    name = f"l{l}.{t}"
-                    rows = tmp[name][0, j * self.page_size:
-                                     j * self.page_size + take]
-                    self.pages[name] = \
-                        self.pages[name].at[pid, :take].set(rows)
+            for name in self.pages:
+                rows = tmp[name][0, j * self.page_size:
+                                 j * self.page_size + take]
+                self.pages[name] = self.pages[name].at[pid, :take].set(rows)
+        # ... and, beside the pages, every recurrent layer's state as the
+        # preamble's last token left it.
+        self._prefix_state = {name: tmp[name] for name in self.state}
         self._prefix_tokens = toks
         self._prefix_len = lp
         self._prefix_pids = pids
@@ -492,10 +547,14 @@ class PagedSlotDecoder:
         return self.lm.tokenizer.decode(np.asarray(tokens, np.int32))
 
     def prefill(self, slot: int, prompt_tokens: np.ndarray,
-                temperature: float, seed: int) -> int:
+                temperature: float, seed: int,
+                span: Callable = _no_span) -> int:
         """Admit one prompt: build the slot's page table (alloc/retain/COW)
-        FIRST, then run the suffix-only prefill program against it. Returns
-        the first sampled token — bit-equal to the contiguous admit."""
+        FIRST, copy the state the suffix starts from into the slot's block
+        (the preamble's snapshot, or zeros; under ``span("slot_state_restore")``
+        and only where the model keeps such state), then run the suffix-only
+        prefill program against both. Returns the first sampled token —
+        bit-equal to the contiguous admit for the paged layers."""
         import jax
         import jax.numpy as jnp
 
@@ -506,18 +565,27 @@ class PagedSlotDecoder:
         ts = self.prompt_bucket * (-(-len(suffix) // self.prompt_bucket))
         cover = -(-(lp + ts) // self.page_size)
         self._table_for_admit(slot, lp, cover)
+        if self.state:
+            with span("slot_state_restore"):
+                self.state = llm.restore_slot_state(
+                    self.state, self._prefix_state if lp else self._zero_state,
+                    jnp.int32(slot))
+            self.state_restores += 1
         padded = np.zeros((1, ts), np.int32)
         padded[0, :len(suffix)] = suffix
-        tok, self.pages = llm.paged_slot_prefill(
+        tok, self.pages, self.state, stats = llm.paged_slot_prefill(
             self.lm.params, jnp.asarray(padded), jnp.int32(n), self.cfg,
             self.pages, jnp.asarray(self._tables[slot, :cover]),
             jnp.float32(temperature),
-            jax.random.PRNGKey(seed & 0x7FFFFFFF), lp)
+            jax.random.PRNGKey(seed & 0x7FFFFFFF), lp, self.state,
+            jnp.int32(slot) if self.state else None)
         self.prefills += 1
         if lp:
             self.prefix_hits += 1
             self.prefix_tokens_saved += lp
-        return int(tok)
+        tok = int(tok)
+        self._count(stats, prefill=True)
+        return tok
 
     # -- decode-window growth & release ----------------------------------
 
@@ -562,6 +630,7 @@ class PagedSlotDecoder:
         self._prefix_pids = []
         self._prefix_len = 0
         self._prefix_tokens = None
+        self._prefix_state = self._zero_state
         self.leaked_pages = self.allocator.in_use
 
     # -- decode ----------------------------------------------------------
@@ -575,7 +644,7 @@ class PagedSlotDecoder:
         import jax.numpy as jnp
 
         with span("slot_launch"):
-            out, new_lens, steps_run, n_act, self.pages = \
+            out, new_lens, steps_run, n_act, self.pages, self.state, stats = \
                 llm.paged_decode_window(
                     self.lm.params, jnp.asarray(tokens, jnp.int32),
                     jnp.asarray(lens, jnp.int32), jnp.asarray(active),
@@ -583,11 +652,13 @@ class PagedSlotDecoder:
                     self.cfg, self.pages, jnp.asarray(self._tables),
                     jnp.asarray(temperatures, jnp.float32),
                     jax.random.PRNGKey(seed & 0x7FFFFFFF), int(steps),
-                    self.max_len)
+                    self.max_len, self.state)
         self.steps += 1
         with span("slot_fetch"):
-            return (np.asarray(out), np.array(new_lens), int(steps_run),
-                    int(n_act))
+            fetched = (np.asarray(out), np.array(new_lens), int(steps_run),
+                       int(n_act))
+            self._count(stats, prefill=False)
+            return fetched
 
     def warm(self, steps: int, prompt: Optional[str] = None) -> None:
         """Compile the decode window + the smallest suffix bucket off the

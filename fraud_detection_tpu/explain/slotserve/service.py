@@ -418,7 +418,7 @@ class SlotServeService:
             with self._span("prefill", req.cid):
                 first = self._decoder.prefill(
                     slot, req.tokens, req.temperature,
-                    self._seed + self._seq)
+                    self._seed + self._seq, span=self._span)
             now = self._clock()
             req.first_token_at = now
             self._first.add(max(0.0, now - req.submitted_at))
@@ -699,6 +699,19 @@ class SlotServeService:
             "cow_copies": self._decoder.cow_copies,
             "kv_bytes_saved_vs_contiguous":
                 self._decoder.kv_bytes_saved_vs_contiguous,
+            # What the model's own programs count (all zero for a dense
+            # model): expert choices made by real tokens and those that
+            # landed on an expert held here; distinct held experts read,
+            # summed over decode steps and expert layers; the busiest and
+            # the mean held expert's tokens, summed over prefills and expert
+            # layers; state snapshots copied into a slot on admission.
+            "moe_picks": self._decoder.moe_picks,
+            "moe_picks_held": self._decoder.moe_picks_held,
+            "moe_experts_touched": self._decoder.moe_experts_touched,
+            "moe_prefill_load_max": self._decoder.moe_prefill_load_max,
+            "moe_prefill_load_mean": round(
+                self._decoder.moe_prefill_load_mean, 4),
+            "state_restores": self._decoder.state_restores,
         }
 
 

@@ -49,6 +49,66 @@ Params = Dict[str, jax.Array]
 
 
 @dataclass(frozen=True)
+class MLAConfig:
+    """Latent attention: K and V of a token are expanded from one cached
+    latent (``kv_rank`` values, RMS-normed) through ``mla_wkvb``; one rotary
+    key of ``rope_dim`` shared by every head rides beside it."""
+
+    kv_rank: int = 512
+    nope_dim: int = 128            # per-head query/key width without RoPE
+    rope_dim: int = 64             # per-head query width with RoPE
+    v_dim: int = 128
+
+    @property
+    def latent_dim(self) -> int:   # what the cache holds per token
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+
+@dataclass(frozen=True)
+class KDAConfig:
+    """Gated delta-rule recurrence with a per-channel decay: per head a
+    float32 ``head_dim x head_dim`` state, a causal depth-wise filter of
+    ``conv_taps`` ahead of q, k and v."""
+
+    n_heads: int = 8
+    head_dim: int = 32
+    conv_taps: int = 4
+    lower_bound: float = -5.0      # log-decay of one step lies in [this, 0]
+    chunk: int = 64                # prefill chunk (a multiple of 16, or < 16)
+
+
+@dataclass(frozen=True)
+class MoEConfig:
+    """Routed experts, of which this device holds ``[held_start, held_start
+    + held_count)``: the router scores all ``n_experts``, every token's
+    ``top_k`` choice and weights are made over all of them, and only picks
+    on held experts are computed (an expert-parallel share; the rest of the
+    sum lives on other devices)."""
+
+    n_experts: int = 16
+    top_k: int = 4
+    n_group: int = 4
+    topk_group: int = 2
+    d_expert: int = 64
+    d_shared: int = 64
+    routed_scale: float = 1.0
+    held_start: int = 0
+    held_count: Optional[int] = None
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.held_count is None else self.held_count
+
+
+MIXERS = ("attention", "mla", "kda")
+FFNS = ("dense", "experts")
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 258          # 256 bytes + BOS + EOS
     d_model: int = 256
@@ -65,6 +125,38 @@ class TransformerConfig:
     embed_scale: float = 1.0           # Gemma scales embeddings by sqrt(D)
     tie_embeddings: bool = True        # False = separate "lm_head" param
     rms_eps: float = 1e-6
+    # --- per-layer kinds: ((mixer, ffn), ...) of MIXERS x FFNS, one pair a
+    # layer; None = ("attention", "dense") throughout. The list alone decides
+    # a layer's arithmetic, parameters, cache and sharding; ``mla`` / ``kda``
+    # / ``moe`` size the kinds it names.
+    layer_kinds: Optional[Tuple[Tuple[str, str], ...]] = None
+    mla: Optional[MLAConfig] = None
+    kda: Optional[KDAConfig] = None
+    moe: Optional[MoEConfig] = None
+
+    def __post_init__(self):
+        kinds = self.kinds
+        if len(kinds) != self.n_layers:
+            raise ValueError(f"layer_kinds names {len(kinds)} layers, "
+                             f"n_layers is {self.n_layers}")
+        for mixer, ffn in kinds:
+            if mixer not in MIXERS or ffn not in FFNS:
+                raise ValueError(f"unknown layer kind {(mixer, ffn)!r}")
+        for kind, sized in (("mla", self.mla), ("kda", self.kda)):
+            if sized is None and any(m == kind for m, _ in kinds):
+                raise ValueError(f"a {kind!r} layer needs cfg.{kind}")
+        if self.moe is None and any(f == "experts" for _, f in kinds):
+            raise ValueError("an 'experts' layer needs cfg.moe")
+
+    @property
+    def kinds(self) -> Tuple[Tuple[str, str], ...]:
+        if self.layer_kinds is None:
+            return (("attention", "dense"),) * self.n_layers
+        return tuple((m, f) for m, f in self.layer_kinds)
+
+    @property
+    def n_expert_layers(self) -> int:
+        return sum(1 for _, f in self.kinds if f == "experts")
 
     @property
     def head_dim(self) -> int:
@@ -83,11 +175,64 @@ class TransformerConfig:
 # init
 # ---------------------------------------------------------------------------
 
+def _layer_shapes(cfg: TransformerConfig, mixer: str, ffn: str
+                  ) -> Dict[str, Tuple[tuple, str, Optional[int]]]:
+    """One layer's leaves by kind: name -> (shape, how it is made, the dim
+    the model axis shards or None). Made: "normal" (N(0, 1/d_model)),
+    "filter" (N(0, 1/taps)), "ones", "zeros", "decay_bias" (U(-8, -2): per
+    channel a decay that forgets in a few tokens up to several hundred)."""
+    D, h, hkv, d = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    out: Dict[str, Tuple[tuple, str, Optional[int]]] = {}
+    if mixer == "attention":
+        out.update(wq=((D, h, d), "normal", 1), wk=((D, hkv, d), "normal", 1),
+                   wv=((D, hkv, d), "normal", 1), wo=((h, d, D), "normal", 0))
+    elif mixer == "mla":
+        m = cfg.mla
+        out.update(mla_wq=((D, h, m.qk_dim), "normal", 1),
+                   mla_wkva=((D, m.latent_dim), "normal", None),
+                   mla_kvnorm=((m.kv_rank,), "ones", None),
+                   mla_wkvb=((m.kv_rank, h, m.nope_dim + m.v_dim), "normal", 1),
+                   mla_wz=((D, h), "normal", 1),
+                   mla_wo=((h, m.v_dim, D), "normal", 0))
+    else:
+        k = cfg.kda
+        hk, dk, taps = k.n_heads, k.head_dim, k.conv_taps
+        out.update(kda_wq=((D, hk, dk), "normal", 1),
+                   kda_wk=((D, hk, dk), "normal", 1),
+                   kda_wv=((D, hk, dk), "normal", 1),
+                   kda_wg=((D, hk, dk), "normal", 1),
+                   kda_conv_q=((taps, hk, dk), "filter", 1),
+                   kda_conv_k=((taps, hk, dk), "filter", 1),
+                   kda_conv_v=((taps, hk, dk), "filter", 1),
+                   kda_A_log=((hk,), "zeros", 0),
+                   kda_dt_bias=((hk, dk), "decay_bias", 0),
+                   kda_wbeta=((D, hk), "normal", 1),
+                   kda_wz=((D, hk), "normal", 1),
+                   kda_onorm=((dk,), "ones", None),
+                   kda_wo=((hk, dk, D), "normal", 0))
+    if ffn == "dense":
+        F = cfg.d_ff
+        out.update(w_gate=((D, F), "normal", 1), w_up=((D, F), "normal", 1),
+                   w_down=((F, D), "normal", 0))
+    else:
+        m = cfg.moe
+        E, F, Fs = m.held, m.d_expert, m.d_shared
+        out.update(moe_router=((D, m.n_experts), "normal", None),
+                   moe_bias=((m.n_experts,), "zeros", None),
+                   moe_wg=((E, D, F), "normal", 2), moe_wu=((E, D, F), "normal", 2),
+                   moe_wd=((E, F, D), "normal", 1),
+                   moe_sg=((D, Fs), "normal", 1), moe_su=((D, Fs), "normal", 1),
+                   moe_sd=((Fs, D), "normal", 0))
+    return out
+
+
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
-    """Random-init parameter pytree. Layout (per layer l):
+    """Random-init parameter pytree. Layout (per layer l) by the layer's
+    kind (``_layer_shapes``): an ("attention", "dense") layer holds
     wq (D, H, d), wk/wv (D, Hkv, d), wo (H, d, D), w_gate/w_up (D, F),
-    w_down (F, D), ln1/ln2 (D,), plus embed (V, D) and ln_f (D,). The output
-    head ties embed unless cfg.tie_embeddings=False adds "lm_head" (V, D)."""
+    w_down (F, D); every layer ln1/ln2 (D,); plus embed (V, D) and ln_f
+    (D,). The output head ties embed unless cfg.tie_embeddings=False adds
+    "lm_head" (V, D)."""
     keys = jax.random.split(rng, cfg.n_layers * 7 + 2)
     scale = 1.0 / math.sqrt(cfg.d_model)
     p: Params = {
@@ -96,16 +241,25 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
     if not cfg.tie_embeddings:
         p["lm_head"] = (jax.random.normal(
             keys[-1], (cfg.vocab_size, cfg.d_model)) * scale).astype(cfg.dtype)
-    h, hkv, d = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    for l in range(cfg.n_layers):
+    for l, (mixer, ffn) in enumerate(cfg.kinds):
         k = keys[1 + l * 7 : 1 + (l + 1) * 7]
-        p[f"l{l}.wq"] = (jax.random.normal(k[0], (cfg.d_model, h, d)) * scale).astype(cfg.dtype)
-        p[f"l{l}.wk"] = (jax.random.normal(k[1], (cfg.d_model, hkv, d)) * scale).astype(cfg.dtype)
-        p[f"l{l}.wv"] = (jax.random.normal(k[2], (cfg.d_model, hkv, d)) * scale).astype(cfg.dtype)
-        p[f"l{l}.wo"] = (jax.random.normal(k[3], (h, d, cfg.d_model)) * scale).astype(cfg.dtype)
-        p[f"l{l}.w_gate"] = (jax.random.normal(k[4], (cfg.d_model, cfg.d_ff)) * scale).astype(cfg.dtype)
-        p[f"l{l}.w_up"] = (jax.random.normal(k[5], (cfg.d_model, cfg.d_ff)) * scale).astype(cfg.dtype)
-        p[f"l{l}.w_down"] = (jax.random.normal(k[6], (cfg.d_ff, cfg.d_model)) * scale).astype(cfg.dtype)
+        for i, (name, (shape, made, _)) in enumerate(
+                _layer_shapes(cfg, mixer, ffn).items()):
+            # the dense layer's seven leaves keep the keys they always had
+            key = k[i] if (mixer, ffn) == ("attention", "dense") else \
+                jax.random.fold_in(k[0], i)
+            if made == "ones":
+                w = jnp.ones(shape)
+            elif made == "zeros":
+                w = jnp.zeros(shape)
+            elif made == "filter":
+                w = jax.random.normal(key, shape) / math.sqrt(shape[0])
+            elif made == "decay_bias":
+                w = jax.random.uniform(key, shape, minval=-8.0, maxval=-2.0)
+            else:
+                w = jax.random.normal(key, shape) * scale
+            p[f"l{l}.{name}"] = w.astype(
+                jnp.float32 if name == "moe_bias" else cfg.dtype)
         p[f"l{l}.ln1"] = jnp.ones(cfg.d_model, cfg.dtype)
         p[f"l{l}.ln2"] = jnp.ones(cfg.d_model, cfg.dtype)
     p["ln_f"] = jnp.ones(cfg.d_model, cfg.dtype)
@@ -113,27 +267,25 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
 
 
 def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, NamedSharding]:
-    """Megatron TP layout as shardings: attention sharded over heads, MLP over
-    the hidden dim; norms/embeddings replicated. GSPMD derives the matching
-    activation collectives (all-reduce after row-parallel wo / w_down)."""
+    """Megatron TP layout as shardings: attention (of any kind) sharded over
+    heads, MLPs and every held expert over the hidden dim; norms, router and
+    embeddings replicated. GSPMD derives the matching activation collectives
+    (all-reduce after row-parallel wo / w_down). GQA: when the kv-head count
+    doesn't divide over the model axis (MQA has a single kv head), k/v are
+    replicated — the Megatron convention; the one latent of an ``mla`` layer
+    (``mla_wkva``) is replicated for the same reason."""
     s: Dict[str, NamedSharding] = {}
     rep = NamedSharding(mesh, P())
     for name in ("embed", "ln_f"):
         s[name] = rep
     if not cfg.tie_embeddings:
         s["lm_head"] = rep
-    # GQA: when the kv-head count doesn't divide over the model axis (MQA has
-    # a single kv head), replicate k/v — the Megatron convention.
-    kv_spec = (P(None, MODEL_AXIS, None)
-               if cfg.kv_heads % mesh.shape[MODEL_AXIS] == 0 else P())
-    for l in range(cfg.n_layers):
-        s[f"l{l}.wq"] = NamedSharding(mesh, P(None, MODEL_AXIS, None))
-        s[f"l{l}.wk"] = NamedSharding(mesh, kv_spec)
-        s[f"l{l}.wv"] = NamedSharding(mesh, kv_spec)
-        s[f"l{l}.wo"] = NamedSharding(mesh, P(MODEL_AXIS, None, None))
-        s[f"l{l}.w_gate"] = NamedSharding(mesh, P(None, MODEL_AXIS))
-        s[f"l{l}.w_up"] = NamedSharding(mesh, P(None, MODEL_AXIS))
-        s[f"l{l}.w_down"] = NamedSharding(mesh, P(MODEL_AXIS, None))
+    n_model = mesh.shape[MODEL_AXIS]
+    for l, (mixer, ffn) in enumerate(cfg.kinds):
+        for name, (shape, _, axis) in _layer_shapes(cfg, mixer, ffn).items():
+            spec = P() if axis is None or shape[axis] % n_model else P(
+                *(MODEL_AXIS if i == axis else None for i in range(len(shape))))
+            s[f"l{l}.{name}"] = NamedSharding(mesh, spec)
         s[f"l{l}.ln1"] = rep
         s[f"l{l}.ln2"] = rep
     return s
@@ -199,6 +351,16 @@ _QUANT_REDUCE_AXES = {
     "w_gate": (0,), "w_up": (0,),            # (D, F): in = D
     "w_down": (0,),                          # (F, D): in = F
     "embed": (1,), "lm_head": (1,),          # (V, D): per-row (gather + head)
+    # latent attention and the KDA recurrence: projections like wq / wo
+    "mla_wq": (0,), "mla_wkva": (0,), "mla_wkvb": (0,), "mla_wz": (0,),
+    "mla_wo": (0, 1),
+    "kda_wq": (0,), "kda_wk": (0,), "kda_wv": (0,), "kda_wg": (0,),
+    "kda_wbeta": (0,), "kda_wz": (0,), "kda_wo": (0, 1),
+    # experts, per expert and per output channel: (E, in, out)
+    "moe_wg": (1,), "moe_wu": (1,), "moe_wd": (1,),
+    "moe_sg": (0,), "moe_su": (0,), "moe_sd": (0,),
+    # the router, the filters, the decay's A_log / dt_bias and every norm
+    # stay at full precision (small, and a routed choice flips on rounding)
 }
 
 
@@ -318,7 +480,8 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 def _attend(q, k, v, mask) -> jax.Array:
-    """Plain masked attention. q: (B,T,H,d), k/v: (B,S,Hkv,d), H % Hkv == 0:
+    """Plain masked attention. q: (B,T,H,d), k: (B,S,Hkv,d), v: (B,S,Hkv,dv)
+    (dv == d but for latent attention), H % Hkv == 0:
     query head h reads kv head h // rep (``jnp.repeat(kv, rep, axis=2)``'s
     order) by contracting against the NARROW k/v — no (B,S,H,d) copy is
     built; MHA is rep == 1, MQA one group. mask (T,S) shared across the
@@ -337,7 +500,7 @@ def _attend(q, k, v, mask) -> jax.Array:
     with jax.named_scope("attn.values"):
         out = jnp.einsum("bgrts,bsgd->bgrtd",
                          probs.reshape(B, g, H // g, T, -1), v)
-        return out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, d)
+        return out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, v.shape[-1])
 
 
 # Below this the materialized-score path is cheaper to compile and its
@@ -665,9 +828,463 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
                          out_specs=spec)(q, k, v)
 
 
+
+# ---------------------------------------------------------------------------
+# layer kinds beside ("attention", "dense"): latent attention, the KDA
+# recurrence, routed experts. Each is written once and called by every
+# program below (full forward, slot prefill, slot step), which read
+# ``cfg.kinds`` and nothing else to decide a layer's arithmetic.
+# ---------------------------------------------------------------------------
+
+_F32 = jnp.float32
+_EXACT = jax.lax.Precision.HIGHEST   # float32 state / routing math stays float32
+
+
+def _head_gate_out(params: Params, cfg: TransformerConfig, l: int, kind: str,
+                   x: jax.Array, h: jax.Array, o: jax.Array) -> jax.Array:
+    """``x + W_o [o * sigmoid(W_z h)_head]``: the head-wise output gate of the
+    ``mla`` and ``kda`` mixers (one scalar a head), then the residual."""
+    with jax.named_scope("attn.out"):
+        z = jax.nn.sigmoid(_mm("btD,Dh->bth", h, params[f"l{l}.{kind}_wz"],
+                               cfg.dtype).astype(_F32)).astype(cfg.dtype)
+        return x + _mm("bthd,hdD->btD", o.astype(cfg.dtype) * z[..., None],
+                       params[f"l{l}.{kind}_wo"], cfg.dtype)
+
+
+# -- latent attention --------------------------------------------------------
+
+def _mla_project(params: Params, cfg: TransformerConfig, l: int, h: jax.Array,
+                 positions: jax.Array):
+    """q (B,T,H,nope+rope) with its rotary part turned, and what the cache
+    holds of each token: (B,T,1,kv_rank+rope) = normed latent || the one
+    rotary key every head shares."""
+    m = cfg.mla
+    with jax.named_scope("attn.qkv"):
+        q = _mm("btD,Dhd->bthd", h, params[f"l{l}.mla_wq"], cfg.dtype)
+        q = jnp.concatenate([q[..., :m.nope_dim],
+                             rope(q[..., m.nope_dim:], positions,
+                                  cfg.rope_theta)], -1)
+    with jax.named_scope("mla.latent"):
+        ckr = _mm("btD,Dc->btc", h, params[f"l{l}.mla_wkva"], cfg.dtype)
+        c = rms_norm(ckr[..., :m.kv_rank], params[f"l{l}.mla_kvnorm"],
+                     cfg.rms_eps)
+        kr = rope(ckr[:, :, None, m.kv_rank:], positions, cfg.rope_theta)
+        return q, jnp.concatenate([c[:, :, None, :], kr], -1)
+
+
+def _mla_expanded(params: Params, cfg: TransformerConfig, l: int,
+                  q: jax.Array, lat: jax.Array, mask: jax.Array) -> jax.Array:
+    """Attention with K and V expanded from the latents ``lat`` (B,S,1,.):
+    the prefill path, MHA at key width nope+rope and value width v_dim."""
+    m = cfg.mla
+    with jax.named_scope("mla.attend"):
+        kv = _mm("bsc,chd->bshd", lat[:, :, 0, :m.kv_rank],
+                 params[f"l{l}.mla_wkvb"], cfg.dtype)
+        kr = jnp.broadcast_to(lat[..., m.kv_rank:],
+                              kv.shape[:3] + (m.rope_dim,))
+        k = jnp.concatenate([kv[..., :m.nope_dim], kr], -1)
+        return _attend(q, k, kv[..., m.nope_dim:], mask)
+
+
+def _mla_absorbed(params: Params, cfg: TransformerConfig, l: int,
+                  q: jax.Array, lat: jax.Array, valid: jax.Array) -> jax.Array:
+    """One query a row against the latents as stored: ``mla_wkvb``'s key half
+    is folded into the query and its value half applied after the weighted
+    sum, so nothing of width H x (nope + v) is built per cached token.
+    q (B,1,H,.), lat (B,S,1,.), valid (B,1,S) -> (B,1,H,v_dim)."""
+    m = cfg.mla
+    w = params[f"l{l}.mla_wkvb"]
+    qn, lat = q[:, 0, :, :m.nope_dim], lat[:, :, 0]
+    with jax.named_scope("mla.absorb"):
+        if isinstance(w, Q8):   # the scale is per key channel: it rides on q
+            qn = (qn * w.scale[0, :, :m.nope_dim]).astype(cfg.dtype)
+            wk = w.q[..., :m.nope_dim].astype(cfg.dtype)
+            wv = Q8(w.q[..., m.nope_dim:], w.scale[..., m.nope_dim:])
+        else:
+            wk, wv = w[..., :m.nope_dim], w[..., m.nope_dim:]
+        qc = jnp.concatenate([jnp.einsum("bhn,chn->bhc", qn, wk),
+                              q[:, 0, :, m.nope_dim:]], -1)
+    with jax.named_scope("mla.attend"):
+        scores = jnp.einsum("bhc,bsc->bhs", qc, lat).astype(_F32)
+        scores = jnp.where(valid, scores / math.sqrt(m.qk_dim), -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        ctx = jnp.einsum("bhs,bsc->bhc", probs, lat[..., :m.kv_rank])
+    with jax.named_scope("mla.absorb"):
+        return _mm("bhc,chv->bhv", ctx, wv, cfg.dtype)[:, None]
+
+
+# -- the KDA recurrence ------------------------------------------------------
+#
+# Per head, with a float32 state S (dk x dv), per-channel decay
+# a_t = exp(g_t), g_t in [lower_bound, 0], and beta_t in (0, 1):
+#     S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T
+#     o_t = S_t^T q_t
+# q, k, v come through a causal depth-wise filter (taps w[0..n-1]:
+# y_t = sum_j w[j] x_{t-(n-1)+j}) and SiLU; q and k are L2-normalised per
+# head, q scaled by dk^-1/2.
+
+def _kda_project(params: Params, cfg: TransformerConfig, l: int, h: jax.Array):
+    """Pre-filter q/k/v (B,T,3,H,d), log-decay g (B,T,H,d) and beta (B,T,H),
+    the last two in float32."""
+    p = f"l{l}.kda_"
+    with jax.named_scope("attn.qkv"):
+        qkv = jnp.stack([_mm("btD,Dhd->bthd", h, params[p + n], cfg.dtype)
+                         for n in ("wq", "wk", "wv")], axis=2)
+    with jax.named_scope("kda.gate"):
+        y = _mm("btD,Dhd->bthd", h, params[p + "wg"], cfg.dtype).astype(_F32)
+        rate = jnp.exp(params[p + "A_log"].astype(_F32))[:, None]
+        g = cfg.kda.lower_bound * jax.nn.sigmoid(
+            rate * (y + params[p + "dt_bias"].astype(_F32)))
+        beta = jax.nn.sigmoid(_mm("btD,Dh->bth", h, params[p + "wbeta"],
+                                  cfg.dtype).astype(_F32))
+    return qkv, g, beta
+
+
+def _kda_filter(params: Params, cfg: TransformerConfig, l: int,
+                window: jax.Array):
+    """``window`` (B, taps-1+T, 3, H, d): the pre-filter inputs behind the
+    taps-1 that came before them -> q, k, v (B,T,H,d) in float32."""
+    taps = cfg.kda.conv_taps
+    T = window.shape[1] - (taps - 1)
+    with jax.named_scope("kda.conv"):
+        w = jnp.stack([params[f"l{l}.kda_conv_{n}"] for n in "qkv"],
+                      axis=1).astype(_F32)                   # (taps,3,H,d)
+        win = window.astype(_F32)
+        y = jax.nn.silu(sum(win[:, j:j + T] * w[j] for j in range(taps)))
+        q, k, v = y[:, :, 0], y[:, :, 1], y[:, :, 2]
+
+        def unit(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
+
+        return unit(q) * cfg.kda.head_dim ** -0.5, unit(k), v
+
+
+def kda_chunked(q, k, v, g, beta, S0, chunk: int):
+    """The recurrence over a whole sequence, chunk by chunk (the WY / UT
+    form): inside a chunk every pair's decay is taken relative to the start
+    of the 16-token sub-block of the later one, so no exponent passes 80;
+    between chunks only the state is carried. All float32.
+    q/k (B,T,H,dk), v (B,T,H,dv), g (B,T,H,dk), beta (B,T,H), S0 (B,H,dk,dv)
+    -> o (B,T,H,dv), S_T. A position with g = 0 and beta = 0 leaves the
+    state as it was (padding)."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    C = chunk
+    sb = min(16, C)
+    if C % sb:
+        raise ValueError(f"kda chunk {C} is not a multiple of {sb}")
+    pad = (-T) % C
+    if pad:
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    n, nS = (T + pad) // C, C // sb
+
+    def chunks(a):                                   # -> (n,B,H,C,.)
+        return a.reshape(B, n, C, H, -1).transpose(1, 0, 3, 2, 4)
+
+    q, k, v, g = (chunks(a.astype(_F32)) for a in (q, k, v, g))
+    beta = chunks(beta.astype(_F32)[..., None])[..., 0]          # (n,B,H,C)
+    G = jnp.cumsum(g, axis=-2)                                   # <= 0
+    R = G[..., ::sb, :]                  # (n,B,H,nS,dk): each sub-block's first
+    rel = jnp.exp(G.reshape(n, B, H, nS, sb, dk) - R[..., None, :])   # <= 1
+    Lk = k.reshape(n, B, H, nS, sb, dk) * rel
+    Lq = q.reshape(n, B, H, nS, sb, dk) * rel
+    # k_s e^(R_i - G_s): <= 1 for s before sub-block i, <= e^75 inside it,
+    # clamped where s lies after it (those pairs are masked below).
+    Kr = k[..., None, :, :] * jnp.exp(jnp.minimum(
+        R[..., :, None, :] - G[..., None, :, :], 80.0))          # (..,nS,C,dk)
+    pair = partial(jnp.einsum, "...itc,...isc->...its", precision=_EXACT)
+    Akk = pair(Lk, Kr).reshape(n, B, H, C, C)
+    Aqk = pair(Lq, Kr).reshape(n, B, H, C, C)
+    t_, s_ = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    Ab = jnp.where(s_ < t_, Akk, 0.0) * beta[..., None, :]
+    Aq = jnp.where(s_ <= t_, Aqk, 0.0) * beta[..., None, :]
+    decay = jnp.exp(G)
+    # (I + Ab) [U0 | W] = [V | K e^G]: u_t = U0_t - W_t S_0
+    sol = jax.scipy.linalg.solve_triangular(
+        jnp.eye(C, dtype=_F32) + Ab, jnp.concatenate([v, k * decay], -1),
+        lower=True, unit_diagonal=True)
+    U0, W = sol[..., :dv], sol[..., dv:]
+    last = G[..., -1:, :]
+    Kb = k * jnp.exp(last - G) * beta[..., None]
+    mm = partial(jnp.einsum, precision=_EXACT)
+
+    def step(S, xs):
+        U0c, Wc, Qc, Aqc, Kbc, dc = xs
+        U = U0c - mm("bhck,bhkv->bhcv", Wc, S)
+        O = mm("bhck,bhkv->bhcv", Qc, S) + mm("bhcs,bhsv->bhcv", Aqc, U)
+        return dc[..., None] * S + mm("bhck,bhcv->bhkv", Kbc, U), O
+
+    S, O = jax.lax.scan(step, S0.astype(_F32),
+                        (U0, W, q * decay, Aq, Kb, decay[..., -1, :]))
+    o = O.transpose(1, 0, 3, 2, 4).reshape(B, n * C, H, dv)
+    return o[:, :T], S
+
+
+def kda_step(q, k, v, g, beta, S):
+    """One token of the recurrence: q/k (B,H,dk), v (B,H,dv), g (B,H,dk),
+    beta (B,H), S (B,H,dk,dv) float32 -> o (B,H,dv), S'."""
+    mm = partial(jnp.einsum, precision=_EXACT)
+    S = S * jnp.exp(g)[..., None]
+    u = v - mm("bhkv,bhk->bhv", S, k)
+    S = S + (beta[..., None] * k)[..., None] * u[..., None, :]
+    return mm("bhkv,bhk->bhv", S, q), S
+
+
+def _kda_mix(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
+             h: jax.Array, S: jax.Array, tail: jax.Array,
+             real: Optional[jax.Array]):
+    """The KDA mixer on its residual. ``S`` (B,H,dk,dv) float32 and ``tail``
+    (B,taps-1,3,H,d: the last pre-filter inputs) are the row's state coming
+    in; returns (x', S', tail'). T == 1 is the decode step; longer inputs run
+    chunked, and ``real`` (B,T) marks the tokens that count (padding, left or
+    right, leaves the state and the tail alone)."""
+    B, T, _ = h.shape
+    qkv, g, beta = _kda_project(params, cfg, l, h)
+    if T > 1 and real is not None:
+        qkv = jnp.where(real[:, :, None, None, None], qkv, 0)
+        g = jnp.where(real[:, :, None, None], g, 0.0)
+        beta = jnp.where(real[:, :, None], beta, 0.0)
+    window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
+    q, k, v = _kda_filter(params, cfg, l, window)
+    keep = cfg.kda.conv_taps - 1
+    if T == 1:
+        with jax.named_scope("kda.step"):
+            o, S = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], S)
+            o, tail = o[:, None], window[:, 1:]
+    else:
+        with jax.named_scope("kda.chunk"):
+            o, S = kda_chunked(q, k, v, g, beta, S, cfg.kda.chunk)
+            # the inputs that precede the position after the last real token
+            end = (jnp.full((B,), T, jnp.int32) if real is None else jnp.max(
+                jnp.where(real, jnp.arange(T) + 1, 0), axis=1))
+            tail = jax.vmap(lambda w_, e: jax.lax.dynamic_slice_in_dim(
+                w_, e, keep, 0))(window, end)
+    with jax.named_scope("attn.out"):
+        o = (o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
+                               + cfg.rms_eps)).astype(cfg.dtype) \
+            * params[f"l{l}.kda_onorm"]
+    return _head_gate_out(params, cfg, l, "kda", x, h, o), S, tail
+
+
+# -- feed-forward kinds ------------------------------------------------------
+
+MOE_STATS = ("picks", "picks_held", "experts_touched", "load_max")
+
+
+def _dense_mlp(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
+               act, names=("w_gate", "w_up", "w_down"),
+               scope: str = "mlp") -> jax.Array:
+    """The gated MLP on its residual (the shared expert is one too)."""
+    with jax.named_scope(scope):
+        h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps)
+        gate = act(_mm("btD,DF->btF", h2, params[f"l{l}.{names[0]}"], cfg.dtype))
+        up = _mm("btD,DF->btF", h2, params[f"l{l}.{names[1]}"], cfg.dtype)
+        return x + _mm("btF,FD->btD", gate * up, params[f"l{l}.{names[2]}"],
+                       cfg.dtype)
+
+
+def moe_route(router, bias, xf: jax.Array, m: MoEConfig):
+    """Every token's ``top_k`` experts over ALL ``n_experts`` and their
+    weights, in float32: scores sigmoid(x W_r); the choice is made on score +
+    bias, group-limited (groups scored by the sum of their two best, the best
+    ``topk_group`` kept); the weights are the chosen scores (without the
+    bias) normalised over the choice, times ``routed_scale``.
+    xf (N,D) -> idx (N,top_k) int32, w (N,top_k) float32."""
+    N = xf.shape[0]
+    s = jax.nn.sigmoid(jnp.dot(xf.astype(_F32), router.astype(_F32),
+                               precision=_EXACT))
+    grp = (s + bias.astype(_F32)).reshape(N, m.n_group, -1)
+    best = jnp.sum(jax.lax.top_k(grp, 2)[0], -1)
+    kept = jax.lax.top_k(best, m.topk_group)[1]
+    keep = jnp.zeros((N, m.n_group), bool).at[
+        jnp.arange(N)[:, None], kept].set(True)
+    idx = jax.lax.top_k(jnp.where(keep[:, :, None], grp, -jnp.inf)
+                        .reshape(N, -1), m.top_k)[1]
+    chosen = jnp.take_along_axis(s, idx, axis=1)
+    return idx.astype(jnp.int32), m.routed_scale * chosen / jnp.sum(
+        chosen, -1, keepdims=True)
+
+
+def _expert(w, e):
+    """Expert ``e``'s matrix of a stacked (E, in, out) weight, int8 or not."""
+    pick = partial(jax.lax.dynamic_index_in_dim, index=e, axis=0,
+                   keepdims=False)
+    return Q8(pick(w.q), pick(w.scale)) if isinstance(w, Q8) else pick(w)
+
+
+def moe_held_experts(wg, wu, wd, xf: jax.Array, local: jax.Array,
+                     held: jax.Array, dtype):
+    """What the held experts give their picks: a grouped product over the
+    picks sorted by expert, one tile of rows and ONE expert's weights a
+    loop step, as many steps as the load needs (so no pick is dropped
+    whatever the imbalance, and an expert nobody chose is never read).
+    xf (N,D); local (N,K) expert index among the held; held (N,K) bool.
+    Returns (per-pick output (N,K,D), zero where not held; tokens per held
+    expert (E,) int32)."""
+    N, K = local.shape
+    E, D = (wg.q if isinstance(wg, Q8) else wg).shape[0], xf.shape[1]
+    M = N * K
+    tile = 16 if N <= 16 else 64
+    flat = jnp.where(held, local, E).reshape(M)       # not held sorts last
+    order = jnp.argsort(flat, stable=True)
+    counts = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+    tiles = (counts + tile - 1) // tile
+    tiles_before = jnp.cumsum(tiles) - tiles
+    rows_before = jnp.cumsum(counts) - counts
+    xs = jnp.pad(xf[order // K], ((0, tile), (0, 0)))
+
+    def body(j, out):
+        e = jnp.sum(tiles_before <= j) - 1
+        r = j - tiles_before[e]
+        at = rows_before[e] + r * tile
+        rows = jax.lax.dynamic_slice_in_dim(xs, at, tile, 0)
+        hid = jax.nn.silu(_mm("nD,DF->nF", rows, _expert(wg, e), dtype)) \
+            * _mm("nD,DF->nF", rows, _expert(wu, e), dtype)
+        res = _mm("nF,FD->nD", hid, _expert(wd, e), dtype)
+        # rows past this expert's own are the next one's: written as zeros
+        # here and over again by the tile that owns them
+        res = jnp.where(jnp.arange(tile)[:, None] < counts[e] - r * tile,
+                        res, 0)
+        return jax.lax.dynamic_update_slice_in_dim(out, res, at, 0)
+
+    out = jax.lax.fori_loop(0, jnp.sum(tiles), body,
+                            jnp.zeros((M + tile, D), dtype))
+    back = jnp.zeros((M,), jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
+    return out[back].reshape(N, K, D), counts
+
+
+def _experts_ffn(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
+                 act, live: Optional[jax.Array]):
+    """The expert layer on its residual: ``x + sum over the token's picks
+    that land on HELD experts of w_e E_e(norm(x)) + E_shared(norm(x))``.
+    ``live`` (B,T) marks the tokens whose picks count (padding and idle rows
+    are routed nowhere, so they read no expert). Returns (x', stats):
+    picks made, picks on held experts, distinct held experts touched, the
+    busiest held expert's tokens — int32 scalars."""
+    m = cfg.moe
+    B, T, D = x.shape
+    p = f"l{l}.moe_"
+    h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps).reshape(B * T, D)
+    with jax.named_scope("moe.route"):
+        idx, w = moe_route(params[p + "router"], params[p + "bias"], h2, m)
+        local = idx - m.held_start
+        held = (local >= 0) & (local < m.held)
+        if live is not None:
+            held &= live.reshape(B * T, 1)
+    with jax.named_scope("moe.experts"):
+        per_pick, counts = moe_held_experts(
+            params[p + "wg"], params[p + "wu"], params[p + "wd"], h2,
+            local, held, cfg.dtype)
+        routed = jnp.sum(per_pick.astype(_F32) * jnp.where(held, w, 0.0)[..., None],
+                         axis=1).astype(cfg.dtype).reshape(B, T, D)
+    n_live = (jnp.int32(B * T) if live is None
+              else jnp.sum(live.astype(jnp.int32)))
+    stats = {"picks": n_live * m.top_k,
+             "picks_held": jnp.sum(held.astype(jnp.int32)),
+             "experts_touched": jnp.sum((counts > 0).astype(jnp.int32)),
+             "load_max": jnp.max(counts)}
+    y = _dense_mlp(params, cfg, l, x, act, ("moe_sg", "moe_su", "moe_sd"),
+                   "moe.shared")
+    return y + routed, stats
+
+
+def _ffn(params: Params, cfg: TransformerConfig, l: int, x: jax.Array, act,
+         live: Optional[jax.Array], stats: Dict[str, jax.Array]):
+    """Layer ``l``'s feed-forward by its kind; an expert layer's counters
+    are added into ``stats`` (which stays {} for a model without one)."""
+    if cfg.kinds[l][1] == "dense":
+        return _dense_mlp(params, cfg, l, x, act), stats
+    x, new = _experts_ffn(params, cfg, l, x, act, live)
+    return x, {k: stats.get(k, 0) + v for k, v in new.items()}
+
+
+def _act(cfg: TransformerConfig):
+    return jax.nn.silu if cfg.activation == "silu" else partial(
+        jax.nn.gelu, approximate=True)
+
+
+def init_state(cfg: TransformerConfig, batch: int) -> Dict[str, jax.Array]:
+    """A fixed block per row for every ``kda`` layer: the float32 state and
+    the filter's tail. Zeros are the state before the first token."""
+    out: Dict[str, jax.Array] = {}
+    for l, (mixer, _) in enumerate(cfg.kinds):
+        if mixer == "kda":
+            k = cfg.kda
+            out[f"l{l}.S"] = jnp.zeros(
+                (batch, k.n_heads, k.head_dim, k.head_dim), _F32)
+            out[f"l{l}.tail"] = jnp.zeros(
+                (batch, k.conv_taps - 1, 3, k.n_heads, k.head_dim), cfg.dtype)
+    return out
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def restore_slot_state(state: Dict[str, jax.Array],
+                       snapshot: Dict[str, jax.Array],
+                       slot: jax.Array) -> Dict[str, jax.Array]:
+    """Copy a one-row state snapshot (the shared preamble's, or zeros) into
+    row ``slot`` of every state array: what admission does for the layers
+    that keep a recurrent state, beside mapping pages for those that page."""
+    with jax.named_scope("state.restore"):
+        return {name: jax.lax.dynamic_update_slice_in_dim(
+                    arr, snapshot[name].astype(arr.dtype), slot, 0)
+                for name, arr in state.items()}
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+
+
+def _decode_mask(T: int, S: int, cache_len, valid_from):
+    """(T,S), or (B,T,S) with ``valid_from`` (B,): key j is visible to the
+    query at cache_len + t where j <= cache_len + t (causal within the
+    appended block) and j is not one of the row's left-pad slots. Each
+    query's OWN slot stays visible even in the pad region: a fully-masked
+    row softmaxes to NaN, and NaN values poison later layers through
+    0-weighted (0 * NaN) attention sums. Pad-query outputs are
+    garbage-but-finite and never read."""
+    at = (cache_len + jnp.arange(T))[:, None]
+    valid = jnp.arange(S)[None, :] <= at
+    if valid_from is None:
+        return valid
+    return ((valid[None] & (jnp.arange(S)[None, None, :]
+                            >= valid_from[:, None, None]))
+            | (jnp.arange(S)[None, :] == at)[None])
+
+
+def _hybrid_mixer(params: Params, cfg: TransformerConfig, l: int,
+                  x: jax.Array, h: jax.Array, positions: jax.Array,
+                  kv_cache, cache_len, valid_from, real):
+    """``forward``'s ``mla`` / ``kda`` layer: (x', what the layer writes to
+    the cache). Without a cache a sequence starts from the zero state."""
+    B, T, _ = h.shape
+    if cfg.kinds[l][0] == "kda":
+        if kv_cache is None:
+            st = init_state(cfg, B)
+            S, tail = st[f"l{l}.S"], st[f"l{l}.tail"]
+        else:
+            S, tail = kv_cache[f"l{l}.S"], kv_cache[f"l{l}.tail"]
+        x, S, tail = _kda_mix(params, cfg, l, x, h, S, tail, real)
+        return x, {f"l{l}.S": S, f"l{l}.tail": tail}
+    q, lat = _mla_project(params, cfg, l, h, positions)
+    if kv_cache is None:
+        o = _mla_expanded(params, cfg, l, q, lat,
+                          jnp.tril(jnp.ones((T, T), bool)))
+        return _head_gate_out(params, cfg, l, "mla", x, h, o), {}
+    view = jax.lax.dynamic_update_slice(kv_cache[f"l{l}.c"], lat,
+                                        (0, cache_len, 0, 0))
+    mask = _decode_mask(T, view.shape[1], cache_len, valid_from)
+    if T == 1:
+        mask3 = jnp.broadcast_to(mask if mask.ndim == 3 else mask[None],
+                                 (B, 1, view.shape[1]))
+        o = _mla_absorbed(params, cfg, l, q, view, mask3)
+    else:
+        o = _mla_expanded(params, cfg, l, q, view, mask)
+    return _head_gate_out(params, cfg, l, "mla", x, h, o), {f"l{l}.c": view}
+
 
 def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
             *, positions: Optional[jax.Array] = None,
@@ -707,11 +1324,26 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     if cfg.embed_scale != 1.0:  # Gemma scales embeddings by sqrt(D)
         x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
     new_cache: Optional[Dict[str, jax.Array]] = {} if kv_cache is not None else None
-    act = jax.nn.silu if cfg.activation == "silu" else partial(
-        jax.nn.gelu, approximate=True)
+    act = _act(cfg)
+    hybrid = cfg.layer_kinds is not None
+    if hybrid and seq_mesh is not None:
+        raise NotImplementedError(
+            "sequence parallelism covers ('attention', 'dense') layers only")
+    # left-padded rows (valid_from): the tokens a recurrence or a router may
+    # count; None = all of them
+    real = None
+    if hybrid and valid_from is not None and kv_cache is not None:
+        real = (cache_len + jnp.arange(T))[None, :] >= valid_from[:, None]
 
-    for l in range(cfg.n_layers):
+    for l, (mixer, ffn) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
+        if mixer != "attention":
+            x, upd = _hybrid_mixer(params, cfg, l, x, h, positions, kv_cache,
+                                   cache_len, valid_from, real)
+            if new_cache is not None:
+                new_cache.update(upd)
+            x, _ = _ffn(params, cfg, l, x, act, real, {})
+            continue
         q = _mm("btD,Dhd->bthd", h, params[f"l{l}.wq"], cfg.dtype)
         k = _mm("btD,Dhd->bthd", h, params[f"l{l}.wk"], cfg.dtype)
         v = _mm("btD,Dhd->bthd", h, params[f"l{l}.wv"], cfg.dtype)
@@ -726,20 +1358,7 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
             cv = jax.lax.dynamic_update_slice(
                 kv_cache[f"l{l}.v"], v, (0, cache_len, 0, 0))
             new_cache[f"l{l}.k"], new_cache[f"l{l}.v"] = ck, cv
-            S = ck.shape[1]
-            # causal within the appended block: row t sees keys <= cache_len+t
-            valid = jnp.arange(S)[None, :] <= (cache_len + jnp.arange(T))[:, None]
-            if valid_from is not None:  # (B,): left-pad slots are not real
-                # Keep each query's OWN slot visible even in the pad region:
-                # a fully-masked row softmaxes to NaN, and NaN values poison
-                # later layers through 0-weighted (0 * NaN) attention sums.
-                # Pad-query outputs are garbage-but-finite and never read.
-                own = (jnp.arange(S)[None, :]
-                       == (cache_len + jnp.arange(T))[:, None])  # (T, S)
-                valid = ((valid[None]
-                          & (jnp.arange(S)[None, None, :]
-                             >= valid_from[:, None, None]))
-                         | own[None])
+            valid = _decode_mask(T, ck.shape[1], cache_len, valid_from)
             attn = _attend(q, ck, cv, valid)
         elif seq_mesh is not None:
             # On a (data, seq) training mesh the batch dim rides the data
@@ -756,6 +1375,9 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
             attn = causal_attention(q, k, v, use_flash)
 
         x = x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
+        if ffn == "experts":
+            x, _ = _ffn(params, cfg, l, x, act, real, {})
+            continue
         h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps)
         gate = act(_mm("btD,DF->btF", h2, params[f"l{l}.w_gate"], cfg.dtype))
         up = _mm("btD,DF->btF", h2, params[f"l{l}.w_up"], cfg.dtype)
@@ -775,9 +1397,26 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     return logits, new_cache
 
 
+def _token_cache(cfg: TransformerConfig, lead: Tuple[int, int]
+                 ) -> Dict[str, jax.Array]:
+    """What grows with the tokens held, per layer kind: k/v of an
+    ``attention`` layer, the latent of an ``mla`` layer (one "head" of
+    kv_rank + rope values), nothing of a ``kda`` layer."""
+    out: Dict[str, jax.Array] = {}
+    for l, (mixer, _) in enumerate(cfg.kinds):
+        if mixer == "attention":
+            for t in ("k", "v"):
+                out[f"l{l}.{t}"] = jnp.zeros(
+                    lead + (cfg.kv_heads, cfg.head_dim), cfg.dtype)
+        elif mixer == "mla":
+            out[f"l{l}.c"] = jnp.zeros(lead + (1, cfg.mla.latent_dim), cfg.dtype)
+    return out
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict[str, jax.Array]:
-    return {f"l{l}.{t}": jnp.zeros((batch, max_len, cfg.kv_heads, cfg.head_dim), cfg.dtype)
-            for l in range(cfg.n_layers) for t in ("k", "v")}
+    """A contiguous cache: (batch, max_len, ...) per token-cache entry, and
+    each row's recurrent state (``init_state``) beside them."""
+    return {**_token_cache(cfg, (batch, max_len)), **init_state(cfg, batch)}
 
 
 # ---------------------------------------------------------------------------
@@ -825,18 +1464,28 @@ def _qkv(params: Params, cfg: TransformerConfig, l: int, h: jax.Array,
     return q, k, v
 
 
-def _attn_out_mlp(params: Params, cfg: TransformerConfig, l: int,
-                  x: jax.Array, attn: jax.Array, act) -> jax.Array:
-    """Layer ``l`` after attention: output projection, then the gated MLP,
-    each on its residual."""
+def _attn_out(params: Params, cfg: TransformerConfig, l: int,
+              x: jax.Array, attn: jax.Array) -> jax.Array:
+    """Layer ``l``'s attention output projection on its residual."""
     with jax.named_scope("attn.out"):
-        x = x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
-    with jax.named_scope("mlp"):
-        h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps)
-        gate = act(_mm("btD,DF->btF", h2, params[f"l{l}.w_gate"], cfg.dtype))
-        up = _mm("btD,DF->btF", h2, params[f"l{l}.w_up"], cfg.dtype)
-        return x + _mm("btF,FD->btD", gate * up, params[f"l{l}.w_down"],
-                       cfg.dtype)
+        return x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
+
+
+def _zero_stats(cfg: TransformerConfig) -> Dict[str, jax.Array]:
+    """The expert layers' counters at zero; {} for a model without any."""
+    return ({k: jnp.int32(0) for k in MOE_STATS} if cfg.n_expert_layers
+            else {})
+
+
+def _pack_stats(stats: Dict[str, jax.Array]) -> Optional[jax.Array]:
+    """The counters as one int32 vector in ``MOE_STATS`` order, so they cost
+    the host one small fetch; None where there are none."""
+    return jnp.stack([stats[k] for k in MOE_STATS]) if stats else None
+
+
+def _put_row(arr: jax.Array, row: jax.Array, slot: jax.Array) -> jax.Array:
+    return jax.lax.dynamic_update_slice_in_dim(arr, row.astype(arr.dtype),
+                                               slot, 0)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -852,75 +1501,119 @@ def slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     Padding-region k/v DO land in cache rows [length, Tp) — they are
     garbage, but every later read masks to [0, len] and decode overwrites
     them in order, so they are never attended. Returns
-    ``(first_token scalar int32, new_cache)`` — the first sampled token is
-    part of the row's output (same convention as ``_generate_batch_jit``:
-    sample from the prefill logits, then feed tokens back one step at a
-    time)."""
+    ``(first_token scalar int32, new_cache, stats)`` — the first sampled
+    token is part of the row's output (same convention as
+    ``_generate_batch_jit``: sample from the prefill logits, then feed tokens
+    back one step at a time); ``stats`` are the expert layers' counters
+    (one int32 vector in ``MOE_STATS`` order; None for a model without
+    expert layers). An ``mla`` layer
+    caches its latents like k/v; a ``kda`` layer starts from the zero state
+    and leaves its state at the last REAL token in the slot's row."""
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T), (B, T))
     x = _embed_rows(params["embed"], tokens, cfg.dtype)
     if cfg.embed_scale != 1.0:
         x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
-    act = jax.nn.silu if cfg.activation == "silu" else partial(
-        jax.nn.gelu, approximate=True)
+    act = _act(cfg)
+    # the tokens a recurrence or a router counts (right padding does not)
+    real = (jnp.arange(T) < length)[None] if cfg.layer_kinds else None
     new_cache: Dict[str, jax.Array] = {}
-    for l in range(cfg.n_layers):
+    stats = _zero_stats(cfg)
+    for l, (mixer, _) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
-        q, k, v = _qkv(params, cfg, l, h, positions)
-        # Write this prompt's k/v into the slot's cache rows. Right-padded
-        # overhang is masked by length everywhere downstream.
-        new_cache[f"l{l}.k"] = jax.lax.dynamic_update_slice(
-            kv_cache[f"l{l}.k"], k, (slot, 0, 0, 0))
-        new_cache[f"l{l}.v"] = jax.lax.dynamic_update_slice(
-            kv_cache[f"l{l}.v"], v, (slot, 0, 0, 0))
-        # Causal attention over the prompt itself (padded queries attend
-        # real+pad keys at or below their position — garbage-but-finite,
-        # and only the length-1 position is ever read).
-        attn = causal_attention(q, k, v, use_flash=False)
-        x = _attn_out_mlp(params, cfg, l, x, attn, act)
+        if mixer == "attention":
+            q, k, v = _qkv(params, cfg, l, h, positions)
+            # Write this prompt's k/v into the slot's cache rows.
+            # Right-padded overhang is masked by length everywhere downstream.
+            new_cache[f"l{l}.k"] = jax.lax.dynamic_update_slice(
+                kv_cache[f"l{l}.k"], k, (slot, 0, 0, 0))
+            new_cache[f"l{l}.v"] = jax.lax.dynamic_update_slice(
+                kv_cache[f"l{l}.v"], v, (slot, 0, 0, 0))
+            # Causal attention over the prompt itself (padded queries attend
+            # real+pad keys at or below their position — garbage-but-finite,
+            # and only the length-1 position is ever read).
+            attn = causal_attention(q, k, v, use_flash=False)
+            x = _attn_out(params, cfg, l, x, attn)
+        elif mixer == "mla":
+            q, lat = _mla_project(params, cfg, l, h, positions)
+            new_cache[f"l{l}.c"] = jax.lax.dynamic_update_slice(
+                kv_cache[f"l{l}.c"], lat, (slot, 0, 0, 0))
+            o = _mla_expanded(params, cfg, l, q, lat,
+                              jnp.tril(jnp.ones((T, T), bool)))
+            x = _head_gate_out(params, cfg, l, "mla", x, h, o)
+        else:                      # a fresh row starts from the zero state
+            zero = init_state(cfg, B)
+            x, S, tail = _kda_mix(params, cfg, l, x, h, zero[f"l{l}.S"],
+                                  zero[f"l{l}.tail"], real)
+            new_cache[f"l{l}.S"] = _put_row(kv_cache[f"l{l}.S"], S, slot)
+            new_cache[f"l{l}.tail"] = _put_row(kv_cache[f"l{l}.tail"], tail,
+                                               slot)
+        x, stats = _ffn(params, cfg, l, x, act, real, stats)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)
     # Logits at the LAST REAL position only (length-1; right padding means
     # it is not at Tp-1) — full (Tp, V) logits would pay T times the head.
     x_last = jax.lax.dynamic_slice_in_dim(x[0], length - 1, 1, 0)  # (1, D)
     logits = _logits_head(x_last, params, cfg)                     # (1, V)
     tok = _sample_token(temperature, logits, rng)
-    return tok[0], new_cache
+    return tok[0], new_cache, _pack_stats(stats)
 
 
 def _slot_step_math(params: Params, cfg: TransformerConfig,
                     kv_cache: Dict[str, jax.Array], tokens: jax.Array,
                     lens: jax.Array, temperature: jax.Array,
-                    step_key: jax.Array) -> Tuple[jax.Array, Dict]:
+                    step_key: jax.Array, live: Optional[jax.Array] = None,
+                    ) -> Tuple[jax.Array, Dict, Dict]:
     """The shared single-step math of the slot pool: feed (B,) tokens,
-    scatter their k/v at per-slot index ``lens[b]``, attend each row over
-    its own prefix [0, lens[b]], sample (B,) next tokens (per-slot
-    temperature: greedy rows argmax, sampled rows draw from
-    (key, row) — a slot's stream never depends on its neighbors)."""
+    scatter their k/v (an ``mla`` layer's latent) at per-slot index
+    ``lens[b]``, attend each row over its own prefix [0, lens[b]], advance
+    each ``kda`` layer's per-row state one token, sample (B,) next tokens
+    (per-slot temperature: greedy rows argmax, sampled rows draw from
+    (key, row) — a slot's stream never depends on its neighbors). ``live``
+    (B,) marks the rows that decode: an expert layer routes the others
+    nowhere. Returns (tokens, new cache, the expert layers' counters)."""
     B = tokens.shape[0]
     positions = lens[:, None]                                   # (B, 1)
     x = _embed_rows(params["embed"], tokens[:, None], cfg.dtype)
     if cfg.embed_scale != 1.0:
         x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
-    act = jax.nn.silu if cfg.activation == "silu" else partial(
-        jax.nn.gelu, approximate=True)
+    act = _act(cfg)
     rows = jnp.arange(B)
+    live = None if live is None else live[:, None]
     new_cache: Dict[str, jax.Array] = {}
-    for l in range(cfg.n_layers):
+    stats = _zero_stats(cfg)
+    for l, (mixer, _) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
-        q, k, v = _qkv(params, cfg, l, h, positions)
+        if mixer == "kda":
+            x, S, tail = _kda_mix(params, cfg, l, x, h, kv_cache[f"l{l}.S"],
+                                  kv_cache[f"l{l}.tail"], None)
+            new_cache[f"l{l}.S"], new_cache[f"l{l}.tail"] = S, tail
+            x, stats = _ffn(params, cfg, l, x, act, live, stats)
+            continue
+        if mixer == "attention":
+            q, k, v = _qkv(params, cfg, l, h, positions)
+        else:
+            q, lat = _mla_project(params, cfg, l, h, positions)
         # Per-slot append: row b writes at its own lens[b] (a scatter —
         # the whole point of slots is rows sitting at different lengths).
         with jax.named_scope("kv.append"):
-            ck = kv_cache[f"l{l}.k"].at[rows, lens].set(k[:, 0])
-            cv = kv_cache[f"l{l}.v"].at[rows, lens].set(v[:, 0])
-        new_cache[f"l{l}.k"], new_cache[f"l{l}.v"] = ck, cv
+            if mixer == "attention":
+                ck = kv_cache[f"l{l}.k"].at[rows, lens].set(k[:, 0])
+                cv = kv_cache[f"l{l}.v"].at[rows, lens].set(v[:, 0])
+                new_cache[f"l{l}.k"], new_cache[f"l{l}.v"] = ck, cv
+            else:
+                ck = kv_cache[f"l{l}.c"].at[rows, lens].set(lat[:, 0])
+                new_cache[f"l{l}.c"] = ck
         S = ck.shape[1]
         # Row b attends its own prefix [0, lens[b]] (the appended token's
         # own slot included — never a fully-masked row, so no NaN).
         valid = (jnp.arange(S)[None, None, :]
                  <= lens[:, None, None])                        # (B, 1, S)
-        attn = _attend(q, ck, cv, valid)
-        x = _attn_out_mlp(params, cfg, l, x, attn, act)
+        if mixer == "attention":
+            x = _attn_out(params, cfg, l, x, _attend(q, ck, cv, valid))
+        else:
+            x = _head_gate_out(params, cfg, l, "mla", x, h,
+                               _mla_absorbed(params, cfg, l, q, ck, valid))
+        x, stats = _ffn(params, cfg, l, x, act, live, stats)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)[:, 0]          # (B, D)
     logits = _logits_head(x, params, cfg)                       # (B, V)
     with jax.named_scope("sample"):
@@ -931,7 +1624,7 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
             row_keys, scaled)
         tok = jnp.where(temperature <= 1e-6, greedy,
                         drawn).astype(jnp.int32)
-    return tok, new_cache
+    return tok, new_cache, stats
 
 
 def _slot_window_loop(params: Params, tokens: jax.Array, lens: jax.Array,
@@ -944,19 +1637,24 @@ def _slot_window_loop(params: Params, tokens: jax.Array, lens: jax.Array,
     shared VERBATIM by the contiguous pool (`slot_decode_window`) and the
     paged pool (`paged_decode_window`, which gathers its pages into exactly
     this layout first). One body means the two paths are bit-equal by
-    construction, not by test luck."""
+    construction, not by test luck. ``kv_cache`` also carries each row's
+    recurrent state where the model keeps one (``init_state``); the last
+    result is the expert layers' counters summed over the window's steps
+    (one int32 vector in ``MOE_STATS`` order; None without expert layers)."""
     B = tokens.shape[0]
     out0 = jnp.full((B, steps), cfg.EOS, jnp.int32)
+    routed = bool(cfg.n_expert_layers)
 
     def cond(carry):
-        i, _, _, act, _, _, _, _ = carry
+        i, _, _, act, _, _, _, _, _ = carry
         return (i < steps) & jnp.any(act)
 
     def body(carry):
-        i, last, lens_c, act_c, rem, cache, out, n_act = carry
-        tok, cache = _slot_step_math(params, cfg, cache, last, lens_c,
-                                     temperature,
-                                     jax.random.fold_in(rng, i))
+        i, last, lens_c, act_c, rem, cache, out, n_act, stats = carry
+        tok, cache, new = _slot_step_math(
+            params, cfg, cache, last, lens_c, temperature,
+            jax.random.fold_in(rng, i), act_c if routed else None)
+        stats = {k: stats[k] + new[k] for k in stats}
         # Rows active this step wrote their fed token's k/v at lens.
         lens_c = lens_c + act_c.astype(jnp.int32)
         n_act = n_act + jnp.sum(act_c.astype(jnp.int32))
@@ -964,13 +1662,13 @@ def _slot_window_loop(params: Params, tokens: jax.Array, lens: jax.Array,
         out = jax.lax.dynamic_update_slice(out, tok[:, None], (0, i))
         rem = rem - act_c.astype(jnp.int32)
         act_c = act_c & (tok != cfg.EOS) & (rem > 0)
-        return i + 1, tok, lens_c, act_c, rem, cache, out, n_act
+        return i + 1, tok, lens_c, act_c, rem, cache, out, n_act, stats
 
     carry = (jnp.int32(0), tokens, lens, active, remaining, kv_cache, out0,
-             jnp.int32(0))
-    i, _, new_lens, _, _, new_cache, out, n_act = jax.lax.while_loop(
+             jnp.int32(0), _zero_stats(cfg))
+    i, _, new_lens, _, _, new_cache, out, n_act, stats = jax.lax.while_loop(
         cond, body, carry)
-    return out, new_lens, i, n_act, new_cache
+    return out, new_lens, i, n_act, new_cache, _pack_stats(stats)
 
 
 @partial(jax.jit, static_argnames=("cfg", "steps"))
@@ -995,7 +1693,7 @@ def slot_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
     freeze rule — and the loop exits early once every row froze.
 
     Returns ``(out (B, steps) EOS-padded, new_lens, steps_run,
-    active_row_steps, new_cache)``; the host appends each row's tokens
+    active_row_steps, new_cache, stats)``; the host appends each row's tokens
     column-by-column under the same freeze rule, so host and device agree
     bit-for-bit, and steps_run/active_row_steps feed the occupancy
     accounting."""
@@ -1024,12 +1722,12 @@ def slot_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
 
 def init_kv_pages(cfg: TransformerConfig, num_pages: int,
                   page_size: int) -> Dict[str, jax.Array]:
-    """The paged twin of ``init_cache``: a flat block pool per layer/tensor.
-    Page ids index the leading axis; a slot's logical position p lives at
-    ``(table[p // page_size], p % page_size)``."""
-    return {f"l{l}.{t}": jnp.zeros(
-                (num_pages, page_size, cfg.kv_heads, cfg.head_dim), cfg.dtype)
-            for l in range(cfg.n_layers) for t in ("k", "v")}
+    """The paged twin of ``init_cache``: a flat block pool per layer/tensor
+    for the layers that cache per token (``attention``: k and v; ``mla``: the
+    latent). Page ids index the leading axis; a slot's logical position p
+    lives at ``(table[p // page_size], p % page_size)``. A ``kda`` layer pages
+    nothing: its per-row state is ``init_state``'s."""
+    return _token_cache(cfg, (num_pages, page_size))
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -1062,7 +1760,9 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
                        cfg: TransformerConfig,
                        kv_pages: Dict[str, jax.Array], table_row: jax.Array,
                        temperature: jax.Array, rng: jax.Array,
-                       prefix_len: int):
+                       prefix_len: int,
+                       state: Optional[Dict[str, jax.Array]] = None,
+                       slot: Optional[jax.Array] = None):
     """Prefill ONE prompt suffix into the pages of ``table_row``.
 
     ``tokens``: (1, Ts) RIGHT-padded suffix — with shared-prefix caching the
@@ -1083,15 +1783,22 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     wise identical; attention reads [cached prefix k/v ; this suffix's
     k/v] under the same causal mask (row j attends positions <=
     prefix_len + j), and the masked tail pads with exact zeros — the
-    zero-pad width invariance the slot tests pin."""
+    zero-pad width invariance the slot tests pin.
+
+    ``state`` / ``slot``: where the model keeps a per-row recurrent state
+    (``init_state``), the pool's state arrays and the row admitted. The
+    row's block holds the state at ``prefix_len`` coming in (admission put
+    the preamble's snapshot, or zeros, there: ``restore_slot_state``) and the
+    state at the last real token going out. Returns ``(first token, pages,
+    state, stats)``: ``state`` is {} for a model without a recurrent layer,
+    ``stats`` None for one without experts."""
     B, Ts = tokens.shape
     page = next(iter(kv_pages.values())).shape[1]
     positions = jnp.broadcast_to(prefix_len + jnp.arange(Ts), (B, Ts))
     x = _embed_rows(params["embed"], tokens, cfg.dtype)
     if cfg.embed_scale != 1.0:
         x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
-    act = jax.nn.silu if cfg.activation == "silu" else partial(
-        jax.nn.gelu, approximate=True)
+    act = _act(cfg)
     # Static per-suffix-position page/offset mapping: position prefix_len+j
     # lives at (table_row[(prefix_len+j)//page], (prefix_len+j)%page).
     pos = prefix_len + jnp.arange(Ts)
@@ -1100,29 +1807,53 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     # Row j attends every resident position at or below its own.
     kv_mask = (jnp.arange(table_row.shape[0] * page)[None, :]
                <= pos[:, None])                          # (Ts, Tkv)
+    real = (pos < length)[None] if cfg.layer_kinds else None
     new_pages: Dict[str, jax.Array] = dict(kv_pages)
-    for l in range(cfg.n_layers):
+    new_state: Dict[str, jax.Array] = dict(state or {})
+    stats = _zero_stats(cfg)
+    for l, (mixer, _) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
-        q, k, v = _qkv(params, cfg, l, h, positions)
+        if mixer == "kda":
+            row = partial(jax.lax.dynamic_index_in_dim, index=slot, axis=0)
+            x, S, tail = _kda_mix(params, cfg, l, x, h,
+                                  row(new_state[f"l{l}.S"]),
+                                  row(new_state[f"l{l}.tail"]), real)
+            new_state[f"l{l}.S"] = _put_row(new_state[f"l{l}.S"], S, slot)
+            new_state[f"l{l}.tail"] = _put_row(new_state[f"l{l}.tail"], tail,
+                                               slot)
+            x, stats = _ffn(params, cfg, l, x, act, real, stats)
+            continue
+        if mixer == "attention":
+            q, k, v = _qkv(params, cfg, l, h, positions)
+            new = {"k": k, "v": v}
+        else:
+            q, lat = _mla_project(params, cfg, l, h, positions)
+            new = {"c": lat}
         # Scatter the suffix k/v into the row's own pages (pad-region
         # overhang included — garbage-but-private, masked downstream and
         # overwritten in order by decode, same as the contiguous path).
         with jax.named_scope("kv.scatter_pages"):
-            pk = new_pages[f"l{l}.k"].at[pids, offs].set(k[0])
-            pv = new_pages[f"l{l}.v"].at[pids, offs].set(v[0])
-        new_pages[f"l{l}.k"], new_pages[f"l{l}.v"] = pk, pv
+            for t, val in new.items():
+                new_pages[f"l{l}.{t}"] = \
+                    new_pages[f"l{l}.{t}"].at[pids, offs].set(val[0])
         # Gather the row's resident view: prefix pages + the suffix just
         # written. (B=1: table_row[None] is the one-row table.)
-        view = _gather_view({"k": pk, "v": pv}, table_row[None])
-        attn = _attend(q, view["k"], view["v"], kv_mask)
-        x = _attn_out_mlp(params, cfg, l, x, attn, act)
+        view = _gather_view({t: new_pages[f"l{l}.{t}"] for t in new},
+                            table_row[None])
+        if mixer == "attention":
+            x = _attn_out(params, cfg, l, x,
+                          _attend(q, view["k"], view["v"], kv_mask))
+        else:
+            x = _head_gate_out(params, cfg, l, "mla", x, h, _mla_expanded(
+                params, cfg, l, q, view["c"], kv_mask))
+        x, stats = _ffn(params, cfg, l, x, act, real, stats)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)
     # Logits at the last REAL position, suffix-local index length-1-prefix.
     x_last = jax.lax.dynamic_slice_in_dim(
         x[0], length - 1 - prefix_len, 1, 0)                       # (1, D)
     logits = _logits_head(x_last, params, cfg)                     # (1, V)
     tok = _sample_token(temperature, logits, rng)
-    return tok[0], new_pages
+    return tok[0], new_pages, new_state, _pack_stats(stats)
 
 
 @partial(jax.jit, static_argnames=("cfg", "steps", "view_len"))
@@ -1131,7 +1862,8 @@ def paged_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
                         cfg: TransformerConfig,
                         kv_pages: Dict[str, jax.Array], tables: jax.Array,
                         temperature: jax.Array, rng: jax.Array,
-                        steps: int, view_len: int):
+                        steps: int, view_len: int,
+                        state: Optional[Dict[str, jax.Array]] = None):
     """`slot_decode_window` over the paged pool: gather every slot's pages
     into the contiguous (B, view_len, Hkv, d) layout, run the IDENTICAL
     fused window loop (``_slot_window_loop``), then scatter each row's
@@ -1148,7 +1880,13 @@ def paged_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
     positions are always table-resident. Frozen/inactive rows write
     in-window garbage at their frozen ``lens`` exactly like the contiguous
     path — it is NOT scattered back (the next admit/step overwrites it
-    before any attend, so dropping it preserves bit-equality)."""
+    before any attend, so dropping it preserves bit-equality).
+
+    ``state``: the pool's per-row recurrent state (``init_state``) where the
+    model keeps one; it rides the loop beside the view and comes back whole
+    (a frozen or idle row's block holds garbage, which the next admission
+    overwrites). Returns ``(out, new_lens, steps_run, active_row_steps,
+    pages, state, stats)``."""
     B = tokens.shape[0]
     page = next(iter(kv_pages.values())).shape[1]
     n_view = tables.shape[1]
@@ -1158,9 +1896,9 @@ def paged_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
                          f"{n_view * page}]")
     view = {name: arr[:, :view_len]
             for name, arr in _gather_view(kv_pages, tables).items()}
-    out, new_lens, i, n_act, new_view = _slot_window_loop(
-        params, tokens, lens, active, remaining, cfg, view, temperature,
-        rng, steps)
+    out, new_lens, i, n_act, new_view, stats = _slot_window_loop(
+        params, tokens, lens, active, remaining, cfg,
+        {**view, **(state or {})}, temperature, rng, steps)
     # Scatter-back: row b wrote view positions [lens[b], new_lens[b]).
     rows = jnp.arange(B)
     pos = lens[:, None] + jnp.arange(steps)[None, :]               # (B, W)
@@ -1177,7 +1915,8 @@ def paged_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
         for name, arr in kv_pages.items():
             vals = new_view[name][rows[:, None], pos_c]    # (B, W, Hkv, d)
             new_pages[name] = arr.at[pids, offs].set(vals)
-    return out, new_lens, i, n_act, new_pages
+    return (out, new_lens, i, n_act, new_pages,
+            {name: new_view[name] for name in state or {}}, stats)
 
 
 # ---------------------------------------------------------------------------

@@ -80,6 +80,9 @@ STAGE_PREFILL = "prefill"    # one prompt's prefill, first token on the host
 # ``slot_iter`` contains the others, in this order.
 STAGE_SLOT_ITER = "slot_iter"
 STAGE_SLOT_ADMIT = "slot_admit"    # queue -> free slots, prefills inside
+# inside a row's ``prefill``, where the model keeps a recurrent state: the
+# preamble's snapshot (or zeros) copied into the slot's block
+STAGE_SLOT_STATE_RESTORE = "slot_state_restore"
 STAGE_SLOT_GROW = "slot_grow"      # page tables grown to cover the window
 STAGE_SLOT_LAUNCH = "slot_launch"  # arguments placed, program enqueued
 STAGE_SLOT_FETCH = "slot_fetch"    # blocked until the tokens are on the host

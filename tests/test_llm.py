@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from fraud_detection_tpu.models import llm
 from fraud_detection_tpu.models.llm import (
     ByteTokenizer,
     LanguageModel,
@@ -588,3 +589,197 @@ def test_attend_narrow_kv_matches_expanded(rep, per_row_mask, T, dtype, tol):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+
+
+# ---------------------------------------------------------------------------
+# layer kinds beside ("attention", "dense"): the tiny hybrid (tests/hybrid_tiny.py)
+# against its family's plain float32 reference
+# ---------------------------------------------------------------------------
+
+def _cached_logits(lm, toks, n_prefill):
+    """Logits of every position through the cache: one prefill of
+    ``n_prefill`` tokens, then one token a step (chunked KDA then stepped,
+    expanded MLA then absorbed, the grouped expert product at both sizes)."""
+    B, T = toks.shape
+    cfg = lm.cfg
+    prefill = jax.jit(lambda p, t, c: llm.forward(
+        p, t, cfg, kv_cache=c, cache_len=jnp.int32(0)))
+    step = jax.jit(lambda p, t, pos, c: llm.forward(
+        p, t, cfg, positions=jnp.full((B, 1), pos), kv_cache=c, cache_len=pos))
+    lg, cache = prefill(lm.params, jnp.asarray(toks[:, :n_prefill]),
+                        llm.init_cache(cfg, B, T))
+    out = [np.asarray(lg)]
+    for t in range(n_prefill, T):
+        lg, cache = step(lm.params, jnp.asarray(toks[:, t:t + 1]),
+                         jnp.int32(t), cache)
+        out.append(np.asarray(lg))
+    return np.concatenate(out, axis=1)
+
+
+@pytest.fixture(scope="module")
+def hybrid_errors():
+    """|program logits - reference logits| at every position of two
+    120-token rows (100 prefilled, 20 decoded through the cache), by
+    (dtype, weights)."""
+    import hybrid_tiny
+
+    fam = hybrid_tiny.family()
+    toks = np.random.default_rng(5).integers(0, 258, (2, 120)).astype(np.int32)
+    memo = {}
+
+    def errors(dtype, weights=None):
+        key = (dtype, weights or dtype)
+        if key not in memo:
+            ref = np.asarray(fam.reference_logits(
+                hybrid_tiny.SEED, hybrid_tiny.config(dtype), dtype, toks))
+            got = _cached_logits(hybrid_tiny.language_model(dtype, weights),
+                                 toks, 100)
+            memo[key] = np.abs(got - ref)
+        return memo[key]
+
+    return errors
+
+
+# Tolerances of the program against the reference, logits of scale ~4:
+# * float32, widest error 1e-4: the two differ in the order of float32 sums
+#   only (chunked against token-by-token recurrence, absorbed against
+#   expanded latent attention, experts by sorted tiles against one by one),
+#   over 8 layers: measured 0.9e-5 to 1.3e-5 on three seeds.
+# * bfloat16, MEDIAN error 0.055: bfloat16 rounding of every matmul's
+#   operands (measured median 0.036-0.038 on three seeds). The widest error
+#   says nothing here (1.4-3.0): a routed model's logits step wherever
+#   rounding flips an expert choice the float32 reference does not, and at 16
+#   experts of width 16 one flipped expert is a large share of a layer.
+# The weight-only int8 path of the same dtype fails each: float32 compute
+# reads a widest error of 1.6-3.2, bfloat16 a median of 0.061-0.076.
+HYBRID_F32_MAX, HYBRID_BF16_MEDIAN = 1e-4, 0.055
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_hybrid_prefill_then_cached_decode_matches_reference(hybrid_errors, dtype):
+    err = hybrid_errors(dtype)
+    if dtype == "float32":
+        assert err.max() < HYBRID_F32_MAX
+    else:
+        assert np.median(err) < HYBRID_BF16_MEDIAN
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_hybrid_int8_path_fails_the_tolerance(hybrid_errors, dtype):
+    err = hybrid_errors(dtype, "int8")
+    if dtype == "float32":
+        assert err.max() > 100 * HYBRID_F32_MAX
+    else:
+        assert np.median(err) > HYBRID_BF16_MEDIAN
+
+
+def test_hybrid_quantize_covers_every_new_matrix():
+    import hybrid_tiny
+
+    lm = hybrid_tiny.language_model("float32", "int8")
+    q8 = {n for n, w in lm.params.items() if isinstance(w, llm.Q8)}
+    full = {n.split(".", 1)[-1] for n in set(lm.params) - q8}
+    # full precision on purpose: norms, router and its bias, filters, decay
+    assert full == {"ln1", "ln2", "ln_f", "mla_kvnorm", "kda_onorm",
+                    "moe_router", "moe_bias", "kda_conv_q", "kda_conv_k",
+                    "kda_conv_v", "kda_A_log", "kda_dt_bias"}
+    held, d, f = 4, 32, 16
+    assert lm.params["l2.moe_wg"].scale.shape == (held, 1, f)   # per expert,
+    assert lm.params["l2.moe_wd"].scale.shape == (held, 1, d)   # per channel
+    assert lm.params["l5.mla_wkvb"].scale.shape == (1, 4, 16)
+
+
+@pytest.mark.parametrize("chunk,T", [(16, 40), (32, 70), (64, 64)])
+def test_kda_chunked_equals_stepped(chunk, T):
+    """The chunked (WY / UT) form of the recurrence is the token-by-token
+    step, decays from the strongest allowed (-5 a token: 1/Gamma overflows
+    float32 within one chunk unless taken per sub-block) to none, padding
+    positions (g = 0, beta = 0) leaving the state alone. Tolerance 2e-5 on
+    outputs of scale ~0.1: float32 sums in another order."""
+    rng = np.random.default_rng(chunk + T)
+    B, H, d = 2, 3, 16
+    q, k, v = (rng.standard_normal((B, T, H, d)).astype(np.float32) for _ in range(3))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * 4
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    g = -5.0 * rng.random((B, T, H, d)).astype(np.float32) ** 3
+    g[:, :, 0] = -5.0
+    beta = rng.random((B, T, H)).astype(np.float32)
+    g[1, 10:14], beta[1, 10:14] = 0.0, 0.0
+    S0 = rng.standard_normal((B, H, d, d)).astype(np.float32)
+    o, S = llm.kda_chunked(*(jnp.asarray(a) for a in (q, k, v, g, beta, S0)), chunk)
+    Sw, ow = jnp.asarray(S0), []
+    for t in range(T):
+        o_t, Sw = llm.kda_step(*(jnp.asarray(a[:, t]) for a in (q, k, v, g, beta)), Sw)
+        ow.append(o_t)
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(np.asarray(o), np.stack(ow, 1), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(S), np.asarray(Sw), atol=2e-5)
+
+
+def test_mla_absorbed_decode_equals_expanded():
+    """One query a row against the cached latents: ``kv_b`` folded into the
+    query and applied after the sum (decode) against K and V expanded per
+    cached token (prefill's path). Float32, tolerance 1e-5."""
+    import hybrid_tiny
+
+    lm = hybrid_tiny.language_model("float32")
+    cfg, l = lm.cfg, 5
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.standard_normal((3, 1, cfg.d_model)), jnp.float32)
+    lat = jnp.asarray(rng.standard_normal((3, 40, 1, cfg.mla.latent_dim)),
+                      jnp.float32)
+    valid = jnp.arange(40)[None, None, :] <= jnp.asarray([39, 7, 20])[:, None, None]
+    q, _ = llm._mla_project(lm.params, cfg, l, h, jnp.asarray([[39], [7], [20]]))
+    a = llm._mla_absorbed(lm.params, cfg, l, q, lat, valid)
+    b = llm._mla_expanded(lm.params, cfg, l, q, lat, valid)
+    assert a.shape == b.shape == (3, 1, cfg.n_heads, cfg.mla.v_dim)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+def test_expert_shares_add_up_to_the_whole_layer():
+    """The four shares' routed parts (each chip computes the picks that land
+    on its own 4 of the 16 experts) plus the shared expert counted once are
+    the uncut reference's whole expert layer. Float32, tolerance 2e-5 on
+    outputs of scale ~1."""
+    import hybrid_tiny
+
+    fam = hybrid_tiny.family()
+    whole = hybrid_tiny.config("float32", num_experts=16)
+    layer = 2                                   # the first expert layer (KDA)
+    key = jax.random.fold_in(fam._root_key(hybrid_tiny.SEED), layer)
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((1, 50, 32)),
+                    jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        w = fam._ref_weights(key, whole, "kda", "experts", jnp.float32)
+        flat = fam._rms(x, whole["rms_norm_eps"]).reshape(-1, 32)
+        want = fam._ref_experts(key, whole, "kda", jnp.float32, flat,
+                                fam._ref_choice(w, whole, flat)) \
+            + (jax.nn.silu(flat @ w["moe_sg"]) * (flat @ w["moe_su"])) @ w["moe_sd"]
+    total, held_picks = 0.0, 0
+    for first in (0, 4, 8, 12):
+        lm = hybrid_tiny.language_model(
+            "float32", expert_share={"first": first, "chips_sharing_a_layer": 4})
+        assert lm.cfg.moe.held_start == first and lm.cfg.moe.held == 4
+        y, stats = llm._experts_ffn(lm.params, lm.cfg, layer, x, jax.nn.silu, None)
+        shared = llm._dense_mlp(lm.params, lm.cfg, layer, x, jax.nn.silu,
+                                ("moe_sg", "moe_su", "moe_sd")) - x
+        total = total + (y - x - shared)        # this share's routed part
+        held_picks += int(stats["picks_held"])
+        assert int(stats["picks"]) == 50 * 4
+    assert held_picks == 50 * 4                 # every pick lands on one share
+    np.testing.assert_allclose(np.asarray(total + shared)[0], np.asarray(want),
+                               atol=2e-5)
+
+
+def test_hybrid_param_shardings_name_every_leaf():
+    import hybrid_tiny
+
+    lm = hybrid_tiny.language_model("float32")
+    mesh = model_mesh(2)
+    sh = llm.param_shardings(lm.cfg, mesh)
+    assert set(sh) == set(lm.params)
+    placed = llm.shard_params(lm.params, lm.cfg, mesh)
+    P = jax.sharding.PartitionSpec
+    assert placed["l2.moe_wg"].sharding.spec == P(None, None, MODEL_AXIS)
+    assert placed["l5.mla_wkva"].sharding.spec == P()
+    assert placed["l2.kda_wq"].sharding.spec == P(None, MODEL_AXIS, None)

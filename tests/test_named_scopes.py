@@ -53,6 +53,49 @@ def _contiguous_decode_case():
             | {"kv.append"})
 
 
+# What the layer kinds beside ("attention", "dense") add (ISSUE 29).
+HYBRID_SCOPES = {"moe.route", "moe.experts", "moe.shared", "kda.conv",
+                 "kda.gate", "mla.latent", "mla.attend"}
+NEW_SCOPES = HYBRID_SCOPES | {"kda.chunk", "kda.step", "mla.absorb",
+                              "state.restore"}
+HYBRID = llm.TransformerConfig(
+    vocab_size=300, d_model=32, n_heads=2, n_layers=3, d_ff=64, max_seq=256,
+    tie_embeddings=False,
+    layer_kinds=(("kda", "dense"), ("mla", "experts"), ("kda", "experts")),
+    mla=llm.MLAConfig(kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8),
+    kda=llm.KDAConfig(n_heads=2, head_dim=16, chunk=16),
+    moe=llm.MoEConfig(n_experts=8, top_k=2, n_group=2, topk_group=1,
+                      d_expert=16, d_shared=16, routed_scale=2.5,
+                      held_start=2, held_count=4))
+
+
+def _hybrid_inputs():
+    params = llm.init_params(jax.random.PRNGKey(0), HYBRID)
+    pages = llm.init_kv_pages(HYBRID, 12, 16)
+    tables = jnp.asarray(np.arange(12).reshape(3, 4), jnp.int32)
+    return params, pages, tables, jax.random.PRNGKey(1), llm.init_state(HYBRID, 3)
+
+
+def _hybrid_decode_case():
+    params, pages, tables, key, state = _hybrid_inputs()
+    args = (params, jnp.asarray([5, 6, 7], jnp.int32),
+            jnp.asarray([10, 20, 3], jnp.int32),
+            jnp.asarray([True, True, False]), jnp.asarray([8, 8, 0], jnp.int32),
+            HYBRID, pages, tables, jnp.zeros(3, jnp.float32), key, 4, 60, state)
+    return (llm.paged_decode_window, (5, 10, 11), args,
+            LAYER_SCOPES - {"attn.scores", "attn.values"} | HYBRID_SCOPES
+            | {"kv.append", "kda.step", "mla.absorb"})
+
+
+def _hybrid_prefill_case():
+    params, pages, tables, key, state = _hybrid_inputs()
+    tokens = jnp.asarray(np.arange(32).reshape(1, 32) % 250, jnp.int32)
+    args = (params, tokens, jnp.int32(40), HYBRID, pages, tables[0],
+            jnp.float32(0.0), key, 16, state, jnp.int32(1))
+    return (llm.paged_slot_prefill, (3, 8), args,
+            LAYER_SCOPES | HYBRID_SCOPES | {"kda.chunk"})
+
+
 def _packed_rows():
     rng = np.random.default_rng(0)
     ids = rng.integers(0, 500, (8, 16)).astype(np.int16)
@@ -89,7 +132,8 @@ def _tree_case():
 
 
 @pytest.mark.parametrize("case", [_decode_case, _contiguous_decode_case,
-                                  _prefill_case, _lr_case, _tree_case])
+                                  _prefill_case, _lr_case, _tree_case,
+                                  _hybrid_decode_case, _hybrid_prefill_case])
 def test_scopes_are_named_and_change_no_number(case, monkeypatch):
     fn, static, args, scopes = case()
     named = fn.lower(*args)
@@ -133,3 +177,26 @@ def test_slot_programs_attend_the_narrow_kv(case, view):
     B, S = view
     assert f"tensor<{B}x{S}x{CFG.kv_heads}x{CFG.head_dim}x" in text
     assert f"tensor<{B}x{S}x{CFG.n_heads}x{CFG.head_dim}x" not in text
+
+
+@pytest.mark.parametrize("case", [_decode_case, _contiguous_decode_case,
+                                  _prefill_case])
+def test_dense_programs_carry_none_of_the_hybrid_scopes(case):
+    """A model of ("attention", "dense") layers lowers to the program it
+    always was: no scope of another layer kind, no counters, no state."""
+    fn, _, args, _ = case()
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert not [s for s in NEW_SCOPES if f"/{s}" in text]
+    out = fn(*args)
+    assert out[-1] is None                       # no expert counters
+    if fn is llm.paged_decode_window:
+        assert out[-2] == {}                     # no recurrent state
+
+
+def test_state_restore_is_named():
+    state = llm.init_state(HYBRID, 3)
+    snapshot = llm.init_state(HYBRID, 1)
+    text = llm.restore_slot_state.lower(state, snapshot, jnp.int32(2)).as_text(
+        debug_info=True)
+    assert "/state.restore" in text
+    assert set(state) == {"l0.S", "l0.tail", "l2.S", "l2.tail"}

@@ -524,3 +524,69 @@ def test_serve_cli_explain_paged_validation():
         serve_main(["--model", "synthetic", "--demo", "10",
                     "--explain", "onpod-demo", "--explain-slots", "2",
                     "--explain-paged", "--explain-kv-pages", "-1"])
+
+
+# ---------------------------------------------------------------------------
+# two kinds of per-slot state under one manager (the tiny hybrid)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def hybrid_lm():
+    import hybrid_tiny
+
+    return hybrid_tiny.language_model("float32")
+
+
+def test_hybrid_pages_count_the_paging_layer_only(hybrid_lm, lm):
+    """Of the hybrid's 8 layers one pages (its latents); the 7 recurrent
+    ones hold a fixed block a slot. ``pages_needed`` / ``can_admit`` count
+    pages, so they read as a dense model's; a page's bytes are the one
+    layer's."""
+    kw = dict(prompt_width=448, max_new_tokens=16, page_size=64)
+    dec = PagedSlotDecoder(hybrid_lm, 2, **kw)
+    dense = PagedSlotDecoder(lm, 2, **kw)
+    cfg = hybrid_lm.cfg
+    assert set(dec.pages) == {"l5.c"}
+    assert dec.page_bytes == 64 * cfg.mla.latent_dim * 4
+    assert set(dec.state) == {f"l{l}.{t}" for l in (0, 1, 2, 3, 4, 6, 7)
+                              for t in ("S", "tail")}
+    state_bytes = sum(a.size * a.dtype.itemsize for a in dec.state.values())
+    assert dec.kv_bytes == dec.page_bytes * dec.total_pages + state_bytes
+    for n in (5, 64, 65, 300):
+        toks = np.arange(n, dtype=np.int32) % 250
+        assert dec.pages_needed(toks) == dense.pages_needed(toks) == -(-n // 64)
+        assert dec.can_admit(toks)
+    dec.close(), dense.close()
+    assert dec.leaked_pages == 0
+
+
+def test_hybrid_preamble_snapshot_and_cow_pages_equal_whole_prompt_prefill(hybrid_lm):
+    """Admission = the preamble's pages mapped copy-on-write + its state
+    snapshot copied into the slot's block; the suffix prefill behind both
+    lands where a prefill of the whole prompt does: the same first token and
+    the same decode, the slot's state within float32 rounding of it (the
+    chunks of the recurrence fall elsewhere)."""
+    prompt = analysis_prompts(1)[0]
+    shared = PagedSlotDecoder(hybrid_lm, 2, prompt_width=1088, max_new_tokens=8,
+                              prefix_text=shared_explain_prefix())
+    whole = PagedSlotDecoder(hybrid_lm, 2, prompt_width=1088, max_new_tokens=8)
+    toks, _ = shared.encode_prompt(prompt)
+    first = [d.prefill(1, toks, 0.0, 0) for d in (shared, whole)]
+    assert first[0] == first[1]
+    assert (shared.prefix_hits, shared.cow_copies, shared.state_restores) == (1, 1, 1)
+    assert (whole.prefix_hits, whole.cow_copies, whole.state_restores) == (0, 0, 1)
+    for name in shared.state:
+        np.testing.assert_allclose(np.asarray(shared.state[name][1], np.float32),
+                                   np.asarray(whole.state[name][1], np.float32),
+                                   atol=2e-5)
+    outs = []
+    for d in (shared, whole):
+        assert d.grow_for_window(1, len(toks), 4)
+        out, *_ = d.step(np.asarray([0, first[0]], np.int32),
+                         np.asarray([0, len(toks)], np.int32),
+                         np.asarray([False, True]), np.asarray([0, 4], np.int32),
+                         np.zeros(2, np.float32), 0, 4)
+        outs.append(out[1].tolist())
+        d.close()
+        assert d.leaked_pages == 0
+    assert outs[0] == outs[1]

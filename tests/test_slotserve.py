@@ -378,6 +378,13 @@ SLOTSERVE_BLOCK_SCHEMA = {
     "prefix_hits": (int,),
     "cow_copies": (int,),
     "kv_bytes_saved_vs_contiguous": (int,),
+    # The model's own counters (ISSUE 29): zeros for a dense model.
+    "moe_picks": (int,),
+    "moe_picks_held": (int,),
+    "moe_experts_touched": (int,),
+    "moe_prefill_load_max": (int,),
+    "moe_prefill_load_mean": (int, float),
+    "state_restores": (int,),
 }
 
 
@@ -547,3 +554,104 @@ def test_gameday_validation_rejects_bad_configs():
     with pytest.raises(ValueError, match="explain_slots must be"):
         GameDay(name="x", description="", traffic=traffic, slos=(),
                 explain_slots=0)
+
+
+# ---------------------------------------------------------------------------
+# the tiny hybrid (tests/hybrid_tiny.py): KDA state + latent pages + experts
+# through the same lane
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def hybrid_runs():
+    """Five prompts (shared preamble, three lengths) through two
+    slots, contiguous and paged, greedy, float32: per mode the tickets'
+    tokens, the snapshot before the close and the one after."""
+    import hybrid_tiny
+    from fraud_detection_tpu.explain.slotserve.service import \
+        shared_explain_prefix
+
+    lm = hybrid_tiny.language_model("float32")
+    # behind the shared preamble each prompt ENDS in its own words: a model
+    # of random weights answers to the last tokens it read
+    prompts = [shared_explain_prefix() + " Caller: read me the one-time code"
+               + " now." * (40 * (i % 3)) + f" Customer {i} hesitates: {'no' * i}"
+               for i in range(5)]
+    runs = {"lm": lm, "prompts": prompts}
+    for paged in (False, True):
+        svc = make_service(lm, slots=2, max_new_tokens=8, prompt_width=832,
+                           decode_window=4, paged=paged, wait_timeout=600.0)
+        reqs = [svc.submit(p, temperature=0.0) for p in prompts]
+        for r in reqs:
+            r.wait(600.0)
+        snap = svc.snapshot()
+        assert svc.close()
+        runs[paged] = {"prompts": [np.asarray(r.tokens) for r in reqs],
+                       "served": [np.asarray(r.out) for r in reqs],
+                       "snapshot": snap, "after": svc.snapshot()}
+    return runs
+
+
+def test_hybrid_slot_window_matches_fixed_batch_greedy(hybrid_runs):
+    """The slot programs (chunked prefill into a slot's state block, stepped
+    decode, slot reuse) emit ``_generate_batch_jit``'s greedy tokens."""
+    lm = hybrid_runs["lm"]
+    want = lm.generate_tokens_batch(
+        [lm.tokenizer.encode(p) for p in hybrid_runs["prompts"]],
+        max_new_tokens=8)
+    for got, row in zip(hybrid_runs[False]["served"], want):
+        assert got.tolist() == row[:len(got)].tolist()
+
+
+def test_hybrid_paged_equals_contiguous(hybrid_runs):
+    """Latent pages + a state snapshot of the preamble restored on admission
+    serve the tokens of whole-prompt prefills into a contiguous pool."""
+    for a, b in zip(hybrid_runs[True]["served"], hybrid_runs[False]["served"]):
+        assert a.tolist() == b.tolist()
+    assert len({tuple(a.tolist()) for a in hybrid_runs[True]["served"]}) > 1
+
+
+def test_hybrid_served_tokens_are_the_references_first_choice(hybrid_runs):
+    """Every token the paged slot lane served in float32 is the plain
+    reference's best at its position, teacher-forced (gap under 1e-4: the
+    float32 tolerance of tests/test_llm.py)."""
+    import hybrid_tiny
+
+    fam = hybrid_tiny.family()
+    run = hybrid_runs[True]
+    reqs = [{"prompt": p, "served": s}
+            for p, s in zip(run["prompts"], run["served"])]
+    gaps = fam.token_gaps(hybrid_tiny.SEED, hybrid_tiny.config("float32"),
+                          "float32", reqs, 832 + 8)
+    assert max(float(g.max()) for g in gaps) < 1e-4
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_hybrid_snapshot_counts_routing_and_restores(hybrid_runs, paged):
+    snap = hybrid_runs[paged]["snapshot"]
+    assert set(snap) == set(SLOTSERVE_BLOCK_SCHEMA)
+    for key, types in SLOTSERVE_BLOCK_SCHEMA.items():
+        assert isinstance(snap[key], types), (key, type(snap[key]))
+    assert snap["completed"] == 5
+    # 16 published experts, 4 held, top 4: one pick in four is held on average
+    assert 0 < snap["moe_picks_held"] < snap["moe_picks"]
+    assert snap["moe_picks"] % 4 == 0
+    # a decode step touches at most the 4 held experts of each of 6 layers
+    assert 0 < snap["moe_experts_touched"] <= 24 * snap["decode_steps"]
+    assert snap["moe_prefill_load_max"] >= snap["moe_prefill_load_mean"] > 0
+    # admission restores the preamble's snapshot into the slot's block where
+    # pages are mapped; the contiguous pool prefills whole prompts
+    assert snap["state_restores"] == (snap["prefills"] + 1 if paged else 0)
+    json.dumps(snap)
+
+
+def test_dense_snapshot_counters_stay_zero(lm):
+    svc = make_service(lm, slots=2, max_new_tokens=4, paged=True)
+    try:
+        svc.generate_batch(["one row"], temperature=0.0, max_tokens=4)
+        snap = svc.snapshot()
+        assert [snap[k] for k in ("moe_picks", "moe_picks_held",
+                                  "moe_experts_touched", "moe_prefill_load_max",
+                                  "moe_prefill_load_mean", "state_restores")] \
+            == [0, 0, 0, 0, 0, 0]
+    finally:
+        svc.close()
