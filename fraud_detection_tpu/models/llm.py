@@ -960,6 +960,24 @@ def _kda_filter(params: Params, cfg: TransformerConfig, l: int,
         return unit(q) * cfg.kda.head_dim ** -0.5, unit(k), v
 
 
+def _unit_lower_inverse(L):
+    """(I + L)^-1 for strictly lower-triangular L (..., m, m) by forward
+    substitution: row i of the inverse is e_i - sum_{j<i} L_ij row_j, m row
+    steps over every leading index at once (a loop, so a program holds the
+    step once). Not the product (I - L)(I + L^2)(I + L^4)...: where
+    neighbouring keys are nearly parallel L's entries sit near beta, its
+    powers grow binomially and cancel in float32."""
+    m, rows = L.shape[-1], L.ndim - 2
+    cols = jnp.arange(m)
+
+    def step(i, M):                                  # rows >= i of M are zero
+        Li = jax.lax.dynamic_index_in_dim(L, i, rows, keepdims=False)
+        row = (cols == i).astype(L.dtype) - jnp.sum(Li[..., :, None] * M, -2)
+        return jax.lax.dynamic_update_index_in_dim(M, row, i, rows)
+
+    return jax.lax.fori_loop(0, m, step, jnp.zeros_like(L))
+
+
 def kda_chunked(q, k, v, g, beta, S0, chunk: int):
     """The recurrence over a whole sequence, chunk by chunk (the WY / UT
     form): inside a chunk every pair's decay is taken relative to the start
@@ -1002,14 +1020,26 @@ def kda_chunked(q, k, v, g, beta, S0, chunk: int):
     Ab = jnp.where(s_ < t_, Akk, 0.0) * beta[..., None, :]
     Aq = jnp.where(s_ <= t_, Aqk, 0.0) * beta[..., None, :]
     decay = jnp.exp(G)
-    # (I + Ab) [U0 | W] = [V | K e^G]: u_t = U0_t - W_t S_0
-    sol = jax.scipy.linalg.solve_triangular(
-        jnp.eye(C, dtype=_F32) + Ab, jnp.concatenate([v, k * decay], -1),
-        lower=True, unit_diagonal=True)
+    mm = partial(jnp.einsum, precision=_EXACT)
+    # (I + Ab) [U0 | W] = [V | K e^G]: u_t = U0_t - W_t S_0. Solved sub-block
+    # by sub-block, X_i = D_i^-1 (RHS_i - sum_{j<i} A_ij X_j), as matrix
+    # products; no power of Ab is formed (see _unit_lower_inverse).
+    Ab = Ab.reshape(n, B, H, nS, sb, C)
+    Dinv = _unit_lower_inverse(jnp.einsum(
+        "...itis->...its", Ab.reshape(n, B, H, nS, sb, nS, sb)))
+    rhs = jnp.concatenate([v, k * decay], -1).reshape(n, B, H, nS, sb, -1)
+
+    def solve(i, X):                           # rows >= i * sb of X are zero
+        Ai, Di, ri = (jax.lax.dynamic_index_in_dim(a, i, 3, keepdims=False)
+                      for a in (Ab, Dinv, rhs))
+        Xi = mm("...ts,...sc->...tc", Di, ri - mm("...ts,...sc->...tc", Ai, X))
+        return jax.lax.dynamic_update_slice_in_dim(X, Xi, i * sb, 3)
+
+    sol = jax.lax.fori_loop(0, nS, solve,
+                            jnp.zeros((n, B, H, C, dv + dk), _F32))
     U0, W = sol[..., :dv], sol[..., dv:]
     last = G[..., -1:, :]
     Kb = k * jnp.exp(last - G) * beta[..., None]
-    mm = partial(jnp.einsum, precision=_EXACT)
 
     def step(S, xs):
         U0c, Wc, Qc, Aqc, Kbc, dc = xs

@@ -689,22 +689,38 @@ def test_hybrid_quantize_covers_every_new_matrix():
     assert lm.params["l5.mla_wkvb"].scale.shape == (1, 4, 16)
 
 
-@pytest.mark.parametrize("chunk,T", [(16, 40), (32, 70), (64, 64)])
-def test_kda_chunked_equals_stepped(chunk, T):
+@pytest.mark.parametrize("chunk,T,case", [
+    (16, 40, "random"), (32, 70, "random"), (64, 64, "random"),
+    (64, 200, "published"), (64, 150, "correlated"), (32, 70, "correlated"),
+    (64, 150, "correlated-floor"), (64, 150, "beta0")])
+def test_kda_chunked_equals_stepped(chunk, T, case):
     """The chunked (WY / UT) form of the recurrence is the token-by-token
     step, decays from the strongest allowed (-5 a token: 1/Gamma overflows
     float32 within one chunk unless taken per sub-block) to none, padding
     positions (g = 0, beta = 0) leaving the state alone. Tolerance 2e-5 on
-    outputs of scale ~0.1: float32 sums in another order."""
+    outputs of scale ~0.1: float32 sums in another order. ``published`` is
+    one row at the configuration's head width, T no multiple of the chunk;
+    ``correlated`` is what one template's prompts give the chunk's triangular
+    system (every key one unit vector plus 1e-2 of noise, beta in [0.9, 1):
+    its strictly lower part sits near beta, which a substitution solves and a
+    product of its powers does not), without decay and with every decay at
+    the floor; ``beta0`` makes the system the identity."""
     rng = np.random.default_rng(chunk + T)
-    B, H, d = 2, 3, 16
+    B, H, d = (1, 2, 128) if case == "published" else (2, 3, 16)
     q, k, v = (rng.standard_normal((B, T, H, d)).astype(np.float32) for _ in range(3))
+    if case.startswith("correlated"):
+        k = (rng.standard_normal(d) + 1e-2 * k).astype(np.float32)
     q /= np.linalg.norm(q, axis=-1, keepdims=True) * 4
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
     g = -5.0 * rng.random((B, T, H, d)).astype(np.float32) ** 3
     g[:, :, 0] = -5.0
     beta = rng.random((B, T, H)).astype(np.float32)
-    g[1, 10:14], beta[1, 10:14] = 0.0, 0.0
+    g[-1, 10:14], beta[-1, 10:14] = 0.0, 0.0
+    if case.startswith("correlated"):
+        g[:] = -5.0 if case == "correlated-floor" else 0.0
+        beta = (0.9 + 0.1 * beta).astype(np.float32)
+    if case == "beta0":
+        beta[:] = 0.0
     S0 = rng.standard_normal((B, H, d, d)).astype(np.float32)
     o, S = llm.kda_chunked(*(jnp.asarray(a) for a in (q, k, v, g, beta, S0)), chunk)
     Sw, ow = jnp.asarray(S0), []
@@ -714,6 +730,10 @@ def test_kda_chunked_equals_stepped(chunk, T):
     assert np.isfinite(np.asarray(o)).all()
     np.testing.assert_allclose(np.asarray(o), np.stack(ow, 1), atol=2e-5)
     np.testing.assert_allclose(np.asarray(S), np.asarray(Sw), atol=2e-5)
+    if case == "beta0":                   # nothing is written: S0 decayed, read
+        decayed = S0[:, None] * np.exp(np.cumsum(g, 1))[..., None]
+        np.testing.assert_allclose(
+            np.asarray(o), np.einsum("bthkv,bthk->bthv", decayed, q), atol=2e-5)
 
 
 def test_mla_absorbed_decode_equals_expanded():

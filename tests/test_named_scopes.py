@@ -5,6 +5,7 @@ outputs are the same bits.
 """
 
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -191,6 +192,20 @@ def test_dense_programs_carry_none_of_the_hybrid_scopes(case):
     assert out[-1] is None                       # no expert counters
     if fn is llm.paged_decode_window:
         assert out[-2] == {}                     # no recurrent state
+
+
+def test_hybrid_prefill_holds_no_triangular_solve():
+    """The chunk's unit-lower system is sub-block substitution and block
+    products (``kda_chunked``): the prefill program carries no triangular
+    solve under any of its spellings (``stablehlo.triangular_solve``, XLA's
+    ``triangular-solve``, jax's ``_solve_triangular``, the CPU's ``trsm``
+    custom call), and ``kda.chunk`` still names what replaced it."""
+    fn, _, args, _ = _hybrid_prefill_case()
+    lowered = fn.lower(*args)
+    assert "/kda.chunk" in lowered.as_text(debug_info=True)
+    # without the locations: they would carry this test's own name
+    assert not re.search(r"triangular[_-]solve|solve_triangular|trsm",
+                         lowered.as_text())
 
 
 def test_state_restore_is_named():
