@@ -163,21 +163,17 @@ def append_bench_trend(line: dict, path=None, *, keep: int = 500,
             "slot_expl_per_s": slotserve.get("slot_expl_per_s"),
             "fixed_expl_per_s": slotserve.get("fixed_expl_per_s"),
             "occupancy": slotserve.get("occupancy"),
-            # Paged KV pool (PR 19): the paged-vs-contiguous expl/s ratio,
-            # the HBM reduction at equal slots, and the prefix-prefill
-            # token savings — the three paging headlines, trended.
+            # Page pool (PR 19) on the shared-preamble workload: its rate
+            # and the prefix-prefill token savings, trended.
             "paged": ({
-                "ratio": (slotserve.get("paged") or {}).get("ratio"),
-                "kv_bytes_saved_vs_contiguous": (slotserve.get("paged")
-                    or {}).get("kv_bytes_saved_vs_contiguous"),
-                "max_slots_at_equal_hbm": (slotserve.get("paged")
-                    or {}).get("max_slots_at_equal_hbm"),
+                "paged_expl_per_s": (slotserve.get("paged")
+                    or {}).get("paged_expl_per_s"),
                 "prefix_tokens_saved": (slotserve.get("paged")
                     or {}).get("prefix_tokens_saved"),
                 "prefix_hits": (slotserve.get("paged")
                     or {}).get("prefix_hits"),
-            } if (slotserve.get("paged") or {}).get("ratio") is not None
-                else None),
+            } if (slotserve.get("paged") or {}).get("paged_expl_per_s")
+                is not None else None),
         } if slotserve.get("ratio") is not None else None),
         # Game-day verdicts (ISSUE 12, docs/scenarios.md): one ok bit per
         # named scenario so an SLO regression diffs in the trend file.
@@ -2050,25 +2046,23 @@ def _slotserve_bench(lm) -> dict:
         "dropped": snap["dropped"],
         "kv_bytes": snap["kv_bytes"],
     }
-    # Paged-vs-contiguous arms (PR 19, docs/explain_serving.md "Paged KV
-    # and prefix sharing"). BENCH_SLOT_PAGED=0 skips.
+    # The capped pool on a shared-preamble workload (PR 19,
+    # docs/explain_serving.md). BENCH_SLOT_PAGED=0 skips.
     if os.environ.get("BENCH_SLOT_PAGED", "1") != "0":
         out["paged"] = _paged_slotserve_bench(lm, max_tokens, window)
     return out
 
 
 def _paged_slotserve_bench(lm, max_tokens: int, window: int) -> dict:
-    """Paged KV pool vs contiguous slot pool on a long-transcript +
-    shared-preamble workload (ISSUE 19 acceptance evidence).
+    """The slot lane's page pool on a long-transcript + shared-preamble
+    workload (ISSUE 19 acceptance evidence).
 
     Every prompt is a full framed analysis prompt — they all open with the
-    explain template's preamble, so every paged admit hits the prefix
-    cache (one COW of the partial page, suffix-only prefill). The paged
+    explain template's preamble, so every admit hits the prefix
+    cache (one COW of the partial page, suffix-only prefill). The
     pool is sized to the workload's TRUE worst case — prefix pages plus
-    the fresh pages one slot can reference — instead of the contiguous
-    worst-case reservation, which is where the kv_bytes reduction at
-    EQUAL slot count comes from; ``max_slots_at_equal_hbm`` inverts the
-    same arithmetic. Exact page accounting (allocator identity, zero
+    the fresh pages one slot can reference — instead of a worst-case row
+    for every slot. Exact page accounting (allocator identity, zero
     leaks at close) is asserted here AND in CI's bench smoke."""
     from fraud_detection_tpu.explain.backends import frame_prompt
     from fraud_detection_tpu.explain.onpod import flatten_chat
@@ -2102,68 +2096,45 @@ def _paged_slotserve_bench(lm, max_tokens: int, window: int) -> dict:
     fresh_per_slot = n_view - n_full
     kv_pages = n_prefix + fresh_per_slot * slots
 
-    def run(paged):
-        svc = SlotServeService(
-            lm, slots=slots, max_new_tokens=max_tokens,
-            prompt_width=prompt_width, decode_window=window,
-            prefill_per_iter=4, max_queue=4096, wait_timeout=1200.0,
-            paged=paged,
-            **({"page_size": page_size, "kv_pages": kv_pages}
-               if paged else {}))
-        ok = False
-        try:
-            # Warm with the SAME framed prompts the timed region submits:
-            # a re-framed warm would miss the prefix cache and leave the
-            # suffix-bucket prefill program compiling inside the timing.
-            warm = [svc.submit(p, max_tokens=max_tokens, temperature=0.0)
-                    for p in prompts[:2]]
-            for r in warm:
-                r.wait(1200.0)
-            t0 = time.perf_counter()
-            reqs = [svc.submit(p, max_tokens=max_tokens, temperature=0.0)
-                    for p in prompts]
-            texts = [r.wait(1200.0) for r in reqs]
-            dt = time.perf_counter() - t0
-            snap = svc.snapshot()
-            dec = svc._decoder
-            acct = (dec.allocator_snapshot() if paged
-                    else {"total": 0, "free": 0, "in_use": 0, "refs": 0,
-                          "pages_in_tables": 0, "prefix_base_refs": 0})
-            saved = dec.prefix_tokens_saved if paged else 0
-            ok = True
-        finally:
-            # On the interrupt path (SIGTERM mid-leg) bound the close drain
-            # so the bench process still exits inside the runner's grace
-            # window; the normal path keeps the full drain for accounting.
-            svc.close(timeout=30.0 if ok else 5.0)
-        assert snap["admitted"] == snap["completed"] + snap["dropped"], snap
-        leaked = dec.leaked_pages if paged else 0
-        assert leaked == 0, f"paged pool leaked {leaked} pages"
-        return texts, dt, snap, acct, saved
-
-    contig_texts, contig_dt, contig_snap, _, _ = run(False)
-    paged_texts, paged_dt, paged_snap, acct, tokens_saved = run(True)
-    # The parity discipline, asserted in the artifact's face: the paged
-    # arm must emit the contiguous arm's exact greedy texts.
-    assert paged_texts == contig_texts, "paged/contiguous outputs diverged"
-    contig_kv, paged_kv = contig_snap["kv_bytes"], paged_snap["kv_bytes"]
-    page_bytes = paged_snap["page_bytes"]
+    svc = SlotServeService(
+        lm, slots=slots, max_new_tokens=max_tokens,
+        prompt_width=prompt_width, decode_window=window,
+        prefill_per_iter=4, max_queue=4096, wait_timeout=1200.0,
+        page_size=page_size, kv_pages=kv_pages)
+    ok = False
+    try:
+        # Warm with the SAME framed prompts the timed region submits:
+        # a re-framed warm would miss the prefix cache and leave the
+        # suffix-bucket prefill program compiling inside the timing.
+        warm = [svc.submit(p, max_tokens=max_tokens, temperature=0.0)
+                for p in prompts[:2]]
+        for r in warm:
+            r.wait(1200.0)
+        t0 = time.perf_counter()
+        reqs = [svc.submit(p, max_tokens=max_tokens, temperature=0.0)
+                for p in prompts]
+        for r in reqs:
+            r.wait(1200.0)
+        paged_dt = time.perf_counter() - t0
+        paged_snap = svc.snapshot()
+        dec = svc._decoder
+        acct = dec.allocator_snapshot()
+        tokens_saved = dec.prefix_tokens_saved
+        ok = True
+    finally:
+        # On the interrupt path (SIGTERM mid-leg) bound the close drain
+        # so the bench process still exits inside the runner's grace
+        # window; the normal path keeps the full drain for accounting.
+        svc.close(timeout=30.0 if ok else 5.0)
+    assert paged_snap["admitted"] == (paged_snap["completed"]
+                                      + paged_snap["dropped"]), paged_snap
+    assert dec.leaked_pages == 0, \
+        f"page pool leaked {dec.leaked_pages} pages"
     return {
         "slots": slots, "rows": rows, "max_tokens": max_tokens,
         "page_size": page_size, "kv_pages": kv_pages,
-        "contig_expl_per_s": round(rows / contig_dt, 2),
         "paged_expl_per_s": round(rows / paged_dt, 2),
-        "ratio": round(contig_dt / paged_dt, 2),
-        "outputs_bit_equal": True,
-        # HBM at EQUAL slot count, and slots at EQUAL HBM (the two ways
-        # to spend the paging win).
-        "contig_kv_bytes": contig_kv,
-        "kv_bytes": paged_kv,
-        "kv_bytes_saved_vs_contiguous":
-            paged_snap["kv_bytes_saved_vs_contiguous"],
-        "max_slots_at_equal_hbm": int(
-            (contig_kv - n_prefix * page_bytes)
-            // (fresh_per_slot * page_bytes)),
+        "kv_bytes": paged_snap["kv_bytes"],
         # Prefix sharing evidence.
         "prefix_hits": paged_snap["prefix_hits"],
         "prefix_pages": paged_snap["prefix_pages"],

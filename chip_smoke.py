@@ -404,7 +404,7 @@ def phase_explain(lr_dir: str, sizes: Sizes) -> dict:
 
     pipe = ServingPipeline.from_checkpoint(lr_dir, batch_size=sizes.batch)
     svc = SlotServeService(lm, slots=sizes.slots,
-                           max_new_tokens=sizes.new_tokens, paged=True)
+                           max_new_tokens=sizes.new_tokens)
     try:
         engine = StreamingClassifier(
             pipe, broker.consumer(["in"], "smoke-explain"), broker.producer(),

@@ -197,7 +197,7 @@ THREAD_ENTRY_POINTS: Tuple[EntryPoint, ...] = (
                "SlotServeService._run", None,
                "single worker by construction (one thread started in "
                "__init__, never respawned); queue/counters under _cv, "
-               "slot-state arrays and the SlotDecoder are worker-only by "
+               "slot-state arrays and the PagedSlotDecoder are worker-only by "
                "the class's role map, waiters block on per-request "
                "events"),
     # Learn lane: the one closed-loop worker; the region also guards the
@@ -477,7 +477,8 @@ OBJECT_BINDINGS: Mapping[str, Tuple[str, ...]] = {
     "fleet/control.py::ControlBus._producer": ("Producer",),
     "fleet/control.py::ControlBus._consumer": ("Consumer",),
     # Slotserve lane: the service drives its decoder from the lane thread.
-    "explain/slotserve/service.py::SlotServeService._decoder": ("SlotDecoder",),
+    "explain/slotserve/service.py::SlotServeService._decoder":
+        ("PagedSlotDecoder",),
     # Learn seams (learn/, docs/online_learning.md): the engine offers
     # scored batches to the loop; the loop drives its window store, the
     # registry, and the shadow scorer's encoded-replay surface.
@@ -975,7 +976,7 @@ SLOT_PROTOCOLS: Tuple[RoleSpec, ...] = (
            ("explain/slotserve/service.py::SlotServeService._admit_pending",),
            ("_emit",)),
         # Host side of the iteration boundary: every busy slot's page
-        # table is extended to cover the coming window (no-op contiguous);
+        # table is extended to cover the coming window;
         # exhaustion preempts the newest admit as an accounted drop.
         _t("grow", "decode", "decode",
            ("explain/slotserve/service.py::"
@@ -1027,10 +1028,11 @@ SLOT_PROTOCOLS: Tuple[RoleSpec, ...] = (
            ("explain/slotserve/decode.py::"
             "PagedSlotDecoder._cow_prefix_page",),
            ("allocator.alloc", "llm.copy_kv_page")),
-        # The shared preamble prefills once into base-referenced pages.
+        # The shared preamble prefills once into base-referenced pages; a
+        # page of the bucketed width past its last goes straight back.
         _t("prefix_seed", "free", "mapped",
            ("explain/slotserve/decode.py::PagedSlotDecoder.set_prefix",),
-           ("allocator.alloc",)),
+           ("allocator.alloc", "allocator.release")),
         # Window growth allocates cover for lens + steps.
         _t("grow", "mapped", "mapped",
            ("explain/slotserve/decode.py::"

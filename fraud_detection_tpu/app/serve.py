@@ -344,24 +344,18 @@ def main(argv=None) -> int:
     ap.add_argument("--explain-slots", type=int, metavar="N", default=0,
                     help="serve explanations through the slot-based "
                          "continuous-batching lane with N decode slots "
-                         "over one persistent KV cache (0 = off; needs an "
-                         "onpod-family --explain backend; implies "
+                         "over one persistent pool of KV pages, the shared "
+                         "explain preamble prefilled once (0 = off; needs "
+                         "an onpod-family --explain backend; implies "
                          "--explain-async — docs/explain_serving.md). "
                          "Every flagged row is explained or accounted, "
                          "and health() gains the 'explain' block")
     ap.add_argument("--explain-queue", type=int, default=1024,
                     help="slotserve admission-queue bound (--explain-slots; "
                          "overflow drops OLDEST with honest accounting)")
-    ap.add_argument("--explain-paged", action="store_true",
-                    help="page the slot lane's KV cache: fixed-size KV "
-                         "pages behind a refcounted allocator, with the "
-                         "shared explain preamble prefilled ONCE and "
-                         "copy-on-write per admit (--explain-slots; "
-                         "greedy outputs stay bit-equal to contiguous — "
-                         "docs/explain_serving.md \"Paged KV and prefix "
-                         "sharing\")")
     ap.add_argument("--explain-kv-pages", type=int, metavar="N", default=0,
-                    help="cap the paged pool at N pages (--explain-paged; "
+                    help="cap the slot lane's page pool at N pages "
+                         "(--explain-slots; "
                          "0 = slots * pages-per-slot, the zero-preemption "
                          "default; smaller pools preempt the NEWEST admit "
                          "with a kv_pages_exhausted drop record)")
@@ -568,16 +562,13 @@ def main(argv=None) -> int:
         # The slot lane IS the async configuration: classification never
         # waits for decode, annotations ride the side topic.
         args.explain_async = True
-    if args.explain_paged and args.explain_slots < 1:
-        raise SystemExit(
-            "--explain-paged pages the slotserve lane's KV cache — it "
-            "needs --explain-slots")
     if args.explain_kv_pages < 0:
         raise SystemExit(
             f"--explain-kv-pages must be >= 0, got {args.explain_kv_pages}")
-    if args.explain_kv_pages > 0 and not args.explain_paged:
+    if args.explain_kv_pages > 0 and args.explain_slots < 1:
         raise SystemExit(
-            "--explain-kv-pages caps the paged pool; set --explain-paged")
+            "--explain-kv-pages caps the slotserve lane's page pool — it "
+            "needs --explain-slots")
     if args.explain_async and args.explain == "off":
         raise SystemExit("--explain-async needs an --explain backend")
     if args.annotations_topic is not None and not args.explain_async:
@@ -843,9 +834,7 @@ def main(argv=None) -> int:
                     slot_lm, slots=args.explain_slots,
                     max_queue=args.explain_queue,
                     max_new_tokens=args.explain_tokens,
-                    paged=args.explain_paged,
-                    **({"kv_pages": args.explain_kv_pages}
-                       if args.explain_kv_pages > 0 else {}))
+                    kv_pages=args.explain_kv_pages or None)
             except ValueError as e:
                 raise SystemExit(f"--explain-slots: {e}")
         if args.breaker > 0:

@@ -1,12 +1,12 @@
 """Slotserve — slot-based continuous-batching on-pod explanation service.
 
-One persistent KV pool of decode slots; newly flagged rows admit into free
-slots at iteration boundaries (no fixed-batch barrier), rows retire
-per-slot at EOS, and every flagged row is explained or accounted
-(docs/explain_serving.md).
+One persistent pool of KV pages under a fixed set of decode slots; newly
+flagged rows admit into free slots at iteration boundaries (no fixed-batch
+barrier), rows retire per-slot at EOS, and every flagged row is explained
+or accounted (docs/explain_serving.md).
 """
 
-from fraud_detection_tpu.explain.slotserve.decode import SlotDecoder
+from fraud_detection_tpu.explain.slotserve.decode import PagedSlotDecoder
 from fraud_detection_tpu.explain.slotserve.service import (
     DROPPED_MARKER,
     UNAVAILABLE_MARKER,
@@ -15,7 +15,7 @@ from fraud_detection_tpu.explain.slotserve.service import (
 )
 
 __all__ = [
-    "SlotDecoder",
+    "PagedSlotDecoder",
     "SlotServeService",
     "make_slot_explain_hook",
     "DROPPED_MARKER",

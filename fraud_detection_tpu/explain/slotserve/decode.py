@@ -1,27 +1,23 @@
-"""Host-side driver of the pooled-slot decode programs (models/llm.py).
+"""Host-side driver of the slot pool's device programs (models/llm.py).
 
-One :class:`SlotDecoder` owns ONE persistent KV pool — ``(slots, S, Hkv, d)``
-per layer, allocated once — and the two jitted entries that touch it:
-``slot_prefill`` (admit one prompt into a free slot at an iteration
-boundary) and ``slot_decode_step`` (advance every busy slot one token).
+One :class:`PagedSlotDecoder` owns ONE persistent KV pool — a flat pool of
+fixed-size pages per layer, allocated once, and a per-slot page table — and
+the two jitted entries that touch it: ``paged_slot_prefill`` (admit one
+prompt into a free slot at an iteration boundary) and
+``paged_decode_window`` (advance every busy slot up to a window of tokens).
 Compile count is bounded by construction: exactly one decode program for
 the pool, plus one prefill program per prompt bucket (prompt lengths round
 up to ``prompt_bucket`` multiples — the same padding-ladder idea
 sched/batcher.py applies to scoring shapes).
 
-:class:`PagedSlotDecoder` is the PagedAttention-shaped alternative: the
-same serving surface over a flat pool of fixed-size KV pages and a
-per-slot page table, with an exact-accounting refcounted
-:class:`PageAllocator` (alloc on admit/growth, free on slot release) and
-shared-prefix caching — the explain template's preamble is prefilled ONCE
-into refcounted read-only pages every slot's table points at, with
-copy-on-write when an admit would append into a partially-filled shared
-page. Greedy outputs are bit-equal to the contiguous pool (the device
-programs gather pages into the contiguous layout and run the identical
-window loop), so the two decoders are interchangeable behind the service.
+Pages come from an exact-accounting refcounted :class:`PageAllocator`
+(alloc on admit/growth, free on slot release), which is also what shares
+the explain template's preamble: it is prefilled ONCE into refcounted
+read-only pages every slot's table points at, with copy-on-write when an
+admit would append into a partially-filled shared page.
 
 All slot/queue policy (admission, retirement, accounting) lives in
-:mod:`fraud_detection_tpu.explain.slotserve.service`; these classes are the
+:mod:`fraud_detection_tpu.explain.slotserve.service`; this class is the
 thin device seam so the policy layer never touches jax directly.
 """
 
@@ -116,190 +112,10 @@ class PageAllocator:
                 "in_use": refd, "refs": sum(self._refs)}
 
 
-class _ModelCounters:
-    """What the device programs count about the model's own routing and
-    state, summed on the host as the results come back (no sync of their
-    own: they ride with the first token and with the window's tokens). All
-    stay 0 for a model with no expert layer and no recurrent state."""
-
-    def _init_counters(self) -> None:
-        self.moe_picks = 0              # expert choices made by real tokens
-        self.moe_picks_held = 0         # ... that landed on a held expert
-        self.moe_experts_touched = 0    # distinct held experts read, summed
-        #                                 over decode steps and expert layers
-        self.moe_prefill_load_max = 0   # busiest held expert's tokens, summed
-        #                                 over prefills and expert layers
-        self.moe_prefill_load_mean = 0.0    # the mean expert's, likewise
-        self.state_restores = 0         # snapshots copied into a slot's block
-
-    def _count(self, stats, *, prefill: bool) -> None:
-        if stats is None:
-            return
-        picks, held, touched, load_max = (int(v) for v in np.asarray(stats))
-        self.moe_picks += picks
-        self.moe_picks_held += held
-        if prefill:
-            self.moe_prefill_load_max += load_max
-            self.moe_prefill_load_mean += held / self.cfg.moe.held
-        else:
-            self.moe_experts_touched += touched
-
-
-class SlotDecoder(_ModelCounters):
-    """One slot pool + its device programs. NOT thread-safe — owned by the
-    slot lane's single worker thread (the service's contract)."""
-
-    def __init__(self, lm, slots: int, *, prompt_width: int = 384,
-                 max_new_tokens: int = 128, prompt_bucket: int = 64):
-        if slots < 1:
-            raise ValueError(f"slots must be >= 1, got {slots}")
-        if prompt_bucket < 1:
-            raise ValueError(
-                f"prompt_bucket must be >= 1, got {prompt_bucket}")
-        cfg = lm.cfg
-        # Bucket the width itself so the widest prefill is a ladder rung.
-        width = prompt_bucket * (-(-prompt_width // prompt_bucket))
-        max_len = width + max_new_tokens
-        if max_len > cfg.max_seq:
-            raise ValueError(
-                f"slot cache needs {max_len} positions (prompt_width "
-                f"{width} + max_new_tokens {max_new_tokens}) but "
-                f"cfg.max_seq is {cfg.max_seq}")
-        self.lm = lm
-        self.cfg = cfg
-        self.slots = slots
-        self.prompt_width = width
-        self.prompt_bucket = prompt_bucket
-        self.max_new_tokens = max_new_tokens
-        self.max_len = max_len
-        self.cache = llm.init_cache(cfg, slots, max_len)
-        self.kv_bytes = int(sum(
-            int(np.prod(a.shape)) * a.dtype.itemsize
-            for a in self.cache.values()))
-        self.prefills = 0
-        self.steps = 0
-        self._init_counters()
-        # Paged-pool stats surface (zero here: the whole region is a single
-        # worst-case reservation). The service snapshot reads these
-        # unconditionally so the health schema is mode-independent.
-        self.kv_pages = 0
-        self.page_bytes = 0
-        self.prefix_pages = 0
-        self.prefix_hits = 0
-        self.cow_copies = 0
-        self.prefix_tokens_saved = 0
-        self.kv_bytes_saved_vs_contiguous = 0
-
-    @property
-    def pages_free(self) -> int:
-        return 0
-
-    # -- paged-lifecycle surface (no-ops: the contiguous pool has nothing
-    #    to allocate or free; a slot's region is overwritten on re-admit) --
-
-    def pages_needed(self, prompt_tokens: np.ndarray) -> int:
-        return 0
-
-    def can_admit(self, prompt_tokens: np.ndarray) -> bool:
-        return True
-
-    def grow_for_window(self, slot: int, length: int, steps: int) -> bool:
-        return True
-
-    def release_slot(self, slot: int) -> None:
-        pass
-
-    def reset_slots(self) -> None:
-        pass
-
-    def close(self) -> None:
-        pass
-
-    def encode_prompt(self, prompt: str):
-        """Tokenize + truncate to the slot width (head kept: analysis
-        prompts front-load the instruction). Returns
-        ``(int32 tokens, truncated bool)`` — truncation is counted, never
-        silent (same honesty rule as the byte-featurize width)."""
-        toks = self.lm.tokenizer.encode(prompt)
-        truncated = len(toks) > self.prompt_width
-        return np.asarray(toks[: self.prompt_width], np.int32), truncated
-
-    def decode_text(self, tokens) -> str:
-        return self.lm.tokenizer.decode(np.asarray(tokens, np.int32))
-
-    def prefill(self, slot: int, prompt_tokens: np.ndarray,
-                temperature: float, seed: int,
-                span: Callable = _no_span) -> int:
-        """Admit one prompt into ``slot``; returns the FIRST sampled token
-        (already part of the row's output)."""
-        import jax
-        import jax.numpy as jnp
-
-        n = len(prompt_tokens)
-        bucket = self.prompt_bucket * (-(-n // self.prompt_bucket))
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = prompt_tokens
-        tok, self.cache, stats = llm.slot_prefill(
-            self.lm.params, jnp.asarray(padded), jnp.int32(n), self.cfg,
-            self.cache, jnp.int32(slot), jnp.float32(temperature),
-            jax.random.PRNGKey(seed & 0x7FFFFFFF))
-        self.prefills += 1
-        tok = int(tok)
-        self._count(stats, prefill=True)
-        return tok
-
-    def step(self, tokens: np.ndarray, lens: np.ndarray, active: np.ndarray,
-             remaining: np.ndarray, temperatures: np.ndarray, seed: int,
-             steps: int, span: Callable = _no_span):
-        """One fused decode window (up to ``steps`` iterations) over the
-        whole pool; returns ``(out (B, steps) EOS-padded, new_lens,
-        steps_run, active_row_steps)``. ONE host sync per window — the
-        per-token dispatch amortized ``steps``-wide is what makes
-        iteration-level scheduling pay on dispatch-bound hosts too.
-        ``span(stage)`` opens the caller's span around each half:
-        ``slot_launch`` (arguments placed, program enqueued) and
-        ``slot_fetch`` (blocked until the window's tokens are on the
-        host)."""
-        import jax
-        import jax.numpy as jnp
-
-        with span("slot_launch"):
-            out, new_lens, steps_run, n_act, self.cache, stats = \
-                llm.slot_decode_window(
-                    self.lm.params, jnp.asarray(tokens, jnp.int32),
-                    jnp.asarray(lens, jnp.int32), jnp.asarray(active),
-                    jnp.asarray(remaining, jnp.int32),
-                    self.cfg, self.cache,
-                    jnp.asarray(temperatures, jnp.float32),
-                    jax.random.PRNGKey(seed & 0x7FFFFFFF), int(steps))
-        self.steps += 1
-        with span("slot_fetch"):
-            # np.array, not asarray: the lens copy must be writable (the
-            # service mutates it per-slot on prefill/release).
-            fetched = (np.asarray(out), np.array(new_lens), int(steps_run),
-                       int(n_act))
-            self._count(stats, prefill=False)
-            return fetched
-
-    def warm(self, steps: int, prompt: Optional[str] = None) -> None:
-        """Compile the decode window + the smallest prefill bucket off the
-        serving path (one throwaway row through slot 0)."""
-        toks, _ = self.encode_prompt(prompt or "warm")
-        self.prefill(0, toks, 0.0, 0)
-        lens = np.zeros(self.slots, np.int32)
-        lens[0] = len(toks)
-        active = np.zeros(self.slots, bool)
-        active[0] = True
-        remaining = np.ones(self.slots, np.int32)
-        self.step(np.full(self.slots, self.cfg.EOS, np.int32), lens, active,
-                  remaining, np.zeros(self.slots, np.float32), 0, steps)
-        self.release_slot(0)
-
-
-class PagedSlotDecoder(_ModelCounters):
-    """The paged twin of :class:`SlotDecoder`: same serving surface, but the
-    KV region is a flat pool of ``total_pages`` fixed-size pages indexed by
-    a per-slot page table (PagedAttention applied to the slot pool).
+class PagedSlotDecoder:
+    """One slot pool + its device programs: the KV region is a flat pool of
+    ``total_pages`` fixed-size pages indexed by a per-slot page table
+    (PagedAttention applied to the slot pool).
 
     Two kinds of per-slot state live under this one manager, by the model's
     layer kinds: what grows with the tokens held (an ``attention`` layer's
@@ -321,10 +137,8 @@ class PagedSlotDecoder(_ModelCounters):
       allocator identity (`PageAllocator.check`) holds at every boundary
       and all pages are free at quiescence.
 
-    Greedy outputs are bit-equal to :class:`SlotDecoder` by construction:
-    the decode window gathers the table into the contiguous layout and
-    runs the identical fused loop (``models/llm.py::_slot_window_loop``).
-    NOT thread-safe — owned by the slot lane's single worker thread.
+    NOT thread-safe — owned by the slot lane's single worker thread (the
+    service's contract).
     """
 
     def __init__(self, lm, slots: int, *, prompt_width: int = 384,
@@ -340,6 +154,7 @@ class PagedSlotDecoder(_ModelCounters):
             raise ValueError(
                 f"page_size must be a power of two, got {page_size}")
         cfg = lm.cfg
+        # Bucket the width itself so the widest prefill is a ladder rung.
         width = prompt_bucket * (-(-prompt_width // prompt_bucket))
         max_len = width + max_new_tokens
         if max_len > cfg.max_seq:
@@ -355,9 +170,8 @@ class PagedSlotDecoder(_ModelCounters):
         self.max_new_tokens = max_new_tokens
         self.max_len = max_len
         self.page_size = page_size
-        # Ceil: the last page may overhang max_len; the decode program
-        # slices the gathered view down to exactly max_len (view_len) so
-        # the window loop runs at the contiguous attention width.
+        # Ceil: the last page may overhang max_len; the overhang is masked
+        # like any position past a row's length.
         self.n_view = -(-max_len // page_size)
         total = slots * self.n_view if total_pages is None else total_pages
         if total < self.n_view:
@@ -385,11 +199,23 @@ class PagedSlotDecoder(_ModelCounters):
         self.cow_copies = 0
         self.prefix_tokens_saved = 0
         self.leaked_pages = 0
-        self._init_counters()
+        # What the device programs count about the model's own routing and
+        # state, summed on the host as the results come back (no sync of
+        # their own: they ride with the first token and with the window's
+        # tokens). All stay 0 for a model with no expert layer and no
+        # recurrent state.
+        self.moe_picks = 0              # expert choices made by real tokens
+        self.moe_picks_held = 0         # ... that landed on a held expert
+        self.moe_experts_touched = 0    # distinct held experts read, summed
+        #                                 over decode steps and expert layers
+        self.moe_prefill_load_max = 0   # busiest held expert's tokens, summed
+        #                                 over prefills and expert layers
+        self.moe_prefill_load_mean = 0.0    # the mean expert's, likewise
+        self.state_restores = 0         # snapshots copied into a slot's block
         # One page's bytes across every layer/tensor array; the pool's
-        # total (pages, plus the slots' state blocks); and the reservation
-        # the contiguous layout would have made for the same slot count
-        # (the headline saving).
+        # total (pages, plus the slots' state blocks); and what the pool
+        # saves against a worst-case reservation of max_len positions for
+        # every slot (the headline saving).
         per_pos = int(sum(a.dtype.itemsize * a.shape[2] * a.shape[3]
                           for a in self.pages.values()))
         self.page_bytes = per_pos * page_size
@@ -399,6 +225,18 @@ class PagedSlotDecoder(_ModelCounters):
             per_pos * max_len * slots - self.page_bytes * total)
         if prefix_text:
             self.set_prefix(prefix_text)
+
+    def _count(self, stats, *, prefill: bool) -> None:
+        if stats is None:
+            return
+        picks, held, touched, load_max = (int(v) for v in np.asarray(stats))
+        self.moe_picks += picks
+        self.moe_picks_held += held
+        if prefill:
+            self.moe_prefill_load_max += load_max
+            self.moe_prefill_load_mean += held / self.cfg.moe.held
+        else:
+            self.moe_experts_touched += touched
 
     # -- stats surface --------------------------------------------------
 
@@ -436,11 +274,10 @@ class PagedSlotDecoder(_ModelCounters):
         iff its text starts with ``prefix_text`` — checked per admit at
         the token level. A layer that keeps a recurrent state leaves a
         snapshot of it at the preamble's last token instead of pages
-        (``_prefix_state``). The prefix k/v are computed by the CONTIGUOUS
-        prefill program at a bucket-aligned width (ragged widths are not
-        bit-stable; bucket-aligned ones are — pinned by the parity tests),
-        which makes them bit-identical to the same positions inside any
-        full-prompt prefill."""
+        (``_prefix_state``). The preamble goes through the prefill program
+        itself (``prefix_len`` 0) at a bucket-aligned width, straight into
+        its own pages: its k/v are then the same positions' of any
+        whole-prompt prefill (pinned by the parity tests)."""
         import jax
         import jax.numpy as jnp
 
@@ -459,25 +296,25 @@ class PagedSlotDecoder(_ModelCounters):
                 f"({n_prefix} pages) plus one worst-case row "
                 f"({self.n_view} pages) — raise the pool or drop sharing")
         wp = self.prompt_bucket * (-(-lp // self.prompt_bucket))
-        tmp = llm.init_cache(self.cfg, 1, wp)
         padded = np.zeros((1, wp), np.int32)
         padded[0, :lp] = toks
-        _, tmp, _ = llm.slot_prefill(
+        # The table row covers the bucketed width; a page past the
+        # preamble's last holds only padding and goes back at once.
+        pids = [self.allocator.alloc()
+                for _ in range(-(-wp // self.page_size))]
+        _, self.pages, state, _ = llm.paged_slot_prefill(
             self.lm.params, jnp.asarray(padded), jnp.int32(lp), self.cfg,
-            tmp, jnp.int32(0), jnp.float32(0.0), jax.random.PRNGKey(0))
-        pids = [self.allocator.alloc() for _ in range(n_prefix)]
-        for j, pid in enumerate(pids):
-            take = min(self.page_size, lp - j * self.page_size)
-            for name in self.pages:
-                rows = tmp[name][0, j * self.page_size:
-                                 j * self.page_size + take]
-                self.pages[name] = self.pages[name].at[pid, :take].set(rows)
+            self.pages, jnp.asarray(pids, jnp.int32), jnp.float32(0.0),
+            jax.random.PRNGKey(0), 0, self._zero_state,
+            jnp.int32(0) if self.state else None)
+        for pid in pids[n_prefix:]:
+            self.allocator.release(pid)
         # ... and, beside the pages, every recurrent layer's state as the
         # preamble's last token left it.
-        self._prefix_state = {name: tmp[name] for name in self.state}
+        self._prefix_state = state
         self._prefix_tokens = toks
         self._prefix_len = lp
-        self._prefix_pids = pids
+        self._prefix_pids = pids[:n_prefix]
 
     def _split_prompt(self, prompt_tokens: np.ndarray):
         """(prefix_len, suffix) — prefix_len is 0 unless the prompt starts
@@ -539,6 +376,10 @@ class PagedSlotDecoder(_ModelCounters):
         self._owned[slot] = row
 
     def encode_prompt(self, prompt: str):
+        """Tokenize + truncate to the slot width (head kept: analysis
+        prompts front-load the instruction). Returns
+        ``(int32 tokens, truncated bool)`` — truncation is counted, never
+        silent (same honesty rule as the byte-featurize width)."""
         toks = self.lm.tokenizer.encode(prompt)
         truncated = len(toks) > self.prompt_width
         return np.asarray(toks[: self.prompt_width], np.int32), truncated
@@ -553,8 +394,8 @@ class PagedSlotDecoder(_ModelCounters):
         FIRST, copy the state the suffix starts from into the slot's block
         (the preamble's snapshot, or zeros; under ``span("slot_state_restore")``
         and only where the model keeps such state), then run the suffix-only
-        prefill program against both. Returns the first sampled token —
-        bit-equal to the contiguous admit for the paged layers."""
+        prefill program against both. Returns the FIRST sampled token
+        (already part of the row's output)."""
         import jax
         import jax.numpy as jnp
 
@@ -638,8 +479,15 @@ class PagedSlotDecoder(_ModelCounters):
     def step(self, tokens: np.ndarray, lens: np.ndarray, active: np.ndarray,
              remaining: np.ndarray, temperatures: np.ndarray, seed: int,
              steps: int, span: Callable = _no_span):
-        """One fused decode window over the paged pool — identical contract
-        (and bit-identical output) to :meth:`SlotDecoder.step`."""
+        """One fused decode window (up to ``steps`` iterations) over the
+        whole pool; returns ``(out (B, steps) EOS-padded, new_lens,
+        steps_run, active_row_steps)``. ONE host sync per window — the
+        per-token dispatch amortized ``steps``-wide is what makes
+        iteration-level scheduling pay on dispatch-bound hosts too.
+        ``span(stage)`` opens the caller's span around each half:
+        ``slot_launch`` (arguments placed, program enqueued) and
+        ``slot_fetch`` (blocked until the window's tokens are on the
+        host)."""
         import jax
         import jax.numpy as jnp
 
@@ -652,9 +500,11 @@ class PagedSlotDecoder(_ModelCounters):
                     self.cfg, self.pages, jnp.asarray(self._tables),
                     jnp.asarray(temperatures, jnp.float32),
                     jax.random.PRNGKey(seed & 0x7FFFFFFF), int(steps),
-                    self.max_len, self.state)
+                    self.state)
         self.steps += 1
         with span("slot_fetch"):
+            # np.array, not asarray: the lens copy must be writable (the
+            # service mutates it per-slot on prefill/release).
             fetched = (np.asarray(out), np.array(new_lens), int(steps_run),
                        int(n_act))
             self._count(stats, prefill=False)

@@ -11,14 +11,13 @@ barrier is why explanations were sampled, not guaranteed.
 This module is the iteration-level alternative (Orca, OSDI '22; slot/KV
 management in the spirit of vLLM, SOSP '23):
 
-* a fixed pool of **decode slots** over ONE persistent KV cache
-  (``SlotDecoder``, models/llm.py ``slot_prefill``/``slot_decode_step``) —
-  or, with ``paged=True``, over a flat pool of fixed-size KV pages and
-  per-slot page tables (``PagedSlotDecoder``): block-granular allocation
-  kills the worst-case per-slot reservation, the shared explain preamble
-  is prefilled ONCE into refcounted read-only pages (copy-on-write on the
-  partial page), and pool exhaustion preempts the newest admit as an
-  accounted ``kv_pages_exhausted`` drop;
+* a fixed pool of **decode slots** over ONE persistent pool of fixed-size
+  KV pages and per-slot page tables (``PagedSlotDecoder``, models/llm.py
+  ``paged_slot_prefill``/``paged_decode_window``): block-granular
+  allocation instead of a worst-case reservation per slot, the shared
+  explain preamble prefilled ONCE into refcounted read-only pages
+  (copy-on-write on the partial page), and pool exhaustion preempts the
+  newest admit as an accounted ``kv_pages_exhausted`` drop;
 * a bounded **admission queue**: newly flagged rows admit into free slots
   at iteration boundaries — prefill interleaves with decode, no fixed-batch
   barrier, and overload drops the OLDEST queued request with honest
@@ -58,8 +57,7 @@ from typing import Callable, List, Optional, Sequence
 from fraud_detection_tpu.explain.backends import (BackendError, ChatMessage,
                                                   frame_prompt)
 from fraud_detection_tpu.explain.onpod import flatten_chat
-from fraud_detection_tpu.explain.slotserve.decode import (PagedSlotDecoder,
-                                                          SlotDecoder)
+from fraud_detection_tpu.explain.slotserve.decode import PagedSlotDecoder
 from fraud_detection_tpu.sched.sketch import LatencySketch
 from fraud_detection_tpu.utils import get_logger
 
@@ -132,7 +130,11 @@ class SlotServeService:
     quantizer is the one knob that moves tokens/sec; params already placed
     on a mesh via ``shard_params`` ride along unchanged). One worker
     thread ("slotserve-lane") owns the decoder; every public surface is
-    callable from any thread.
+    callable from any thread. ``kv_pages`` caps the page pool (default: a
+    worst-case row for every slot); ``shared_prefix`` prefills the explain
+    preamble once where it fits. ``paged`` is kept for the one caller that
+    still states it (benchmark/desk.py): the pool is always paged, and
+    ``paged=False`` raises.
     """
 
     def __init__(self, lm, *, slots: int = 8, max_queue: int = 1024,
@@ -141,7 +143,7 @@ class SlotServeService:
                  decode_window: int = 16,
                  temperature: float = 0.0, seed: int = 0,
                  rowtrace=None, wait_timeout: float = 600.0,
-                 warm: bool = True, paged: bool = False,
+                 warm: bool = True, paged: bool = True,
                  page_size: int = 64, kv_pages: Optional[int] = None,
                  shared_prefix: bool = True,
                  clock: Callable[[], float] = time.perf_counter):
@@ -153,37 +155,30 @@ class SlotServeService:
         if decode_window < 1:
             raise ValueError(
                 f"decode_window must be >= 1, got {decode_window}")
-        if not paged and kv_pages is not None:
-            raise ValueError("kv_pages is a paged-pool budget; pass "
-                             "paged=True to use it")
-        if paged:
-            self._decoder = PagedSlotDecoder(lm, slots,
-                                             prompt_width=prompt_width,
-                                             max_new_tokens=max_new_tokens,
-                                             prompt_bucket=prompt_bucket,
-                                             page_size=page_size,
-                                             total_pages=kv_pages)
-            if shared_prefix:
-                prefix = shared_explain_prefix()
-                lp = len(lm.tokenizer.encode(prefix))
-                n_prefix = -(-lp // self._decoder.page_size)
-                fits = (lp < self._decoder.prompt_width
-                        and self._decoder.total_pages
-                        >= self._decoder.n_view + n_prefix)
-                if fits:
-                    self._decoder.set_prefix(prefix)
-                else:
-                    log.warning(
-                        "shared explain prefix (%d tokens, %d pages) does "
-                        "not fit prompt_width %d / pool %d; serving paged "
-                        "WITHOUT prefix sharing", lp, n_prefix,
-                        self._decoder.prompt_width,
-                        self._decoder.total_pages)
-        else:
-            self._decoder = SlotDecoder(lm, slots,
-                                        prompt_width=prompt_width,
-                                        max_new_tokens=max_new_tokens,
-                                        prompt_bucket=prompt_bucket)
+        if not paged:
+            raise ValueError("the slot lane has one pool, and it is paged: "
+                             "drop paged=False")
+        self._decoder = PagedSlotDecoder(lm, slots,
+                                         prompt_width=prompt_width,
+                                         max_new_tokens=max_new_tokens,
+                                         prompt_bucket=prompt_bucket,
+                                         page_size=page_size,
+                                         total_pages=kv_pages)
+        if shared_prefix:
+            prefix = shared_explain_prefix()
+            lp = len(lm.tokenizer.encode(prefix))
+            n_prefix = -(-lp // self._decoder.page_size)
+            fits = (lp < self._decoder.prompt_width
+                    and self._decoder.total_pages
+                    >= self._decoder.n_view + n_prefix)
+            if fits:
+                self._decoder.set_prefix(prefix)
+            else:
+                log.warning(
+                    "shared explain prefix (%d tokens, %d pages) does not "
+                    "fit prompt_width %d / pool %d; serving WITHOUT prefix "
+                    "sharing", lp, n_prefix, self._decoder.prompt_width,
+                    self._decoder.total_pages)
         import numpy as np
 
         self.slots = slots
@@ -385,10 +380,10 @@ class SlotServeService:
         with self._cv:
             while (self._free and self._q
                    and len(grabbed) < self.prefill_per_iter):
-                # Page-pool gate (paged decoder; contiguous needs 0 of 0):
-                # stop admitting this boundary once the free pages can't
-                # cover every grabbed prompt's table — decode retirements
-                # free pages for the next boundary, so nothing deadlocks.
+                # Page-pool gate: stop admitting this boundary once the
+                # free pages can't cover every grabbed prompt's table —
+                # decode retirements free pages for the next boundary, so
+                # nothing deadlocks.
                 need = self._decoder.pages_needed(self._q[0].tokens)
                 if self._decoder.pages_free < pages_planned + need:
                     break
@@ -440,8 +435,7 @@ class SlotServeService:
         if not busy_rows:
             return
         # Host side of the iteration boundary: every busy row's page table
-        # must cover this window's writes BEFORE the compiled program runs
-        # (paged decoder; the contiguous one grows trivially).
+        # must cover this window's writes BEFORE the compiled program runs.
         with self._span("slot_grow"):
             self._ensure_window_pages(busy_rows)
         busy_rows = np.flatnonzero(self._active_arr).tolist()
@@ -689,8 +683,7 @@ class SlotServeService:
             "slot_steps_backlogged": backlogged,
             "tokens_out": tokens_out,
             "kv_bytes": self._decoder.kv_bytes,
-            # Paged-pool block (all-zero when the contiguous decoder runs
-            # — the schema is mode-independent so pollers never branch).
+            # The page pool.
             "kv_pages": self._decoder.kv_pages,
             "page_bytes": self._decoder.page_bytes,
             "pages_free": self._decoder.pages_free,

@@ -1450,18 +1450,29 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict[str, ja
 
 
 # ---------------------------------------------------------------------------
-# slot decode (continuous batching: explain/slotserve/)
+# slot decode (continuous batching over a paged KV pool: explain/slotserve/)
 #
 # The fixed-batch decode below (`_generate_batch_jit`) runs B prompts behind
 # ONE barrier: every row pays device steps until the SLOWEST row finishes,
-# and a new request waits for the whole batch to drain. These two functions
-# are the iteration-level alternative (Orca, OSDI '22): one PERSISTENT
-# (slots, S, Hkv, d) KV pool where each row owns a slot, a prompt prefills
-# into a free slot at any iteration boundary, and one decode step advances
-# every busy slot — per-slot lengths, per-slot retirement, no barrier. The
-# host-side slot/queue management lives in explain/slotserve/; these are the
-# only device programs it runs (exactly one decode compile for the pool, one
-# prefill compile per prompt bucket).
+# and a new request waits for the whole batch to drain. The slot programs
+# are the iteration-level alternative (Orca, OSDI '22): each row owns a slot,
+# a prompt prefills into a free slot at any iteration boundary, and one
+# decode step advances every busy slot — per-slot lengths, per-slot
+# retirement, no barrier.
+#
+# What a slot holds per token lives in ONE flat pool of fixed-size KV blocks
+# — per layer/tensor (num_pages, page, Hkv, d) — indexed by a per-slot PAGE
+# TABLE of page ids (PagedAttention applied to the slot pool). Device
+# programs see only gathers and scatters by page id (no data-dependent
+# shapes; table shapes are static), and the page tables themselves mutate on
+# the HOST side of the iteration boundary, so the compiled programs stay
+# shape-stable across any allocation pattern: exactly one decode compile for
+# the pool, one prefill compile per prompt bucket. Shared-prefix caching
+# falls out of the indirection: several tables may point at the same
+# refcounted read-only pages holding the explain template's preamble k/v,
+# prefilled once (RadixAttention's idea). Allocation policy — refcounts,
+# copy-on-write, exhaustion preemption — lives with the host-side allocator
+# in explain/slotserve/decode.py; nothing here allocates.
 # ---------------------------------------------------------------------------
 
 
@@ -1518,84 +1529,15 @@ def _put_row(arr: jax.Array, row: jax.Array, slot: jax.Array) -> jax.Array:
                                                slot, 0)
 
 
-@partial(jax.jit, static_argnames=("cfg",))
-def slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
-                 cfg: TransformerConfig, kv_cache: Dict[str, jax.Array],
-                 slot: jax.Array, temperature: jax.Array,
-                 rng: jax.Array):
-    """Prefill ONE prompt into row ``slot`` of a pooled slot cache.
-
-    ``tokens``: (1, Tp) RIGHT-padded (Tp is the prompt bucket — compile
-    count is bounded by the bucket ladder, and ``slot``/``length`` are
-    traced so admitting into any slot reuses the same program).
-    Padding-region k/v DO land in cache rows [length, Tp) — they are
-    garbage, but every later read masks to [0, len] and decode overwrites
-    them in order, so they are never attended. Returns
-    ``(first_token scalar int32, new_cache, stats)`` — the first sampled
-    token is part of the row's output (same convention as
-    ``_generate_batch_jit``: sample from the prefill logits, then feed tokens
-    back one step at a time); ``stats`` are the expert layers' counters
-    (one int32 vector in ``MOE_STATS`` order; None for a model without
-    expert layers). An ``mla`` layer
-    caches its latents like k/v; a ``kda`` layer starts from the zero state
-    and leaves its state at the last REAL token in the slot's row."""
-    B, T = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)
-    if cfg.embed_scale != 1.0:
-        x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
-    act = _act(cfg)
-    # the tokens a recurrence or a router counts (right padding does not)
-    real = (jnp.arange(T) < length)[None] if cfg.layer_kinds else None
-    new_cache: Dict[str, jax.Array] = {}
-    stats = _zero_stats(cfg)
-    for l, (mixer, _) in enumerate(cfg.kinds):
-        h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
-        if mixer == "attention":
-            q, k, v = _qkv(params, cfg, l, h, positions)
-            # Write this prompt's k/v into the slot's cache rows.
-            # Right-padded overhang is masked by length everywhere downstream.
-            new_cache[f"l{l}.k"] = jax.lax.dynamic_update_slice(
-                kv_cache[f"l{l}.k"], k, (slot, 0, 0, 0))
-            new_cache[f"l{l}.v"] = jax.lax.dynamic_update_slice(
-                kv_cache[f"l{l}.v"], v, (slot, 0, 0, 0))
-            # Causal attention over the prompt itself (padded queries attend
-            # real+pad keys at or below their position — garbage-but-finite,
-            # and only the length-1 position is ever read).
-            attn = causal_attention(q, k, v, use_flash=False)
-            x = _attn_out(params, cfg, l, x, attn)
-        elif mixer == "mla":
-            q, lat = _mla_project(params, cfg, l, h, positions)
-            new_cache[f"l{l}.c"] = jax.lax.dynamic_update_slice(
-                kv_cache[f"l{l}.c"], lat, (slot, 0, 0, 0))
-            o = _mla_expanded(params, cfg, l, q, lat,
-                              jnp.tril(jnp.ones((T, T), bool)))
-            x = _head_gate_out(params, cfg, l, "mla", x, h, o)
-        else:                      # a fresh row starts from the zero state
-            zero = init_state(cfg, B)
-            x, S, tail = _kda_mix(params, cfg, l, x, h, zero[f"l{l}.S"],
-                                  zero[f"l{l}.tail"], real)
-            new_cache[f"l{l}.S"] = _put_row(kv_cache[f"l{l}.S"], S, slot)
-            new_cache[f"l{l}.tail"] = _put_row(kv_cache[f"l{l}.tail"], tail,
-                                               slot)
-        x, stats = _ffn(params, cfg, l, x, act, real, stats)
-    x = rms_norm(x, params["ln_f"], cfg.rms_eps)
-    # Logits at the LAST REAL position only (length-1; right padding means
-    # it is not at Tp-1) — full (Tp, V) logits would pay T times the head.
-    x_last = jax.lax.dynamic_slice_in_dim(x[0], length - 1, 1, 0)  # (1, D)
-    logits = _logits_head(x_last, params, cfg)                     # (1, V)
-    tok = _sample_token(temperature, logits, rng)
-    return tok[0], new_cache, _pack_stats(stats)
-
-
 def _slot_step_math(params: Params, cfg: TransformerConfig,
                     kv_cache: Dict[str, jax.Array], tokens: jax.Array,
                     lens: jax.Array, temperature: jax.Array,
                     step_key: jax.Array, live: Optional[jax.Array] = None,
                     ) -> Tuple[jax.Array, Dict, Dict]:
-    """The shared single-step math of the slot pool: feed (B,) tokens,
-    scatter their k/v (an ``mla`` layer's latent) at per-slot index
-    ``lens[b]``, attend each row over its own prefix [0, lens[b]], advance
+    """One decode step of the slot pool over its gathered (B, S, ...) view:
+    feed (B,) tokens, scatter their k/v (an ``mla`` layer's latent) at
+    per-slot index ``lens[b]``, attend each row over its own prefix
+    [0, lens[b]], advance
     each ``kda`` layer's per-row state one token, sample (B,) next tokens
     (per-slot temperature: greedy rows argmax, sampled rows draw from
     (key, row) — a slot's stream never depends on its neighbors). ``live``
@@ -1663,14 +1605,26 @@ def _slot_window_loop(params: Params, tokens: jax.Array, lens: jax.Array,
                       kv_cache: Dict[str, jax.Array],
                       temperature: jax.Array, rng: jax.Array,
                       steps: int):
-    """The fused multi-step decode loop over a (B, S, Hkv, d) cache layout —
-    shared VERBATIM by the contiguous pool (`slot_decode_window`) and the
-    paged pool (`paged_decode_window`, which gathers its pages into exactly
-    this layout first). One body means the two paths are bit-equal by
-    construction, not by test luck. ``kv_cache`` also carries each row's
-    recurrent state where the model keeps one (``init_state``); the last
-    result is the expert layers' counters summed over the window's steps
-    (one int32 vector in ``MOE_STATS`` order; None without expert layers)."""
+    """The fused multi-step decode loop of `paged_decode_window`, over the
+    (B, S, Hkv, d) view that program gathers from its pages. ``kv_cache``
+    also carries each row's recurrent state where the model keeps one
+    (``init_state``).
+
+    ``tokens``: (B,) last sampled token per slot (written this window);
+    ``lens``: (B,) valid length per slot; ``active``: (B,) bool — inactive
+    slots compute garbage into index ``lens[b]`` of the view and always emit
+    EOS; ``remaining``: (B,) per-slot token budget left. A row that samples
+    EOS or exhausts its budget FREEZES for the rest of the window (emits
+    EOS, writes nothing further) — exactly the `_generate_batch_jit` freeze
+    rule — and the loop exits early once every row froze.
+
+    Returns ``(out (B, steps) EOS-padded, new_lens, steps_run,
+    active_row_steps, new_view, stats)``; the host appends each row's tokens
+    column-by-column under the same freeze rule, so host and device agree
+    bit-for-bit, and steps_run/active_row_steps feed the occupancy
+    accounting. ``stats`` are the expert layers' counters summed over the
+    window's steps (one int32 vector in ``MOE_STATS`` order; None without
+    expert layers)."""
     B = tokens.shape[0]
     out0 = jnp.full((B, steps), cfg.EOS, jnp.int32)
     routed = bool(cfg.n_expert_layers)
@@ -1701,58 +1655,9 @@ def _slot_window_loop(params: Params, tokens: jax.Array, lens: jax.Array,
     return out, new_lens, i, n_act, new_cache, _pack_stats(stats)
 
 
-@partial(jax.jit, static_argnames=("cfg", "steps"))
-def slot_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
-                       active: jax.Array, remaining: jax.Array,
-                       cfg: TransformerConfig,
-                       kv_cache: Dict[str, jax.Array],
-                       temperature: jax.Array, rng: jax.Array,
-                       steps: int):
-    """Up to ``steps`` fused decode iterations for the WHOLE slot pool —
-    iteration-level scheduling with the per-token dispatch amortized
-    (multi-step scheduling: admissions land at window boundaries, which
-    is the continuous-batching granularity knob).
-
-    ``tokens``: (B,) last sampled token per slot (written this window);
-    ``lens``: (B,) valid cache length per slot; ``active``: (B,) bool —
-    inactive slots compute garbage into index ``lens[b]`` (free slots
-    keep lens 0) which the next prefill overwrites, and always emit EOS;
-    ``remaining``: (B,) per-slot token budget left. A row that samples
-    EOS or exhausts its budget FREEZES for the rest of the window (emits
-    EOS, writes nothing further) — exactly the `_generate_batch_jit`
-    freeze rule — and the loop exits early once every row froze.
-
-    Returns ``(out (B, steps) EOS-padded, new_lens, steps_run,
-    active_row_steps, new_cache, stats)``; the host appends each row's tokens
-    column-by-column under the same freeze rule, so host and device agree
-    bit-for-bit, and steps_run/active_row_steps feed the occupancy
-    accounting."""
-    return _slot_window_loop(params, tokens, lens, active, remaining, cfg,
-                             kv_cache, temperature, rng, steps)
-
-
-# ---------------------------------------------------------------------------
-# paged slot decode (PagedAttention-style KV pool: explain/slotserve/)
-#
-# The pooled cache above still reserves a worst-case (slots, S, Hkv, d)
-# region per slot. The paged layout below replaces it with a flat pool of
-# fixed-size KV blocks — per layer/tensor (num_pages, page, Hkv, d) — plus a
-# per-slot PAGE TABLE of page ids. Device programs see only gathers and
-# scatters by page id (no data-dependent shapes; table shapes are static),
-# and the page tables themselves mutate on the HOST side of the iteration
-# boundary, so the compiled programs stay shape-stable across any
-# allocation pattern. Shared-prefix caching falls out of the indirection:
-# several tables may point at the same refcounted read-only pages holding
-# the explain template's preamble k/v, prefilled once (PagedAttention /
-# RadixAttention, applied to the slot pool). Allocation policy — refcounts,
-# copy-on-write, exhaustion preemption — lives with the host-side allocator
-# in explain/slotserve/decode.py; nothing here allocates.
-# ---------------------------------------------------------------------------
-
-
 def init_kv_pages(cfg: TransformerConfig, num_pages: int,
                   page_size: int) -> Dict[str, jax.Array]:
-    """The paged twin of ``init_cache``: a flat block pool per layer/tensor
+    """The slot pool's pages: a flat block pool per layer/tensor
     for the layers that cache per token (``attention``: k and v; ``mla``: the
     latent). Page ids index the leading axis; a slot's logical position p
     lives at ``(table[p // page_size], p % page_size)``. A ``kda`` layer pages
@@ -1770,7 +1675,7 @@ def copy_kv_page(kv_pages: Dict[str, jax.Array], src: jax.Array,
 
 def _gather_view(kv_pages: Dict[str, jax.Array],
                  tables: jax.Array) -> Dict[str, jax.Array]:
-    """Materialize the contiguous-layout view of ``tables`` (B, n_view):
+    """Materialize the per-row view of ``tables`` (B, n_view):
     (B, n_view*page, Hkv, d) per layer/tensor. Unallocated table slots hold
     filler id 0 — their gathered content is stale pool data, which the
     decode/prefill masks (never attended) and the scatter-back never
@@ -1799,21 +1704,25 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     first ``prefix_len`` positions of the row are already resident (read-only
     preamble pages every table points at), so only the transcript suffix is
     computed; ``prefix_len == 0`` is the plain no-sharing path. ``length`` is
-    the FULL prompt length (prefix + real suffix), matching the contiguous
-    ``slot_prefill`` convention so the sampled-token position is identical.
+    the FULL prompt length (prefix + real suffix); the first token is
+    sampled at its last real position (same convention as
+    ``_generate_batch_jit``: sample from the prefill logits, then feed tokens
+    back one step at a time).
 
     ``table_row``: (n_view,) page ids covering at least
     ``prefix_len + Ts`` positions. Suffix k/v scatter into the row's own
     pages; the prefix region is only gathered (COW in the allocator
     guarantees a table never points a WRITE position at a shared page).
     ``prefix_len`` is static: one shared preamble per service -> one
-    compile per suffix bucket, same bound as the contiguous ladder.
+    compile per suffix bucket (the bucket ladder bounds the compile count).
 
-    Bit-equality with ``slot_prefill``: suffix activations are position-
-    wise identical; attention reads [cached prefix k/v ; this suffix's
-    k/v] under the same causal mask (row j attends positions <=
-    prefix_len + j), and the masked tail pads with exact zeros — the
-    zero-pad width invariance the slot tests pin.
+    A suffix prefill lands where a whole-prompt prefill does: suffix
+    activations are position-wise identical; attention reads [cached prefix
+    k/v ; this suffix's k/v] under the same causal mask (row j attends
+    positions <= prefix_len + j), and the masked tail contributes exact
+    zeros — the width invariance the slot tests pin. Padding-region k/v DO
+    land in the row's pages at [length, prefix_len + Ts) — garbage, but every
+    later read masks to [0, len] and decode overwrites them in order.
 
     ``state`` / ``slot``: where the model keeps a per-row recurrent state
     (``init_state``), the pool's state arrays and the row admitted. The
@@ -1861,7 +1770,7 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
             new = {"c": lat}
         # Scatter the suffix k/v into the row's own pages (pad-region
         # overhang included — garbage-but-private, masked downstream and
-        # overwritten in order by decode, same as the contiguous path).
+        # overwritten in order by decode).
         with jax.named_scope("kv.scatter_pages"):
             for t, val in new.items():
                 new_pages[f"l{l}.{t}"] = \
@@ -1886,31 +1795,31 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     return tok[0], new_pages, new_state, _pack_stats(stats)
 
 
-@partial(jax.jit, static_argnames=("cfg", "steps", "view_len"))
+@partial(jax.jit, static_argnames=("cfg", "steps"))
 def paged_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
                         active: jax.Array, remaining: jax.Array,
                         cfg: TransformerConfig,
                         kv_pages: Dict[str, jax.Array], tables: jax.Array,
                         temperature: jax.Array, rng: jax.Array,
-                        steps: int, view_len: int,
+                        steps: int,
                         state: Optional[Dict[str, jax.Array]] = None):
-    """`slot_decode_window` over the paged pool: gather every slot's pages
-    into the contiguous (B, view_len, Hkv, d) layout, run the IDENTICAL
-    fused window loop (``_slot_window_loop``), then scatter each row's
-    newly written positions [lens, new_lens) back to its pages.
-
-    ``view_len`` is the contiguous pool's max_len: the gathered view is
-    SLICED to it (the last page may overhang when max_len is not
-    page-aligned), so the window loop runs at exactly the contiguous
-    attention width — bit-equal by construction, not by reduction-order
-    luck.
+    """Up to ``steps`` fused decode iterations for the WHOLE slot pool —
+    iteration-level scheduling with the per-token dispatch amortized
+    (multi-step scheduling: admissions land at window boundaries, which
+    is the continuous-batching granularity knob). Gathers every slot's pages
+    into a (B, n_view*page, Hkv, d) view, runs the fused window loop
+    (``_slot_window_loop``, which documents the row arguments and the freeze
+    rule) over it, then scatters each row's newly written positions
+    [lens, new_lens) back to its pages. A view position past a row's
+    ``lens`` — the last page's overhang past the pool's max_len included —
+    is masked like any other position not yet written.
 
     ``tables``: (B, n_view) page ids; the allocator guarantees every active
     row's table covers [0, lens + steps) before the call, so scatter-back
     positions are always table-resident. Frozen/inactive rows write
-    in-window garbage at their frozen ``lens`` exactly like the contiguous
-    path — it is NOT scattered back (the next admit/step overwrites it
-    before any attend, so dropping it preserves bit-equality).
+    in-window garbage into the view at their frozen ``lens`` — it is NOT
+    scattered back (free slots keep lens 0; the next admit/step overwrites
+    the position before any attend).
 
     ``state``: the pool's per-row recurrent state (``init_state``) where the
     model keeps one; it rides the loop beside the view and comes back whole
@@ -1918,17 +1827,12 @@ def paged_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
     overwrites). Returns ``(out, new_lens, steps_run, active_row_steps,
     pages, state, stats)``."""
     B = tokens.shape[0]
-    page = next(iter(kv_pages.values())).shape[1]
+    num_pages, page = next(iter(kv_pages.values())).shape[:2]
     n_view = tables.shape[1]
-    num_pages = next(iter(kv_pages.values())).shape[0]
-    if not 0 < view_len <= n_view * page:
-        raise ValueError(f"view_len {view_len} outside (0, "
-                         f"{n_view * page}]")
-    view = {name: arr[:, :view_len]
-            for name, arr in _gather_view(kv_pages, tables).items()}
     out, new_lens, i, n_act, new_view, stats = _slot_window_loop(
         params, tokens, lens, active, remaining, cfg,
-        {**view, **(state or {})}, temperature, rng, steps)
+        {**_gather_view(kv_pages, tables), **(state or {})},
+        temperature, rng, steps)
     # Scatter-back: row b wrote view positions [lens[b], new_lens[b]).
     rows = jnp.arange(B)
     pos = lens[:, None] + jnp.arange(steps)[None, :]               # (B, W)
@@ -1939,7 +1843,7 @@ def paged_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
     # out-of-bounds writes, so masked positions never touch the pool.
     pids = jnp.where(valid, pids, num_pages)
     offs = pos % page
-    pos_c = jnp.minimum(pos, view_len - 1)
+    pos_c = jnp.minimum(pos, n_view * page - 1)
     new_pages: Dict[str, jax.Array] = {}
     with jax.named_scope("kv.scatter_pages"):
         for name, arr in kv_pages.items():

@@ -311,20 +311,17 @@ class GameDay:
     hot_swap_at: Optional[float] = None   # virtual seconds
     breaker_threshold: Optional[int] = None
     # Slot-based continuous-batching explain lane (explain/slotserve/,
-    # docs/explain_serving.md): N decode slots serve every flagged row
-    # through the async annotation lane; evidence gains the coverage
+    # docs/explain_serving.md): N decode slots over one refcounted page
+    # pool, the shared explain preamble prefilled once, serve every flagged
+    # row through the async annotation lane; evidence gains the coverage
     # accounting the explain_coverage gate judges.
     explain_slots: Optional[int] = None
     explain_queue: int = 48               # lane queue bound (small = drops
                                           # exercised; every drop records)
     explain_tokens: int = 12
-    # Paged-KV variant of the slotserve lane (docs/explain_serving.md
-    # "Paged KV and prefix sharing"): the lane's KV cache becomes a
-    # refcounted page pool with the shared explain preamble prefilled
-    # once, and ``explain_kv_pages`` caps the pool — pick a budget where
-    # the contiguous per-slot cache could NOT fit ``explain_slots`` slots
-    # and the coverage gate proves paging holds the line anyway.
-    explain_paged: bool = False
+    # Caps the lane's page pool (default: a worst-case row for every
+    # slot) — pick a budget under that reservation and the coverage gate
+    # proves paging holds the line anyway.
     explain_kv_pages: Optional[int] = None
     # The run's watchdog (obs/sentinel/): rules evaluated on the scenario
     # clock while the game day runs, with detects_within gates per seeded
@@ -395,15 +392,11 @@ class GameDay:
             raise ValueError(
                 f"game day {self.name!r}: explain_slots must be >= 1, "
                 f"got {self.explain_slots}")
-        if self.explain_paged and self.explain_slots is None:
-            raise ValueError(
-                f"game day {self.name!r}: explain_paged pages the "
-                "slotserve lane's KV cache — it needs explain_slots")
         if self.explain_kv_pages is not None:
-            if not self.explain_paged:
+            if self.explain_slots is None:
                 raise ValueError(
                     f"game day {self.name!r}: explain_kv_pages caps the "
-                    "paged pool; set explain_paged=True")
+                    "slotserve lane's page pool — it needs explain_slots")
             if self.explain_kv_pages < 1:
                 raise ValueError(
                     f"game day {self.name!r}: explain_kv_pages must be "
@@ -841,22 +834,13 @@ def _run_single(gd: GameDay, serving, broker, feeder: TrafficFeeder,
             TransformerConfig(d_model=64, n_layers=2, n_heads=4, d_ff=128,
                               max_seq=1024),
             seed=clock.derive_seed("explain-lm") % (2 ** 31))
-        # Paged variant: prompt_width widens to 448 so the ~293-token
-        # shared explain preamble fits ahead of the transcript (at 256
-        # the service degrades to unshared with a warning), and the page
-        # pool is capped at gd.explain_kv_pages — a budget the scenario
-        # picks so the contiguous cache could not fit this slot count.
-        paged_kw: dict = {}
-        width = 256
-        if gd.explain_paged:
-            width = 448
-            paged_kw = {"paged": True, "page_size": 64}
-            if gd.explain_kv_pages is not None:
-                paged_kw["kv_pages"] = gd.explain_kv_pages
+        # prompt_width 448: the ~293-token shared explain preamble fits
+        # ahead of the transcript (at 256 the service degrades to unshared
+        # with a warning).
         explain_service = SlotServeService(
             lm, slots=gd.explain_slots, max_queue=4096,
-            max_new_tokens=gd.explain_tokens, prompt_width=width,
-            rowtrace=tracer, **paged_kw)
+            max_new_tokens=gd.explain_tokens, prompt_width=448,
+            rowtrace=tracer, kv_pages=gd.explain_kv_pages)
         hook = make_slot_explain_hook(explain_service,
                                       max_tokens=gd.explain_tokens)
 
@@ -1371,22 +1355,22 @@ def _campaign_explain(seed: int, scale: float) -> GameDay:
 
 
 def _campaign_explain_paged(seed: int, scale: float) -> GameDay:
-    # Pool arithmetic at the paged lane's geometry (page_size 64,
+    # Pool arithmetic at the lane's geometry (page_size 64,
     # prompt_width 448, 12 new tokens → max_len 460, 8 view pages; the
     # ~293-token shared preamble is 5 pages, 4 of them full): each admit
     # needs 4 fresh pages, so 5 + 4*8 = 37 pages serves all 8 slots with
     # zero pool drops — while a 37-page budget would fit only FOUR
-    # contiguous 8-page slots. Coverage == 1.0 at a slot count the
-    # unpaged cache cannot afford is the point of this scenario.
+    # worst-case rows of 8 pages. Coverage == 1.0 at a slot count a
+    # reservation per slot cannot afford is the point of this scenario.
     return GameDay(
         name="campaign_explain_paged",
-        description="The campaign_explain wave on the PAGED slotserve "
-                    "lane: the shared explain preamble is prefilled once "
+        description="The campaign_explain wave on a CAPPED page pool: "
+                    "the shared explain preamble is prefilled once "
                     "into refcounted pages, every admit copy-on-writes "
                     "the partial prefix page and allocates only suffix "
-                    "pages, and the pool is capped where a contiguous "
-                    "cache could not fit the slot count — coverage must "
-                    "still be exactly 1.0 with exact page accounting.",
+                    "pages, and the pool is capped where a worst-case "
+                    "row per slot could not fit the slot count — coverage "
+                    "must still be exactly 1.0 with exact page accounting.",
         seed=seed,
         traffic=(
             SteadyLoad(name="baseline", rate=100 * scale, duration_s=2.5,
@@ -1398,7 +1382,6 @@ def _campaign_explain_paged(seed: int, scale: float) -> GameDay:
         explain_slots=8,
         explain_queue=48,
         explain_tokens=12,
-        explain_paged=True,
         explain_kv_pages=37,
         slos=(
             SloSpec("exact_accounting", kind="exact_accounting"),
@@ -1408,10 +1391,10 @@ def _campaign_explain_paged(seed: int, scale: float) -> GameDay:
                     limit=1),
             SloSpec("slot_accounting_exact", path="explain_accounting_exact",
                     op="==", limit=True),
-            # The paged gates: the preamble must actually be shared (a
+            # The pool's gates: the preamble must actually be shared (a
             # prefix hit per admitted request), the pool must hold the
             # declared cap, and the lane must report real HBM savings
-            # against the contiguous layout at the same slot count.
+            # against a worst-case row for every slot.
             SloSpec("prefix_shared", path="explain.prefix_hits", op=">=",
                     limit=1),
             SloSpec("paged_pool_capped", path="explain.kv_pages", op="==",

@@ -33,8 +33,8 @@ def _decode_case():
     args = (params, jnp.asarray([5, 6, 7], jnp.int32),
             jnp.asarray([10, 20, 3], jnp.int32),
             jnp.asarray([True, True, False]), jnp.asarray([8, 8, 0], jnp.int32),
-            CFG, pages, tables, jnp.zeros(3, jnp.float32), key, 4, 60)
-    return (llm.paged_decode_window, (5, 10, 11), args,
+            CFG, pages, tables, jnp.zeros(3, jnp.float32), key, 4)
+    return (llm.paged_decode_window, (5, 10), args,
             LAYER_SCOPES | {"kv.append"})
 
 
@@ -44,14 +44,6 @@ def _prefill_case():
     args = (params, tokens, jnp.int32(40), CFG, pages, tables[0],
             jnp.float32(0.0), key, 16)
     return llm.paged_slot_prefill, (3, 8), args, LAYER_SCOPES
-
-
-def _contiguous_decode_case():
-    _, _, a, _ = _decode_case()       # the same window over a contiguous pool
-    args = a[:6] + (llm.init_cache(CFG, 3, a[11]),) + a[8:11]
-    return (llm.slot_decode_window, (5, 9), args,
-            LAYER_SCOPES - {"kv.gather_pages", "kv.scatter_pages"}
-            | {"kv.append"})
 
 
 # What the layer kinds beside ("attention", "dense") add (ISSUE 29).
@@ -82,8 +74,8 @@ def _hybrid_decode_case():
     args = (params, jnp.asarray([5, 6, 7], jnp.int32),
             jnp.asarray([10, 20, 3], jnp.int32),
             jnp.asarray([True, True, False]), jnp.asarray([8, 8, 0], jnp.int32),
-            HYBRID, pages, tables, jnp.zeros(3, jnp.float32), key, 4, 60, state)
-    return (llm.paged_decode_window, (5, 10, 11), args,
+            HYBRID, pages, tables, jnp.zeros(3, jnp.float32), key, 4, state)
+    return (llm.paged_decode_window, (5, 10), args,
             LAYER_SCOPES - {"attn.scores", "attn.values"} | HYBRID_SCOPES
             | {"kv.append", "kda.step", "mla.absorb"})
 
@@ -132,7 +124,7 @@ def _tree_case():
             {"score.unpack", "score.traverse"})
 
 
-@pytest.mark.parametrize("case", [_decode_case, _contiguous_decode_case,
+@pytest.mark.parametrize("case", [_decode_case,
                                   _prefill_case, _lr_case, _tree_case,
                                   _hybrid_decode_case, _hybrid_prefill_case])
 def test_scopes_are_named_and_change_no_number(case, monkeypatch):
@@ -163,8 +155,7 @@ def test_scopes_are_named_and_change_no_number(case, monkeypatch):
 
 
 @pytest.mark.parametrize("case,view", [
-    (_decode_case, (3, 60)),              # 3 slots, view_len 60
-    (_contiguous_decode_case, (3, 60)),
+    (_decode_case, (3, 64)),              # 3 slots, 4 pages of 16
     (_prefill_case, (1, 64)),             # one row, 4 pages of 16
 ])
 def test_slot_programs_attend_the_narrow_kv(case, view):
@@ -180,8 +171,7 @@ def test_slot_programs_attend_the_narrow_kv(case, view):
     assert f"tensor<{B}x{S}x{CFG.n_heads}x{CFG.head_dim}x" not in text
 
 
-@pytest.mark.parametrize("case", [_decode_case, _contiguous_decode_case,
-                                  _prefill_case])
+@pytest.mark.parametrize("case", [_decode_case, _prefill_case])
 def test_dense_programs_carry_none_of_the_hybrid_scopes(case):
     """A model of ("attention", "dense") layers lowers to the program it
     always was: no scope of another layer kind, no counters, no state."""

@@ -247,8 +247,8 @@ def _explained_desk(pipeline, slot_lm, tracer, n=48, scam_every=6):
                                                        make_slot_explain_hook)
 
     svc = SlotServeService(slot_lm, slots=2, max_new_tokens=24,
-                           prompt_width=960, decode_window=4, paged=True,
-                           page_size=64, rowtrace=tracer,
+                           prompt_width=960, decode_window=4, page_size=64,
+                           rowtrace=tracer,
                            wait_timeout=120.0)
     try:
         broker = InProcessBroker(num_partitions=3)

@@ -1,10 +1,10 @@
-"""Paged-KV invariant suite (docs/explain_serving.md, PR 19).
+"""Page-pool invariant suite (docs/explain_serving.md, PR 19).
 
 Pins the Pagecraft CLAIMS:
 
-* **bit-equality** — greedy decode through the paged pool (page-table
-  gather/scatter + shared-prefix reuse + COW) emits exactly the contiguous
-  slot pool's tokens, including after slot reuse;
+* **bit-equality** — greedy decode through the page pool (page-table
+  gather/scatter + shared-prefix reuse + COW) emits exactly the fixed-batch
+  decode's tokens, including after slot reuse;
 * **exact accounting** — the page allocator identity
   ``free + pages_with_refs == total`` (and the ref ledger
   ``refs == pages_in_tables + prefix_base_refs``) holds at every
@@ -54,7 +54,7 @@ def make_service(lm, **kw):
 
 def analysis_prompts(n):
     """Framed analysis prompts — every one opens with the shared preamble,
-    so paged admits hit the prefix cache."""
+    so admits hit the prefix cache."""
     out = []
     for i in range(n):
         d = ("Caller: this is your bank security department, read me the "
@@ -65,8 +65,17 @@ def analysis_prompts(n):
     return out
 
 
+def fixed_batch_greedy(lm, svc, framed, max_new):
+    """The fixed-batch decode's greedy texts (``generate_tokens_batch``: what
+    ``OnPodBackend.generate_batch`` runs) for already-framed prompts, cut to
+    the lane's prompt width as the lane cuts them."""
+    toks = [svc._decoder.encode_prompt(p)[0] for p in framed]
+    return [lm.tokenizer.decode(t) for t in lm.generate_tokens_batch(
+        toks, max_new_tokens=max_new)]
+
+
 def assert_quiescent(svc):
-    """Paged decoder at quiescence after close(): identity + zero leaks."""
+    """The decoder at quiescence after close(): identity + zero leaks."""
     dec = svc._decoder
     assert dec.leaked_pages == 0
     assert dec.allocator.free == dec.total_pages
@@ -210,33 +219,27 @@ def test_pages_needed_counts_only_fresh_pages(lm):
 
 
 # ---------------------------------------------------------------------------
-# bit-equality: paged vs contiguous through the full service
+# bit-equality: the page pool vs the fixed-batch decode, through the service
 # ---------------------------------------------------------------------------
 
 def test_paged_outputs_bit_equal_with_reuse_and_cow(lm):
     """10 analysis prompts through 4 slots: slot reuse, shared-prefix
     admits, COW on the partial preamble page — outputs must match the
-    contiguous pool byte for byte (the paged view is sliced to max_len
-    472, a non-page-aligned width, so this also pins the overhang
-    slice)."""
+    fixed-batch greedy decode byte for byte (max_len is 472, not
+    page-aligned: the view's 40-position overhang is masked, which this
+    also pins)."""
     prompts = analysis_prompts(10)
-
-    def serve(svc):
-        reqs = [svc.submit(p, temperature=0.0) for p in prompts]
-        return [r.wait(120.0) for r in reqs]
-
-    contig = make_service(lm)
+    paged = make_service(lm)
     try:
-        want = serve(contig)
-    finally:
-        contig.close()
-    paged = make_service(lm, paged=True, page_size=64)
-    try:
-        got = serve(paged)
+        reqs = [paged.submit(p, temperature=0.0) for p in prompts]
+        got = [r.wait(120.0) for r in reqs]
         snap = paged.snapshot()
+        want = fixed_batch_greedy(lm, paged, prompts, 24)
     finally:
         paged.close()
+    assert paged._decoder.max_len == 472
     assert got == want
+    assert len(set(got)) > 1
     assert snap["prefix_hits"] == 10
     assert snap["cow_copies"] == 10          # 293-token preamble: partial page
     assert snap["prefix_pages"] == 5
@@ -245,19 +248,16 @@ def test_paged_outputs_bit_equal_with_reuse_and_cow(lm):
 
 
 def test_paged_without_prefix_still_bit_equal(lm):
-    """shared_prefix=False: the plain paged path (prefix_len 0) must also
-    match contiguous — no hidden dependence on the preamble cache."""
+    """shared_prefix=False: whole-prompt admission (prefix_len 0) must also
+    match the fixed-batch decode — no hidden dependence on the preamble
+    cache."""
     prompts = analysis_prompts(6)
-    contig = make_service(lm, slots=2)
+    paged = make_service(lm, slots=2, shared_prefix=False)
     try:
-        want = contig.generate_batch(prompts, temperature=0.0)
-    finally:
-        contig.close()
-    paged = make_service(lm, slots=2, paged=True, page_size=64,
-                         shared_prefix=False)
-    try:
-        got = paged.generate_batch(prompts, temperature=0.0)
+        reqs = [paged.submit(p, temperature=0.0) for p in prompts]
+        got = [r.wait(120.0) for r in reqs]
         snap = paged.snapshot()
+        want = fixed_batch_greedy(lm, paged, prompts, 24)
     finally:
         paged.close()
     assert got == want
@@ -266,12 +266,11 @@ def test_paged_without_prefix_still_bit_equal(lm):
 
 
 def test_paged_sampled_decode_deterministic_per_seed(lm):
-    """Non-greedy rows stay per-seed deterministic through the paged pool
-    (same PRNG threading as contiguous)."""
+    """Non-greedy rows stay per-seed deterministic through the page pool."""
     p = analysis_prompts(2)
     outs = []
     for _ in range(2):
-        svc = make_service(lm, slots=2, paged=True, page_size=64, seed=5)
+        svc = make_service(lm, slots=2, seed=5)
         try:
             outs.append(svc.generate_batch(p, temperature=0.8,
                                            max_tokens=12))
@@ -285,8 +284,7 @@ def test_paged_sampled_decode_deterministic_per_seed(lm):
 # ---------------------------------------------------------------------------
 
 def test_paged_queue_overflow_accounting_and_no_leaks(lm):
-    svc = make_service(lm, slots=1, max_queue=2, max_new_tokens=8,
-                       paged=True, page_size=64)
+    svc = make_service(lm, slots=1, max_queue=2, max_new_tokens=8)
     try:
         reqs = [svc.submit(p, max_tokens=8) for p in analysis_prompts(8)]
         texts = [r.wait(120.0) for r in reqs]
@@ -301,7 +299,7 @@ def test_paged_queue_overflow_accounting_and_no_leaks(lm):
 
 
 def test_paged_close_residue_accounting_and_no_leaks(lm):
-    svc = make_service(lm, slots=1, max_queue=64, paged=True, page_size=64)
+    svc = make_service(lm, slots=1, max_queue=64)
     reqs = [svc.submit(p, max_tokens=24) for p in analysis_prompts(6)]
     svc.close(timeout=0.05)
     texts = [r.wait(120.0) for r in reqs]
@@ -313,7 +311,7 @@ def test_paged_close_residue_accounting_and_no_leaks(lm):
 
 def test_paged_decoder_death_releases_pages_then_recovers(lm):
     from fraud_detection_tpu.explain.backends import BackendError
-    svc = make_service(lm, slots=2, paged=True, page_size=64)
+    svc = make_service(lm, slots=2)
     try:
         real_step = svc._decoder.step
 
@@ -343,8 +341,7 @@ def test_pool_exhaustion_preempts_newest_admit(lm):
     admit as an accounted ``kv_pages_exhausted`` drop and the survivors
     finish. Forced deterministically by denying growth for whichever slot
     was admitted last."""
-    svc = make_service(lm, slots=2, paged=True, page_size=64,
-                       shared_prefix=False)
+    svc = make_service(lm, slots=2, shared_prefix=False)
     try:
         real_grow = svc._decoder.grow_for_window
         denied = {"armed": True}
@@ -392,18 +389,10 @@ def test_grow_for_window_reports_real_exhaustion(lm):
 # ---------------------------------------------------------------------------
 
 def test_snapshot_paged_block_values(lm):
-    """Contiguous mode reports zeros; paged mode reports the pool. The key
-    SET is pinned by test_slotserve.py::SLOTSERVE_BLOCK_SCHEMA."""
-    contig = make_service(lm, slots=2)
-    try:
-        snap = contig.snapshot()
-        assert snap["kv_pages"] == 0 and snap["page_bytes"] == 0
-        assert snap["pages_free"] == 0 and snap["prefix_pages"] == 0
-        assert snap["kv_bytes_saved_vs_contiguous"] == 0
-    finally:
-        contig.close()
+    """The snapshot reports the pool. The key SET is pinned by
+    test_slotserve.py::SLOTSERVE_BLOCK_SCHEMA."""
     # Reduced pool: the headline kv_bytes saving is positive.
-    paged = make_service(lm, slots=2, paged=True, page_size=64, kv_pages=13)
+    paged = make_service(lm, slots=2, kv_pages=13)
     try:
         snap = paged.snapshot()
         assert snap["kv_pages"] == 13
@@ -420,6 +409,64 @@ def test_snapshot_paged_block_values(lm):
     assert_quiescent(paged)
 
 
+def test_default_service_serves_from_pages(lm):
+    """A service built with defaults has the one pool there is: a worst-case
+    row of pages for every slot (2 x 8 at width 448 + 24), the preamble
+    prefilled into 5 of them. The ``paged`` keyword is all that is left of
+    the switch."""
+    svc = SlotServeService(lm, slots=2, max_new_tokens=24, prompt_width=448)
+    try:
+        snap = svc.snapshot()
+        assert snap["kv_pages"] == 16 and snap["page_bytes"] > 0
+        assert snap["prefix_pages"] == 5
+        assert snap["pages_free"] == 11
+    finally:
+        svc.close()
+    assert_quiescent(svc)
+    with pytest.raises(ValueError, match="paged"):
+        SlotServeService(lm, slots=2, paged=False)
+
+
+def test_set_prefix_returns_the_scratch_page(lm):
+    """The preamble's prefill runs at a bucketed width (64) over pages of
+    16: 20 tokens hold 2 pages and the table row covers 4; the 2 that held
+    only padding are back on the free list before the first admission."""
+    dec = PagedSlotDecoder(lm, 2, prompt_width=128, max_new_tokens=32,
+                           page_size=16, prompt_bucket=64)
+    dec.set_prefix("p" * 19)                   # 20 tokens with BOS
+    assert dec.prefix_pages == 2
+    assert dec.allocator.in_use == dec.prefix_pages
+    snap = dec.allocator_snapshot()
+    assert snap["refs"] == snap["prefix_base_refs"] == 2
+    dec.close()
+    assert dec.leaked_pages == 0
+
+
+def test_dense_preamble_pages_equal_whole_prompt_prefill(lm):
+    """After ``set_prefix`` the preamble's pages hold, position for
+    position, what a whole-prompt prefill of a prompt that starts with the
+    preamble writes into its own pages (the dense twin of the hybrid's
+    snapshot test below)."""
+    prompt = analysis_prompts(1)[0]
+    shared = PagedSlotDecoder(lm, 2, prompt_width=448, max_new_tokens=8,
+                              prefix_text=shared_explain_prefix())
+    whole = PagedSlotDecoder(lm, 2, prompt_width=448, max_new_tokens=8)
+    toks, _ = shared.encode_prompt(prompt)
+    whole.prefill(1, toks, 0.0, 0)
+    lp = shared._prefix_len
+    assert lp == 293 and np.array_equal(toks[:lp], shared._prefix_tokens)
+    for name in shared.pages:
+        pre = np.concatenate([np.asarray(shared.pages[name][pid])
+                              for pid in shared._prefix_pids])[:lp]
+        row = np.concatenate([np.asarray(whole.pages[name][pid])
+                              for pid in whole._owned[1]])[:lp]
+        assert pre.any()
+        assert np.array_equal(pre, row), name
+    for d in (shared, whole):
+        d.close()
+        assert d.leaked_pages == 0
+
+
 def test_shared_prefix_matches_analysis_prompts(lm):
     """Every framed analysis prompt tokenizes to preamble + suffix —
     the split the prefix cache keys on."""
@@ -432,13 +479,13 @@ def test_shared_prefix_matches_analysis_prompts(lm):
 
 
 # ---------------------------------------------------------------------------
-# game day: the paged lane under a campaign wave
+# game day: the lane on a capped pool under a campaign wave
 # ---------------------------------------------------------------------------
 
 @pytest.mark.scenario
 def test_campaign_explain_paged_gameday_passes():
-    """The paged slotserve lane holds coverage == 1.0 on a 37-page pool
-    where a contiguous cache would fit only half the slot count, with a
+    """The slotserve lane holds coverage == 1.0 on a 37-page pool where a
+    worst-case row per slot would fit only half the slot count, with a
     prefix hit per admit and exact page accounting (the scenario's own
     prefix_shared / paged_pool_capped / hbm_saved gates)."""
     from fraud_detection_tpu.scenarios.gameday import (get_scenario,
@@ -467,20 +514,17 @@ def test_gameday_validation_rejects_bad_paged_configs():
     traffic = (SteadyLoad(name="s", rate=10, duration_s=1.0),)
     with pytest.raises(ValueError, match="needs explain_slots"):
         GameDay(name="x", description="", traffic=traffic, slos=(),
-                explain_paged=True)
-    with pytest.raises(ValueError, match="set explain_paged"):
-        GameDay(name="x", description="", traffic=traffic, slos=(),
-                explain_slots=4, explain_kv_pages=37)
+                explain_kv_pages=37)
     with pytest.raises(ValueError, match="explain_kv_pages must be"):
         GameDay(name="x", description="", traffic=traffic, slos=(),
-                explain_slots=4, explain_paged=True, explain_kv_pages=0)
+                explain_slots=4, explain_kv_pages=0)
 
 
 # ---------------------------------------------------------------------------
-# serve CLI: --explain-paged / --explain-kv-pages
+# serve CLI: --explain-kv-pages
 # ---------------------------------------------------------------------------
 
-def test_serve_cli_explain_paged_e2e(capsys):
+def test_serve_cli_explain_kv_pages_e2e(capsys):
     import json
 
     from fraud_detection_tpu.app.serve import main as serve_main
@@ -488,12 +532,11 @@ def test_serve_cli_explain_paged_e2e(capsys):
     # Pool arithmetic at the CLI lane's geometry (prompt_width 384 +
     # 8 new tokens -> max_len 392 -> 7 view pages; the ~293-token shared
     # preamble is 5 pages, 4 full): 12 pages holds prefix + both slots
-    # (5 + 3*2 = 11) and undercuts the contiguous 2*392-row cache.
+    # (5 + 3*2 = 11) and undercuts a reservation of 2 * 7 pages.
     rc = serve_main(["--model", "synthetic", "--demo", "120",
                      "--batch-size", "64", "--max-wait", "0.01",
                      "--explain", "onpod-demo", "--explain-slots", "2",
-                     "--explain-tokens", "8", "--explain-paged",
-                     "--explain-kv-pages", "12"])
+                     "--explain-tokens", "8", "--explain-kv-pages", "12"])
     assert rc == 0
     out = capsys.readouterr().out
     stats = json.loads([l for l in out.splitlines()
@@ -502,7 +545,7 @@ def test_serve_cli_explain_paged_e2e(capsys):
     assert snap["slots"] == 2
     assert snap["admitted"] == snap["completed"] + snap["dropped"]
     assert snap["completed"] > 0
-    # The paged pool is live, capped, saving HBM, and the preamble was
+    # The pool is capped, saving HBM, and the preamble was
     # shared across every admit.
     assert snap["kv_pages"] == 12 and snap["page_bytes"] > 0
     assert snap["prefix_hits"] == snap["admitted"]
@@ -510,20 +553,16 @@ def test_serve_cli_explain_paged_e2e(capsys):
     assert stats["health"]["explain"]["kv_pages"] == 12
 
 
-def test_serve_cli_explain_paged_validation():
+def test_serve_cli_explain_kv_pages_validation():
     from fraud_detection_tpu.app.serve import main as serve_main
 
     with pytest.raises(SystemExit, match="needs --explain-slots"):
         serve_main(["--model", "synthetic", "--demo", "10",
-                    "--explain", "onpod-demo", "--explain-paged"])
-    with pytest.raises(SystemExit, match="set --explain-paged"):
-        serve_main(["--model", "synthetic", "--demo", "10",
-                    "--explain", "onpod-demo", "--explain-slots", "2",
-                    "--explain-kv-pages", "32"])
+                    "--explain", "onpod-demo", "--explain-kv-pages", "32"])
     with pytest.raises(SystemExit, match="explain-kv-pages must be"):
         serve_main(["--model", "synthetic", "--demo", "10",
                     "--explain", "onpod-demo", "--explain-slots", "2",
-                    "--explain-paged", "--explain-kv-pages", "-1"])
+                    "--explain-kv-pages", "-1"])
 
 
 # ---------------------------------------------------------------------------

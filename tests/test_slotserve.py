@@ -369,8 +369,8 @@ SLOTSERVE_BLOCK_SCHEMA = {
     "slot_steps_backlogged": (int,),
     "tokens_out": (int,),
     "kv_bytes": (int,),
-    # Paged-pool block (PR 19): zeros in contiguous mode so the schema is
-    # mode-independent — FC301 pins these against snapshot()'s literal.
+    # The page pool (PR 19) — FC301 pins these against snapshot()'s
+    # literal.
     "kv_pages": (int,),
     "page_bytes": (int,),
     "pages_free": (int,),
@@ -410,14 +410,18 @@ def test_snapshot_schema_contract(lm):
         svc.close()
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_slot_steps_partition_into_occupied_starved_backlogged(lm, paged):
+@pytest.mark.parametrize("shared_prefix", [True, False])
+def test_slot_steps_partition_into_occupied_starved_backlogged(lm,
+                                                               shared_prefix):
     """Every slot-step of every decode window is counted once: a row
     decoded in it, or the slot was free with the queue empty (starved), or
     free with requests waiting (backlogged). A mixed run has all three:
     12 rows over 4 slots admit 2 an iteration (free slots, rows queued),
-    then one row decodes alone (free slots, nothing queued)."""
-    svc = make_service(lm, slots=4, max_new_tokens=16, paged=paged)
+    then one row decodes alone (free slots, nothing queued). Both
+    deployments of the pool count alike: the preamble resident in 5 shared
+    pages, or every page a slot's own."""
+    svc = make_service(lm, slots=4, max_new_tokens=16,
+                       shared_prefix=shared_prefix)
     try:
         svc.generate_batch(prompts_varied(12), temperature=0.0, max_tokens=16)
         svc.generate_batch(prompts_varied(1, base=90), temperature=0.0,
@@ -430,6 +434,7 @@ def test_slot_steps_partition_into_occupied_starved_backlogged(lm, paged):
         assert starved > 0
         assert snap["occupancy"] == pytest.approx(
             occupied / (snap["decode_steps"] * 4), abs=1e-4)
+        assert snap["prefix_pages"] == (5 if shared_prefix else 0)
     finally:
         assert svc.close()
 
@@ -563,9 +568,10 @@ def test_gameday_validation_rejects_bad_configs():
 
 @pytest.fixture(scope="module")
 def hybrid_runs():
-    """Five prompts (shared preamble, three lengths) through two
-    slots, contiguous and paged, greedy, float32: per mode the tickets'
-    tokens, the snapshot before the close and the one after."""
+    """Five prompts (shared preamble, three lengths) through two slots,
+    greedy, float32, admitted behind the shared preamble (``True``) and as
+    whole prompts (``False``): per run the tickets' tokens, the snapshot
+    before the close and the one after."""
     import hybrid_tiny
     from fraud_detection_tpu.explain.slotserve.service import \
         shared_explain_prefix
@@ -577,41 +583,43 @@ def hybrid_runs():
                + " now." * (40 * (i % 3)) + f" Customer {i} hesitates: {'no' * i}"
                for i in range(5)]
     runs = {"lm": lm, "prompts": prompts}
-    for paged in (False, True):
+    for shared in (True, False):
         svc = make_service(lm, slots=2, max_new_tokens=8, prompt_width=832,
-                           decode_window=4, paged=paged, wait_timeout=600.0)
+                           decode_window=4, shared_prefix=shared,
+                           wait_timeout=600.0)
         reqs = [svc.submit(p, temperature=0.0) for p in prompts]
         for r in reqs:
             r.wait(600.0)
         snap = svc.snapshot()
         assert svc.close()
-        runs[paged] = {"prompts": [np.asarray(r.tokens) for r in reqs],
-                       "served": [np.asarray(r.out) for r in reqs],
-                       "snapshot": snap, "after": svc.snapshot()}
+        runs[shared] = {"prompts": [np.asarray(r.tokens) for r in reqs],
+                        "served": [np.asarray(r.out) for r in reqs],
+                        "snapshot": snap, "after": svc.snapshot()}
     return runs
 
 
 def test_hybrid_slot_window_matches_fixed_batch_greedy(hybrid_runs):
-    """The slot programs (chunked prefill into a slot's state block, stepped
-    decode, slot reuse) emit ``_generate_batch_jit``'s greedy tokens."""
+    """The slot programs (chunked prefill behind the preamble's snapshot
+    into a slot's state block, stepped decode, slot reuse) emit
+    ``_generate_batch_jit``'s greedy tokens."""
     lm = hybrid_runs["lm"]
     want = lm.generate_tokens_batch(
         [lm.tokenizer.encode(p) for p in hybrid_runs["prompts"]],
         max_new_tokens=8)
-    for got, row in zip(hybrid_runs[False]["served"], want):
+    for got, row in zip(hybrid_runs[True]["served"], want):
         assert got.tolist() == row[:len(got)].tolist()
 
 
-def test_hybrid_paged_equals_contiguous(hybrid_runs):
-    """Latent pages + a state snapshot of the preamble restored on admission
-    serve the tokens of whole-prompt prefills into a contiguous pool."""
+def test_hybrid_shared_preamble_serves_whole_prompt_tokens(hybrid_runs):
+    """Latent pages mapped copy-on-write + a state snapshot of the preamble
+    restored on admission serve the tokens of whole-prompt admission."""
     for a, b in zip(hybrid_runs[True]["served"], hybrid_runs[False]["served"]):
         assert a.tolist() == b.tolist()
     assert len({tuple(a.tolist()) for a in hybrid_runs[True]["served"]}) > 1
 
 
 def test_hybrid_served_tokens_are_the_references_first_choice(hybrid_runs):
-    """Every token the paged slot lane served in float32 is the plain
+    """Every token the slot lane served in float32 is the plain
     reference's best at its position, teacher-forced (gap under 1e-4: the
     float32 tolerance of tests/test_llm.py)."""
     import hybrid_tiny
@@ -625,9 +633,10 @@ def test_hybrid_served_tokens_are_the_references_first_choice(hybrid_runs):
     assert max(float(g.max()) for g in gaps) < 1e-4
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_hybrid_snapshot_counts_routing_and_restores(hybrid_runs, paged):
-    snap = hybrid_runs[paged]["snapshot"]
+@pytest.mark.parametrize("shared_prefix", [True, False])
+def test_hybrid_snapshot_counts_routing_and_restores(hybrid_runs,
+                                                     shared_prefix):
+    snap = hybrid_runs[shared_prefix]["snapshot"]
     assert set(snap) == set(SLOTSERVE_BLOCK_SCHEMA)
     for key, types in SLOTSERVE_BLOCK_SCHEMA.items():
         assert isinstance(snap[key], types), (key, type(snap[key]))
@@ -638,14 +647,16 @@ def test_hybrid_snapshot_counts_routing_and_restores(hybrid_runs, paged):
     # a decode step touches at most the 4 held experts of each of 6 layers
     assert 0 < snap["moe_experts_touched"] <= 24 * snap["decode_steps"]
     assert snap["moe_prefill_load_max"] >= snap["moe_prefill_load_mean"] > 0
-    # admission restores the preamble's snapshot into the slot's block where
-    # pages are mapped; the contiguous pool prefills whole prompts
-    assert snap["state_restores"] == (snap["prefills"] + 1 if paged else 0)
+    # every admission copies the state its prefill starts from into the
+    # slot's block: the preamble's snapshot where the prompt shares it, zeros
+    # for a whole prompt (and once more for the warm-up's row)
+    assert snap["state_restores"] == snap["prefills"] + 1
+    assert snap["prefix_hits"] == (5 if shared_prefix else 0)
     json.dumps(snap)
 
 
 def test_dense_snapshot_counters_stay_zero(lm):
-    svc = make_service(lm, slots=2, max_new_tokens=4, paged=True)
+    svc = make_service(lm, slots=2, max_new_tokens=4)
     try:
         svc.generate_batch(["one row"], temperature=0.0, max_tokens=4)
         snap = svc.snapshot()
