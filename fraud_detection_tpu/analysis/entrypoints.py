@@ -257,10 +257,13 @@ CONCURRENT_CLASSES: Mapping[str, ClassSpec] = {
     "sched/batcher.py::DispatchLane": _spec(
         any_thread=("stats",),
         dispatch_lane=("_run",)),
-    # Annotation lane: one worker drains the queue; stats() polls cross-
-    # thread; submit() comes from the engine driver.
+    # Annotation lane: one worker hands queued rows to the hook and
+    # delivers them; stats() polls cross-thread; submit() comes from the
+    # engine driver; _ticket_done from whoever resolves a handed-over
+    # row's ticket (the slotserve lane, a closer) — under _cv like the
+    # rest of the shared state.
     "stream/annotations.py::AsyncAnnotationLane": _spec(
-        any_thread=("stats",),
+        any_thread=("stats", "_ticket_done"),
         annotation_lane=("_run",)),
     # Shadow scorer: worker rescopes batches; the engine driver calls
     # wants()/submit(); the lifecycle watcher sets/clears candidates;
@@ -373,8 +376,8 @@ CONCURRENT_CLASSES: Mapping[str, ClassSpec] = {
     # worker-only, request resolution via per-request events.
     "explain/slotserve/service.py::SlotServeService": _spec(
         any_thread=("submit", "chat", "generate", "generate_batch",
-                    "explain_rows", "snapshot", "drain", "close",
-                    "set_rowtrace"),
+                    "submit_rows", "explain_rows", "snapshot", "drain",
+                    "close", "set_rowtrace"),
         slotserve_lane=("_run",)),
     # Learn loop (learn/loop.py, docs/online_learning.md): _run (and the
     # ingestion/retrain/replay methods it reaches) executes on the one

@@ -31,12 +31,15 @@ management in the spirit of vLLM, SOSP '23):
 Surfaces: the ``LLMBackend`` protocol (``chat``/``generate``/
 ``generate_batch``) so the service drops in anywhere ``OnPodBackend`` does
 (incl. behind the PR 1 circuit breaker — explain/circuit.py forwards
-``explain_rows`` too), plus :meth:`SlotServeService.explain_rows` which
-also takes the rows' PR 10 trace cids so every explained row's
-``chain(cid)`` shows poll→flag→explain→annotate with its slot and queue
-wait. :func:`make_slot_explain_hook` adapts it to the engine's
+``explain_rows`` too), plus :meth:`SlotServeService.submit_rows` /
+:meth:`~SlotServeService.explain_rows` (tickets back unresolved / wait for
+them all) which also take the rows' PR 10 trace cids so every explained
+row's ``chain(cid)`` shows poll→flag→explain→annotate with its slot and
+queue wait. :func:`make_slot_explain_hook` adapts them to the engine's
 ``explain_batch_fn`` shape; the async annotation lane passes cids through
-when the hook advertises ``accepts_cids``.
+when the hook advertises ``accepts_cids`` and, where it advertises
+``submit_rows``, keeps a window of rows' tickets in the service and
+delivers each as it resolves (stream/annotations.py).
 
 Degradation contract: a decoder failure fails every in-flight and queued
 request with :class:`~fraud_detection_tpu.explain.backends.BackendError`
@@ -86,7 +89,7 @@ class _SlotRequest:
 
     __slots__ = ("tokens", "max_new", "temperature", "cid", "submitted_at",
                  "submitted_wall", "first_token_at", "out", "text", "dropped",
-                 "error", "done", "slot")
+                 "error", "done", "slot", "_watchers", "_watch_lock")
 
     def __init__(self, tokens, max_new: int, temperature: float,
                  cid: Optional[str], submitted_at: float,
@@ -106,6 +109,32 @@ class _SlotRequest:
         self.error: Optional[BaseException] = None
         self.done = threading.Event()
         self.slot: Optional[int] = None
+        self._watchers: List[Callable] = []
+        self._watch_lock = threading.Lock()
+
+    def resolve(self) -> None:
+        """Latch ``done`` and tell whoever asked to be told. The result
+        fields (``text`` / ``dropped`` / ``error``) are set before this."""
+        with self._watch_lock:
+            self.done.set()
+            watchers, self._watchers = self._watchers, []
+        for fn in watchers:
+            try:
+                fn(self)
+            except Exception:  # noqa: BLE001 — a watcher must not fail the lane
+                log.exception("slot ticket's done callback raised")
+
+    def add_done_callback(self, fn: Callable) -> None:
+        """Call ``fn(ticket)`` once the request has resolved: at once on
+        the caller's thread if it already has, else on the thread that
+        resolves it (the slot lane's; a submitter's or closer's for a
+        drop). ``fn`` must be quick: the annotation lane's only notes the
+        ticket and wakes its worker."""
+        with self._watch_lock:
+            if not self.done.is_set():
+                self._watchers.append(fn)
+                return
+        fn(self)
 
     def wait(self, timeout: Optional[float]) -> str:
         """Block until the request resolves; returns the explanation text
@@ -279,7 +308,7 @@ class SlotServeService:
             if self._rowtrace is not None and old.cid is not None:
                 self._rowtrace.record_event(old.cid, "explain", ok=False,
                                             detail=f"dropped:{old.dropped}")
-            old.done.set()
+            old.resolve()
         return req
 
     # ------------------------------------------------------------------
@@ -309,16 +338,18 @@ class SlotServeService:
                 for p in prompts]
         return [r.wait(self.wait_timeout) for r in reqs]
 
-    def explain_rows(self, texts: Sequence[str], labels: Sequence[int],
-                     confs: Sequence[float], *,
-                     cids: Optional[Sequence[Optional[str]]] = None,
-                     temperature: float = 0.0,
-                     max_tokens: int = 128) -> List[str]:
-        """Explain flagged rows WITH their trace identity: each row's
-        analysis prompt is built here (same ``analysis_prompt`` +
-        chat-template framing as every other path) and its cid rides into
-        the slot, so the completed row's ``chain(cid)`` carries an
-        "explain" span with slot + latency detail."""
+    def submit_rows(self, texts: Sequence[str], labels: Sequence[int],
+                    confs: Sequence[float], *,
+                    cids: Optional[Sequence[Optional[str]]] = None,
+                    temperature: float = 0.0,
+                    max_tokens: int = 128) -> List[_SlotRequest]:
+        """Enqueue flagged rows WITH their trace identity and hand back
+        their tickets unresolved; never blocks. Each row's analysis prompt
+        is built here (same ``analysis_prompt`` + chat-template framing as
+        every other path) and its cid rides into the slot, so the
+        completed row's ``chain(cid)`` carries an "explain" span with slot
+        + latency detail. The annotation lane's hook hands rows over
+        through this and delivers each ticket as it resolves."""
         from fraud_detection_tpu.explain.prompts import analysis_prompt
 
         reqs = []
@@ -328,6 +359,18 @@ class SlotServeService:
             reqs.append(self.submit(prompt, max_tokens=max_tokens,
                                     temperature=temperature,
                                     cid=cids[i] if cids else None))
+        return reqs
+
+    def explain_rows(self, texts: Sequence[str], labels: Sequence[int],
+                     confs: Sequence[float], *,
+                     cids: Optional[Sequence[Optional[str]]] = None,
+                     temperature: float = 0.0,
+                     max_tokens: int = 128) -> List[str]:
+        """:meth:`submit_rows`, then wait for every ticket: the blocking
+        form (the breaker forwards this one; ``warm`` paths call it)."""
+        reqs = self.submit_rows(texts, labels, confs, cids=cids,
+                                temperature=temperature,
+                                max_tokens=max_tokens)
         return [r.wait(self.wait_timeout) for r in reqs]
 
     # ------------------------------------------------------------------
@@ -505,7 +548,7 @@ class SlotServeService:
                     "(%d tokens emitted) to free its pages",
                     slot, len(req.out))
         self._release(slot)
-        req.done.set()
+        req.resolve()
 
     def _emit(self, slot: int, tok: int) -> None:
         """Record one prefill-emitted token; a row whose FIRST token is
@@ -544,7 +587,7 @@ class SlotServeService:
                 req.cid, "explain", dt, start=self._rowtrace.wall(),
                 detail=f"slot={slot} tokens={len(req.out)} "
                        f"admit_ms={wait_ms}")
-        req.done.set()
+        req.resolve()
 
     def _release(self, slot: int) -> None:
         # Pages first, slot second: a slot on the free list ALWAYS has an
@@ -589,7 +632,7 @@ class SlotServeService:
             if self._rowtrace is not None and req.cid is not None:
                 self._rowtrace.record_event(req.cid, "explain", ok=False,
                                             detail=type(exc).__name__)
-            req.done.set()
+            req.resolve()
 
     # ------------------------------------------------------------------
     # lifecycle + observability (any thread)
@@ -624,7 +667,7 @@ class SlotServeService:
             self._closed = True
             self._cv.notify()
         for req in residual:
-            req.done.set()
+            req.resolve()
         self._thread.join(timeout=min(10.0, max(0.2, timeout)))
         if not self._thread.is_alive():
             # Quiescence: the lane is down, every slot released — return
@@ -708,6 +751,32 @@ class SlotServeService:
         }
 
 
+class _RowTicket:
+    """One row's slot ticket as the annotation lane holds it
+    (stream/annotations.py: ``add_done_callback`` + ``result`` +
+    ``timeout``). ``result`` is the row's analysis — the served text, the
+    drop marker, or the unavailable marker where the decoder failed or
+    the ticket outlived ``timeout`` unresolved — so every flagged row
+    lands explained or accounted, each on its own."""
+
+    __slots__ = ("_req", "timeout")
+
+    def __init__(self, req: _SlotRequest, timeout: Optional[float]):
+        self._req = req
+        self.timeout = timeout
+
+    def add_done_callback(self, fn: Callable) -> None:
+        self._req.add_done_callback(lambda _req: fn(self))
+
+    def result(self) -> str:
+        try:
+            return self._req.wait(0)     # resolved (or given up on): no wait
+        except Exception as e:  # noqa: BLE001 — annotation only; accounted
+            log.warning("slotserve ticket failed: %r (row annotated with "
+                        "an unavailable marker)", e)
+            return UNAVAILABLE_MARKER.format(reason=type(e).__name__)
+
+
 def make_slot_explain_hook(backend, *, temperature: float = 0.0,
                            max_tokens: int = 128, only_scams: bool = True):
     """Build a ``StreamingClassifier.explain_batch_fn`` over a slotserve
@@ -716,21 +785,26 @@ def make_slot_explain_hook(backend, *, temperature: float = 0.0,
 
     Differences from ``make_stream_explain_hook``: (1) the hook advertises
     ``accepts_cids`` so the async annotation lane passes each row's trace
-    cid through to the slots, and (2) a backend failure (decoder death,
+    cid through to the slots, (2) a backend failure (decoder death,
     breaker fast-fail) yields an ``[explanation unavailable: ...]`` MARKER
     per row instead of dropping the batch's annotations — every flagged
     row lands in the annotations topic explained or accounted, the slot
-    lane's coverage invariant, even mid-outage."""
+    lane's coverage invariant, even mid-outage — and (3) over a backend
+    that hands out tickets (``submit_rows``: the service itself) the hook
+    advertises ``submit_rows`` too: the lane then gets each row's ticket
+    back unresolved and delivers it when it resolves, so no row waits for
+    another's decode. Over the breaker, which forwards the blocking call
+    only, every row resolves at the call's return."""
     rows_fn = backend.explain_rows     # AttributeError now beats one later
 
-    def explain_batch(texts, labels, confs, cids=None):
+    def over_picked(fn, texts, labels, confs, cids):
         picked = [i for i, lab in enumerate(labels)
                   if (lab != 0 or not only_scams)]
         out = [None] * len(texts)
         if not picked:
             return out
         try:
-            replies = rows_fn(
+            replies = fn(
                 [texts[i] for i in picked],
                 [labels[i] for i in picked],
                 [confs[i] for i in picked],
@@ -752,5 +826,19 @@ def make_slot_explain_hook(backend, *, temperature: float = 0.0,
             out[i] = reply
         return out
 
+    def explain_batch(texts, labels, confs, cids=None):
+        return over_picked(rows_fn, texts, labels, confs, cids)
+
     explain_batch.accepts_cids = True
+    if hasattr(backend, "submit_rows"):
+        def ticket_rows(*args, **kw):
+            # ``submit_rows`` (and the ``submit`` under it) looked up on
+            # the instance at call time: a caller may wrap either.
+            return [_RowTicket(req, backend.wait_timeout)
+                    for req in backend.submit_rows(*args, **kw)]
+
+        def submit_rows(texts, labels, confs, cids=None):
+            return over_picked(ticket_rows, texts, labels, confs, cids)
+
+        explain_batch.submit_rows = submit_rows
     return explain_batch

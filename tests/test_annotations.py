@@ -5,8 +5,10 @@ while the classified frames ship analysis-free through the native fast path.
 """
 
 import json
+import queue
 import threading
 import time
+from concurrent.futures import Future
 
 import pytest
 
@@ -41,7 +43,7 @@ def test_lane_annotates_and_keys_records():
     assert by_key[b"k2"]["prediction"] == 2
     assert lane.stats() == {"submitted": 2, "annotated": 2, "dropped": 0,
                             "drop_records": 0, "backend_errors": 0,
-                            "queue_depth": 0}
+                            "queue_depth": 0, "in_flight": 0}
 
 
 def test_lane_bounded_queue_drops_oldest():
@@ -388,3 +390,255 @@ def test_lane_close_is_idempotent_and_latching():
     lane.submit([(b"late", "text", 1, 0.5)])  # latched: dropped silently
     assert lane.stats()["submitted"] == 1
     assert [m.key for m in broker.messages("annotations")] == [b"k"]
+
+
+# ---------------------------------------------------------------------------
+# the window: a hook that hands back tickets (ISSUE 32). Every test here
+# resolves the tickets itself and waits on what the lane does next (the
+# hook's next call, a row's annotate event), never on the clock.
+# ---------------------------------------------------------------------------
+
+WAIT_S = 10.0      # a bound on a wait for the worker thread, never a sleep
+
+
+class TicketHook:
+    """A hook that serves rows one at a time: ``submit_rows`` hands back a
+    ``Future`` a row (keyed by the row's text in ``tickets``) and says on
+    ``calls`` which rows each call was given and how many were in flight."""
+
+    def __init__(self):
+        self.calls = queue.Queue()
+        self.tickets = {}
+        self.lane = None
+
+    def __call__(self, texts, labels, confs):
+        raise AssertionError("the lane must call submit_rows")
+
+    def submit_rows(self, texts, labels, confs):
+        made = [Future() for _ in texts]
+        self.tickets.update(zip(texts, made))
+        self.calls.put((list(texts), self.lane.stats()["in_flight"]))
+        return made
+
+    def next_call(self):
+        return self.calls.get(timeout=WAIT_S)
+
+
+class RecordingProducer:
+    """The lane's producer, keeping the order of its produces and flushes."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.log = []
+
+    def produce(self, topic, value, key=None):
+        self.log.append(("produce", key))
+        self.inner.produce(topic, value, key=key)
+
+    def flush(self):
+        self.log.append(("flush",))
+        return self.inner.flush()
+
+
+def _ticket_lane(broker, **kw):
+    hook = TicketHook()
+    producer = RecordingProducer(broker.producer())
+    hook.lane = AsyncAnnotationLane(hook, producer, "annotations", **kw)
+    return hook, hook.lane, producer
+
+
+def _rows(n, start=0, cid=None):
+    return [(b"k%d" % i, f"t{i}", 1, 0.5) + (() if cid is None
+                                              else (f"{cid}{i}",))
+            for i in range(start, start + n)]
+
+
+def _keys(broker):
+    return [m.key for m in broker.messages("annotations")]
+
+
+def test_window_holds_max_batch_and_one_resolution_admits_one_row():
+    """At most ``max_batch`` rows are in flight; a resolved ticket's record
+    is produced, flushed and counted while its neighbours are unresolved,
+    and only then does exactly one more row take its place."""
+    broker = InProcessBroker()
+    hook, lane, producer = _ticket_lane(broker, max_batch=3)
+    lane.submit(_rows(7))
+    assert hook.next_call() == (["t0", "t1", "t2"], 3)
+    assert lane.stats()["queue_depth"] == 4
+    hook.tickets["t1"].set_result("one")     # the MIDDLE one: no order kept
+    # The hook's next call is the lane's next act after the delivery.
+    assert hook.next_call() == (["t3"], 3)
+    assert _keys(broker) == [b"k1"]
+    assert json.loads(broker.messages("annotations")[0].value)[
+        "analysis"] == "one"
+    assert producer.log == [("produce", b"k1"), ("flush",)]
+    s = lane.stats()
+    assert (s["annotated"], s["in_flight"], s["queue_depth"]) == (1, 3, 3)
+    assert not hook.tickets["t0"].done() and not hook.tickets["t2"].done()
+    seen = 3
+    for i in (0, 2, 3, 4, 5, 6):             # each frees one place
+        hook.tickets[f"t{i}"].set_result(f"a{i}")
+        if seen < 6:
+            seen += 1
+            texts, in_flight = hook.next_call()
+            assert texts == [f"t{seen}"] and in_flight == 3
+    assert lane.close(timeout=WAIT_S)
+    assert hook.calls.empty()
+    assert _keys(broker) == [b"k1", b"k0", b"k2", b"k3", b"k4", b"k5", b"k6"]
+    assert lane.stats() == {"submitted": 7, "annotated": 7, "dropped": 0,
+                            "drop_records": 0, "backend_errors": 0,
+                            "queue_depth": 0, "in_flight": 0}
+
+
+def test_window_takes_rows_as_they_arrive_without_a_barrier():
+    """With places free a row goes to the hook when it arrives, whatever
+    is still unresolved ahead of it."""
+    broker = InProcessBroker()
+    hook, lane, _ = _ticket_lane(broker, max_batch=4)
+    lane.submit(_rows(1))
+    assert hook.next_call() == (["t0"], 1)
+    lane.submit(_rows(2, start=1))           # t0 still decoding
+    assert hook.next_call() == (["t1", "t2"], 3)
+    assert lane.drain(timeout=0.05) is False  # in flight is not drained
+    for t in ("t2", "t0", "t1"):
+        hook.tickets[t].set_result(t.upper())
+    assert lane.drain(timeout=WAIT_S)
+    assert _keys(broker) == [b"k2", b"k0", b"k1"]
+    assert lane.close(timeout=WAIT_S)
+
+
+def test_window_close_with_rows_in_flight_clears_queue_and_delivers_them():
+    """close() with tickets out: the residual queue is cleared and counted,
+    the lane latches, and the rows already handed over are still delivered
+    when they resolve; then the worker exits and stats() stand still."""
+    broker = InProcessBroker()
+    hook, lane, _ = _ticket_lane(broker, max_batch=2)
+    lane.submit(_rows(5))
+    assert hook.next_call() == (["t0", "t1"], 2)
+    assert lane.close(timeout=0.2) is False   # honest: rows still out
+    s = lane.stats()
+    assert (s["queue_depth"], s["dropped"], s["in_flight"]) == (0, 3, 2)
+    assert lane._thread.is_alive()
+    lane.submit(_rows(1, start=9))            # latched: ignored
+    hook.tickets["t1"].set_result("late one")
+    hook.tickets["t0"].set_exception(RuntimeError("decoder died"))
+    lane._thread.join(timeout=WAIT_S)
+    assert not lane._thread.is_alive()
+    assert hook.calls.empty()                 # nothing new was handed over
+    assert _keys(broker) == [b"k1"]           # t0's error cost t0 alone
+    assert lane.stats() == {"submitted": 5, "annotated": 1, "dropped": 3,
+                            "drop_records": 0, "backend_errors": 1,
+                            "queue_depth": 0, "in_flight": 0}
+    assert lane.close(timeout=WAIT_S)         # nothing left: clean
+
+
+def test_window_overflow_drop_records_go_out_while_tickets_are_in_flight():
+    """The backlog stays in the lane's bounded queue: overflow evicts the
+    oldest QUEUED row, and its structured drop record reaches the topic at
+    once, not behind the unresolved tickets."""
+    from fraud_detection_tpu.obs.trace import RowTracer
+
+    events = queue.Queue()
+
+    class SignalTracer(RowTracer):
+        def record_event(self, cid, stage, **kw):
+            super().record_event(cid, stage, **kw)
+            events.put((cid, stage, kw.get("ok", True), kw.get("detail")))
+
+    broker = InProcessBroker()
+    hook, lane, _ = _ticket_lane(broker, max_batch=2, max_queue=2,
+                                 rowtrace=SignalTracer(worker="w0"))
+    lane.submit(_rows(2, cid="c"))
+    assert hook.next_call() == (["t0", "t1"], 2)
+    lane.submit(_rows(4, start=2, cid="c"))   # 2 queue, the 2 oldest drop
+    dropped = [events.get(timeout=WAIT_S) for _ in range(2)]
+    assert dropped == [(f"c{i}", "annotate", False, "dropped:queue_overflow")
+                       for i in (2, 3)]
+    s = lane.stats()
+    assert (s["dropped"], s["drop_records"], s["in_flight"],
+            s["queue_depth"], s["annotated"]) == (2, 2, 2, 2, 0)
+    recs = [json.loads(m.value) for m in broker.messages("annotations")]
+    assert [(r["dropped"], r["reason"], r["trace"]) for r in recs] == [
+        (True, "queue_overflow", "c2"), (True, "queue_overflow", "c3")]
+    assert hook.calls.empty()                 # the window was full throughout
+    for i in range(2):
+        hook.tickets[f"t{i}"].set_result("ok")
+    topped_up = []          # one call or two: tickets may resolve together
+    while len(topped_up) < 2:
+        topped_up += hook.next_call()[0]
+    assert topped_up == ["t4", "t5"]
+    for i in (4, 5):
+        hook.tickets[f"t{i}"].set_result("ok")
+    assert lane.close(timeout=WAIT_S)
+    s = lane.stats()
+    assert s["submitted"] == s["annotated"] + s["dropped"] == 6
+
+
+def test_window_ticket_that_outlives_its_timeout_is_taken_as_it_is():
+    """A ticket may carry a ``timeout``: past it (on the lane's clock) the
+    lane stops waiting and takes ``result()`` as it stands; a late
+    resolution of the same ticket delivers nothing twice."""
+    skew = [0.0]
+
+    class Ticket(Future):
+        timeout = 30.0
+
+        def result(self, timeout=None):
+            return super().result(0) if self.done() else "[gave up]"
+
+    made = queue.Queue()
+
+    def fn(texts, labels, confs):
+        raise AssertionError("the lane must call submit_rows")
+
+    def submit_rows(texts, labels, confs):
+        out = [Ticket() for _ in texts]
+        made.put(out)
+        return out
+
+    fn.submit_rows = submit_rows
+    broker = InProcessBroker()
+    lane = AsyncAnnotationLane(fn, broker.producer(), "annotations",
+                               clock=lambda: time.perf_counter() + skew[0])
+    lane.submit(_rows(2))
+    t0, t1 = made.get(timeout=WAIT_S)
+    skew[0] = 31.0                      # t0's wait has run out ...
+    t1.set_result("in time")            # ... which the woken worker sees
+    assert lane.drain(timeout=WAIT_S)
+    assert sorted(json.loads(m.value)["analysis"]
+                  for m in broker.messages("annotations")) == [
+        "[gave up]", "in time"]
+    t0.set_result("too late")           # settled already: ignored
+    assert lane.close(timeout=WAIT_S)
+    assert lane.stats() == {"submitted": 2, "annotated": 2, "dropped": 0,
+                            "drop_records": 0, "backend_errors": 0,
+                            "queue_depth": 0, "in_flight": 0}
+    assert len(broker.messages("annotations")) == 2
+
+
+def test_batch_hook_is_the_windows_degenerate_case():
+    """A plain callable resolves its rows at the call's return: the lane
+    hands it ``max_batch`` rows at a time, one call after the other, as it
+    always did, and ``in_flight`` shows the batch while it decodes."""
+    broker = InProcessBroker()
+    seen = queue.Queue()
+    gate = threading.Event()
+    lane_box = []
+
+    def fn(texts, labels, confs):
+        seen.put((list(texts), lane_box[0].stats()["in_flight"]))
+        gate.wait(WAIT_S)
+        return [t.upper() for t in texts]
+
+    lane_box.append(_lane(broker, fn, max_batch=3))
+    lane = lane_box[0]
+    lane.submit(_rows(7))
+    assert seen.get(timeout=WAIT_S) == (["t0", "t1", "t2"], 3)
+    assert seen.empty() and broker.messages("annotations") == []
+    gate.set()
+    assert lane.close(timeout=WAIT_S)
+    assert [seen.get_nowait()[0] for _ in range(2)] == [
+        ["t3", "t4", "t5"], ["t6"]]
+    assert _keys(broker) == [b"k%d" % i for i in range(7)]
+    assert lane.stats()["in_flight"] == 0

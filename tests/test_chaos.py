@@ -622,6 +622,7 @@ ANNOTATION_STATS_SCHEMA = {
     "drop_records": (int,),
     "backend_errors": (int,),
     "queue_depth": (int,),
+    "in_flight": (int,),
 }
 
 

@@ -642,7 +642,7 @@ def test_cache_salt_folds_in_checker_and_spec_sources(tmp_path, monkeypatch):
 _EXPECTED_PRAGMAS = {
     ("fleet/worker.py", "FC102"): 1,          # lock-free stop latch
     ("stream/engine.py", "FC102"): 2,         # lock-free stop latches
-    ("stream/annotations.py", "FC102"): 5,    # worker-only counters
+    ("stream/annotations.py", "FC102"): 3,    # worker-only counters
     ("models/pipeline.py", "FC201"): 1,       # one-shot donation probe
     ("models/train_llm.py", "FC201"): 1,      # once-per-run opt-state init
 }

@@ -342,6 +342,185 @@ def test_chaos_every_flagged_row_explained_or_accounted(lm, pipeline):
 
 
 # ---------------------------------------------------------------------------
+# the annotation lane's window over slot tickets (ISSUE 32)
+# ---------------------------------------------------------------------------
+
+WAIT_S = 10.0      # a bound on a wait for a worker thread, never a sleep
+
+
+class TicketBackend:
+    """Stands where the service stands under the hook: ``submit_rows``
+    hands out real slot tickets, which the test resolves itself."""
+
+    wait_timeout = 120.0
+
+    def __init__(self):
+        import queue
+
+        self.handed = queue.Queue()
+
+    def explain_rows(self, *args, **kw):
+        raise AssertionError("the lane must not take the blocking form")
+
+    def submit_rows(self, texts, labels, confs, *, cids=None,
+                    temperature=0.0, max_tokens=128):
+        from fraud_detection_tpu.explain.slotserve.service import _SlotRequest
+
+        out = [_SlotRequest(np.zeros(1, np.int32), max_tokens, temperature,
+                            cids[i] if cids else None, 0.0)
+               for i in range(len(texts))]
+        self.handed.put(out)
+        return out
+
+
+class FlushSignal:
+    """The lane's producer; says on ``flushed`` when a flush has returned."""
+
+    def __init__(self, inner):
+        import queue
+
+        self.inner = inner
+        self.flushed = queue.Queue()
+
+    def produce(self, topic, value, key=None):
+        self.inner.produce(topic, value, key=key)
+
+    def flush(self):
+        left = self.inner.flush()
+        self.flushed.put(left)
+        return left
+
+
+def test_hook_advertises_tickets_over_the_service_only(lm):
+    """Which way the lane serves a hook follows from what the hook can
+    hand back: over the service, tickets; over the breaker (which forwards
+    the blocking ``explain_rows`` only), every row at the call's return."""
+    svc = make_service(lm, slots=2, max_new_tokens=4)
+    try:
+        assert callable(make_slot_explain_hook(svc).submit_rows)
+        wrapped = make_slot_explain_hook(CircuitBreakerBackend(svc))
+        assert not hasattr(wrapped, "submit_rows")
+        assert wrapped.accepts_cids
+    finally:
+        svc.close()
+
+
+def test_lane_delivers_each_slot_ticket_as_it_resolves():
+    """Through the real hook: a served ticket becomes its row's record
+    while its neighbours are unresolved; a ticket that resolves with an
+    error becomes the unavailable marker of ITS row alone, a dropped one
+    its drop marker; unflagged rows get no ticket and no record."""
+    from fraud_detection_tpu.stream.annotations import AsyncAnnotationLane
+
+    backend = TicketBackend()
+    broker = InProcessBroker()
+    producer = FlushSignal(broker.producer())
+    lane = AsyncAnnotationLane(make_slot_explain_hook(backend), producer,
+                               "notes")
+    lane.submit([(b"k0", "a", 1, 0.9, "c0"), (b"benign", "b", 0, 0.1, "c1"),
+                 (b"k2", "c", 1, 0.8, "c2"), (b"k3", "d", 1, 0.7, "c3"),
+                 (b"k4", "e", 1, 0.6, "c4")])
+    r0, r2, r3, r4 = backend.handed.get(timeout=WAIT_S)
+    assert [r.cid for r in (r0, r2, r3, r4)] == ["c0", "c2", "c3", "c4"]
+
+    def notes():
+        return [(m.key, json.loads(m.value)["analysis"])
+                for m in broker.messages("notes")]
+
+    r2.text = "served"
+    r2.resolve()
+    producer.flushed.get(timeout=WAIT_S)
+    assert notes() == [(b"k2", "served")]
+    r3.error = RuntimeError("device lost")
+    r3.resolve()
+    producer.flushed.get(timeout=WAIT_S)
+    assert notes()[1:] == [
+        (b"k3", UNAVAILABLE_MARKER.format(reason="BackendError"))]
+    assert lane.stats()["in_flight"] == 2      # r0 and r4: untouched
+    r4.dropped = "queue_overflow"
+    r4.resolve()
+    r0.text = "first in, last out"
+    r0.resolve()
+    assert lane.close(timeout=WAIT_S)
+    assert sorted(notes()[2:]) == [
+        (b"k0", "first in, last out"),
+        (b"k4", DROPPED_MARKER.format(reason="queue_overflow"))]
+    assert lane.stats() == {"submitted": 5, "annotated": 4, "dropped": 0,
+                            "drop_records": 0, "backend_errors": 0,
+                            "queue_depth": 0, "in_flight": 0}
+
+
+def test_hook_failure_at_hand_over_marks_every_picked_row():
+    """``submit_rows`` itself raising is the batch failure it always was:
+    an unavailable marker a picked row, none lost."""
+    from fraud_detection_tpu.stream.annotations import AsyncAnnotationLane
+
+    backend = TicketBackend()
+    backend.submit_rows = lambda *a, **k: (_ for _ in ()).throw(
+        BreakerOpenError("open"))
+    broker = InProcessBroker()
+    lane = AsyncAnnotationLane(make_slot_explain_hook(backend),
+                               broker.producer(), "notes")
+    lane.submit([(b"k0", "a", 1, 0.9), (b"k1", "b", 1, 0.8)])
+    assert lane.close(timeout=WAIT_S)
+    assert [json.loads(m.value)["analysis"]
+            for m in broker.messages("notes")] == [
+        UNAVAILABLE_MARKER.format(reason="BreakerOpenError")] * 2
+
+
+def test_backlog_through_the_engine_never_starves_the_slots(lm, pipeline):
+    """End to end at test size: 4 slots behind the lane's window of 64 and
+    a backlog of flagged rows through the engine. While rows queue on the
+    lane, a free slot-step is never a STARVED one (the window keeps the
+    service's own queue fed); every row lands explained."""
+    svc = make_service(lm, slots=4, max_new_tokens=6)
+    try:
+        broker = InProcessBroker(num_partitions=2)
+        _feed(broker, 112, scam_every=1)
+        engine = StreamingClassifier(
+            pipeline, broker.consumer(["in"], "g"), broker.producer(), "out",
+            batch_size=16, max_wait=0.01,
+            explain_batch_fn=make_slot_explain_hook(svc, max_tokens=6),
+            explain_async=True, annotations_producer=broker.producer(),
+            explain_service=svc)
+        lane = engine._annotation_lane
+        samples = []              # taken on the lane's thread, a row each
+        inner = svc.submit
+
+        def submit(*args, **kw):
+            req = inner(*args, **kw)
+            snap = svc.snapshot()
+            samples.append((lane.stats()["queue_depth"],
+                            snap["slot_steps_starved"], snap["completed"],
+                            lane.stats()["in_flight"]))
+            return req
+
+        svc.submit = submit       # looked up on the instance at call time
+        engine.run(max_messages=112, idle_timeout=1.0)
+        assert engine.close_annotations(timeout=120.0)
+        stats = engine.annotation_stats()
+        assert stats["submitted"] == stats["annotated"] == len(samples)
+        assert stats["submitted"] > 64 + 16     # a backlog past the window
+        assert max(s[3] for s in samples) == lane.max_batch == 64
+        # Only the lane takes rows off its queue, and it samples after
+        # every one: two samples in a row with rows queued = rows queued
+        # all the while between them.
+        backlogged = [(a, b) for a, b in zip(samples, samples[1:])
+                      if a[0] > 0 and b[0] > 0]
+        assert len(backlogged) >= 16
+        assert all(a[1] == b[1] for a, b in backlogged)
+        assert backlogged[-1][1][2] > backlogged[0][0][2]   # slots served
+        snap = svc.snapshot()
+        assert snap["completed"] == stats["annotated"]
+        assert (snap["slot_steps_occupied"] + snap["slot_steps_starved"]
+                + snap["slot_steps_backlogged"]
+                == snap["decode_steps"] * snap["slots"])
+        assert snap["slot_steps_backlogged"] > 0
+    finally:
+        svc.close()
+
+
+# ---------------------------------------------------------------------------
 # health schema (FC301 contract)
 # ---------------------------------------------------------------------------
 
