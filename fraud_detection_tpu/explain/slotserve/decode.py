@@ -206,6 +206,7 @@ class PagedSlotDecoder:
         # recurrent state.
         self.moe_picks = 0              # expert choices made by real tokens
         self.moe_picks_held = 0         # ... that landed on a held expert
+        self.moe_picks_zero = 0         # ... on a zero-compute expert
         self.moe_experts_touched = 0    # distinct held experts read, summed
         #                                 over decode steps and expert layers
         self.moe_prefill_load_max = 0   # busiest held expert's tokens, summed
@@ -229,14 +230,16 @@ class PagedSlotDecoder:
     def _count(self, stats, *, prefill: bool) -> None:
         if stats is None:
             return
-        picks, held, touched, load_max = (int(v) for v in np.asarray(stats))
-        self.moe_picks += picks
-        self.moe_picks_held += held
+        got = dict(zip(llm.moe_stat_names(self.cfg),
+                       (int(v) for v in np.asarray(stats))))
+        self.moe_picks += got["picks"]
+        self.moe_picks_held += got["picks_held"]
+        self.moe_picks_zero += got.get("picks_zero", 0)
         if prefill:
-            self.moe_prefill_load_max += load_max
-            self.moe_prefill_load_mean += held / self.cfg.moe.held
+            self.moe_prefill_load_max += got["load_max"]
+            self.moe_prefill_load_mean += got["picks_held"] / self.cfg.moe.held
         else:
-            self.moe_experts_touched += touched
+            self.moe_experts_touched += got["experts_touched"]
 
     # -- stats surface --------------------------------------------------
 

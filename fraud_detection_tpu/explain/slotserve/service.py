@@ -743,6 +743,7 @@ class SlotServeService:
             # layers; state snapshots copied into a slot on admission.
             "moe_picks": self._decoder.moe_picks,
             "moe_picks_held": self._decoder.moe_picks_held,
+            "moe_picks_zero": self._decoder.moe_picks_zero,
             "moe_experts_touched": self._decoder.moe_experts_touched,
             "moe_prefill_load_max": self._decoder.moe_prefill_load_max,
             "moe_prefill_load_mean": round(

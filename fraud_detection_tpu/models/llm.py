@@ -52,12 +52,21 @@ Params = Dict[str, jax.Array]
 class MLAConfig:
     """Latent attention: K and V of a token are expanded from one cached
     latent (``kv_rank`` values, RMS-normed) through ``mla_wkvb``; one rotary
-    key of ``rope_dim`` shared by every head rides beside it."""
+    key of ``rope_dim`` shared by every head rides beside it. With ``q_rank``
+    the query too goes through a latent of its own (``mla_wqa``, RMS-normed,
+    then ``mla_wqb``) instead of one matrix ``mla_wq``. ``q_scale`` multiplies
+    the query, ``kv_scale`` the normed key-value latent (so keys and values,
+    and what the cache holds, carry it). ``out_gate``: the head-wise sigmoid
+    gate ``mla_wz`` ahead of the output projection."""
 
     kv_rank: int = 512
     nope_dim: int = 128            # per-head query/key width without RoPE
     rope_dim: int = 64             # per-head query width with RoPE
     v_dim: int = 128
+    q_rank: Optional[int] = None
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
+    out_gate: bool = True
 
     @property
     def latent_dim(self) -> int:   # what the cache holds per token
@@ -84,10 +93,16 @@ class KDAConfig:
 @dataclass(frozen=True)
 class MoEConfig:
     """Routed experts, of which this device holds ``[held_start, held_start
-    + held_count)``: the router scores all ``n_experts``, every token's
-    ``top_k`` choice and weights are made over all of them, and only picks
-    on held experts are computed (an expert-parallel share; the rest of the
-    sum lives on other devices)."""
+    + held_count)``: the router scores all ``n_experts`` (and the ``n_zero``
+    zero-compute experts that follow them: ``n_router`` outputs), every
+    token's ``top_k`` choice and weights are made over all of them, and only
+    picks on held experts are computed (an expert-parallel share; the rest
+    of the sum lives on other devices). A pick on a zero-compute expert is
+    an identity: it adds its weight times the expert layer's input, reads no
+    weights, and is computed for every row that lives here. ``score``: how
+    the router scores ("sigmoid" | "softmax" over all outputs); ``n_group``
+    1 = no group limit; ``norm_topk``: the chosen weights normalised over
+    the choice or taken as scored; ``d_shared`` 0 = no shared expert."""
 
     n_experts: int = 16
     top_k: int = 4
@@ -98,14 +113,26 @@ class MoEConfig:
     routed_scale: float = 1.0
     held_start: int = 0
     held_count: Optional[int] = None
+    score: str = "sigmoid"
+    norm_topk: bool = True
+    n_zero: int = 0
 
     @property
     def held(self) -> int:
         return self.n_experts if self.held_count is None else self.held_count
 
+    @property
+    def n_router(self) -> int:
+        return self.n_experts + self.n_zero
+
 
 MIXERS = ("attention", "mla", "kda")
-FFNS = ("dense", "experts")
+# "dense+experts": a dense MLP on the residual, and an expert branch computed
+# from the same normed input whose result is carried; "dense+join": a dense
+# MLP, and the carried branch added after it (a branch joins one sub-layer
+# after it starts: the shortcut-connected expert layer).
+FFNS = ("dense", "experts", "dense+experts", "dense+join")
+ROUTED_FFNS = ("experts", "dense+experts")
 
 
 @dataclass(frozen=True)
@@ -139,14 +166,29 @@ class TransformerConfig:
         if len(kinds) != self.n_layers:
             raise ValueError(f"layer_kinds names {len(kinds)} layers, "
                              f"n_layers is {self.n_layers}")
+        open_branch = False
         for mixer, ffn in kinds:
             if mixer not in MIXERS or ffn not in FFNS:
-                raise ValueError(f"unknown layer kind {(mixer, ffn)!r}")
+                raise ValueError(f"unknown layer kind {(mixer, ffn)!r}: a "
+                                 f"mixer of {MIXERS}, a feed-forward of {FFNS}")
+            if ffn in ("dense+experts", "dense+join"):
+                if open_branch != (ffn == "dense+join"):
+                    raise ValueError(
+                        "a 'dense+experts' layer's branch is joined by the "
+                        "next 'dense+join' layer, and by no other: "
+                        f"{[f for _, f in kinds]}")
+                open_branch = not open_branch
+        if open_branch:
+            raise ValueError("the last 'dense+experts' layer's branch is "
+                             "never joined")
         for kind, sized in (("mla", self.mla), ("kda", self.kda)):
             if sized is None and any(m == kind for m, _ in kinds):
                 raise ValueError(f"a {kind!r} layer needs cfg.{kind}")
-        if self.moe is None and any(f == "experts" for _, f in kinds):
-            raise ValueError("an 'experts' layer needs cfg.moe")
+        if self.moe is None and any(f in ROUTED_FFNS for _, f in kinds):
+            raise ValueError(f"a layer of {ROUTED_FFNS} needs cfg.moe")
+        if self.moe is not None and self.moe.score not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown router score {self.moe.score!r}: "
+                             "'sigmoid' or 'softmax'")
 
     @property
     def kinds(self) -> Tuple[Tuple[str, str], ...]:
@@ -156,7 +198,7 @@ class TransformerConfig:
 
     @property
     def n_expert_layers(self) -> int:
-        return sum(1 for _, f in self.kinds if f == "experts")
+        return sum(1 for _, f in self.kinds if f in ROUTED_FFNS)
 
     @property
     def head_dim(self) -> int:
@@ -188,12 +230,18 @@ def _layer_shapes(cfg: TransformerConfig, mixer: str, ffn: str
                    wv=((D, hkv, d), "normal", 1), wo=((h, d, D), "normal", 0))
     elif mixer == "mla":
         m = cfg.mla
-        out.update(mla_wq=((D, h, m.qk_dim), "normal", 1),
-                   mla_wkva=((D, m.latent_dim), "normal", None),
+        if m.q_rank is None:
+            out.update(mla_wq=((D, h, m.qk_dim), "normal", 1))
+        else:
+            out.update(mla_wqa=((D, m.q_rank), "normal", None),
+                       mla_qnorm=((m.q_rank,), "ones", None),
+                       mla_wqb=((m.q_rank, h, m.qk_dim), "normal", 1))
+        out.update(mla_wkva=((D, m.latent_dim), "normal", None),
                    mla_kvnorm=((m.kv_rank,), "ones", None),
-                   mla_wkvb=((m.kv_rank, h, m.nope_dim + m.v_dim), "normal", 1),
-                   mla_wz=((D, h), "normal", 1),
-                   mla_wo=((h, m.v_dim, D), "normal", 0))
+                   mla_wkvb=((m.kv_rank, h, m.nope_dim + m.v_dim), "normal", 1))
+        if m.out_gate:
+            out.update(mla_wz=((D, h), "normal", 1))
+        out.update(mla_wo=((h, m.v_dim, D), "normal", 0))
     else:
         k = cfg.kda
         hk, dk, taps = k.n_heads, k.head_dim, k.conv_taps
@@ -210,19 +258,21 @@ def _layer_shapes(cfg: TransformerConfig, mixer: str, ffn: str
                    kda_wz=((D, hk), "normal", 1),
                    kda_onorm=((dk,), "ones", None),
                    kda_wo=((hk, dk, D), "normal", 0))
-    if ffn == "dense":
+    if ffn != "experts":
         F = cfg.d_ff
         out.update(w_gate=((D, F), "normal", 1), w_up=((D, F), "normal", 1),
                    w_down=((F, D), "normal", 0))
-    else:
+    if ffn in ROUTED_FFNS:
         m = cfg.moe
         E, F, Fs = m.held, m.d_expert, m.d_shared
-        out.update(moe_router=((D, m.n_experts), "normal", None),
-                   moe_bias=((m.n_experts,), "zeros", None),
+        out.update(moe_router=((D, m.n_router), "normal", None),
+                   moe_bias=((m.n_router,), "zeros", None),
                    moe_wg=((E, D, F), "normal", 2), moe_wu=((E, D, F), "normal", 2),
-                   moe_wd=((E, F, D), "normal", 1),
-                   moe_sg=((D, Fs), "normal", 1), moe_su=((D, Fs), "normal", 1),
-                   moe_sd=((Fs, D), "normal", 0))
+                   moe_wd=((E, F, D), "normal", 1))
+        if Fs:
+            out.update(moe_sg=((D, Fs), "normal", 1),
+                       moe_su=((D, Fs), "normal", 1),
+                       moe_sd=((Fs, D), "normal", 0))
     return out
 
 
@@ -353,7 +403,7 @@ _QUANT_REDUCE_AXES = {
     "embed": (1,), "lm_head": (1,),          # (V, D): per-row (gather + head)
     # latent attention and the KDA recurrence: projections like wq / wo
     "mla_wq": (0,), "mla_wkva": (0,), "mla_wkvb": (0,), "mla_wz": (0,),
-    "mla_wo": (0, 1),
+    "mla_wo": (0, 1), "mla_wqa": (0,), "mla_wqb": (0,),
     "kda_wq": (0,), "kda_wk": (0,), "kda_wv": (0,), "kda_wg": (0,),
     "kda_wbeta": (0,), "kda_wz": (0,), "kda_wo": (0, 1),
     # experts, per expert and per output channel: (E, in, out)
@@ -853,23 +903,49 @@ def _head_gate_out(params: Params, cfg: TransformerConfig, l: int, kind: str,
 
 # -- latent attention --------------------------------------------------------
 
+def _scaled(x: jax.Array, scale: float) -> jax.Array:
+    """``x * scale`` through float32 (a scale such as 12^1/2 has no exact
+    bfloat16 value: rounded first it would tilt every element one way)."""
+    return x if scale == 1.0 else (x.astype(_F32) * scale).astype(x.dtype)
+
+
 def _mla_project(params: Params, cfg: TransformerConfig, l: int, h: jax.Array,
                  positions: jax.Array):
     """q (B,T,H,nope+rope) with its rotary part turned, and what the cache
     holds of each token: (B,T,1,kv_rank+rope) = normed latent || the one
-    rotary key every head shares."""
+    rotary key every head shares. With ``q_rank`` the query is expanded from
+    its own normed latent; ``q_scale`` / ``kv_scale`` as ``MLAConfig`` says."""
     m = cfg.mla
+    if m.q_rank is not None:
+        with jax.named_scope("mla.q_latent"):
+            cq = rms_norm(_mm("btD,Dr->btr", h, params[f"l{l}.mla_wqa"],
+                              cfg.dtype), params[f"l{l}.mla_qnorm"], cfg.rms_eps)
     with jax.named_scope("attn.qkv"):
-        q = _mm("btD,Dhd->bthd", h, params[f"l{l}.mla_wq"], cfg.dtype)
+        if m.q_rank is None:
+            q = _mm("btD,Dhd->bthd", h, params[f"l{l}.mla_wq"], cfg.dtype)
+        else:
+            q = _mm("btr,rhd->bthd", cq, params[f"l{l}.mla_wqb"], cfg.dtype)
+        q = _scaled(q, m.q_scale)
         q = jnp.concatenate([q[..., :m.nope_dim],
                              rope(q[..., m.nope_dim:], positions,
                                   cfg.rope_theta)], -1)
     with jax.named_scope("mla.latent"):
         ckr = _mm("btD,Dc->btc", h, params[f"l{l}.mla_wkva"], cfg.dtype)
-        c = rms_norm(ckr[..., :m.kv_rank], params[f"l{l}.mla_kvnorm"],
-                     cfg.rms_eps)
+        c = _scaled(rms_norm(ckr[..., :m.kv_rank], params[f"l{l}.mla_kvnorm"],
+                             cfg.rms_eps), m.kv_scale)
         kr = rope(ckr[:, :, None, m.kv_rank:], positions, cfg.rope_theta)
         return q, jnp.concatenate([c[:, :, None, :], kr], -1)
+
+
+def _mla_out(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
+             h: jax.Array, o: jax.Array) -> jax.Array:
+    """The ``mla`` mixer's output projection on its residual, behind the
+    head-wise gate where the configuration has one."""
+    if cfg.mla.out_gate:
+        return _head_gate_out(params, cfg, l, "mla", x, h, o)
+    with jax.named_scope("attn.out"):
+        return x + _mm("bthd,hdD->btD", o.astype(cfg.dtype),
+                       params[f"l{l}.mla_wo"], cfg.dtype)
 
 
 def _mla_expanded(params: Params, cfg: TransformerConfig, l: int,
@@ -1104,6 +1180,15 @@ def _kda_mix(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
 MOE_STATS = ("picks", "picks_held", "experts_touched", "load_max")
 
 
+def moe_stat_names(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The expert layers' counters, in the order the slot programs pack
+    them: ``MOE_STATS``, and ``picks_zero`` where the router has
+    zero-compute outputs; () for a model without expert layers."""
+    if not cfg.n_expert_layers:
+        return ()
+    return MOE_STATS + (("picks_zero",) if cfg.moe.n_zero else ())
+
+
 def _dense_mlp(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
                act, names=("w_gate", "w_up", "w_down"),
                scope: str = "mlp") -> jax.Array:
@@ -1117,25 +1202,33 @@ def _dense_mlp(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
 
 
 def moe_route(router, bias, xf: jax.Array, m: MoEConfig):
-    """Every token's ``top_k`` experts over ALL ``n_experts`` and their
-    weights, in float32: scores sigmoid(x W_r); the choice is made on score +
-    bias, group-limited (groups scored by the sum of their two best, the best
+    """Every token's ``top_k`` experts over ALL ``n_router`` outputs (the
+    routed experts, then the zero-compute ones) and their weights, in
+    float32: scores sigmoid(x W_r), or softmax(x W_r) over all the outputs
+    (``m.score``); the choice is made on score + bias, group-limited where
+    ``n_group`` > 1 (groups scored by the sum of their two best, the best
     ``topk_group`` kept); the weights are the chosen scores (without the
-    bias) normalised over the choice, times ``routed_scale``.
+    bias), normalised over the choice where ``norm_topk``, times
+    ``routed_scale``.
     xf (N,D) -> idx (N,top_k) int32, w (N,top_k) float32."""
     N = xf.shape[0]
-    s = jax.nn.sigmoid(jnp.dot(xf.astype(_F32), router.astype(_F32),
-                               precision=_EXACT))
-    grp = (s + bias.astype(_F32)).reshape(N, m.n_group, -1)
-    best = jnp.sum(jax.lax.top_k(grp, 2)[0], -1)
-    kept = jax.lax.top_k(best, m.topk_group)[1]
-    keep = jnp.zeros((N, m.n_group), bool).at[
-        jnp.arange(N)[:, None], kept].set(True)
-    idx = jax.lax.top_k(jnp.where(keep[:, :, None], grp, -jnp.inf)
-                        .reshape(N, -1), m.top_k)[1]
+    logits = jnp.dot(xf.astype(_F32), router.astype(_F32), precision=_EXACT)
+    s = (jax.nn.sigmoid(logits) if m.score == "sigmoid"
+         else jax.nn.softmax(logits, axis=-1))
+    sel = s + bias.astype(_F32)
+    if m.n_group > 1:
+        grp = sel.reshape(N, m.n_group, -1)
+        best = jnp.sum(jax.lax.top_k(grp, 2)[0], -1)
+        kept = jax.lax.top_k(best, m.topk_group)[1]
+        keep = jnp.zeros((N, m.n_group), bool).at[
+            jnp.arange(N)[:, None], kept].set(True)
+        sel = jnp.where(keep[:, :, None], grp, -jnp.inf).reshape(N, -1)
+    idx = jax.lax.top_k(sel, m.top_k)[1]
     chosen = jnp.take_along_axis(s, idx, axis=1)
-    return idx.astype(jnp.int32), m.routed_scale * chosen / jnp.sum(
-        chosen, -1, keepdims=True)
+    w = m.routed_scale * chosen
+    if m.norm_topk:
+        w = w / jnp.sum(chosen, -1, keepdims=True)
+    return idx.astype(jnp.int32), w
 
 
 def _expert(w, e):
@@ -1186,18 +1279,22 @@ def moe_held_experts(wg, wu, wd, xf: jax.Array, local: jax.Array,
     return out[back].reshape(N, K, D), counts
 
 
-def _experts_ffn(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
-                 act, live: Optional[jax.Array]):
-    """The expert layer on its residual: ``x + sum over the token's picks
-    that land on HELD experts of w_e E_e(norm(x)) + E_shared(norm(x))``.
+def _expert_branch(params: Params, cfg: TransformerConfig, l: int,
+                   h2: jax.Array, live: Optional[jax.Array]):
+    """What layer ``l``'s router and experts give the normed input ``h2``
+    (B,T,D): the sum over each token's picks that land on HELD experts of
+    ``w_e E_e(h2)``, plus, where the router has zero-compute outputs, ``(sum
+    of the weights of the token's picks among them) * h2`` in full (an
+    identity reads no weights, so it is computed where the token lives).
     ``live`` (B,T) marks the tokens whose picks count (padding and idle rows
-    are routed nowhere, so they read no expert). Returns (x', stats):
+    are routed nowhere, so they read no expert). Returns ((B,T,D), stats):
     picks made, picks on held experts, distinct held experts touched, the
-    busiest held expert's tokens — int32 scalars."""
+    busiest held expert's tokens, and with zero-compute outputs the picks
+    on them (``moe_stat_names``) — int32 scalars."""
     m = cfg.moe
-    B, T, D = x.shape
+    B, T, D = h2.shape
     p = f"l{l}.moe_"
-    h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps).reshape(B * T, D)
+    h2 = h2.reshape(B * T, D)
     with jax.named_scope("moe.route"):
         idx, w = moe_route(params[p + "router"], params[p + "bias"], h2, m)
         local = idx - m.held_start
@@ -1209,26 +1306,63 @@ def _experts_ffn(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
             params[p + "wg"], params[p + "wu"], params[p + "wd"], h2,
             local, held, cfg.dtype)
         routed = jnp.sum(per_pick.astype(_F32) * jnp.where(held, w, 0.0)[..., None],
-                         axis=1).astype(cfg.dtype).reshape(B, T, D)
+                         axis=1)
+        if not m.n_zero:        # else the zero-compute part joins in float32
+            routed = routed.astype(cfg.dtype).reshape(B, T, D)
     n_live = (jnp.int32(B * T) if live is None
               else jnp.sum(live.astype(jnp.int32)))
     stats = {"picks": n_live * m.top_k,
              "picks_held": jnp.sum(held.astype(jnp.int32)),
              "experts_touched": jnp.sum((counts > 0).astype(jnp.int32)),
              "load_max": jnp.max(counts)}
-    y = _dense_mlp(params, cfg, l, x, act, ("moe_sg", "moe_su", "moe_sd"),
-                   "moe.shared")
-    return y + routed, stats
+    if m.n_zero:
+        with jax.named_scope("moe.zero"):
+            zero = idx >= m.n_experts
+            if live is not None:
+                zero &= live.reshape(B * T, 1)
+            w_zero = jnp.sum(jnp.where(zero, w, 0.0), axis=1, keepdims=True)
+            routed = (routed + w_zero * h2.astype(_F32)).astype(
+                cfg.dtype).reshape(B, T, D)
+            stats["picks_zero"] = jnp.sum(zero.astype(jnp.int32))
+    return routed, stats
+
+
+def _experts_ffn(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
+                 act, live: Optional[jax.Array]):
+    """The expert layer on its residual: ``x + E(norm(x)) + E_shared(norm(x))``
+    (``_expert_branch``; no shared expert where its width is 0). Returns
+    (x', stats)."""
+    routed, stats = _expert_branch(
+        params, cfg, l, rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps), live)
+    if cfg.moe.d_shared:
+        x = _dense_mlp(params, cfg, l, x, act, ("moe_sg", "moe_su", "moe_sd"),
+                       "moe.shared")
+    return x + routed, stats
 
 
 def _ffn(params: Params, cfg: TransformerConfig, l: int, x: jax.Array, act,
-         live: Optional[jax.Array], stats: Dict[str, jax.Array]):
-    """Layer ``l``'s feed-forward by its kind; an expert layer's counters
-    are added into ``stats`` (which stays {} for a model without one)."""
-    if cfg.kinds[l][1] == "dense":
-        return _dense_mlp(params, cfg, l, x, act), stats
-    x, new = _experts_ffn(params, cfg, l, x, act, live)
-    return x, {k: stats.get(k, 0) + v for k, v in new.items()}
+         live: Optional[jax.Array], stats: Dict[str, jax.Array],
+         branch: Optional[jax.Array] = None):
+    """Layer ``l``'s feed-forward by its kind -> (x', stats, branch). An
+    expert layer's counters are added into ``stats`` (which stays {} for a
+    model without one). ``branch`` is the expert branch a "dense+experts"
+    layer starts (from the normed input its dense MLP reads) and hands on,
+    past the next layer's mixer, to the "dense+join" layer that adds it
+    after its own MLP; None wherever no branch is open."""
+    kind = cfg.kinds[l][1]
+    if kind == "dense":
+        return _dense_mlp(params, cfg, l, x, act), stats, branch
+    if kind == "dense+join":
+        x = _dense_mlp(params, cfg, l, x, act)
+        with jax.named_scope("moe.join"):
+            return x + branch, stats, None
+    if kind == "experts":
+        x, new = _experts_ffn(params, cfg, l, x, act, live)
+    else:
+        branch, new = _expert_branch(
+            params, cfg, l, rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps), live)
+        x = _dense_mlp(params, cfg, l, x, act)
+    return x, {k: stats.get(k, 0) + v for k, v in new.items()}, branch
 
 
 def _act(cfg: TransformerConfig):
@@ -1303,7 +1437,7 @@ def _hybrid_mixer(params: Params, cfg: TransformerConfig, l: int,
     if kv_cache is None:
         o = _mla_expanded(params, cfg, l, q, lat,
                           jnp.tril(jnp.ones((T, T), bool)))
-        return _head_gate_out(params, cfg, l, "mla", x, h, o), {}
+        return _mla_out(params, cfg, l, x, h, o), {}
     view = jax.lax.dynamic_update_slice(kv_cache[f"l{l}.c"], lat,
                                         (0, cache_len, 0, 0))
     mask = _decode_mask(T, view.shape[1], cache_len, valid_from)
@@ -1313,7 +1447,7 @@ def _hybrid_mixer(params: Params, cfg: TransformerConfig, l: int,
         o = _mla_absorbed(params, cfg, l, q, view, mask3)
     else:
         o = _mla_expanded(params, cfg, l, q, view, mask)
-    return _head_gate_out(params, cfg, l, "mla", x, h, o), {f"l{l}.c": view}
+    return _mla_out(params, cfg, l, x, h, o), {f"l{l}.c": view}
 
 
 def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
@@ -1365,6 +1499,7 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     if hybrid and valid_from is not None and kv_cache is not None:
         real = (cache_len + jnp.arange(T))[None, :] >= valid_from[:, None]
 
+    branch = None           # an open expert branch on its way to its join
     for l, (mixer, ffn) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
         if mixer != "attention":
@@ -1372,7 +1507,7 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
                                    cache_len, valid_from, real)
             if new_cache is not None:
                 new_cache.update(upd)
-            x, _ = _ffn(params, cfg, l, x, act, real, {})
+            x, _, branch = _ffn(params, cfg, l, x, act, real, {}, branch)
             continue
         q = _mm("btD,Dhd->bthd", h, params[f"l{l}.wq"], cfg.dtype)
         k = _mm("btD,Dhd->bthd", h, params[f"l{l}.wk"], cfg.dtype)
@@ -1405,8 +1540,8 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
             attn = causal_attention(q, k, v, use_flash)
 
         x = x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
-        if ffn == "experts":
-            x, _ = _ffn(params, cfg, l, x, act, real, {})
+        if ffn != "dense":
+            x, _, branch = _ffn(params, cfg, l, x, act, real, {}, branch)
             continue
         h2 = rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps)
         gate = act(_mm("btD,DF->btF", h2, params[f"l{l}.w_gate"], cfg.dtype))
@@ -1514,14 +1649,14 @@ def _attn_out(params: Params, cfg: TransformerConfig, l: int,
 
 def _zero_stats(cfg: TransformerConfig) -> Dict[str, jax.Array]:
     """The expert layers' counters at zero; {} for a model without any."""
-    return ({k: jnp.int32(0) for k in MOE_STATS} if cfg.n_expert_layers
-            else {})
+    return {k: jnp.int32(0) for k in moe_stat_names(cfg)}
 
 
 def _pack_stats(stats: Dict[str, jax.Array]) -> Optional[jax.Array]:
-    """The counters as one int32 vector in ``MOE_STATS`` order, so they cost
-    the host one small fetch; None where there are none."""
-    return jnp.stack([stats[k] for k in MOE_STATS]) if stats else None
+    """The counters as one int32 vector in ``moe_stat_names`` order, so they
+    cost the host one small fetch; None where there are none."""
+    names = [k for k in MOE_STATS + ("picks_zero",) if k in stats]
+    return jnp.stack([stats[k] for k in names]) if stats else None
 
 
 def _put_row(arr: jax.Array, row: jax.Array, slot: jax.Array) -> jax.Array:
@@ -1553,13 +1688,15 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
     live = None if live is None else live[:, None]
     new_cache: Dict[str, jax.Array] = {}
     stats = _zero_stats(cfg)
+    branch = None
     for l, (mixer, _) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
         if mixer == "kda":
             x, S, tail = _kda_mix(params, cfg, l, x, h, kv_cache[f"l{l}.S"],
                                   kv_cache[f"l{l}.tail"], None)
             new_cache[f"l{l}.S"], new_cache[f"l{l}.tail"] = S, tail
-            x, stats = _ffn(params, cfg, l, x, act, live, stats)
+            x, stats, branch = _ffn(params, cfg, l, x, act, live, stats,
+                                    branch)
             continue
         if mixer == "attention":
             q, k, v = _qkv(params, cfg, l, h, positions)
@@ -1583,9 +1720,9 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
         if mixer == "attention":
             x = _attn_out(params, cfg, l, x, _attend(q, ck, cv, valid))
         else:
-            x = _head_gate_out(params, cfg, l, "mla", x, h,
-                               _mla_absorbed(params, cfg, l, q, ck, valid))
-        x, stats = _ffn(params, cfg, l, x, act, live, stats)
+            x = _mla_out(params, cfg, l, x, h,
+                         _mla_absorbed(params, cfg, l, q, ck, valid))
+        x, stats, branch = _ffn(params, cfg, l, x, act, live, stats, branch)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)[:, 0]          # (B, D)
     logits = _logits_head(x, params, cfg)                       # (B, V)
     with jax.named_scope("sample"):
@@ -1750,6 +1887,7 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     new_pages: Dict[str, jax.Array] = dict(kv_pages)
     new_state: Dict[str, jax.Array] = dict(state or {})
     stats = _zero_stats(cfg)
+    branch = None
     for l, (mixer, _) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
         if mixer == "kda":
@@ -1760,7 +1898,8 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
             new_state[f"l{l}.S"] = _put_row(new_state[f"l{l}.S"], S, slot)
             new_state[f"l{l}.tail"] = _put_row(new_state[f"l{l}.tail"], tail,
                                                slot)
-            x, stats = _ffn(params, cfg, l, x, act, real, stats)
+            x, stats, branch = _ffn(params, cfg, l, x, act, real, stats,
+                                    branch)
             continue
         if mixer == "attention":
             q, k, v = _qkv(params, cfg, l, h, positions)
@@ -1783,9 +1922,9 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
             x = _attn_out(params, cfg, l, x,
                           _attend(q, view["k"], view["v"], kv_mask))
         else:
-            x = _head_gate_out(params, cfg, l, "mla", x, h, _mla_expanded(
+            x = _mla_out(params, cfg, l, x, h, _mla_expanded(
                 params, cfg, l, q, view["c"], kv_mask))
-        x, stats = _ffn(params, cfg, l, x, act, real, stats)
+        x, stats, branch = _ffn(params, cfg, l, x, act, real, stats, branch)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)
     # Logits at the last REAL position, suffix-local index length-1-prefix.
     x_last = jax.lax.dynamic_slice_in_dim(

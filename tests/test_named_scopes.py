@@ -89,6 +89,52 @@ def _hybrid_prefill_case():
             LAYER_SCOPES | HYBRID_SCOPES | {"kda.chunk"})
 
 
+# What the shortcut-connected routed layer kinds add (ISSUE 33): two latent
+# attentions with a query latent and no output gate, two dense MLPs, an expert
+# branch with zero-compute outputs that joins one sub-layer later.
+SHORTCUT_SCOPES = {"moe.zero", "moe.join", "mla.q_latent"}
+SHORTCUT = llm.TransformerConfig(
+    vocab_size=300, d_model=32, n_heads=2, n_layers=2, d_ff=64, max_seq=256,
+    tie_embeddings=False,
+    layer_kinds=(("mla", "dense+experts"), ("mla", "dense+join")),
+    mla=llm.MLAConfig(kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8, q_rank=24,
+                      q_scale=(32 / 24) ** 0.5, kv_scale=2 ** 0.5,
+                      out_gate=False),
+    moe=llm.MoEConfig(n_experts=8, n_zero=4, top_k=3, n_group=1, topk_group=1,
+                      d_expert=16, d_shared=0, routed_scale=6.0,
+                      score="softmax", norm_topk=False, held_start=2,
+                      held_count=4))
+
+
+def _shortcut_inputs():
+    params = llm.init_params(jax.random.PRNGKey(0), SHORTCUT)
+    pages = llm.init_kv_pages(SHORTCUT, 12, 16)
+    tables = jnp.asarray(np.arange(12).reshape(3, 4), jnp.int32)
+    return params, pages, tables, jax.random.PRNGKey(1)
+
+
+def _shortcut_decode_case():
+    params, pages, tables, key = _shortcut_inputs()
+    args = (params, jnp.asarray([5, 6, 7], jnp.int32),
+            jnp.asarray([10, 20, 3], jnp.int32),
+            jnp.asarray([True, True, False]), jnp.asarray([8, 8, 0], jnp.int32),
+            SHORTCUT, pages, tables, jnp.zeros(3, jnp.float32), key, 4)
+    return (llm.paged_decode_window, (5, 10), args,
+            LAYER_SCOPES - {"attn.scores", "attn.values"} | SHORTCUT_SCOPES
+            | {"kv.append", "moe.route", "moe.experts", "mla.latent",
+               "mla.attend", "mla.absorb"})
+
+
+def _shortcut_prefill_case():
+    params, pages, tables, key = _shortcut_inputs()
+    tokens = jnp.asarray(np.arange(32).reshape(1, 32) % 250, jnp.int32)
+    args = (params, tokens, jnp.int32(40), SHORTCUT, pages, tables[0],
+            jnp.float32(0.0), key, 16)
+    return (llm.paged_slot_prefill, (3, 8), args,
+            LAYER_SCOPES | SHORTCUT_SCOPES
+            | {"moe.route", "moe.experts", "mla.latent", "mla.attend"})
+
+
 def _packed_rows():
     rng = np.random.default_rng(0)
     ids = rng.integers(0, 500, (8, 16)).astype(np.int16)
@@ -126,7 +172,8 @@ def _tree_case():
 
 @pytest.mark.parametrize("case", [_decode_case,
                                   _prefill_case, _lr_case, _tree_case,
-                                  _hybrid_decode_case, _hybrid_prefill_case])
+                                  _hybrid_decode_case, _hybrid_prefill_case,
+                                  _shortcut_decode_case, _shortcut_prefill_case])
 def test_scopes_are_named_and_change_no_number(case, monkeypatch):
     fn, static, args, scopes = case()
     named = fn.lower(*args)
@@ -205,3 +252,32 @@ def test_state_restore_is_named():
         debug_info=True)
     assert "/state.restore" in text
     assert set(state) == {"l0.S", "l0.tail", "l2.S", "l2.tail"}
+
+
+@pytest.mark.parametrize("case", [_decode_case, _prefill_case,
+                                  _hybrid_decode_case, _hybrid_prefill_case])
+def test_other_programs_carry_none_of_the_shortcut_scopes(case):
+    """The dense decoder and the hybrid lower to the programs they were: no
+    query latent, no zero-compute part, no branch to join, and no
+    ``picks_zero`` among the hybrid's counters."""
+    fn, _, args, _ = case()
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert not [s for s in SHORTCUT_SCOPES if f"/{s}" in text]
+    cfg = next(a for a in args if isinstance(a, llm.TransformerConfig))
+    assert "picks_zero" not in llm.moe_stat_names(cfg)
+
+
+def test_shortcut_programs_count_the_zero_compute_picks():
+    """Both slot programs hand back five counters (``moe_stat_names``), the
+    fifth the picks on zero-compute outputs; the shortcut model keeps no
+    recurrent state, and ``moe.shared`` is in neither program."""
+    assert llm.moe_stat_names(SHORTCUT) == llm.MOE_STATS + ("picks_zero",)
+    for case in (_shortcut_decode_case, _shortcut_prefill_case):
+        fn, _, args, _ = case()
+        assert "/moe.shared" not in fn.lower(*args).as_text(debug_info=True)
+        out = fn(*args)
+        stats = dict(zip(llm.moe_stat_names(SHORTCUT), np.asarray(out[-1])))
+        assert stats["picks"] > 0 and stats["picks"] % 3 == 0
+        assert 0 < stats["picks_zero"] < stats["picks"]
+        assert stats["picks_held"] + stats["picks_zero"] <= stats["picks"]
+        assert out[-2] == {}

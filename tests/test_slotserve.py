@@ -560,6 +560,7 @@ SLOTSERVE_BLOCK_SCHEMA = {
     # The model's own counters (ISSUE 29): zeros for a dense model.
     "moe_picks": (int,),
     "moe_picks_held": (int,),
+    "moe_picks_zero": (int,),       # ISSUE 33: zero-compute experts only
     "moe_experts_touched": (int,),
     "moe_prefill_load_max": (int,),
     "moe_prefill_load_mean": (int, float),
@@ -840,8 +841,9 @@ def test_dense_snapshot_counters_stay_zero(lm):
         svc.generate_batch(["one row"], temperature=0.0, max_tokens=4)
         snap = svc.snapshot()
         assert [snap[k] for k in ("moe_picks", "moe_picks_held",
+                                  "moe_picks_zero",
                                   "moe_experts_touched", "moe_prefill_load_max",
                                   "moe_prefill_load_mean", "state_restores")] \
-            == [0, 0, 0, 0, 0, 0]
+            == [0, 0, 0, 0, 0, 0, 0]
     finally:
         svc.close()
