@@ -194,6 +194,7 @@ class PagedSlotDecoder:
         self._prefix_len = 0
         self._prefix_pids: List[int] = []
         self.prefills = 0
+        self.prefills_flash = 0         # ... whose suffix took the flash kernel
         self.steps = 0
         self.prefix_hits = 0
         self.cow_copies = 0
@@ -424,6 +425,7 @@ class PagedSlotDecoder:
             jax.random.PRNGKey(seed & 0x7FFFFFFF), lp, self.state,
             jnp.int32(slot) if self.state else None)
         self.prefills += 1
+        self.prefills_flash += llm.prefill_takes_flash(self.cfg, ts)
         if lp:
             self.prefix_hits += 1
             self.prefix_tokens_saved += lp

@@ -718,6 +718,9 @@ class SlotServeService:
                           if decode_steps else None),
             "iterations": iterations,
             "prefills": prefills,
+            # ... whose suffix was long enough for the flash kernel
+            # (llm.prefill_takes_flash, the rule the program is traced by).
+            "prefills_flash": self._decoder.prefills_flash,
             "decode_steps": decode_steps,
             # decode_steps * slots, partitioned: a row decoded / the slot
             # was free with nothing queued / free with requests waiting.

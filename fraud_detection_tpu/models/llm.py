@@ -706,6 +706,47 @@ def causal_attention(q, k, v, use_flash: Optional[bool] = None) -> jax.Array:
     return _attend(q, k, v, causal)
 
 
+# A head narrower than this fills under half of the flash kernel's 128 lanes:
+# most of both products would be padding, and materialized scores of such
+# heads are small.
+_FLASH_MIN_D = 64
+
+
+def _suffix_takes_flash(suffix_len: int, key_dim: int) -> bool:
+    """The slot prefill's choice between the flash kernel and materialized
+    scores, from the two shapes it sees: ``causal_attention``'s rule and
+    constant for the length, and a head wide enough for the kernel's lanes."""
+    return suffix_len >= _FLASH_MIN_T and key_dim >= _FLASH_MIN_D
+
+
+def prefill_takes_flash(cfg: TransformerConfig, suffix_len: int) -> bool:
+    """Whether ``paged_slot_prefill`` attends a suffix of this (bucketed)
+    length through the flash kernel in some layer of the model: the predicate
+    the program is traced by (``_suffix_attention``), asked by the slot
+    decoder where it counts ``prefills_flash``."""
+    widths = {"attention": cfg.head_dim,
+              "mla": cfg.mla.qk_dim if cfg.mla is not None else 0}
+    return any(_suffix_takes_flash(suffix_len, widths[mixer])
+               for mixer, _ in cfg.kinds if mixer in widths)
+
+
+def _suffix_attention(q, k, v, mask, prefix_len: int) -> jax.Array:
+    """A suffix's queries (B,Ts,H,d) at position ``prefix_len`` against the
+    row's gathered view k (B,S,Hkv,d) / v (B,S,Hkv,dv): row j attends view
+    positions <= prefix_len + j, which is what ``mask`` (Ts,S) says. A long
+    suffix of wide heads goes blockwise through the flash kernel, which takes
+    the offset and no mask, and no (H, Ts, S) scores exist; any other
+    materializes them under the mask."""
+    if _suffix_takes_flash(q.shape[1], q.shape[-1]):
+        from fraud_detection_tpu.ops.attention import flash_attention
+        from fraud_detection_tpu.utils.device import pallas_interpret
+
+        with jax.named_scope("attn.flash"):
+            return flash_attention(q, k, v, q_offset=prefix_len,
+                                   interpret=pallas_interpret())
+    return _attend(q, k, v, mask)
+
+
 # ---------------------------------------------------------------------------
 # ring attention (sequence parallelism)
 # ---------------------------------------------------------------------------
@@ -949,9 +990,13 @@ def _mla_out(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
 
 
 def _mla_expanded(params: Params, cfg: TransformerConfig, l: int,
-                  q: jax.Array, lat: jax.Array, mask: jax.Array) -> jax.Array:
+                  q: jax.Array, lat: jax.Array, mask: jax.Array, *,
+                  suffix_at: Optional[int] = None) -> jax.Array:
     """Attention with K and V expanded from the latents ``lat`` (B,S,1,.):
-    the prefill path, MHA at key width nope+rope and value width v_dim."""
+    the prefill path, MHA at key width nope+rope and value width v_dim, under
+    ``mask`` (T,S). ``suffix_at``: q is a suffix at that static position of
+    its row's view and ``mask`` the offset-causal one, so a long suffix may go
+    through the flash kernel (``_suffix_attention``)."""
     m = cfg.mla
     with jax.named_scope("mla.attend"):
         kv = _mm("bsc,chd->bshd", lat[:, :, 0, :m.kv_rank],
@@ -959,6 +1004,9 @@ def _mla_expanded(params: Params, cfg: TransformerConfig, l: int,
         kr = jnp.broadcast_to(lat[..., m.kv_rank:],
                               kv.shape[:3] + (m.rope_dim,))
         k = jnp.concatenate([kv[..., :m.nope_dim], kr], -1)
+        if suffix_at is not None:
+            return _suffix_attention(q, k, kv[..., m.nope_dim:], mask,
+                                     suffix_at)
         return _attend(q, k, kv[..., m.nope_dim:], mask)
 
 
@@ -1919,11 +1967,11 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
         view = _gather_view({t: new_pages[f"l{l}.{t}"] for t in new},
                             table_row[None])
         if mixer == "attention":
-            x = _attn_out(params, cfg, l, x,
-                          _attend(q, view["k"], view["v"], kv_mask))
+            x = _attn_out(params, cfg, l, x, _suffix_attention(
+                q, view["k"], view["v"], kv_mask, prefix_len))
         else:
             x = _mla_out(params, cfg, l, x, h, _mla_expanded(
-                params, cfg, l, q, view["c"], kv_mask))
+                params, cfg, l, q, view["c"], kv_mask, suffix_at=prefix_len))
         x, stats, branch = _ffn(params, cfg, l, x, act, real, stats, branch)
     x = rms_norm(x, params["ln_f"], cfg.rms_eps)
     # Logits at the last REAL position, suffix-local index length-1-prefix.

@@ -36,6 +36,75 @@ def test_flash_matches_attend(shape):
                                atol=2e-5, rtol=2e-5)
 
 
+def _offset_mask(T, S, q_offset):
+    return jnp.arange(S)[None, :] <= q_offset + jnp.arange(T)[:, None]
+
+
+@pytest.mark.parametrize("case", [
+    # (T, H, Hkv, d, dv, q_offset, columns past q_offset + T, blocks)
+    pytest.param((256, 2, 2, 128, 128, 0, 0, 0), id="square-offset-0"),
+    pytest.param((192, 2, 2, 64, 64, 37, 27, 128), id="offset-garbage-tail"),
+    pytest.param((256, 4, 2, 32, 32, 128, 64, 128), id="gqa-2"),
+    pytest.param((128, 2, 2, 192, 128, 64, 0, 128), id="mha-d192-dv128"),
+    pytest.param((200, 1, 1, 64, 64, 100, 20, 128), id="ragged-T"),
+    pytest.param((384, 1, 1, 64, 32, 293, 27, (128, 256)), id="blk_q-ne-blk_k"),
+])
+def test_flash_suffix_matches_attend_under_the_offset_mask(case):
+    """What a suffix prefill is: T queries at the static ``q_offset`` against
+    S >= q_offset + T keys, values of a width of their own. Query row j
+    attends columns <= q_offset + j, and what lies past q_offset + T is never
+    read: filling it with other garbage changes no bit."""
+    T, H, Hkv, d, dv, q_offset, tail, blocks = case
+    blk_q, blk_k = blocks if isinstance(blocks, tuple) else (blocks, blocks)
+    S = q_offset + T + tail
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.normal(size=(1, T, H, d)).astype(np.float32))
+    k = rng.normal(size=(1, S, Hkv, d)).astype(np.float32)
+    v = rng.normal(size=(1, S, Hkv, dv)).astype(np.float32)
+    k[:, q_offset + T:], v[:, q_offset + T:] = 1e4, -1e4
+    got = flash_attention(q, jnp.asarray(k), jnp.asarray(v), q_offset=q_offset,
+                          blk_q=blk_q, blk_k=blk_k, interpret=True)
+    want = llm._attend(q, jnp.asarray(k), jnp.asarray(v),
+                       _offset_mask(T, S, q_offset))
+    assert got.shape == (1, T, H, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    if tail:
+        k[:, q_offset + T:], v[:, q_offset + T:] = -7.0, 3e3
+        again = flash_attention(q, jnp.asarray(k), jnp.asarray(v),
+                                q_offset=q_offset, blk_q=blk_q, blk_k=blk_k,
+                                interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
+
+
+def test_flash_over_several_major_key_blocks(monkeypatch):
+    """Keys past what one head keeps resident come as further major blocks
+    (the grid's innermost axis); the accumulators carry across them and a
+    block above a query block's diagonal is skipped whole. Two chunks of 128
+    a block here: five blocks for 600 + 640 columns."""
+    from fraud_detection_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_MAJOR_KEYS", 256)
+    T, q_offset = 640, 600
+    rng = np.random.default_rng(13)
+    q = jnp.asarray(rng.normal(size=(1, T, 2, 64)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(1, q_offset + T, 1, 64)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(1, q_offset + T, 1, 64)).astype(np.float32))
+    flash = jax.jit(attention.flash_attention.__wrapped__, static_argnames=(
+        "q_offset", "blk_q", "blk_k", "interpret"))   # a trace of this limit
+    got = flash(q, k, v, q_offset=q_offset, blk_q=128, blk_k=128,
+                interpret=True)
+    want = llm._attend(q, k, v, _offset_mask(T, q_offset + T, q_offset))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_flash_refuses_a_view_shorter_than_its_queries_see():
+    q = jnp.zeros((1, 128, 1, 32))
+    with pytest.raises(ValueError, match="need 192 keys"):
+        flash_attention(q, q, q, q_offset=64, interpret=True)
+
+
 def test_flash_matches_attend_bf16():
     rng = np.random.default_rng(9)
     shape = (1, 256, 2, 64)

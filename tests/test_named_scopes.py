@@ -5,6 +5,7 @@ outputs are the same bits.
 """
 
 import contextlib
+import dataclasses
 import re
 
 import jax
@@ -229,6 +230,69 @@ def test_dense_programs_carry_none_of_the_hybrid_scopes(case):
     assert out[-1] is None                       # no expert counters
     if fn is llm.paged_decode_window:
         assert out[-2] == {}                     # no recurrent state
+
+
+def _long_prefill_case(cfg, state=None):
+    """A suffix of 512 tokens behind a prefix of 16: long enough for the flash
+    kernel (``llm.prefill_takes_flash``), 33 pages of 16."""
+    params = llm.init_params(jax.random.PRNGKey(0), cfg)
+    pages = llm.init_kv_pages(cfg, 34, 16)
+    tokens = jnp.asarray(np.arange(512).reshape(1, 512) % 250, jnp.int32)
+    args = (params, tokens, jnp.int32(500), cfg, pages,
+            jnp.arange(1, 34, dtype=jnp.int32), jnp.float32(0.0),
+            jax.random.PRNGKey(1), 16)
+    if state is not None:
+        args += (state, jnp.int32(1))
+    return args
+
+
+@pytest.mark.parametrize("tiny,state", [(CFG, False), (HYBRID, True),
+                                        (SHORTCUT, False)],
+                         ids=["dense", "hybrid", "shortcut"])
+def test_long_suffix_prefill_attends_under_attn_flash(tiny, state, monkeypatch):
+    """At 512 suffix tokens and heads 64 wide the prefill's attention is the
+    flash kernel under a scope of its own: no ``attn.scores`` / ``attn.values``
+    (no (H, Ts, S) array) is left in the program; the short cases above keep
+    theirs, and so does the same length at the tiny models' narrow heads;
+    compiled for a TPU the kernel is a ``tpu_custom_call``."""
+    wide = (dict(head_dim_override=64) if tiny.mla is None else
+            dict(mla=dataclasses.replace(tiny.mla, nope_dim=48, rope_dim=16)))
+    cfg = dataclasses.replace(tiny, max_seq=1024, **wide)
+    short = llm.paged_slot_prefill.lower(*_long_prefill_case(
+        dataclasses.replace(tiny, max_seq=1024),
+        llm.init_state(tiny, 3) if state else None)).as_text(debug_info=True)
+    assert "/attn.flash" not in short and "/attn.scores" in short   # heads of 8-16
+    args = _long_prefill_case(cfg, llm.init_state(cfg, 3) if state else None)
+    text = llm.paged_slot_prefill.lower(*args).as_text(debug_info=True)
+    assert "/attn.flash" in text
+    assert "/attn.scores" not in text and "/attn.values" not in text
+    assert "tpu_custom_call" not in text         # interpreted on the CPU
+    from fraud_detection_tpu.utils import device
+
+    monkeypatch.setattr(device, "pallas_interpret", lambda: False)
+    llm.paged_slot_prefill.clear_cache()
+    try:
+        on_chip = llm.paged_slot_prefill.trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    finally:
+        llm.paged_slot_prefill.clear_cache()
+    assert "/attn.flash" in on_chip and "tpu_custom_call" in on_chip
+    assert "/attn.scores" not in on_chip
+
+
+@pytest.mark.parametrize("case", [_decode_case, _hybrid_decode_case,
+                                  _shortcut_decode_case])
+def test_decode_window_holds_no_kernel_call(case, monkeypatch):
+    """The decode window keeps ``_attend`` / the absorbed latent attention:
+    lowered for a TPU it carries no ``tpu_custom_call`` and no ``attn.flash``
+    in any of the three tiny configurations."""
+    from fraud_detection_tpu.utils import device
+
+    monkeypatch.setattr(device, "pallas_interpret", lambda: False)
+    fn, _, args, _ = case()
+    text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text(
+        debug_info=True)
+    assert "tpu_custom_call" not in text and "/attn.flash" not in text
 
 
 def test_hybrid_prefill_holds_no_triangular_solve():

@@ -479,6 +479,86 @@ def test_shared_prefix_matches_analysis_prompts(lm):
 
 
 # ---------------------------------------------------------------------------
+# a long suffix attends through the flash kernel (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+# Heads wide enough for the kernel's lanes (llm._FLASH_MIN_D): the suite's
+# other tiny models have heads of 8 to 16 and keep materialized scores.
+WIDE = {
+    "attention": llm.TransformerConfig(d_model=128, n_layers=2, n_heads=4,
+                                       n_kv_heads=2, head_dim_override=64,
+                                       d_ff=128, max_seq=1024),
+    "mla": llm.TransformerConfig(
+        d_model=64, n_layers=2, n_heads=2, d_ff=128, max_seq=1024,
+        tie_embeddings=False, layer_kinds=(("mla", "dense"),) * 2,
+        mla=llm.MLAConfig(kv_rank=32, nope_dim=48, rope_dim=16, v_dim=32,
+                          q_rank=24, kv_scale=2 ** 0.5, out_gate=False)),
+}
+
+
+@pytest.fixture
+def down_attend(monkeypatch):
+    """``with down_attend():`` traces ``paged_slot_prefill`` with every suffix
+    under the flash threshold. jit keeps a trace by shapes and static
+    arguments, not by the threshold, so the program's cache is emptied on the
+    way in and on the way out."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def forced():
+        llm.paged_slot_prefill.clear_cache()
+        with monkeypatch.context() as m:
+            m.setattr(llm, "_FLASH_MIN_T", 10 ** 9)
+            yield
+        llm.paged_slot_prefill.clear_cache()
+
+    return forced
+
+
+@pytest.mark.parametrize("mixer", ["attention", "mla"])
+def test_long_suffix_prefill_through_flash_equals_attend(mixer, down_attend):
+    """A suffix of 539 tokens (bucket 576 >= 512) behind the shared preamble:
+    the flash kernel over the row's gathered view writes the same pages and
+    samples the same first token as materialized scores under the offset
+    mask, for grouped dense attention (4 heads over 2 kv heads of 64; k and v
+    pages) and for latent attention expanded to keys of 64 and values of 32
+    (latent pages); the decoder counts the prefill under ``prefills_flash``."""
+    model_lm = llm.LanguageModel.init_random(WIDE[mixer], seed=3)
+    cfg = model_lm.cfg
+    toks = None
+
+    def prefill():
+        nonlocal toks
+        dec = PagedSlotDecoder(model_lm, 2, prompt_width=832, max_new_tokens=8,
+                               prefix_text=shared_explain_prefix())
+        toks, _ = dec.encode_prompt(analysis_prompts(1)[0])
+        assert len(toks) - dec._prefix_len == 539
+        first = dec.prefill(1, toks, 0.0, 0)
+        pages = {name: np.concatenate([np.asarray(arr[pid], np.float32)
+                                       for pid in dec._owned[1]])[:len(toks)]
+                 for name, arr in dec.pages.items()}
+        counted = (dec.prefills, dec.prefills_flash)
+        dec.close()
+        assert dec.leaked_pages == 0
+        return first, pages, counted
+
+    with down_attend():
+        assert not llm.prefill_takes_flash(cfg, 576)
+        want_first, want_pages, counted = prefill()
+        assert counted == (1, 0)
+    assert llm.prefill_takes_flash(cfg, 576)
+    assert not llm.prefill_takes_flash(cfg, 448)
+    first, pages, counted = prefill()
+    assert counted == (1, 1)
+    assert first == want_first
+    assert set(pages) == set(want_pages)
+    for name in pages:
+        assert pages[name].any()
+        np.testing.assert_allclose(pages[name], want_pages[name], atol=2e-5,
+                                   rtol=2e-5, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
 # game day: the lane on a capped pool under a campaign wave
 # ---------------------------------------------------------------------------
 

@@ -86,6 +86,7 @@ def test_slot_outputs_match_fixed_batch_greedy(lm):
         assert snap["dropped"] == 0
         assert snap["truncated"] == 0
         assert snap["prefills"] == 12
+        assert snap["prefills_flash"] == 0      # every suffix under 512 tokens
     finally:
         assert svc.close()
 
@@ -540,6 +541,8 @@ SLOTSERVE_BLOCK_SCHEMA = {
     "occupancy": (type(None), int, float),
     "iterations": (int,),
     "prefills": (int,),
+    # ... whose suffix took the flash kernel (ISSUE 34): 0 in a tiny service.
+    "prefills_flash": (int,),
     "decode_steps": (int,),
     # decode_steps * slots, partitioned (ISSUE 26): a row decoded / the
     # slot was free with nothing queued / free with requests waiting.
