@@ -119,11 +119,12 @@ class PagedSlotDecoder:
 
     Two kinds of per-slot state live under this one manager, by the model's
     layer kinds: what grows with the tokens held (an ``attention`` layer's
-    k/v, an ``mla`` layer's latents) is paged; a layer that keeps a
-    recurrent state (``kda``) gets a fixed block per slot, allocated once
+    k/v, an ``mla`` layer's latents) is paged; a layer whose mixer keeps a
+    row state (``kda``: its recurrent matrix and filter tails; ``conv``: its
+    filter's tail) gets a fixed block per slot, allocated once
     (``self.state``). Pages are counted for the paging layers only; the
     blocks need no allocator. The shared preamble is both: pages mapped
-    copy-on-write, and a snapshot of every recurrent layer's state at its
+    copy-on-write, and a snapshot of every such layer's state at its
     last token, copied into the slot's block on admission.
 
     * **Admission** builds the slot's table — shared full prefix pages are
@@ -210,6 +211,9 @@ class PagedSlotDecoder:
         self.moe_picks_zero = 0         # ... on a zero-compute expert
         self.moe_experts_touched = 0    # distinct held experts read, summed
         #                                 over decode steps and expert layers
+        self.moe_expert_slots = 0       # the experts held, summed likewise:
+        #                                 what moe_experts_touched could at
+        #                                 most have been (the host's product)
         self.moe_prefill_load_max = 0   # busiest held expert's tokens, summed
         #                                 over prefills and expert layers
         self.moe_prefill_load_mean = 0.0    # the mean expert's, likewise
@@ -228,7 +232,9 @@ class PagedSlotDecoder:
         if prefix_text:
             self.set_prefix(prefix_text)
 
-    def _count(self, stats, *, prefill: bool) -> None:
+    def _count(self, stats, *, prefill: bool, steps_run: int = 0) -> None:
+        """Add a program's expert counters to the host's sums; ``steps_run``
+        is the decode steps a window ran."""
         if stats is None:
             return
         got = dict(zip(llm.moe_stat_names(self.cfg),
@@ -241,6 +247,8 @@ class PagedSlotDecoder:
             self.moe_prefill_load_mean += got["picks_held"] / self.cfg.moe.held
         else:
             self.moe_experts_touched += got["experts_touched"]
+            self.moe_expert_slots += (steps_run * self.cfg.n_expert_layers
+                                      * self.cfg.moe.held)
 
     # -- stats surface --------------------------------------------------
 
@@ -512,7 +520,7 @@ class PagedSlotDecoder:
             # service mutates it per-slot on prefill/release).
             fetched = (np.asarray(out), np.array(new_lens), int(steps_run),
                        int(n_act))
-            self._count(stats, prefill=False)
+            self._count(stats, prefill=False, steps_run=fetched[2])
             return fetched
 
     def warm(self, steps: int, prompt: Optional[str] = None) -> None:

@@ -748,6 +748,7 @@ class SlotServeService:
             "moe_picks_held": self._decoder.moe_picks_held,
             "moe_picks_zero": self._decoder.moe_picks_zero,
             "moe_experts_touched": self._decoder.moe_experts_touched,
+            "moe_expert_slots": self._decoder.moe_expert_slots,
             "moe_prefill_load_max": self._decoder.moe_prefill_load_max,
             "moe_prefill_load_mean": round(
                 self._decoder.moe_prefill_load_mean, 4),

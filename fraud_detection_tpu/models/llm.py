@@ -91,6 +91,14 @@ class KDAConfig:
 
 
 @dataclass(frozen=True)
+class ConvConfig:
+    """Gated short convolution: a causal depth-wise filter of ``taps`` over
+    one gated stream of ``d_model`` channels (nothing else to size)."""
+
+    taps: int = 3
+
+
+@dataclass(frozen=True)
 class MoEConfig:
     """Routed experts, of which this device holds ``[held_start, held_start
     + held_count)``: the router scores all ``n_experts`` (and the ``n_zero``
@@ -102,7 +110,8 @@ class MoEConfig:
     weights, and is computed for every row that lives here. ``score``: how
     the router scores ("sigmoid" | "softmax" over all outputs); ``n_group``
     1 = no group limit; ``norm_topk``: the chosen weights normalised over
-    the choice or taken as scored; ``d_shared`` 0 = no shared expert."""
+    the choice (``norm_eps`` added to the sum they are divided by; 0 = the
+    bare sum) or taken as scored; ``d_shared`` 0 = no shared expert."""
 
     n_experts: int = 16
     top_k: int = 4
@@ -116,6 +125,7 @@ class MoEConfig:
     score: str = "sigmoid"
     norm_topk: bool = True
     n_zero: int = 0
+    norm_eps: float = 0.0
 
     @property
     def held(self) -> int:
@@ -126,7 +136,9 @@ class MoEConfig:
         return self.n_experts + self.n_zero
 
 
-MIXERS = ("attention", "mla", "kda")
+MIXERS = ("attention", "mla", "kda", "conv")
+# the mixers that keep a fixed state a row and page nothing (``_state_shapes``)
+STATEFUL_MIXERS = ("kda", "conv")
 # "dense+experts": a dense MLP on the residual, and an expert branch computed
 # from the same normed input whose result is carried; "dense+join": a dense
 # MLP, and the carried branch added after it (a branch joins one sub-layer
@@ -155,11 +167,15 @@ class TransformerConfig:
     # --- per-layer kinds: ((mixer, ffn), ...) of MIXERS x FFNS, one pair a
     # layer; None = ("attention", "dense") throughout. The list alone decides
     # a layer's arithmetic, parameters, cache and sharding; ``mla`` / ``kda``
-    # / ``moe`` size the kinds it names.
+    # / ``conv`` / ``moe`` size the kinds it names.
     layer_kinds: Optional[Tuple[Tuple[str, str], ...]] = None
     mla: Optional[MLAConfig] = None
     kda: Optional[KDAConfig] = None
+    conv: Optional[ConvConfig] = None
     moe: Optional[MoEConfig] = None
+    # ``attention`` layers RMS-norm q and k per head ahead of RoPE (gammas
+    # ``q_norm`` / ``k_norm`` of head_dim, eps ``rms_eps``)
+    qk_norm: bool = False
 
     def __post_init__(self):
         kinds = self.kinds
@@ -170,7 +186,8 @@ class TransformerConfig:
         for mixer, ffn in kinds:
             if mixer not in MIXERS or ffn not in FFNS:
                 raise ValueError(f"unknown layer kind {(mixer, ffn)!r}: a "
-                                 f"mixer of {MIXERS}, a feed-forward of {FFNS}")
+                                 f"mixer of MIXERS {MIXERS}, a feed-forward "
+                                 f"of FFNS {FFNS}")
             if ffn in ("dense+experts", "dense+join"):
                 if open_branch != (ffn == "dense+join"):
                     raise ValueError(
@@ -181,7 +198,8 @@ class TransformerConfig:
         if open_branch:
             raise ValueError("the last 'dense+experts' layer's branch is "
                              "never joined")
-        for kind, sized in (("mla", self.mla), ("kda", self.kda)):
+        for kind, sized in (("mla", self.mla), ("kda", self.kda),
+                            ("conv", self.conv)):
             if sized is None and any(m == kind for m, _ in kinds):
                 raise ValueError(f"a {kind!r} layer needs cfg.{kind}")
         if self.moe is None and any(f in ROUTED_FFNS for _, f in kinds):
@@ -242,6 +260,12 @@ def _layer_shapes(cfg: TransformerConfig, mixer: str, ffn: str
         if m.out_gate:
             out.update(mla_wz=((D, h), "normal", 1))
         out.update(mla_wo=((h, m.v_dim, D), "normal", 0))
+    elif mixer == "conv":
+        # one projection to the streams B, C, X; the filter a scalar a channel
+        # a tap; channels over the model axis up to the output projection
+        out.update(conv_win=((D, 3, D), "normal", 2),
+                   conv_w=((cfg.conv.taps, D), "filter", 1),
+                   conv_wout=((D, D), "normal", 0))
     else:
         k = cfg.kda
         hk, dk, taps = k.n_heads, k.head_dim, k.conv_taps
@@ -273,6 +297,8 @@ def _layer_shapes(cfg: TransformerConfig, mixer: str, ffn: str
             out.update(moe_sg=((D, Fs), "normal", 1),
                        moe_su=((D, Fs), "normal", 1),
                        moe_sd=((Fs, D), "normal", 0))
+    if mixer == "attention" and cfg.qk_norm:    # last: no other leaf's key moves
+        out.update(q_norm=((d,), "ones", None), k_norm=((d,), "ones", None))
     return out
 
 
@@ -296,8 +322,8 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
         for i, (name, (shape, made, _)) in enumerate(
                 _layer_shapes(cfg, mixer, ffn).items()):
             # the dense layer's seven leaves keep the keys they always had
-            key = k[i] if (mixer, ffn) == ("attention", "dense") else \
-                jax.random.fold_in(k[0], i)
+            key = k[i] if (mixer, ffn) == ("attention", "dense") and i < 7 \
+                else jax.random.fold_in(k[0], i)
             if made == "ones":
                 w = jnp.ones(shape)
             elif made == "zeros":
@@ -318,9 +344,11 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Params:
 
 def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, NamedSharding]:
     """Megatron TP layout as shardings: attention (of any kind) sharded over
-    heads, MLPs and every held expert over the hidden dim; norms, router and
-    embeddings replicated. GSPMD derives the matching activation collectives
-    (all-reduce after row-parallel wo / w_down). GQA: when the kv-head count
+    heads, a gated convolution over its channels (column-parallel in, the
+    filter local, row-parallel out), MLPs and every held expert over the
+    hidden dim; norms, router and embeddings replicated. GSPMD derives the
+    matching activation collectives (all-reduce after row-parallel wo /
+    w_down). GQA: when the kv-head count
     doesn't divide over the model axis (MQA has a single kv head), k/v are
     replicated — the Megatron convention; the one latent of an ``mla`` layer
     (``mla_wkva``) is replicated for the same reason."""
@@ -406,6 +434,8 @@ _QUANT_REDUCE_AXES = {
     "mla_wo": (0, 1), "mla_wqa": (0,), "mla_wqb": (0,),
     "kda_wq": (0,), "kda_wk": (0,), "kda_wv": (0,), "kda_wg": (0,),
     "kda_wbeta": (0,), "kda_wz": (0,), "kda_wo": (0, 1),
+    # the gated short convolution's two projections
+    "conv_win": (0,), "conv_wout": (0,),
     # experts, per expert and per output channel: (E, in, out)
     "moe_wg": (1,), "moe_wu": (1,), "moe_wd": (1,),
     "moe_sg": (0,), "moe_su": (0,), "moe_sd": (0,),
@@ -1187,6 +1217,18 @@ def kda_step(q, k, v, g, beta, S):
     return mm("bhkv,bhk->bhv", S, q), S
 
 
+def _last_inputs(window: jax.Array, keep: int,
+                 real: Optional[jax.Array]) -> jax.Array:
+    """The ``keep`` inputs of ``window`` (B, keep+T, ...: a filter's inputs
+    behind the ``keep`` that came before them) that precede the position
+    after the last real token: the tail a causal filter hands on."""
+    B, T = window.shape[0], window.shape[1] - keep
+    end = (jnp.full((B,), T, jnp.int32) if real is None else jnp.max(
+        jnp.where(real, jnp.arange(T) + 1, 0), axis=1))
+    return jax.vmap(lambda w_, e: jax.lax.dynamic_slice_in_dim(
+        w_, e, keep, 0))(window, end)
+
+
 def _kda_mix(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
              h: jax.Array, S: jax.Array, tail: jax.Array,
              real: Optional[jax.Array]):
@@ -1195,7 +1237,7 @@ def _kda_mix(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
     in; returns (x', S', tail'). T == 1 is the decode step; longer inputs run
     chunked, and ``real`` (B,T) marks the tokens that count (padding, left or
     right, leaves the state and the tail alone)."""
-    B, T, _ = h.shape
+    T = h.shape[1]
     qkv, g, beta = _kda_project(params, cfg, l, h)
     if T > 1 and real is not None:
         qkv = jnp.where(real[:, :, None, None, None], qkv, 0)
@@ -1211,16 +1253,45 @@ def _kda_mix(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
     else:
         with jax.named_scope("kda.chunk"):
             o, S = kda_chunked(q, k, v, g, beta, S, cfg.kda.chunk)
-            # the inputs that precede the position after the last real token
-            end = (jnp.full((B,), T, jnp.int32) if real is None else jnp.max(
-                jnp.where(real, jnp.arange(T) + 1, 0), axis=1))
-            tail = jax.vmap(lambda w_, e: jax.lax.dynamic_slice_in_dim(
-                w_, e, keep, 0))(window, end)
+            tail = _last_inputs(window, keep, real)
     with jax.named_scope("attn.out"):
         o = (o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
                                + cfg.rms_eps)).astype(cfg.dtype) \
             * params[f"l{l}.kda_onorm"]
     return _head_gate_out(params, cfg, l, "kda", x, h, o), S, tail
+
+
+# -- the gated short convolution ---------------------------------------------
+#
+# [B | C | X] = W_in h (three streams of d_model channels); u_t = B_t * X_t;
+# c_t = sum_j w[j] u_{t-(taps-1)+j} (depth-wise, causal; u before the
+# sequence's first token is 0); out = W_out (C_t * c_t). No activation, no
+# bias, no norm inside. The row state is the last taps-1 values of u.
+
+def _conv_mix(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
+              h: jax.Array, tail: jax.Array, real: Optional[jax.Array]):
+    """The gated short convolution on its residual. ``tail`` (B,taps-1,D:
+    the last gated inputs u, in the serving dtype as computed) is the row's
+    state coming in; returns (x', tail'). T == 1 is the decode step, which
+    shifts the tail by one; over a longer input ``real`` (B,T) marks the
+    tokens that count (padding, left or right, leaves the tail alone). The
+    taps are summed in float32."""
+    T = h.shape[1]
+    taps = cfg.conv.taps
+    with jax.named_scope("conv.in"):
+        bcx = _mm("btD,DsC->btsC", h, params[f"l{l}.conv_win"], cfg.dtype)
+        u = bcx[:, :, 0] * bcx[:, :, 2]
+        if T > 1 and real is not None:
+            u = jnp.where(real[:, :, None], u, 0)
+    with jax.named_scope("conv.filter"):
+        window = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+        w = params[f"l{l}.conv_w"].astype(_F32)
+        win = window.astype(_F32)
+        c = sum(win[:, j:j + T] * w[j] for j in range(taps)).astype(cfg.dtype)
+        tail = window[:, 1:] if T == 1 else _last_inputs(window, taps - 1, real)
+    with jax.named_scope("conv.out"):
+        return x + _mm("btC,CD->btD", bcx[:, :, 1] * c,
+                       params[f"l{l}.conv_wout"], cfg.dtype), tail
 
 
 # -- feed-forward kinds ------------------------------------------------------
@@ -1256,7 +1327,8 @@ def moe_route(router, bias, xf: jax.Array, m: MoEConfig):
     (``m.score``); the choice is made on score + bias, group-limited where
     ``n_group`` > 1 (groups scored by the sum of their two best, the best
     ``topk_group`` kept); the weights are the chosen scores (without the
-    bias), normalised over the choice where ``norm_topk``, times
+    bias), normalised over the choice where ``norm_topk`` (divided by
+    their sum, plus ``norm_eps`` where the configuration has one), times
     ``routed_scale``.
     xf (N,D) -> idx (N,top_k) int32, w (N,top_k) float32."""
     N = xf.shape[0]
@@ -1275,7 +1347,8 @@ def moe_route(router, bias, xf: jax.Array, m: MoEConfig):
     chosen = jnp.take_along_axis(s, idx, axis=1)
     w = m.routed_scale * chosen
     if m.norm_topk:
-        w = w / jnp.sum(chosen, -1, keepdims=True)
+        total = jnp.sum(chosen, -1, keepdims=True)
+        w = w / (total + m.norm_eps if m.norm_eps else total)
     return idx.astype(jnp.int32), w
 
 
@@ -1418,18 +1491,55 @@ def _act(cfg: TransformerConfig):
         jax.nn.gelu, approximate=True)
 
 
+def _state_shapes(cfg: TransformerConfig, mixer: str
+                  ) -> Dict[str, Tuple[tuple, jnp.dtype]]:
+    """What a mixer of ``STATEFUL_MIXERS`` keeps of a row, name -> (shape
+    behind the row axis, dtype): ``kda`` its float32 matrix ``S`` and its
+    filters' ``tail`` (the last pre-filter inputs), ``conv`` the ``tail`` of
+    its one filter (the last gated inputs)."""
+    if mixer == "kda":
+        k = cfg.kda
+        return {"S": ((k.n_heads, k.head_dim, k.head_dim), _F32),
+                "tail": ((k.conv_taps - 1, 3, k.n_heads, k.head_dim), cfg.dtype)}
+    return {"tail": ((cfg.conv.taps - 1, cfg.d_model), cfg.dtype)}
+
+
 def init_state(cfg: TransformerConfig, batch: int) -> Dict[str, jax.Array]:
-    """A fixed block per row for every ``kda`` layer: the float32 state and
-    the filter's tail. Zeros are the state before the first token."""
-    out: Dict[str, jax.Array] = {}
-    for l, (mixer, _) in enumerate(cfg.kinds):
-        if mixer == "kda":
-            k = cfg.kda
-            out[f"l{l}.S"] = jnp.zeros(
-                (batch, k.n_heads, k.head_dim, k.head_dim), _F32)
-            out[f"l{l}.tail"] = jnp.zeros(
-                (batch, k.conv_taps - 1, 3, k.n_heads, k.head_dim), cfg.dtype)
-    return out
+    """A fixed block per row for every layer whose mixer keeps a row state
+    (``_state_shapes``), as ``l{l}.{name}``. Zeros are the state before the
+    first token."""
+    return {f"l{l}.{name}": jnp.zeros((batch,) + shape, dtype)
+            for l, (mixer, _) in enumerate(cfg.kinds)
+            if mixer in STATEFUL_MIXERS
+            for name, (shape, dtype) in _state_shapes(cfg, mixer).items()}
+
+
+def _put_row(arr: jax.Array, row: jax.Array, slot: jax.Array) -> jax.Array:
+    return jax.lax.dynamic_update_slice_in_dim(arr, row.astype(arr.dtype),
+                                               slot, 0)
+
+
+def _stateful_mix(params: Params, cfg: TransformerConfig, l: int,
+                  x: jax.Array, h: jax.Array, state: Dict[str, jax.Array],
+                  real: Optional[jax.Array], slot: Optional[jax.Array] = None):
+    """Layer ``l``'s mixer of ``STATEFUL_MIXERS`` on its residual: the one
+    seam the three layer loops hand such a mixer its named state arrays
+    through (``state``: any dict that holds the layer's ``l{l}.{name}``
+    entries) and take them back by. With ``slot`` the arrays are the pool's
+    and the mixer runs on row ``slot`` of each, which alone is written.
+    Returns (x', {``l{l}.{name}``: the array going out})."""
+    mixer = cfg.kinds[l][0]
+    names = [f"l{l}.{name}" for name in _state_shapes(cfg, mixer)]
+    held = [state[n] for n in names]
+    if slot is not None:
+        held = [jax.lax.dynamic_index_in_dim(a, slot, 0) for a in held]
+    if mixer == "kda":
+        x, *new = _kda_mix(params, cfg, l, x, h, *held, real)
+    else:
+        x, *new = _conv_mix(params, cfg, l, x, h, *held, real)
+    if slot is not None:
+        new = [_put_row(state[n], a, slot) for n, a in zip(names, new)]
+    return x, dict(zip(names, new))
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -1438,7 +1548,7 @@ def restore_slot_state(state: Dict[str, jax.Array],
                        slot: jax.Array) -> Dict[str, jax.Array]:
     """Copy a one-row state snapshot (the shared preamble's, or zeros) into
     row ``slot`` of every state array: what admission does for the layers
-    that keep a recurrent state, beside mapping pages for those that page."""
+    whose mixer keeps a row state, beside mapping pages for those that page."""
     with jax.named_scope("state.restore"):
         return {name: jax.lax.dynamic_update_slice_in_dim(
                     arr, snapshot[name].astype(arr.dtype), slot, 0)
@@ -1470,17 +1580,14 @@ def _decode_mask(T: int, S: int, cache_len, valid_from):
 def _hybrid_mixer(params: Params, cfg: TransformerConfig, l: int,
                   x: jax.Array, h: jax.Array, positions: jax.Array,
                   kv_cache, cache_len, valid_from, real):
-    """``forward``'s ``mla`` / ``kda`` layer: (x', what the layer writes to
-    the cache). Without a cache a sequence starts from the zero state."""
+    """``forward``'s layer of a mixer other than ``attention``: (x', what
+    the layer writes to the cache). Without a cache a sequence starts from
+    the zero state."""
     B, T, _ = h.shape
-    if cfg.kinds[l][0] == "kda":
-        if kv_cache is None:
-            st = init_state(cfg, B)
-            S, tail = st[f"l{l}.S"], st[f"l{l}.tail"]
-        else:
-            S, tail = kv_cache[f"l{l}.S"], kv_cache[f"l{l}.tail"]
-        x, S, tail = _kda_mix(params, cfg, l, x, h, S, tail, real)
-        return x, {f"l{l}.S": S, f"l{l}.tail": tail}
+    if cfg.kinds[l][0] in STATEFUL_MIXERS:
+        return _stateful_mix(
+            params, cfg, l, x, h,
+            init_state(cfg, B) if kv_cache is None else kv_cache, real)
     q, lat = _mla_project(params, cfg, l, h, positions)
     if kv_cache is None:
         o = _mla_expanded(params, cfg, l, q, lat,
@@ -1557,11 +1664,7 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
                 new_cache.update(upd)
             x, _, branch = _ffn(params, cfg, l, x, act, real, {}, branch)
             continue
-        q = _mm("btD,Dhd->bthd", h, params[f"l{l}.wq"], cfg.dtype)
-        k = _mm("btD,Dhd->bthd", h, params[f"l{l}.wk"], cfg.dtype)
-        v = _mm("btD,Dhd->bthd", h, params[f"l{l}.wv"], cfg.dtype)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q, k, v = _qkv(params, cfg, l, h, positions)
 
         if kv_cache is not None:
             # decode: append this step's k/v at cache_len, attend over prefix
@@ -1614,7 +1717,8 @@ def _token_cache(cfg: TransformerConfig, lead: Tuple[int, int]
                  ) -> Dict[str, jax.Array]:
     """What grows with the tokens held, per layer kind: k/v of an
     ``attention`` layer, the latent of an ``mla`` layer (one "head" of
-    kv_rank + rope values), nothing of a ``kda`` layer."""
+    kv_rank + rope values), nothing of a layer whose mixer keeps a row state
+    instead (``STATEFUL_MIXERS``: ``init_state``)."""
     out: Dict[str, jax.Array] = {}
     for l, (mixer, _) in enumerate(cfg.kinds):
         if mixer == "attention":
@@ -1628,7 +1732,7 @@ def _token_cache(cfg: TransformerConfig, lead: Tuple[int, int]
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict[str, jax.Array]:
     """A contiguous cache: (batch, max_len, ...) per token-cache entry, and
-    each row's recurrent state (``init_state``) beside them."""
+    each row's state (``init_state``) beside them."""
     return {**_token_cache(cfg, (batch, max_len)), **init_state(cfg, batch)}
 
 
@@ -1678,11 +1782,17 @@ def _logits_head(x: jax.Array, params: Params, cfg: TransformerConfig) -> jax.Ar
 
 def _qkv(params: Params, cfg: TransformerConfig, l: int, h: jax.Array,
          positions: jax.Array):
-    """Layer ``l``'s q/k/v projections with rotary positions applied."""
+    """Layer ``l``'s q/k/v projections with rotary positions applied; with
+    ``cfg.qk_norm`` q and k are RMS-normed per head (``q_norm`` / ``k_norm``)
+    ahead of the rotation, so a cache holds the normed, rotated k."""
     with jax.named_scope("attn.qkv"):
         q = _mm("btD,Dhd->bthd", h, params[f"l{l}.wq"], cfg.dtype)
         k = _mm("btD,Dhd->bthd", h, params[f"l{l}.wk"], cfg.dtype)
         v = _mm("btD,Dhd->bthd", h, params[f"l{l}.wv"], cfg.dtype)
+        if cfg.qk_norm:
+            with jax.named_scope("attn.qk_norm"):
+                q = rms_norm(q, params[f"l{l}.q_norm"], cfg.rms_eps)
+                k = rms_norm(k, params[f"l{l}.k_norm"], cfg.rms_eps)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
     return q, k, v
@@ -1707,11 +1817,6 @@ def _pack_stats(stats: Dict[str, jax.Array]) -> Optional[jax.Array]:
     return jnp.stack([stats[k] for k in names]) if stats else None
 
 
-def _put_row(arr: jax.Array, row: jax.Array, slot: jax.Array) -> jax.Array:
-    return jax.lax.dynamic_update_slice_in_dim(arr, row.astype(arr.dtype),
-                                               slot, 0)
-
-
 def _slot_step_math(params: Params, cfg: TransformerConfig,
                     kv_cache: Dict[str, jax.Array], tokens: jax.Array,
                     lens: jax.Array, temperature: jax.Array,
@@ -1721,7 +1826,7 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
     feed (B,) tokens, scatter their k/v (an ``mla`` layer's latent) at
     per-slot index ``lens[b]``, attend each row over its own prefix
     [0, lens[b]], advance
-    each ``kda`` layer's per-row state one token, sample (B,) next tokens
+    the per-row state of each layer whose mixer keeps one by a token, sample (B,) next tokens
     (per-slot temperature: greedy rows argmax, sampled rows draw from
     (key, row) — a slot's stream never depends on its neighbors). ``live``
     (B,) marks the rows that decode: an expert layer routes the others
@@ -1739,10 +1844,9 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
     branch = None
     for l, (mixer, _) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
-        if mixer == "kda":
-            x, S, tail = _kda_mix(params, cfg, l, x, h, kv_cache[f"l{l}.S"],
-                                  kv_cache[f"l{l}.tail"], None)
-            new_cache[f"l{l}.S"], new_cache[f"l{l}.tail"] = S, tail
+        if mixer in STATEFUL_MIXERS:
+            x, upd = _stateful_mix(params, cfg, l, x, h, kv_cache, None)
+            new_cache.update(upd)
             x, stats, branch = _ffn(params, cfg, l, x, act, live, stats,
                                     branch)
             continue
@@ -1792,7 +1896,7 @@ def _slot_window_loop(params: Params, tokens: jax.Array, lens: jax.Array,
                       steps: int):
     """The fused multi-step decode loop of `paged_decode_window`, over the
     (B, S, Hkv, d) view that program gathers from its pages. ``kv_cache``
-    also carries each row's recurrent state where the model keeps one
+    also carries each row's state where a mixer keeps one
     (``init_state``).
 
     ``tokens``: (B,) last sampled token per slot (written this window);
@@ -1845,8 +1949,9 @@ def init_kv_pages(cfg: TransformerConfig, num_pages: int,
     """The slot pool's pages: a flat block pool per layer/tensor
     for the layers that cache per token (``attention``: k and v; ``mla``: the
     latent). Page ids index the leading axis; a slot's logical position p
-    lives at ``(table[p // page_size], p % page_size)``. A ``kda`` layer pages
-    nothing: its per-row state is ``init_state``'s."""
+    lives at ``(table[p // page_size], p % page_size)``. A layer whose mixer
+    keeps a row state (``kda``, ``conv``) pages nothing: its state is
+    ``init_state``'s."""
     return _token_cache(cfg, (num_pages, page_size))
 
 
@@ -1909,12 +2014,12 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     land in the row's pages at [length, prefix_len + Ts) — garbage, but every
     later read masks to [0, len] and decode overwrites them in order.
 
-    ``state`` / ``slot``: where the model keeps a per-row recurrent state
+    ``state`` / ``slot``: where a mixer of the model keeps a row state
     (``init_state``), the pool's state arrays and the row admitted. The
     row's block holds the state at ``prefix_len`` coming in (admission put
     the preamble's snapshot, or zeros, there: ``restore_slot_state``) and the
     state at the last real token going out. Returns ``(first token, pages,
-    state, stats)``: ``state`` is {} for a model without a recurrent layer,
+    state, stats)``: ``state`` is {} for a model none of whose mixers keeps one,
     ``stats`` None for one without experts."""
     B, Ts = tokens.shape
     page = next(iter(kv_pages.values())).shape[1]
@@ -1938,14 +2043,9 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     branch = None
     for l, (mixer, _) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
-        if mixer == "kda":
-            row = partial(jax.lax.dynamic_index_in_dim, index=slot, axis=0)
-            x, S, tail = _kda_mix(params, cfg, l, x, h,
-                                  row(new_state[f"l{l}.S"]),
-                                  row(new_state[f"l{l}.tail"]), real)
-            new_state[f"l{l}.S"] = _put_row(new_state[f"l{l}.S"], S, slot)
-            new_state[f"l{l}.tail"] = _put_row(new_state[f"l{l}.tail"], tail,
-                                               slot)
+        if mixer in STATEFUL_MIXERS:
+            x, upd = _stateful_mix(params, cfg, l, x, h, new_state, real, slot)
+            new_state.update(upd)
             x, stats, branch = _ffn(params, cfg, l, x, act, real, stats,
                                     branch)
             continue
@@ -2008,7 +2108,7 @@ def paged_decode_window(params: Params, tokens: jax.Array, lens: jax.Array,
     scattered back (free slots keep lens 0; the next admit/step overwrites
     the position before any attend).
 
-    ``state``: the pool's per-row recurrent state (``init_state``) where the
+    ``state``: the pool's per-row state (``init_state``) where the
     model keeps one; it rides the loop beside the view and comes back whole
     (a frozen or idle row's block holds garbage, which the next admission
     overwrites). Returns ``(out, new_lens, steps_run, active_row_steps,

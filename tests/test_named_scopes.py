@@ -136,6 +136,48 @@ def _shortcut_prefill_case():
             | {"moe.route", "moe.experts", "mla.latent", "mla.attend"})
 
 
+# What the convolution-attention hybrid's layer kinds add (ISSUE 35): a gated
+# short convolution that keeps its filter's tail, attention with per-head q/k
+# norms at four query heads a key-value head, every expert held.
+CONV_SCOPES = {"conv.in", "conv.filter", "conv.out", "attn.qk_norm"}
+CONVMIX = llm.TransformerConfig(
+    vocab_size=300, d_model=32, n_heads=4, n_layers=3, d_ff=64, max_seq=256,
+    n_kv_heads=1, head_dim_override=8, rms_eps=1e-5, qk_norm=True,
+    layer_kinds=(("conv", "dense"), ("attention", "experts"),
+                 ("conv", "experts")),
+    conv=llm.ConvConfig(taps=3),
+    moe=llm.MoEConfig(n_experts=8, top_k=2, n_group=1, topk_group=1,
+                      d_expert=16, d_shared=0, norm_eps=1e-6, held_start=0,
+                      held_count=8))
+
+
+def _convmix_inputs():
+    params = llm.init_params(jax.random.PRNGKey(0), CONVMIX)
+    pages = llm.init_kv_pages(CONVMIX, 12, 16)
+    tables = jnp.asarray(np.arange(12).reshape(3, 4), jnp.int32)
+    return params, pages, tables, jax.random.PRNGKey(1), llm.init_state(CONVMIX, 3)
+
+
+def _convmix_decode_case():
+    params, pages, tables, key, state = _convmix_inputs()
+    args = (params, jnp.asarray([5, 6, 7], jnp.int32),
+            jnp.asarray([10, 20, 3], jnp.int32),
+            jnp.asarray([True, True, False]), jnp.asarray([8, 8, 0], jnp.int32),
+            CONVMIX, pages, tables, jnp.zeros(3, jnp.float32), key, 4, state)
+    return (llm.paged_decode_window, (5, 10), args,
+            LAYER_SCOPES | CONV_SCOPES | {"kv.append", "moe.route",
+                                          "moe.experts"})
+
+
+def _convmix_prefill_case():
+    params, pages, tables, key, state = _convmix_inputs()
+    tokens = jnp.asarray(np.arange(32).reshape(1, 32) % 250, jnp.int32)
+    args = (params, tokens, jnp.int32(40), CONVMIX, pages, tables[0],
+            jnp.float32(0.0), key, 16, state, jnp.int32(1))
+    return (llm.paged_slot_prefill, (3, 8), args,
+            LAYER_SCOPES | CONV_SCOPES | {"moe.route", "moe.experts"})
+
+
 def _packed_rows():
     rng = np.random.default_rng(0)
     ids = rng.integers(0, 500, (8, 16)).astype(np.int16)
@@ -174,7 +216,8 @@ def _tree_case():
 @pytest.mark.parametrize("case", [_decode_case,
                                   _prefill_case, _lr_case, _tree_case,
                                   _hybrid_decode_case, _hybrid_prefill_case,
-                                  _shortcut_decode_case, _shortcut_prefill_case])
+                                  _shortcut_decode_case, _shortcut_prefill_case,
+                                  _convmix_decode_case, _convmix_prefill_case])
 def test_scopes_are_named_and_change_no_number(case, monkeypatch):
     fn, static, args, scopes = case()
     named = fn.lower(*args)
@@ -247,8 +290,8 @@ def _long_prefill_case(cfg, state=None):
 
 
 @pytest.mark.parametrize("tiny,state", [(CFG, False), (HYBRID, True),
-                                        (SHORTCUT, False)],
-                         ids=["dense", "hybrid", "shortcut"])
+                                        (SHORTCUT, False), (CONVMIX, True)],
+                         ids=["dense", "hybrid", "shortcut", "convmix"])
 def test_long_suffix_prefill_attends_under_attn_flash(tiny, state, monkeypatch):
     """At 512 suffix tokens and heads 64 wide the prefill's attention is the
     flash kernel under a scope of its own: no ``attn.scores`` / ``attn.values``
@@ -281,11 +324,11 @@ def test_long_suffix_prefill_attends_under_attn_flash(tiny, state, monkeypatch):
 
 
 @pytest.mark.parametrize("case", [_decode_case, _hybrid_decode_case,
-                                  _shortcut_decode_case])
+                                  _shortcut_decode_case, _convmix_decode_case])
 def test_decode_window_holds_no_kernel_call(case, monkeypatch):
     """The decode window keeps ``_attend`` / the absorbed latent attention:
     lowered for a TPU it carries no ``tpu_custom_call`` and no ``attn.flash``
-    in any of the three tiny configurations."""
+    in any of the four tiny configurations."""
     from fraud_detection_tpu.utils import device
 
     monkeypatch.setattr(device, "pallas_interpret", lambda: False)
@@ -345,3 +388,34 @@ def test_shortcut_programs_count_the_zero_compute_picks():
         assert 0 < stats["picks_zero"] < stats["picks"]
         assert stats["picks_held"] + stats["picks_zero"] <= stats["picks"]
         assert out[-2] == {}
+
+
+@pytest.mark.parametrize("case", [_decode_case, _prefill_case,
+                                  _hybrid_decode_case, _hybrid_prefill_case,
+                                  _shortcut_decode_case, _shortcut_prefill_case])
+def test_other_programs_carry_none_of_the_conv_scopes(case):
+    """The dense decoder, the hybrid and the shortcut-connected model lower
+    to the programs they were: no gated convolution, no q/k norm."""
+    fn, _, args, _ = case()
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert not [s for s in CONV_SCOPES if f"/{s}" in text]
+
+
+def test_convmix_programs_keep_filter_tails_and_hold_every_expert():
+    """Both slot programs of the convolution-attention hybrid carry the four
+    new scopes (the case list above) and none of the other kinds'; the state
+    that rides them is one filter tail a convolution layer; with every expert
+    held each pick is a held pick; ``moe.shared`` is in neither program."""
+    assert set(llm.init_state(CONVMIX, 3)) == {"l0.tail", "l2.tail"}
+    assert llm.moe_stat_names(CONVMIX) == llm.MOE_STATS
+    others = (NEW_SCOPES | SHORTCUT_SCOPES) - {"moe.route", "moe.experts"}
+    for case in (_convmix_decode_case, _convmix_prefill_case):
+        fn, _, args, _ = case()
+        text = fn.lower(*args).as_text(debug_info=True)
+        assert not [s for s in others if f"/{s}" in text]
+        assert "/attn.qkv/attn.qk_norm" in text       # inside the projection's
+        out = fn(*args)
+        stats = dict(zip(llm.moe_stat_names(CONVMIX), np.asarray(out[-1])))
+        assert stats["picks"] > 0 and stats["picks_held"] == stats["picks"]
+        assert set(out[-2]) == {"l0.tail", "l2.tail"}
+        assert out[-2]["l0.tail"].shape == (3, 2, 32)

@@ -565,6 +565,7 @@ SLOTSERVE_BLOCK_SCHEMA = {
     "moe_picks_held": (int,),
     "moe_picks_zero": (int,),       # ISSUE 33: zero-compute experts only
     "moe_experts_touched": (int,),
+    "moe_expert_slots": (int,),     # ISSUE 35: steps x expert layers x held
     "moe_prefill_load_max": (int,),
     "moe_prefill_load_mean": (int, float),
     "state_restores": (int,),
@@ -844,9 +845,42 @@ def test_dense_snapshot_counters_stay_zero(lm):
         svc.generate_batch(["one row"], temperature=0.0, max_tokens=4)
         snap = svc.snapshot()
         assert [snap[k] for k in ("moe_picks", "moe_picks_held",
-                                  "moe_picks_zero",
-                                  "moe_experts_touched", "moe_prefill_load_max",
+                                  "moe_picks_zero", "moe_experts_touched",
+                                  "moe_expert_slots", "moe_prefill_load_max",
                                   "moe_prefill_load_mean", "state_restores")] \
-            == [0, 0, 0, 0, 0, 0, 0]
+            == [0, 0, 0, 0, 0, 0, 0, 0]
     finally:
         svc.close()
+
+
+# ---------------------------------------------------------------------------
+# the host's own expert counter (ISSUE 35): any routed model reports it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("tiny", ["hybrid_tiny", "longcat_tiny", "lfm2_tiny"])
+def test_expert_slots_count_steps_times_layers_times_held(tiny):
+    """``moe_expert_slots`` is the host's product at each decode window,
+    steps run x expert layers x experts held: what ``moe_experts_touched``,
+    the device's count of the experts a step really read, could at most
+    have been. The lane's warm-up runs a window of its own before the
+    service counts ``decode_steps``, so both are read as differences."""
+    import importlib
+
+    lm = importlib.import_module(tiny).language_model("float32")
+    cfg = lm.cfg
+    svc = make_service(lm, slots=2, max_new_tokens=6, prompt_width=128,
+                       decode_window=4, shared_prefix=False)
+    try:
+        before = svc.snapshot()
+        svc.generate_batch(prompts_varied(3), temperature=0.0, max_tokens=6)
+        after = svc.snapshot()
+    finally:
+        svc.close()
+    steps = after["decode_steps"] - before["decode_steps"]
+    slots = after["moe_expert_slots"] - before["moe_expert_slots"]
+    touched = after["moe_experts_touched"] - before["moe_experts_touched"]
+    assert steps > 0 and cfg.n_expert_layers > 0
+    assert slots == steps * cfg.n_expert_layers * cfg.moe.held
+    assert 0 < touched <= slots
+    if tiny == "lfm2_tiny":             # every expert held: every pick computed
+        assert after["moe_picks_held"] == after["moe_picks"] > 0
