@@ -216,7 +216,14 @@ class PagedSlotDecoder:
         #                                 most have been (the host's product)
         self.moe_prefill_load_max = 0   # busiest held expert's tokens, summed
         #                                 over prefills and expert layers
-        self.moe_prefill_load_mean = 0.0    # the mean expert's, likewise
+        # How the prefills' grouped expert product was sized, summed over
+        # prefills and expert layers: tile steps run (one read of an expert's
+        # weights each) over distinct experts touched = reads per touched
+        # expert; held picks over the rows the tiles covered = tile fill.
+        self.moe_prefill_tiles = 0
+        self.moe_prefill_tile_rows = 0
+        self.moe_prefill_experts_touched = 0
+        self.moe_prefill_picks_held = 0
         self.state_restores = 0         # snapshots copied into a slot's block
         # One page's bytes across every layer/tensor array; the pool's
         # total (pages, plus the slots' state blocks); and what the pool
@@ -244,13 +251,23 @@ class PagedSlotDecoder:
         self.moe_picks_zero += got.get("picks_zero", 0)
         if prefill:
             self.moe_prefill_load_max += got["load_max"]
-            self.moe_prefill_load_mean += got["picks_held"] / self.cfg.moe.held
+            self.moe_prefill_tiles += got["tiles"]
+            self.moe_prefill_tile_rows += got["tile_rows"]
+            self.moe_prefill_experts_touched += got["experts_touched"]
+            self.moe_prefill_picks_held += got["picks_held"]
         else:
             self.moe_experts_touched += got["experts_touched"]
             self.moe_expert_slots += (steps_run * self.cfg.n_expert_layers
                                       * self.cfg.moe.held)
 
     # -- stats surface --------------------------------------------------
+
+    @property
+    def moe_prefill_load_mean(self) -> float:
+        """The mean held expert's tokens, summed over prefills and expert
+        layers (beside ``moe_prefill_load_max``, the busiest one's)."""
+        held = self.moe_prefill_picks_held
+        return held / self.cfg.moe.held if held else 0.0
 
     @property
     def kv_pages(self) -> int:

@@ -743,7 +743,11 @@ class SlotServeService:
             # landed on an expert held here; distinct held experts read,
             # summed over decode steps and expert layers; the busiest and
             # the mean held expert's tokens, summed over prefills and expert
-            # layers; state snapshots copied into a slot on admission.
+            # layers, and how the prefills' grouped product was sized (tile
+            # steps, the rows they covered, distinct experts touched, held
+            # picks: tiles / touched = reads of an expert's weights per
+            # touched expert, picks / rows = tile fill), summed likewise;
+            # state snapshots copied into a slot on admission.
             "moe_picks": self._decoder.moe_picks,
             "moe_picks_held": self._decoder.moe_picks_held,
             "moe_picks_zero": self._decoder.moe_picks_zero,
@@ -752,6 +756,11 @@ class SlotServeService:
             "moe_prefill_load_max": self._decoder.moe_prefill_load_max,
             "moe_prefill_load_mean": round(
                 self._decoder.moe_prefill_load_mean, 4),
+            "moe_prefill_tiles": self._decoder.moe_prefill_tiles,
+            "moe_prefill_tile_rows": self._decoder.moe_prefill_tile_rows,
+            "moe_prefill_experts_touched":
+                self._decoder.moe_prefill_experts_touched,
+            "moe_prefill_picks_held": self._decoder.moe_prefill_picks_held,
             "state_restores": self._decoder.state_restores,
         }
 

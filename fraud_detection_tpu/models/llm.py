@@ -1296,7 +1296,8 @@ def _conv_mix(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
 
 # -- feed-forward kinds ------------------------------------------------------
 
-MOE_STATS = ("picks", "picks_held", "experts_touched", "load_max")
+MOE_STATS = ("picks", "picks_held", "experts_touched", "load_max", "tiles",
+             "tile_rows")
 
 
 def moe_stat_names(cfg: TransformerConfig) -> Tuple[str, ...]:
@@ -1359,45 +1360,145 @@ def _expert(w, e):
     return Q8(pick(w.q), pick(w.scale)) if isinstance(w, Q8) else pick(w)
 
 
+def moe_tile_rows(n_rows: int, top_k: int, n_router: int) -> int:
+    """Rows of one tile of the grouped expert product, from the load the
+    shapes give: a held expert's mean load is ``n_rows * top_k / n_router``
+    rows (``n_router``: every output the router scores). A tile reads its
+    expert's weights once whatever its rows, so up to 16 rows (a decode
+    step) take 16; a mean load under 60 takes 64, where most touched
+    experts fill one tile; from 60 on 128, where at 64 most experts would
+    be read twice (the break-even of the chip sweep, PERF.md, PR 36, which
+    also found that 256 does not pay at these widths: its products are
+    bound by the MXU and no longer by the read)."""
+    if n_rows <= 16:
+        return 16
+    return 128 if n_rows * top_k >= 60 * n_router else 64
+
+
+def moe_block_rows(n_rows: int, top_k: int, n_router: int, n_held: int,
+                   tile: int) -> int:
+    """Sorted picks one block step of ``moe_held_experts`` covers: twice the
+    held picks the shapes expect (``n_rows * top_k * n_held / n_router``) in
+    whole thousands of 1,024, and never more than every pick in whole tiles.
+    One step then covers the held picks of any prompt routed within twice
+    the expectation, and its buffer is sized by them, not by N x K."""
+    m = n_rows * top_k
+    expected = -(-2 * m * n_held // n_router)
+    return min(-(-m // tile) * tile, -(-expected // 1024) * 1024)
+
+
 def moe_held_experts(wg, wu, wd, xf: jax.Array, local: jax.Array,
-                     held: jax.Array, dtype):
-    """What the held experts give their picks: a grouped product over the
-    picks sorted by expert, one tile of rows and ONE expert's weights a
-    loop step, as many steps as the load needs (so no pick is dropped
-    whatever the imbalance, and an expert nobody chose is never read).
-    xf (N,D); local (N,K) expert index among the held; held (N,K) bool.
-    Returns (per-pick output (N,K,D), zero where not held; tokens per held
-    expert (E,) int32)."""
+                     held: jax.Array, w: jax.Array, dtype, n_router: int):
+    """What the held experts give their tokens: a grouped product over the
+    HELD picks sorted by expert, and each token's weighted sum of it. The
+    sorted picks are taken a block at a time (``moe_block_rows``; as many
+    steps as the held picks need, one for a prompt routed as the shapes
+    expect): the block's rows are gathered; a loop runs one tile of rows
+    (``moe_tile_rows``) and ONE expert's weights a step, as many steps as the
+    load needs (so no pick is dropped whatever the imbalance, and an expert
+    nobody chose is never read); and the block's results are added into the
+    float32 sum by token, one gather a rank of a token's held picks (as many
+    as the token with the most has). Nothing here has a row for a pick that
+    is not held, unless a block holds every pick. A decode step's rows
+    (N <= 16: one block of every pick) are summed over (N, K, D) whole, the
+    cheaper form for a handful of rows. Each pick's output is rounded to
+    ``dtype``, weighted and summed in float32.
+    xf (N,D); local (N,K) expert index among the held; held (N,K) bool;
+    w (N,K) float32. Returns (the sum (N,D) float32; tokens per held expert
+    (E,) int32; tile steps run, int32)."""
     N, K = local.shape
     E, D = (wg.q if isinstance(wg, Q8) else wg).shape[0], xf.shape[1]
     M = N * K
-    tile = 16 if N <= 16 else 64
+    tile = moe_tile_rows(N, K, n_router)
+    block = moe_block_rows(N, K, n_router, E, tile)
+    n_blocks = -(-M // block)               # what every pick held would need
     flat = jnp.where(held, local, E).reshape(M)       # not held sorts last
     order = jnp.argsort(flat, stable=True)
     counts = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
     tiles = (counts + tile - 1) // tile
     tiles_before = jnp.cumsum(tiles) - tiles
     rows_before = jnp.cumsum(counts) - counts
-    xs = jnp.pad(xf[order // K], ((0, tile), (0, 0)))
+    token = jnp.pad((order // K).astype(jnp.int32),
+                    (0, n_blocks * block + tile - M))
+    at = jnp.zeros((M,), jnp.int32).at[order].set(      # where a pick sorted to
+        jnp.arange(M, dtype=jnp.int32)).reshape(N, K)
 
-    def body(j, out):
-        e = jnp.sum(tiles_before <= j) - 1
-        r = j - tiles_before[e]
-        at = rows_before[e] + r * tile
-        rows = jax.lax.dynamic_slice_in_dim(xs, at, tile, 0)
-        hid = jax.nn.silu(_mm("nD,DF->nF", rows, _expert(wg, e), dtype)) \
-            * _mm("nD,DF->nF", rows, _expert(wu, e), dtype)
-        res = _mm("nF,FD->nD", hid, _expert(wd, e), dtype)
-        # rows past this expert's own are the next one's: written as zeros
-        # here and over again by the tile that owns them
-        res = jnp.where(jnp.arange(tile)[:, None] < counts[e] - r * tile,
-                        res, 0)
-        return jax.lax.dynamic_update_slice_in_dim(out, res, at, 0)
+    def block_outputs(base, first, steps):
+        """The outputs of the tiles ``first .. first + steps - 1``, which
+        start in the block at sorted row ``base``: (block + tile, D), zero
+        wherever none of them wrote."""
+        xs = xf[jax.lax.dynamic_slice_in_dim(token, base, block + tile)]
 
-    out = jax.lax.fori_loop(0, jnp.sum(tiles), body,
-                            jnp.zeros((M + tile, D), dtype))
-    back = jnp.zeros((M,), jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
-    return out[back].reshape(N, K, D), counts
+        def one_tile(i, out):
+            j = first + i
+            e = jnp.sum(tiles_before <= j) - 1
+            r = j - tiles_before[e]
+            start = rows_before[e] + r * tile - base
+            rows = jax.lax.dynamic_slice_in_dim(xs, start, tile, 0)
+            hid = jax.nn.silu(_mm("nD,DF->nF", rows, _expert(wg, e), dtype)) \
+                * _mm("nD,DF->nF", rows, _expert(wu, e), dtype)
+            res = _mm("nF,FD->nD", hid, _expert(wd, e), dtype)
+            # rows past this expert's own are the next one's: written as
+            # zeros here and over again by the tile that owns them
+            res = jnp.where(jnp.arange(tile)[:, None] < counts[e] - r * tile,
+                            res, 0)
+            return jax.lax.dynamic_update_slice_in_dim(out, res, start, 0)
+
+        # counted from 0: a loop that starts at a traced step runs each of
+        # its steps ~1.5 us slower on the chip (PERF.md, PR 36)
+        return jax.lax.fori_loop(0, steps, one_tile,
+                                 jnp.zeros((block + tile, D), dtype))
+
+    n_tiles = jnp.sum(tiles)
+    if N <= 16:
+        per_pick = block_outputs(0, 0, n_tiles)[at].astype(_F32)
+        return jnp.sum(per_pick * jnp.where(held, w, 0.0)[..., None], axis=1), \
+            counts, n_tiles
+
+    # by rank among its token's held picks (K, N): the row a pick sorted to,
+    # its weight, and the block its tile starts in, -1 where the token has no
+    # such pick (a tile may run past its block's end: its rows' results are
+    # in that block's buffer)
+    nth = jnp.cumsum(held.astype(jnp.int32), axis=1)
+    pick = held & (nth == jnp.arange(1, K + 1)[:, None, None])     # (K, N, K)
+    row_of = jnp.sum(jnp.where(pick, at, 0), axis=-1)
+    w_of = jnp.sum(jnp.where(pick, w, 0.0), axis=-1)
+    if n_blocks == 1:
+        home = jnp.zeros((N, K), jnp.int32)
+    else:
+        first_row = rows_before[jnp.where(held, local, 0)]
+        home = (first_row + (at - first_row) // tile * tile) // block
+    home_of = jnp.sum(jnp.where(pick, home + 1, 0), axis=-1) - 1
+
+    def tiles_started_before(row):
+        return jnp.sum(jnp.clip((row - rows_before + tile - 1) // tile, 0, tiles))
+
+    def one_block(b, acc):
+        base = b * block
+        if n_blocks == 1:               # the one block starts every tile
+            first, steps = 0, n_tiles
+        else:
+            first = tiles_started_before(base)
+            steps = tiles_started_before(base + block) - first
+        out = block_outputs(base, first, steps)
+
+        def one_rank(r, acc):
+            here = jax.lax.dynamic_index_in_dim(home_of, r, 0, False) == b
+            row = jax.lax.dynamic_index_in_dim(row_of, r, 0, False) - base
+            wr = jax.lax.dynamic_index_in_dim(w_of, r, 0, False)
+            return acc + jnp.where(
+                here[:, None],
+                out[jnp.where(here, row, 0)].astype(_F32) * wr[:, None], 0.0)
+
+        return jax.lax.fori_loop(0, jnp.max(nth[:, -1]), one_rank, acc)
+
+    acc = jnp.zeros((N, D), _F32)
+    if n_blocks == 1:
+        acc = one_block(0, acc)
+    else:
+        acc = jax.lax.fori_loop(0, (jnp.sum(counts) + block - 1) // block,
+                                one_block, acc)
+    return acc, counts, n_tiles
 
 
 def _expert_branch(params: Params, cfg: TransformerConfig, l: int,
@@ -1410,8 +1511,10 @@ def _expert_branch(params: Params, cfg: TransformerConfig, l: int,
     ``live`` (B,T) marks the tokens whose picks count (padding and idle rows
     are routed nowhere, so they read no expert). Returns ((B,T,D), stats):
     picks made, picks on held experts, distinct held experts touched, the
-    busiest held expert's tokens, and with zero-compute outputs the picks
-    on them (``moe_stat_names``) — int32 scalars."""
+    busiest held expert's tokens, the tile steps the grouped product ran
+    (one read of an expert's weights each) and the rows they covered, and
+    with zero-compute outputs the picks on them (``moe_stat_names``) —
+    int32 scalars."""
     m = cfg.moe
     B, T, D = h2.shape
     p = f"l{l}.moe_"
@@ -1423,11 +1526,9 @@ def _expert_branch(params: Params, cfg: TransformerConfig, l: int,
         if live is not None:
             held &= live.reshape(B * T, 1)
     with jax.named_scope("moe.experts"):
-        per_pick, counts = moe_held_experts(
+        routed, counts, tiles = moe_held_experts(
             params[p + "wg"], params[p + "wu"], params[p + "wd"], h2,
-            local, held, cfg.dtype)
-        routed = jnp.sum(per_pick.astype(_F32) * jnp.where(held, w, 0.0)[..., None],
-                         axis=1)
+            local, held, w, cfg.dtype, m.n_router)
         if not m.n_zero:        # else the zero-compute part joins in float32
             routed = routed.astype(cfg.dtype).reshape(B, T, D)
     n_live = (jnp.int32(B * T) if live is None
@@ -1435,7 +1536,9 @@ def _expert_branch(params: Params, cfg: TransformerConfig, l: int,
     stats = {"picks": n_live * m.top_k,
              "picks_held": jnp.sum(held.astype(jnp.int32)),
              "experts_touched": jnp.sum((counts > 0).astype(jnp.int32)),
-             "load_max": jnp.max(counts)}
+             "load_max": jnp.max(counts),
+             "tiles": tiles,
+             "tile_rows": tiles * moe_tile_rows(B * T, m.top_k, m.n_router)}
     if m.n_zero:
         with jax.named_scope("moe.zero"):
             zero = idx >= m.n_experts

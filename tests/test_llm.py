@@ -803,3 +803,201 @@ def test_hybrid_param_shardings_name_every_leaf():
     assert placed["l2.moe_wg"].sharding.spec == P(None, None, MODEL_AXIS)
     assert placed["l5.mla_wkva"].sharding.spec == P()
     assert placed["l2.kda_wq"].sharding.spec == P(None, MODEL_AXIS, None)
+
+
+# ---------------------------------------------------------------------------
+# the grouped expert product, sized by its load (ISSUE 36): moe_held_experts
+# (tiles over the held picks, each token's float32 sum) against a plain
+# reference; the tile rule at the published shapes; no (N x K, D) tensor
+# ---------------------------------------------------------------------------
+
+def _plain_experts(wg, wu, wd, xf, local, held, w, dtype):
+    """Every held expert applied to EVERY token, the picks it did not get
+    masked out; each expert's output rounded to ``dtype`` as the program
+    rounds it, weighted and summed in float32."""
+    def matrix(stack, e):
+        if isinstance(stack, llm.Q8):
+            return stack.q[e].astype(jnp.float32) * stack.scale[e]
+        return stack[e].astype(jnp.float32)
+
+    x = xf.astype(jnp.float32)
+    total = jnp.zeros(xf.shape, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        for e in range((wg.q if isinstance(wg, llm.Q8) else wg).shape[0]):
+            hid = (jax.nn.silu((x @ matrix(wg, e)).astype(dtype))
+                   * (x @ matrix(wu, e)).astype(dtype))
+            out = (hid.astype(jnp.float32) @ matrix(wd, e)).astype(dtype)
+            w_e = jnp.sum(jnp.where(held & (local == e), w, 0.0), axis=1)
+            total = total + out.astype(jnp.float32) * w_e[:, None]
+    return total
+
+
+def _all_held(rng, n, k, e, r):
+    return np.stack([rng.permutation(e)[:k] for _ in range(n)])
+
+
+def _over_the_router(rng, n, k, e, r):
+    return np.stack([rng.permutation(r)[:k] for _ in range(n)])
+
+
+def _one_expert_past_two_tiles(rng, n, k, e, r):
+    """Every token's first pick is expert 1; expert 3 is nobody's."""
+    others = np.asarray([0, 2] + list(range(e, r)))
+    return np.stack([np.concatenate([[1], rng.permutation(others)[:k - 1]])
+                     for _ in range(n)])
+
+
+def _nothing_held(rng, n, k, e, r):
+    return np.stack([e + rng.permutation(r - e)[:k] for _ in range(n)])
+
+
+# name: (N, K, held E, router outputs R, picks, dtype, int8 stacks, live rows)
+HELD_EXPERT_CASES = {
+    "all-held-n16": (16, 4, 8, 8, _all_held, "float32", False, None),
+    "all-held-n40": (40, 4, 8, 8, _all_held, "float32", False, None),
+    "all-held-n300-tile128": (300, 4, 8, 8, _all_held, "float32", False, None),
+    "quarter-held-n16": (16, 4, 4, 16, _over_the_router, "float32", False, None),
+    "quarter-held-n40": (40, 4, 4, 16, _over_the_router, "float32", False, None),
+    "quarter-held-n300": (300, 4, 4, 16, _over_the_router, "float32", False, None),
+    # 64 routed experts of which 2 are held, then 32 zero-compute outputs
+    "one-in-48-n16": (16, 12, 2, 96, _over_the_router, "float32", False, None),
+    "one-in-48-n40": (40, 12, 2, 96, _over_the_router, "float32", False, None),
+    "one-in-48-n300": (300, 12, 2, 96, _over_the_router, "float32", False, None),
+    "one-expert-past-two-tiles": (300, 4, 4, 64, _one_expert_past_two_tiles,
+                                  "float32", False, None),
+    "nothing-held": (40, 4, 4, 16, _nothing_held, "float32", False, None),
+    # every pick held where the shapes expect an eighth: several blocks
+    "eight-times-the-expected-load": (300, 12, 12, 96, _all_held, "float32",
+                                      False, None),
+    "padding-rows-not-live": (40, 4, 4, 16, _over_the_router, "float32", False, 29),
+    "int8-stacks": (40, 4, 8, 8, _all_held, "float32", True, None),
+    "int8-stacks-quarter-held-n300": (300, 4, 4, 16, _over_the_router,
+                                      "float32", True, None),
+    "bfloat16-all-held-n300": (300, 4, 8, 8, _all_held, "bfloat16", False, None),
+    "bfloat16-one-in-48-n300": (300, 12, 2, 96, _over_the_router, "bfloat16",
+                                False, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HELD_EXPERT_CASES))
+def test_held_experts_match_every_expert_on_every_token(case):
+    """Output within the dtype's tolerance of the plain reference (values of
+    scale ~1: float32 2e-5, the order of a token's float32 additions;
+    bfloat16 0.03, a product's accumulation order before its rounding),
+    ``counts`` the held picks an expert got, and the tile steps
+    ``sum(ceil(counts / tile))``: none for an expert nobody chose."""
+    N, K, E, R, picks, dtype, int8, live_rows = HELD_EXPERT_CASES[case]
+    D, F = 32, 16
+    dtype = jnp.dtype(dtype).type
+    rng = np.random.default_rng(sorted(HELD_EXPERT_CASES).index(case))
+    stacks = {name: jnp.asarray(rng.standard_normal(shape) / math.sqrt(shape[1]),
+                                dtype)
+              for name, shape in (("moe_wg", (E, D, F)), ("moe_wu", (E, D, F)),
+                                  ("moe_wd", (E, F, D)))}
+    if int8:
+        stacks = llm.quantize_params(stacks)
+    wg, wu, wd = stacks["moe_wg"], stacks["moe_wu"], stacks["moe_wd"]
+    xf = jnp.asarray(rng.standard_normal((N, D)), dtype)
+    idx = jnp.asarray(picks(rng, N, K, E, R), jnp.int32)
+    w = jnp.asarray(rng.uniform(0.05, 1.0, (N, K)), jnp.float32)
+    held = idx < E
+    if live_rows is not None:
+        held &= (jnp.arange(N) < live_rows)[:, None]
+    got, counts, tiles = jax.jit(
+        lambda *a: llm.moe_held_experts(*a, dtype, R))(wg, wu, wd, xf, idx, held, w)
+    assert got.dtype == jnp.float32 and got.shape == (N, D)
+    want = _plain_experts(wg, wu, wd, xf, idx, held, w, dtype)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5 if dtype == jnp.float32 else 0.03)
+    load = np.bincount(np.asarray(idx)[np.asarray(held)], minlength=E)
+    assert np.asarray(counts).tolist() == load.tolist()
+    tile = llm.moe_tile_rows(N, K, R)
+    assert int(tiles) == int(np.sum(-(-load // tile)))
+    held_np = np.asarray(held)
+    if case == "nothing-held":
+        assert int(tiles) == 0 and not np.asarray(got).any()
+    else:
+        assert np.abs(np.asarray(got)).max() > 0.05
+    if case == "one-expert-past-two-tiles":
+        assert load[1] == N > 2 * tile and load[3] == 0 < load[0]
+    if case.startswith(("quarter-held", "one-in-48")):
+        assert (~held_np.any(axis=1)).any()     # a token with no held pick ...
+        assert not np.asarray(got)[~held_np.any(axis=1)].any()   # ... gets 0
+    if case == "eight-times-the-expected-load":
+        assert held_np.sum() > 2 * llm.moe_block_rows(N, K, R, E, tile)
+    if live_rows is not None:
+        assert not np.asarray(got)[live_rows:].any()
+
+
+def _published_routing(name):
+    """(top_k, router outputs) of a benchmark configuration as published,
+    and the prompt width it is served at."""
+    import json, os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    if cfg["model_type"] == "longcat_flash":
+        top_k = cfg["moe_topk"]
+        router = cfg["published"]["n_routed_experts"] + cfg["zero_expert_num"]
+    else:
+        top_k = cfg["num_experts_per_tok"]
+        router = cfg.get("published", {}).get("num_experts", cfg["num_experts"])
+    return top_k, router, cfg["desk"]["explain"]["prompt_width"]
+
+
+@pytest.mark.parametrize("name,routing,bucket_tile", [
+    ("desk-lr-ling-3.0-flash", (8, 512), 64),
+    ("desk-lr-longcat-flash-chat", (12, 768), 64),
+    ("desk-lr-lfm2-24b-a2b", (4, 64), 128),
+])
+def test_tile_rows_at_the_published_shapes(name, routing, bucket_tile):
+    """The tile is a function of the bucket's rows, ``top_k`` and the
+    router's width alone: 16 for a decode step's rows; 64 where a held
+    expert's mean load is a fraction of 64 (the hybrid's and LongCat's
+    17-27 rows at the prompts' buckets, every 320-token preamble's 20);
+    128 for LFM2's 66-107."""
+    top_k, router, width = _published_routing(name)
+    assert (top_k, router) == routing and width == 2048
+    for rows in (1, 2, 16):
+        assert llm.moe_tile_rows(rows, top_k, router) == 16
+    assert llm.moe_tile_rows(320, top_k, router) == 64
+    for rows in (1088, 1728):
+        assert llm.moe_tile_rows(rows, top_k, router) == bucket_tile
+    # a block step covers twice the held picks the shapes expect, and for no
+    # configuration every pick of a bucket unless every pick is held
+    held = {"desk-lr-ling-3.0-flash": 128, "desk-lr-longcat-flash-chat": 16,
+            "desk-lr-lfm2-24b-a2b": 64}[name]
+    for rows in (1088, 1728):
+        block = llm.moe_block_rows(rows, top_k, router, held, bucket_tile)
+        assert 2 * rows * top_k * held / router <= block or block >= rows * top_k
+        assert (block < rows * top_k) == (held < router)
+
+
+def test_expert_branch_lowers_without_a_row_for_every_pick():
+    """At a one-in-48-held shape the lowered ``_expert_branch`` holds no
+    tensor of N x K (or more) rows by D, whatever its type: the gather of
+    every pick, the un-sort of every pick's output and the float32 product
+    over (N, K, D) are not in the program."""
+    import re
+
+    N, K, D = 300, 12, 40
+    cfg = TransformerConfig(
+        vocab_size=300, d_model=D, n_heads=2, n_layers=1, d_ff=64, max_seq=512,
+        layer_kinds=(("attention", "experts"),),
+        moe=llm.MoEConfig(n_experts=64, n_zero=32, top_k=K, n_group=1,
+                          topk_group=1, d_expert=24, d_shared=0,
+                          score="softmax", norm_topk=False, held_start=0,
+                          held_count=2))
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    h2 = jax.ShapeDtypeStruct((1, N, D), cfg.dtype)
+    live = jax.ShapeDtypeStruct((1, N), jnp.bool_)
+    text = jax.jit(lambda p, h, lv: llm._expert_branch(p, cfg, 0, h, lv)
+                   ).lower(params, h2, live).as_text()
+    shapes = {tuple(int(d) for d in m.group(1).split("x"))
+              for m in re.finditer(r"tensor<(\d+(?:x\d+)*)x[a-z]+\d+>", text)}
+    assert (N, D) in {s[-2:] for s in shapes if len(s) >= 2}    # the parse works
+    wide = [s for s in shapes if len(s) >= 2 and s[-1] == D
+            and math.prod(s[:-1]) >= N * K]
+    assert not wide, wide
+    assert (N, K) in shapes and D not in (N, K)

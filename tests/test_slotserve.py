@@ -568,6 +568,11 @@ SLOTSERVE_BLOCK_SCHEMA = {
     "moe_expert_slots": (int,),     # ISSUE 35: steps x expert layers x held
     "moe_prefill_load_max": (int,),
     "moe_prefill_load_mean": (int, float),
+    # ISSUE 36: how the prefills' grouped expert product was sized
+    "moe_prefill_tiles": (int,),
+    "moe_prefill_tile_rows": (int,),
+    "moe_prefill_experts_touched": (int,),
+    "moe_prefill_picks_held": (int,),
     "state_restores": (int,),
 }
 
@@ -831,6 +836,11 @@ def test_hybrid_snapshot_counts_routing_and_restores(hybrid_runs,
     # a decode step touches at most the 4 held experts of each of 6 layers
     assert 0 < snap["moe_experts_touched"] <= 24 * snap["decode_steps"]
     assert snap["moe_prefill_load_max"] >= snap["moe_prefill_load_mean"] > 0
+    # a touched expert is read at least once, a tile covers at least its picks
+    assert snap["moe_prefill_tiles"] >= snap["moe_prefill_experts_touched"] > 0
+    assert snap["moe_prefill_tile_rows"] >= snap["moe_prefill_picks_held"] > 0
+    assert snap["moe_prefill_tile_rows"] % 16 == 0
+    assert snap["moe_prefill_picks_held"] < snap["moe_picks_held"]   # + decode's
     # every admission copies the state its prefill starts from into the
     # slot's block: the preamble's snapshot where the prompt shares it, zeros
     # for a whole prompt (and once more for the warm-up's row)
@@ -847,8 +857,11 @@ def test_dense_snapshot_counters_stay_zero(lm):
         assert [snap[k] for k in ("moe_picks", "moe_picks_held",
                                   "moe_picks_zero", "moe_experts_touched",
                                   "moe_expert_slots", "moe_prefill_load_max",
-                                  "moe_prefill_load_mean", "state_restores")] \
-            == [0, 0, 0, 0, 0, 0, 0, 0]
+                                  "moe_prefill_load_mean", "moe_prefill_tiles",
+                                  "moe_prefill_tile_rows",
+                                  "moe_prefill_experts_touched",
+                                  "moe_prefill_picks_held", "state_restores")] \
+            == [0] * 12
     finally:
         svc.close()
 
