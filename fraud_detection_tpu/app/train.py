@@ -21,7 +21,6 @@ import argparse
 import json
 import os
 import sys
-import time
 from typing import Dict, List, Tuple
 
 import numpy as np
@@ -150,6 +149,7 @@ def main(argv=None) -> int:
     from fraud_detection_tpu.models.train_linear import fit_logistic_regression
     from fraud_detection_tpu.models.train_trees import (
         TreeTrainConfig, fit_decision_tree, fit_gradient_boosting, fit_random_forest)
+    from fraud_detection_tpu.obs.trace import STAGE_SETUP_TRAIN, setup_span
 
     chosen = [m.strip() for m in args.models.split(",") if m.strip()]
     save_pairs = []
@@ -200,27 +200,28 @@ def main(argv=None) -> int:
     trained = {}
     timings: Dict[str, float] = {}
     for name in chosen:
-        t0 = time.perf_counter()
-        if name == "dt":
-            trained[name] = fit_decision_tree(Xtr, ytr, config=cfg, mesh=mesh)
-        elif name == "rf":
-            trained[name] = fit_random_forest(
-                Xtr, ytr, n_trees=args.n_trees, seed=args.seed, config=cfg, mesh=mesh,
-                tree_chunk=args.tree_chunk,
-                checkpoint_dir=_ckpt_subdir(args, name),
-                checkpoint_every=args.checkpoint_every)
-        elif name == "xgb":
-            trained[name] = fit_gradient_boosting(
-                Xtr, ytr, n_rounds=args.n_rounds, mesh=mesh,
-                config=TreeTrainConfig(max_depth=args.max_depth, criterion="xgb"),
-                checkpoint_dir=_ckpt_subdir(args, name),
-                checkpoint_every=args.checkpoint_every)
-        elif name == "lr":
-            trained[name] = fit_logistic_regression(
-                Xtr, ytr.astype(np.float32), mesh=mesh)
-        else:
-            raise SystemExit(f"unknown model {name!r} (choose from dt,rf,xgb,lr)")
-        timings[name] = round(time.perf_counter() - t0, 3)
+        with setup_span(STAGE_SETUP_TRAIN,
+                        detail=f"family={name} rows={len(ytr)}") as fit:
+            if name == "dt":
+                trained[name] = fit_decision_tree(Xtr, ytr, config=cfg, mesh=mesh)
+            elif name == "rf":
+                trained[name] = fit_random_forest(
+                    Xtr, ytr, n_trees=args.n_trees, seed=args.seed, config=cfg, mesh=mesh,
+                    tree_chunk=args.tree_chunk,
+                    checkpoint_dir=_ckpt_subdir(args, name),
+                    checkpoint_every=args.checkpoint_every)
+            elif name == "xgb":
+                trained[name] = fit_gradient_boosting(
+                    Xtr, ytr, n_rounds=args.n_rounds, mesh=mesh,
+                    config=TreeTrainConfig(max_depth=args.max_depth, criterion="xgb"),
+                    checkpoint_dir=_ckpt_subdir(args, name),
+                    checkpoint_every=args.checkpoint_every)
+            elif name == "lr":
+                trained[name] = fit_logistic_regression(
+                    Xtr, ytr.astype(np.float32), mesh=mesh)
+            else:
+                raise SystemExit(f"unknown model {name!r} (choose from dt,rf,xgb,lr)")
+        timings[name] = round(fit.seconds, 3)
         print(f"trained {name} in {timings[name]:.2f}s")
 
     def scores(model, X):

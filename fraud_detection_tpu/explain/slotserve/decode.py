@@ -295,8 +295,9 @@ class PagedSlotDecoder:
 
     # -- prefix caching --------------------------------------------------
 
-    def set_prefix(self, prefix_text: str) -> None:
-        """Prefill the shared preamble ONCE into read-only pages.
+    def set_prefix(self, prefix_text: str, span: Callable = _no_span) -> None:
+        """Prefill the shared preamble ONCE into read-only pages (under
+        ``span("setup_preamble")``: the prefill and the state it leaves).
 
         The byte tokenizer is concatenation-safe (``encode(a + b)`` =
         ``[BOS] + bytes(a) + bytes(b)``), so a prompt shares the prefix
@@ -331,11 +332,12 @@ class PagedSlotDecoder:
         # preamble's last holds only padding and goes back at once.
         pids = [self.allocator.alloc()
                 for _ in range(-(-wp // self.page_size))]
-        _, self.pages, state, _ = llm.paged_slot_prefill(
-            self.lm.params, jnp.asarray(padded), jnp.int32(lp), self.cfg,
-            self.pages, jnp.asarray(pids, jnp.int32), jnp.float32(0.0),
-            jax.random.PRNGKey(0), 0, self._zero_state,
-            jnp.int32(0) if self.state else None)
+        with span("setup_preamble"):
+            _, self.pages, state, _ = llm.paged_slot_prefill(
+                self.lm.params, jnp.asarray(padded), jnp.int32(lp), self.cfg,
+                self.pages, jnp.asarray(pids, jnp.int32), jnp.float32(0.0),
+                jax.random.PRNGKey(0), 0, self._zero_state,
+                jnp.int32(0) if self.state else None)
         for pid in pids[n_prefix:]:
             self.allocator.release(pid)
         # ... and, beside the pages, every recurrent layer's state as the

@@ -61,6 +61,8 @@ from fraud_detection_tpu.explain.backends import (BackendError, ChatMessage,
                                                   frame_prompt)
 from fraud_detection_tpu.explain.onpod import flatten_chat
 from fraud_detection_tpu.explain.slotserve.decode import PagedSlotDecoder
+from fraud_detection_tpu.obs.trace import (STAGE_SETUP_SERVICE,
+                                           STAGE_SETUP_WARM, setup_span)
 from fraud_detection_tpu.sched.sketch import LatencySketch
 from fraud_detection_tpu.utils import get_logger
 
@@ -187,27 +189,35 @@ class SlotServeService:
         if not paged:
             raise ValueError("the slot lane has one pool, and it is paged: "
                              "drop paged=False")
-        self._decoder = PagedSlotDecoder(lm, slots,
-                                         prompt_width=prompt_width,
-                                         max_new_tokens=max_new_tokens,
-                                         prompt_bucket=prompt_bucket,
-                                         page_size=page_size,
-                                         total_pages=kv_pages)
-        if shared_prefix:
-            prefix = shared_explain_prefix()
-            lp = len(lm.tokenizer.encode(prefix))
-            n_prefix = -(-lp // self._decoder.page_size)
-            fits = (lp < self._decoder.prompt_width
-                    and self._decoder.total_pages
-                    >= self._decoder.n_view + n_prefix)
-            if fits:
-                self._decoder.set_prefix(prefix)
-            else:
-                log.warning(
-                    "shared explain prefix (%d tokens, %d pages) does not "
-                    "fit prompt_width %d / pool %d; serving WITHOUT prefix "
-                    "sharing", lp, n_prefix, self._decoder.prompt_width,
-                    self._decoder.total_pages)
+        with setup_span(STAGE_SETUP_SERVICE) as phase:
+            self._decoder = PagedSlotDecoder(lm, slots,
+                                             prompt_width=prompt_width,
+                                             max_new_tokens=max_new_tokens,
+                                             prompt_bucket=prompt_bucket,
+                                             page_size=page_size,
+                                             total_pages=kv_pages)
+            phase.detail = f"slots={slots} pages={self._decoder.total_pages}"
+            if shared_prefix:
+                prefix = shared_explain_prefix()
+                lp = len(lm.tokenizer.encode(prefix))
+                n_prefix = -(-lp // self._decoder.page_size)
+                fits = (lp < self._decoder.prompt_width
+                        and self._decoder.total_pages
+                        >= self._decoder.n_view + n_prefix)
+                if fits:
+                    self._decoder.set_prefix(prefix, span=lambda stage: (
+                        setup_span(stage, detail=f"tokens={lp}")))
+                else:
+                    log.warning(
+                        "shared explain prefix (%d tokens, %d pages) does "
+                        "not fit prompt_width %d / pool %d; serving WITHOUT "
+                        "prefix sharing", lp, n_prefix,
+                        self._decoder.prompt_width,
+                        self._decoder.total_pages)
+            if warm:
+                with setup_span(STAGE_SETUP_WARM,
+                                detail=f"steps={decode_window}"):
+                    self._decoder.warm(decode_window)
         import numpy as np
 
         self.slots = slots
@@ -259,8 +269,6 @@ class SlotServeService:
         self._started_at: Optional[float] = None
         self._lat = LatencySketch()         # submit -> complete (sec)
         self._first = LatencySketch()       # submit -> first token (sec)
-        if warm:
-            self._decoder.warm(decode_window)
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="slotserve-lane")
         self._thread.start()

@@ -312,9 +312,13 @@ class ServingPipeline:
                         mesh=None) -> "ServingPipeline":
         """Load a native checkpoint directory (checkpoint/native.py layout)."""
         from fraud_detection_tpu.checkpoint.native import load_checkpoint
+        from fraud_detection_tpu.obs.trace import (STAGE_SETUP_PIPELINE,
+                                                   setup_span)
 
-        featurizer, model = load_checkpoint(path)
-        return cls(featurizer, model, batch_size=batch_size, mesh=mesh)
+        with setup_span(STAGE_SETUP_PIPELINE) as span:
+            featurizer, model = load_checkpoint(path)
+            span.detail = f"family={type(model).__name__}"
+            return cls(featurizer, model, batch_size=batch_size, mesh=mesh)
 
     @classmethod
     def from_spark_artifact(cls, artifact: SparkPipelineArtifact,

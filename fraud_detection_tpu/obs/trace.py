@@ -46,6 +46,16 @@ module adds the missing attribution layer, Dapper-style but sized for a
   (bounded memory, lossless merge), independent of sampling — the fleet
   aggregation and the bench's ``stages`` attribution block read these, so
   p50/p99 per stage covers ALL batches, not the sampled subset.
+* **Set-up is on the same chain.** Before any tracer exists the process
+  trains, loads weights and obtains its executables; those spans (the five
+  ``setup_*`` phases and one ``compile`` per executable, fed from
+  ``jax.monitoring`` by ``utils/jax_cache.py``) go to a small process-wide
+  **boot log** (``BOOT``), bounded and never blocking. A :class:`RowTracer`
+  starts its ring with the boot log's spans, true ``start``s and all, and
+  every live tracer receives each later one (which the log keeps too, for
+  a tracer built later still) — a ``compile`` span that begins after
+  ``StreamingClassifier.run()`` first polled is a request that paid for a
+  program (``health()["compile"]["compiles_since_serving"]``).
 
 Thread model: a batch's trace is owned by whichever thread is driving that
 batch leg (engine driver, dispatch lane, annotation lane) — legs hand off
@@ -59,6 +69,7 @@ from __future__ import annotations
 import random
 import threading
 import time
+import weakref
 from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 from fraud_detection_tpu.sched.sketch import LatencySketch
@@ -88,6 +99,16 @@ STAGE_SLOT_LAUNCH = "slot_launch"  # arguments placed, program enqueued
 STAGE_SLOT_FETCH = "slot_fetch"    # blocked until the tokens are on the host
 STAGE_SLOT_EMIT = "slot_emit"      # host replay of the window's tokens
 STAGE_SLOT_RETIRE = "slot_retire"  # finished rows resolved, slots freed
+# Set-up (cid ``setup``), where the work happens; and one ``compile`` per
+# executable the process obtains (cid ``compile-<n>``, detail ``fn= hit=
+# fetch_ms=``), recorded after the fact from JAX's own monitoring events:
+# ``duration`` is the whole time to obtain it, loaded or built.
+STAGE_SETUP_TRAIN = "setup_train"        # one classifier's fit (app/train.py)
+STAGE_SETUP_PIPELINE = "setup_pipeline"  # ServingPipeline.from_checkpoint
+STAGE_SETUP_SERVICE = "setup_service"    # SlotServeService.__init__, whole
+STAGE_SETUP_PREAMBLE = "setup_preamble"  # inside it: the preamble's prefill
+STAGE_SETUP_WARM = "setup_warm"          # inside it: PagedSlotDecoder.warm
+STAGE_COMPILE = "compile"
 EVENT_SHED = "shed"          # row diverted by admission control
 EVENT_DLQ = "dlq"            # row dead-lettered (malformed/poison)
 EVENT_FLAG = "flag"          # row classified non-benign
@@ -302,11 +323,13 @@ def _annotation(stage: str, cid: str):
 class _SpanCtx:
     """A span open as a context manager: true start, exception-safe end,
     and a ``fraud/<stage>`` annotation in the profiler's trace while it is
-    open. ``sink`` is the batch-local buffer's ``append`` (a batch leg) or
-    the ring's (a leg after the batch's terminal)."""
+    open. ``sink`` is the batch-local buffer's ``append`` (a batch leg),
+    the ring's (a leg after the batch's terminal) or the boot log's (a
+    phase of set-up). ``detail`` may be set while the span is open;
+    ``seconds`` is its duration once it has closed."""
 
-    __slots__ = ("tracer", "sink", "cid", "stage", "detail", "_t0", "_w0",
-                 "_ann")
+    __slots__ = ("tracer", "sink", "cid", "stage", "detail", "seconds",
+                 "_t0", "_w0", "_ann")
 
     def __init__(self, tracer: "RowTracer", sink, cid: str, stage: str,
                  detail: Optional[str]):
@@ -324,7 +347,7 @@ class _SpanCtx:
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dt = time.perf_counter() - self._t0
+        dt = self.seconds = time.perf_counter() - self._t0
         self._ann.__exit__(exc_type, exc, tb)
         t = self.tracer
         t._count_begin_end()
@@ -335,6 +358,97 @@ class _SpanCtx:
                        self.detail if exc_type is None else exc_type.__name__))
         t._observe_stage(self.stage, dt)
         return False
+
+
+BOOT_CAPACITY = 2048    # spans the boot log keeps; the rest are counted
+
+COMPILE_COUNTERS = ("compile_requests", "compile_cache_hits",
+                    "compile_obtain_s", "compile_fetch_s", "trace_s",
+                    "lower_s", "compiles_since_serving")
+
+
+class _BootLog:
+    """The process's record of its own set-up (module docstring): every
+    ``setup_*`` and ``compile`` span up to its bound, the live tracers that
+    are also handed each one as it ends, and the compile counters. One
+    lock, taken once a span — a handful a process but for ``compile``,
+    which JAX's compile dwarfs. To :class:`_SpanCtx` it stands where a
+    tracer stands: the tracer that adopts a span does the counting."""
+
+    def __init__(self, capacity: int = BOOT_CAPACITY):
+        self.capacity = capacity
+        self.spans: List[Span] = []
+        self.dropped = 0
+        self.serving = False    # StreamingClassifier.run() has polled
+        self.counters: Dict[str, float] = dict.fromkeys(COMPILE_COUNTERS, 0)
+        self._tracers: "weakref.WeakSet[RowTracer]" = weakref.WeakSet()
+        self._lock = threading.Lock()
+
+    _wall = staticmethod(time.time)
+
+    def _count_begin_end(self) -> None:
+        pass
+
+    def _observe_stage(self, stage: str, duration_sec: float) -> None:
+        pass
+
+    def _route(self, span: Span) -> None:
+        """To every live tracer, and to the log for those built later
+        (lock held)."""
+        for t in list(self._tracers):
+            t._adopt((span,))
+        if len(self.spans) < self.capacity:
+            self.spans.append(span)
+        else:
+            self.dropped += 1
+
+    def record(self, span: Span) -> None:
+        with self._lock:
+            self._route(span)
+
+    def attach(self, tracer: "RowTracer") -> List[Span]:
+        """``tracer`` takes every later span; returns the log so far."""
+        with self._lock:
+            self._tracers.add(tracer)
+            return list(self.spans)
+
+    def compiled(self, fn: str, duration_sec: float, *, hit: bool,
+                 fetch_sec: float = 0.0) -> None:
+        """One executable obtained in ``duration_sec``, ending now: from
+        the persistent cache in ``fetch_sec`` (``hit``) or built. Called by
+        ``utils/jax_cache.py``'s listener on the compiling thread."""
+        with self._lock:
+            c = self.counters
+            c["compile_requests"] += 1
+            c["compile_cache_hits"] += int(hit)
+            c["compile_obtain_s"] += duration_sec
+            c["compile_fetch_s"] += fetch_sec
+            c["compiles_since_serving"] += int(self.serving)
+            self._route(Span(
+                f"compile-{c['compile_requests']}", STAGE_COMPILE,
+                time.time() - duration_sec, duration_sec * 1e3, True,
+                f"fn={'_'.join(fn.split())} hit={int(hit)} "
+                f"fetch_ms={fetch_sec * 1e3:.3f}"))
+
+    def add_seconds(self, counter: str, duration_sec: float) -> None:
+        """``trace_s`` / ``lower_s``: JAX's tracing and lowering time,
+        summed (they fire for every inner jit, so they get no spans)."""
+        with self._lock:
+            self.counters[counter] += duration_sec
+
+    def health(self) -> Dict[str, float]:
+        """The ``compile`` block of ``health()``: process-wide, since one
+        process has one JAX; ``boot_dropped`` is what a tracer built from
+        now on will lack. A racy copy, like the rest of ``health()``."""
+        return {**self.counters, "boot_dropped": self.dropped}
+
+
+BOOT = _BootLog()
+
+
+def setup_span(stage: str, *, detail: Optional[str] = None) -> _SpanCtx:
+    """Context manager around one phase of set-up (a ``STAGE_SETUP_*``)."""
+    return _SpanCtx(BOOT, BOOT.record, "setup", stage, detail)
 
 
 class RowTracer:
@@ -378,6 +492,7 @@ class RowTracer:
         self.kept = 0               # batches whose spans entered the ring
         self.sampled_out = 0        # clean batches discarded by sampling
         self._stages: Dict[str, LatencySketch] = {}
+        self._adopt(BOOT.attach(self))
 
     # -- internal hooks (BatchTrace) ------------------------------------
 
@@ -395,6 +510,14 @@ class RowTracer:
             with self._lock:
                 sk = self._stages.setdefault(stage, LatencySketch())
         sk.add(duration_sec)
+
+    def _adopt(self, spans: Sequence[Span]) -> None:
+        """Set-up and ``compile`` spans, timed before or outside this
+        tracer: counted, kept and sketched like its own."""
+        self._count(len(spans))
+        self.ring.extend(spans)
+        for s in spans:
+            self._observe_stage(s.stage, s.duration_ms / 1e3)
 
     # -- engine surface -------------------------------------------------
 

@@ -35,6 +35,7 @@ import numpy as np
 
 from fraud_detection_tpu.explain.prompts import label_name
 from fraud_detection_tpu.models.pipeline import ServingPipeline
+from fraud_detection_tpu.obs import trace as obs_trace
 from fraud_detection_tpu.sched.sketch import LatencySketch
 from fraud_detection_tpu.stream.broker import (CommitFailedError, Consumer,
                                                Message, Producer)
@@ -985,6 +986,10 @@ class StreamingClassifier:
             # counters, ring depth/drops, per-stage latency quantiles.
             "trace": (self._rowtrace.snapshot()
                       if self._rowtrace is not None else None),
+            # What this process's executables cost to obtain (obs/trace.py,
+            # fed from jax.monitoring): requests, persistent-cache hits,
+            # seconds, and how many came after run() first polled.
+            "compile": obs_trace.BOOT.health(),
             # Alerting (obs/sentinel/, docs/observability.md): rule
             # states, firing/critical lists, incident accounting
             # (fired == resolved + still_firing), recent incidents.
@@ -1274,6 +1279,9 @@ class StreamingClassifier:
                 pin()
             started = time.perf_counter()
             idle_since: Optional[float] = None
+            # From the first poll on, an executable obtained is one a
+            # request paid for (health()["compile"]).
+            obs_trace.BOOT.serving = True
             if self.async_dispatch:
                 return self._run_loop_async(started, idle_since,
                                             max_messages, idle_timeout)

@@ -66,6 +66,18 @@ def _bound_compiled_code_maps():
         gc.collect()
 
 
+@pytest.fixture
+def boot_log(monkeypatch):
+    """A boot log of the test's own (obs/trace.py): every tracer starts its
+    ring with the process's, so a test that counts a fresh tracer's spans,
+    or reads set-up's, must not find what earlier tests compiled."""
+    from fraud_detection_tpu.obs import trace
+
+    fresh = trace._BootLog()
+    monkeypatch.setattr(trace, "BOOT", fresh)
+    return fresh
+
+
 REFERENCE_ARTIFACT = "/root/reference/dialogue_classification_model"
 
 

@@ -570,7 +570,7 @@ def test_health_snapshot_fields_and_monotonic_ages(pipeline):
                        "rebalanced_commits", "commits_skipped",
                        "row_latency_ms", "device", "sched", "dlq",
                        "annotations", "breaker", "explain", "model",
-                       "learn", "trace", "alerts"}
+                       "learn", "trace", "compile", "alerts"}
     assert h1["shed"] == 0 and h1["sched"] is None   # no scheduler attached
     assert h1["model"] is None          # plain pipeline: no lifecycle block
     assert h1["running"] is False

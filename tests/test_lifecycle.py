@@ -386,6 +386,7 @@ ENGINE_HEALTH_SCHEMA = {
     "model": (type(None), dict),
     "learn": (type(None), dict),
     "trace": (type(None), dict),
+    "compile": (dict,),
     "alerts": (type(None), dict),
 }
 

@@ -36,7 +36,9 @@ from fraud_detection_tpu.sched.sketch import LatencySketch
 from fraud_detection_tpu.stream import InProcessBroker, StreamingClassifier
 from fraud_detection_tpu.utils.atomicio import atomic_write_json
 
-pytestmark = pytest.mark.obs
+# Every tracer starts its ring with the process's boot log
+# (tests/test_obs_compile.py): each test here gets an empty one.
+pytestmark = [pytest.mark.obs, pytest.mark.usefixtures("boot_log")]
 
 
 @pytest.fixture(scope="module")
@@ -395,22 +397,34 @@ def test_untraced_engine_builds_no_span_objects(pipeline, slot_lm,
                                                 monkeypatch):
     """No tracer attached: the engine, the annotation lane and the slot
     lane construct no Span, open no span context and enter no profiler
-    annotation, while the pipeline still hands its phase timings up."""
+    annotation, while the pipeline still hands its phase timings up. (Set-up
+    writes its own few to the boot log, tracer or none — the service's
+    three phases and a ``compile`` per executable: only a span of those six
+    stages, or a context whose tracer is the boot log, goes uncounted.)"""
     from fraud_detection_tpu.obs import trace as trace_mod
 
     built = {"n": 0}
+    setup = {trace_mod.STAGE_SETUP_TRAIN, trace_mod.STAGE_SETUP_PIPELINE,
+             trace_mod.STAGE_SETUP_SERVICE, trace_mod.STAGE_SETUP_PREAMBLE,
+             trace_mod.STAGE_SETUP_WARM, trace_mod.STAGE_COMPILE}
+    # By position: Span(cid, stage, ...), _SpanCtx(tracer, ...),
+    # _annotation(stage, cid); a row event is never set-up's.
+    is_setups = {"Span": lambda a: a[1] in setup,
+                 "_SpanCtx": lambda a: a[0] is trace_mod.BOOT,
+                 "_annotation": lambda a: a[0] in setup,
+                 "_RowEvents": lambda a: False}
 
-    def counting(name):
+    def counting(name, is_setups):
         real = getattr(trace_mod, name)
 
         def make(*a, **kw):
-            built["n"] += 1
+            built["n"] += not is_setups(a)
             return real(*a, **kw)
 
         monkeypatch.setattr(trace_mod, name, make)
 
-    for name in ("Span", "_RowEvents", "_SpanCtx", "_annotation"):
-        counting(name)
+    for name, exempt in is_setups.items():
+        counting(name, exempt)
     snap = _explained_desk(pipeline, slot_lm, None, n=16, scam_every=8)
     assert snap["completed"] == 2 and snap["decode_steps"] > 0
     assert built["n"] == 0
