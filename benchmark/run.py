@@ -43,6 +43,7 @@ from benchmark.metrics._lib import percentile  # noqa: E402
 
 MISSING_MS = 1e9          # a row that never came: late beyond any limit
 TRACE_SECONDS = 3.0       # profiled slice of the window (--trace 1)
+TRACE_STOP_WAIT_S = 200.0  # the profiler's stop has taken up to 110 s
 CHECK_FRAMES = 256        # output frames compared with the reference
 CHECK_REQUESTS = 16       # explained requests compared (128 tokens each)
 
@@ -203,13 +204,16 @@ def explained_in_window(tickets, delivered, at_open, at_close,
 
 class Marks(threading.Thread):
     """At fixed times of the run, off the feeder's thread: slot-lane
-    snapshots, and the profiler's start and stop."""
+    snapshots, and the profiler's start and stop. The stop runs on a thread
+    of its own (``stopper``): over a slice of many device events it takes
+    minutes, and the marks after it are still taken on time."""
 
     def __init__(self, desk, t0: float, schedule, cap=None):
         super().__init__(name="bench-marks", daemon=True)
         self.desk, self.t0, self.cap = desk, t0, cap
         self.schedule = sorted(schedule)
         self.marks, self.stamps, self.emitted = {}, {}, {}
+        self.stopper = None
 
     def run(self) -> None:
         for at, label in self.schedule:
@@ -222,7 +226,36 @@ class Marks(threading.Thread):
             self.marks[label] = self.desk.svc.snapshot()
             self.emitted[label] = self.desk.emitted()
             if label == "trace_stop" and self.cap is not None:
-                self.cap.stop()
+                self.stopper = Stopper(self.cap)
+                self.stopper.start()
+
+
+class Stopper(threading.Thread):
+    """``cap.stop()``, timed (``seconds``)."""
+
+    def __init__(self, cap):
+        super().__init__(name="bench-trace-stop", daemon=True)
+        self.cap, self.seconds = cap, None
+
+    def run(self) -> None:
+        began = time.time()
+        self.cap.stop()
+        self.seconds = time.time() - began
+
+
+def stopped_trace(stopper, timeout: float = TRACE_STOP_WAIT_S):
+    """The capture's file once the profiler's stop has returned; None,
+    logged, where it has not within ``timeout`` seconds or never began."""
+    if stopper is None:
+        log("the profiler was never stopped; the line has no device trace")
+        return None
+    stopper.join(timeout=timeout)
+    if stopper.is_alive():
+        log(f"the profiler had not stopped {timeout:g} s after the run began "
+            f"waiting for it; the line has no device trace")
+        return None
+    log(f"the profiler's stop took {stopper.seconds:.1f} s")
+    return stopper.cap.path
 
 
 def _settled(desk_mod, desk, plan, follow: str) -> bool:
@@ -303,6 +336,7 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
         feeder.run(t0)
         at_close = compiles["n"]
         marks.join(timeout=60.0)
+        stopper = marks.stopper
         deadline = time.time() + plan.settle_s
         while (not _settled(desk_mod, desk, plan, mix.get("follow", "frames"))
                and time.time() < deadline):
@@ -405,8 +439,9 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, *, seed: int,
         breakdown = None
         if trace:
             reduced = None
-            if cap is not None and cap.path:
-                reduced = trace_reduce.reduce(trace_reduce.load(cap.path),
+            path = stopped_trace(stopper)
+            if path:
+                reduced = trace_reduce.reduce(trace_reduce.load(path),
                                               cap.window_s)
                 device["busy_s"] = reduced["busy_s"]
                 device["window_s"] = reduced["window_s"]

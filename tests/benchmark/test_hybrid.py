@@ -224,4 +224,4 @@ def test_decode_roofline_from_the_programs_own_count(spec, family, cfg):
     fewer = both[1](ctx_with(0.85 * even))
     assert 0.9 * first < fewer < first
     listed = {m["name"]: m for m in spec["per_layer"]}
-    assert listed["paged_decode_window_touched_roofline"]["workloads"] == [CELL]
+    assert CELL in listed["paged_decode_window_touched_roofline"]["workloads"]

@@ -212,18 +212,19 @@ def test_configuration_states_its_cut_and_its_limit(spec, cfg):
 def test_the_cell_is_listed_where_the_issue_says(spec):
     by = {m["name"]: m for m in spec["per_layer"]}
     listed = {n for n, m in by.items() if CELL in m["workloads"]}
-    assert listed == set(LISTED)
-    assert by["moe.decode_touched_pct"]["workloads"] == [CELL]
+    assert set(LISTED) <= listed
+    assert CELL in by["moe.decode_touched_pct"]["workloads"]
     assert by["moe.decode_touched_pct"]["better"] == "lower"
     assert by["moe.decode_touched_pct"]["layer"] == "model step"
-    assert spec["per_layer"][-1]["name"] == "moe.decode_touched_pct"
-    assert spec["workloads"][-1]["name"] == CELL
-    assert spec["configs"][-1]["name"] == CONFIG
+    assert "moe.decode_touched_pct" in [m["name"] for m in spec["per_layer"]]
+    assert CELL in [w["name"] for w in spec["workloads"]]
+    assert CONFIG in [c["name"] for c in spec["configs"]]
     e2e = {m["name"] for m in spec["end_to_end"]
            if CELL in m.get("workloads", [CELL])}
     assert e2e == {"explanations_per_s", "setup_s"}
-    # its list is pinned by another family's test; its reader counts expert
-    # layers by the hybrid's keys; no zero-compute outputs here
+    # no traced chip run of this cell has read the touched roofline; the
+    # reader of experts touched counts expert layers by the hybrid's keys;
+    # no zero-compute outputs here
     for name in ("paged_decode_window_touched_roofline",
                  "moe.experts_touched_per_step", "moe.zero_pick_share_pct"):
         assert CELL not in by[name]["workloads"]
@@ -248,7 +249,9 @@ def test_tiny_lfm2_cell_is_correct_and_reads_its_metrics(run_tiny, spec):
     assert line["metrics"]["moe.held_pick_share_pct"]["value"] == 100.0
     assert 12.5 <= line["metrics"]["moe.decode_touched_pct"]["value"] <= 100.0
     assert line["metrics"]["moe.prefill_load_max_over_mean"]["value"] >= 1.0
-    assert set(line["metrics"]) <= set(LISTED)
+    # these among those printed, and nothing printed that is not listed
+    assert set(COUNTER_METRICS) <= set(line["metrics"]) <= {
+        m["name"] for m in spec["per_layer"] if CELL in m["workloads"]}
 
 
 def test_tiny_lfm2_int8_control_is_not_correct(run_tiny):

@@ -187,9 +187,9 @@ def test_configuration_states_its_cut_and_its_limit(spec, cfg):
 def test_the_cell_is_listed_where_the_issue_says(spec):
     by = {m["name"]: m for m in spec["per_layer"]}
     listed = {n for n, m in by.items() if CELL in m["workloads"]}
-    assert listed == set(LISTED)
-    assert by["moe.zero_pick_share_pct"]["workloads"] == [CELL]
-    assert spec["per_layer"][-1]["name"] == "moe.zero_pick_share_pct"
+    assert set(LISTED) <= listed
+    assert CELL in by["moe.zero_pick_share_pct"]["workloads"]
+    assert "moe.zero_pick_share_pct" in [m["name"] for m in spec["per_layer"]]
     e2e = {m["name"] for m in spec["end_to_end"]
            if CELL in m.get("workloads", [CELL])}
     assert e2e == {"explanations_per_s", "setup_s"}
@@ -213,7 +213,9 @@ def test_tiny_longcat_cell_is_correct_and_reads_its_metrics(run_tiny, spec):
     assert 10.0 < line["metrics"]["moe.zero_pick_share_pct"]["value"] < 60.0
     assert 3.0 < line["metrics"]["moe.held_pick_share_pct"]["value"] < 40.0
     assert line["metrics"]["moe.prefill_load_max_over_mean"]["value"] >= 1.0
-    assert set(line["metrics"]) <= set(LISTED)
+    # these among those printed, and nothing printed that is not listed
+    assert set(COUNTER_METRICS) <= set(line["metrics"]) <= {
+        m["name"] for m in spec["per_layer"] if CELL in m["workloads"]}
 
 
 def test_tiny_longcat_int8_control_is_not_correct(run_tiny):

@@ -104,8 +104,10 @@ def test_reader_is_silent_against_the_parent_program(spec, name):
 
 
 def test_the_new_entries_are_appended_and_name_their_cells(spec):
+    """Each entry is present, with these cells among its ``workloads``: by
+    name, never by position, so later entries and cells may follow."""
     names = [m["name"] for m in spec["per_layer"]]
-    assert set(names[-len(WANT):]) == set(WANT)
+    assert set(WANT) <= set(names)
     by = {m["name"]: m for m in spec["per_layer"]}
     cell = "desk-lr-internlm2-1.8b."
     for name in WANT:
@@ -113,15 +115,15 @@ def test_the_new_entries_are_appended_and_name_their_cells(spec):
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert m["better"] == "lower"
-        kinds = {w[len(cell):] for w in m["workloads"]}
+        cells = set(m["workloads"])
         e2e = {e["name"]: e for e in spec["end_to_end"]}[m["moves"]]
-        assert set(m["workloads"]) <= set(e2e.get("workloads", m["workloads"]))
+        assert cells <= set(e2e.get("workloads", m["workloads"]))
         if name.startswith(("engine.", "featurize.", "score.")):
-            assert kinds == {"stream-quiet"}
+            assert cell + "stream-quiet" in cells
         elif m["moves"] == "explanations_per_s":
-            assert kinds == {"campaign", "steady"}
+            assert {cell + "campaign", cell + "steady"} <= cells
         else:
-            assert kinds == {"steady"}
+            assert cell + "steady" in cells
     assert {by[n]["layer"] for n in ("lane.wait_p50_ms",
                                      "lane.tail_p50_ms")} == {"annotation lane"}
 
@@ -136,9 +138,9 @@ def test_traced_tiny_cells_report_the_new_metrics(run_tiny, spec, mix, kind):
     want = {m["name"] for m in spec["per_layer"]
             if m["name"] in WANT and cell in m["workloads"]}
     assert want and want <= set(line["metrics"])
-    for name in want:
+    for name in want:       # present and finite: 0 is slot.starved_pct's best
         value = line["metrics"][name]["value"]
-        assert np.isfinite(value) and value != 0, (name, value)
+        assert np.isfinite(value), (name, value)
     if kind != "stream-quiet":
         # occupancy + starved + backlogged = 100 (slot.occupancy is read
         # from a mean the program rounds to four places)

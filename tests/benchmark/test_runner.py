@@ -6,6 +6,8 @@ import json
 import os
 import subprocess
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -72,7 +74,9 @@ def test_traced_run_reports_per_layer_metrics(run_tiny, spec, mix, kind):
                              "engine.finish_ms", "featurize.us_per_row",
                              "score.step_mfu"}}[kind]
     assert want <= set(line["metrics"])
-    assert all(m["value"] != 0 for m in line["metrics"].values())
+    # present and finite: 0 is the sound reading of slot.starved_pct and
+    # jit.compiles_in_window
+    assert all(np.isfinite(m["value"]) for m in line["metrics"].values())
     assert "paged_decode_window_roofline" not in line["metrics"]
 
 
@@ -179,6 +183,84 @@ def test_control_mode_judges_the_lower_precision(run_tiny):
     assert check.control_verdict(
         {**{k: v[0] for k, v in line["compared"].items()}, **numbers},
         tight)["correct"] is False
+
+
+class _StubDesk:
+    """Snapshots stamped with the clock, and nothing else of a desk."""
+
+    def __init__(self):
+        self.svc = self
+
+    def snapshot(self):
+        return {"taken": time.time()}
+
+    def emitted(self):
+        return []
+
+
+class _SlowCapture:
+    """A capture whose stop returns only once ``release`` is set (or after
+    ``stop_s``): the profiler's stop over a slice of many device events."""
+
+    def __init__(self, stop_s):
+        self.stop_s, self.release = stop_s, threading.Event()
+        self.path, self.window_s, self.returned = None, 0.0, None
+
+    def start(self):
+        self.started = time.time()
+
+    def stop(self):
+        self.window_s = time.time() - self.started
+        self.release.wait(self.stop_s)
+        self.path = "trace.xplane.pb"
+        self.returned = time.time()
+
+
+SCHEDULE = [(0.0, "open"), (0.1, "trace_start"), (0.3, "trace_stop"),
+            (0.6, "close")]
+
+
+def _marks(cap):
+    from benchmark import run
+
+    t0 = time.time() + 0.05
+    marks = run.Marks(_StubDesk(), t0, SCHEDULE, cap)
+    marks.start()
+    marks.join(timeout=5.0)
+    return t0, marks
+
+
+def test_the_close_mark_is_taken_on_time_while_the_profiler_stops():
+    from benchmark import run
+
+    cap = _SlowCapture(stop_s=2.0)
+    t0, marks = _marks(cap)
+    # the marks thread ended at the close, the stop is still running
+    assert not marks.is_alive() and marks.stopper.is_alive()
+    assert cap.path is None
+    for at, label in SCHEDULE:
+        assert 0 <= marks.stamps[label] - (t0 + at) < 0.25, label
+    assert marks.marks["close"]["taken"] - (t0 + 0.6) < 0.25
+    # run_cell's path waits for the stop before it reads the file's name
+    assert run.stopped_trace(marks.stopper) == "trace.xplane.pb"
+    assert marks.marks["close"]["taken"] < cap.returned
+    assert cap.window_s == pytest.approx(0.2, abs=0.2)
+
+
+def test_a_stop_past_its_wait_leaves_the_line_without_a_trace(capsys):
+    from benchmark import run
+
+    cap = _SlowCapture(stop_s=30.0)
+    _, marks = _marks(cap)
+    assert "close" in marks.marks
+    try:
+        assert run.stopped_trace(marks.stopper, timeout=0.2) is None
+        assert "had not stopped 0.2 s" in capsys.readouterr().err
+    finally:
+        cap.release.set()
+        marks.stopper.join(timeout=5.0)
+    assert not marks.stopper.is_alive()
+    assert run.stopped_trace(None) is None     # never stopped
 
 
 def test_no_tpu_no_result():
