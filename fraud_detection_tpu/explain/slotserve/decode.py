@@ -225,6 +225,11 @@ class PagedSlotDecoder:
         self.moe_prefill_experts_touched = 0
         self.moe_prefill_picks_held = 0
         self.state_restores = 0         # snapshots copied into a slot's block
+        # A KDA gate without a lower bound: per-channel log-decays of real
+        # tokens over its layers, prefill and decode, and those below
+        # llm.DEEP_DECAY (the least one step of the bounded gate gives)
+        self.kda_decays = 0
+        self.kda_decays_deep = 0
         # One page's bytes across every layer/tensor array; the pool's
         # total (pages, plus the slots' state blocks); and what the pool
         # saves against a worst-case reservation of max_len positions for
@@ -240,12 +245,16 @@ class PagedSlotDecoder:
             self.set_prefix(prefix_text)
 
     def _count(self, stats, *, prefill: bool, steps_run: int = 0) -> None:
-        """Add a program's expert counters to the host's sums; ``steps_run``
-        is the decode steps a window ran."""
+        """Add a program's counters to the host's sums; ``steps_run`` is the
+        decode steps a window ran."""
         if stats is None:
             return
-        got = dict(zip(llm.moe_stat_names(self.cfg),
+        got = dict(zip(llm.stat_names(self.cfg),
                        (int(v) for v in np.asarray(stats))))
+        self.kda_decays += got.get("kda_decays", 0)
+        self.kda_decays_deep += got.get("kda_decays_deep", 0)
+        if "picks" not in got:
+            return
         self.moe_picks += got["picks"]
         self.moe_picks_held += got["picks_held"]
         self.moe_picks_zero += got.get("picks_zero", 0)

@@ -770,6 +770,11 @@ class SlotServeService:
                 self._decoder.moe_prefill_experts_touched,
             "moe_prefill_picks_held": self._decoder.moe_prefill_picks_held,
             "state_restores": self._decoder.state_restores,
+            # A KDA gate without a lower bound (zeros otherwise): per-channel
+            # log-decays of real tokens, prefill and decode, over its layers,
+            # and those below -5 (llm.DEEP_DECAY).
+            "kda_decays": self._decoder.kda_decays,
+            "kda_decays_deep": self._decoder.kda_decays_deep,
         }
 
 

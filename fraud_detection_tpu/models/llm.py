@@ -57,7 +57,9 @@ class MLAConfig:
     then ``mla_wqb``) instead of one matrix ``mla_wq``. ``q_scale`` multiplies
     the query, ``kv_scale`` the normed key-value latent (so keys and values,
     and what the cache holds, carry it). ``out_gate``: the head-wise sigmoid
-    gate ``mla_wz`` ahead of the output projection."""
+    gate ``mla_wz`` ahead of the output projection. ``rotary`` False: the
+    ``rope_dim`` lanes of q and the shared key are content lanes, turned by
+    no position (latent attention without RoPE); the cache holds them alike."""
 
     kv_rank: int = 512
     nope_dim: int = 128            # per-head query/key width without RoPE
@@ -67,6 +69,7 @@ class MLAConfig:
     q_scale: float = 1.0
     kv_scale: float = 1.0
     out_gate: bool = True
+    rotary: bool = True
 
     @property
     def latent_dim(self) -> int:   # what the cache holds per token
@@ -81,13 +84,19 @@ class MLAConfig:
 class KDAConfig:
     """Gated delta-rule recurrence with a per-channel decay: per head a
     float32 ``head_dim x head_dim`` state, a causal depth-wise filter of
-    ``conv_taps`` ahead of q, k and v."""
+    ``conv_taps`` ahead of q, k and v. ``form`` names the gates: "bounded"
+    = the log-decay ``lower_bound * sigmoid(exp(A_log) (W_g x + dt_bias))``
+    (W_g full rank) and one sigmoid gate a head on the normed output
+    (``W_z x``); "kimi" = the log-decay ``-exp(A_log) softplus(W_fb W_fa x +
+    dt_bias)``, which has no lower bound, and one sigmoid gate a channel
+    (``W_gb W_ga x``), both through a rank of ``head_dim``."""
 
     n_heads: int = 8
     head_dim: int = 32
     conv_taps: int = 4
     lower_bound: float = -5.0      # log-decay of one step lies in [this, 0]
     chunk: int = 64                # prefill chunk (a multiple of 16, or < 16)
+    form: str = "bounded"
 
 
 @dataclass(frozen=True)
@@ -207,6 +216,9 @@ class TransformerConfig:
         if self.moe is not None and self.moe.score not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown router score {self.moe.score!r}: "
                              "'sigmoid' or 'softmax'")
+        if self.kda is not None and self.kda.form not in ("bounded", "kimi"):
+            raise ValueError(f"unknown KDA form {self.kda.form!r}: "
+                             "'bounded' or 'kimi'")
 
     @property
     def kinds(self) -> Tuple[Tuple[str, str], ...]:
@@ -269,18 +281,27 @@ def _layer_shapes(cfg: TransformerConfig, mixer: str, ffn: str
     else:
         k = cfg.kda
         hk, dk, taps = k.n_heads, k.head_dim, k.conv_taps
+        kimi = k.form == "kimi"
         out.update(kda_wq=((D, hk, dk), "normal", 1),
                    kda_wk=((D, hk, dk), "normal", 1),
-                   kda_wv=((D, hk, dk), "normal", 1),
-                   kda_wg=((D, hk, dk), "normal", 1),
-                   kda_conv_q=((taps, hk, dk), "filter", 1),
+                   kda_wv=((D, hk, dk), "normal", 1))
+        if kimi:
+            out.update(kda_wfa=((D, dk), "normal", None),
+                       kda_wfb=((dk, hk, dk), "normal", 1))
+        else:
+            out.update(kda_wg=((D, hk, dk), "normal", 1))
+        out.update(kda_conv_q=((taps, hk, dk), "filter", 1),
                    kda_conv_k=((taps, hk, dk), "filter", 1),
                    kda_conv_v=((taps, hk, dk), "filter", 1),
                    kda_A_log=((hk,), "zeros", 0),
                    kda_dt_bias=((hk, dk), "decay_bias", 0),
-                   kda_wbeta=((D, hk), "normal", 1),
-                   kda_wz=((D, hk), "normal", 1),
-                   kda_onorm=((dk,), "ones", None),
+                   kda_wbeta=((D, hk), "normal", 1))
+        if kimi:
+            out.update(kda_wga=((D, dk), "normal", None),
+                       kda_wgb=((dk, hk, dk), "normal", 1))
+        else:
+            out.update(kda_wz=((D, hk), "normal", 1))
+        out.update(kda_onorm=((dk,), "ones", None),
                    kda_wo=((hk, dk, D), "normal", 0))
     if ffn != "experts":
         F = cfg.d_ff
@@ -434,6 +455,7 @@ _QUANT_REDUCE_AXES = {
     "mla_wo": (0, 1), "mla_wqa": (0,), "mla_wqb": (0,),
     "kda_wq": (0,), "kda_wk": (0,), "kda_wv": (0,), "kda_wg": (0,),
     "kda_wbeta": (0,), "kda_wz": (0,), "kda_wo": (0, 1),
+    "kda_wfa": (0,), "kda_wfb": (0,), "kda_wga": (0,), "kda_wgb": (0,),
     # the gated short convolution's two projections
     "conv_win": (0,), "conv_wout": (0,),
     # experts, per expert and per output channel: (E, in, out)
@@ -985,7 +1007,8 @@ def _mla_project(params: Params, cfg: TransformerConfig, l: int, h: jax.Array,
     """q (B,T,H,nope+rope) with its rotary part turned, and what the cache
     holds of each token: (B,T,1,kv_rank+rope) = normed latent || the one
     rotary key every head shares. With ``q_rank`` the query is expanded from
-    its own normed latent; ``q_scale`` / ``kv_scale`` as ``MLAConfig`` says."""
+    its own normed latent; ``q_scale`` / ``kv_scale`` / ``rotary`` as
+    ``MLAConfig`` says."""
     m = cfg.mla
     if m.q_rank is not None:
         with jax.named_scope("mla.q_latent"):
@@ -997,14 +1020,17 @@ def _mla_project(params: Params, cfg: TransformerConfig, l: int, h: jax.Array,
         else:
             q = _mm("btr,rhd->bthd", cq, params[f"l{l}.mla_wqb"], cfg.dtype)
         q = _scaled(q, m.q_scale)
-        q = jnp.concatenate([q[..., :m.nope_dim],
-                             rope(q[..., m.nope_dim:], positions,
-                                  cfg.rope_theta)], -1)
+        if m.rotary:
+            q = jnp.concatenate([q[..., :m.nope_dim],
+                                 rope(q[..., m.nope_dim:], positions,
+                                      cfg.rope_theta)], -1)
     with jax.named_scope("mla.latent"):
         ckr = _mm("btD,Dc->btc", h, params[f"l{l}.mla_wkva"], cfg.dtype)
         c = _scaled(rms_norm(ckr[..., :m.kv_rank], params[f"l{l}.mla_kvnorm"],
                              cfg.rms_eps), m.kv_scale)
-        kr = rope(ckr[:, :, None, m.kv_rank:], positions, cfg.rope_theta)
+        kr = ckr[:, :, None, m.kv_rank:]
+        if m.rotary:
+            kr = rope(kr, positions, cfg.rope_theta)
         return q, jnp.concatenate([c[:, :, None, :], kr], -1)
 
 
@@ -1070,12 +1096,26 @@ def _mla_absorbed(params: Params, cfg: TransformerConfig, l: int,
 # -- the KDA recurrence ------------------------------------------------------
 #
 # Per head, with a float32 state S (dk x dv), per-channel decay
-# a_t = exp(g_t), g_t in [lower_bound, 0], and beta_t in (0, 1):
+# a_t = exp(g_t), g_t <= 0 (in [lower_bound, 0] for the bounded gate), and
+# beta_t in (0, 1):
 #     S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T
 #     o_t = S_t^T q_t
 # q, k, v come through a causal depth-wise filter (taps w[0..n-1]:
 # y_t = sum_j w[j] x_{t-(n-1)+j}) and SiLU; q and k are L2-normalised per
 # head, q scaled by dk^-1/2.
+
+# A per-channel log-decay below this is "deep": less than one step of the
+# bounded gate can give
+DEEP_DECAY = -5.0
+KDA_STATS = ("kda_decays", "kda_decays_deep")
+
+
+def _low_rank(params: Params, cfg: TransformerConfig, p: str, a: str, b: str,
+              h: jax.Array) -> jax.Array:
+    """``W_b (W_a h)`` (B,T,H,d) through the rank in between."""
+    return _mm("btr,rhd->bthd", _mm("btD,Dr->btr", h, params[p + a], cfg.dtype),
+               params[p + b], cfg.dtype)
+
 
 def _kda_project(params: Params, cfg: TransformerConfig, l: int, h: jax.Array):
     """Pre-filter q/k/v (B,T,3,H,d), log-decay g (B,T,H,d) and beta (B,T,H),
@@ -1085,10 +1125,15 @@ def _kda_project(params: Params, cfg: TransformerConfig, l: int, h: jax.Array):
         qkv = jnp.stack([_mm("btD,Dhd->bthd", h, params[p + n], cfg.dtype)
                          for n in ("wq", "wk", "wv")], axis=2)
     with jax.named_scope("kda.gate"):
-        y = _mm("btD,Dhd->bthd", h, params[p + "wg"], cfg.dtype).astype(_F32)
-        rate = jnp.exp(params[p + "A_log"].astype(_F32))[:, None]
-        g = cfg.kda.lower_bound * jax.nn.sigmoid(
-            rate * (y + params[p + "dt_bias"].astype(_F32)))
+        if cfg.kda.form == "kimi":
+            y = _low_rank(params, cfg, p, "wfa", "wfb", h).astype(_F32)
+            rate = jnp.exp(params[p + "A_log"].astype(_F32))[:, None]
+            g = -rate * jax.nn.softplus(y + params[p + "dt_bias"].astype(_F32))
+        else:
+            y = _mm("btD,Dhd->bthd", h, params[p + "wg"], cfg.dtype).astype(_F32)
+            rate = jnp.exp(params[p + "A_log"].astype(_F32))[:, None]
+            g = cfg.kda.lower_bound * jax.nn.sigmoid(
+                rate * (y + params[p + "dt_bias"].astype(_F32)))
         beta = jax.nn.sigmoid(_mm("btD,Dh->bth", h, params[p + "wbeta"],
                                   cfg.dtype).astype(_F32))
     return qkv, g, beta
@@ -1132,11 +1177,49 @@ def _unit_lower_inverse(L):
     return jax.lax.fori_loop(0, m, step, jnp.zeros_like(L))
 
 
-def kda_chunked(q, k, v, g, beta, S0, chunk: int):
+def _diag_pairs(q, k, G, Akk, Aqk, sb: int):
+    """``kda_chunked``'s pairs (t, s) inside each sub-block of ``sb`` tokens,
+    s <= t, elementwise and added to ``Akk`` / ``Aqk`` (..,C,C):
+    ``sum_c a_tc k_sc exp(G_tc - G_sc)`` for a = k, q. Every exponent is
+    <= 0 however deep the decays (s > t gives exp(-inf) = 0); one product
+    over all sub-blocks at once. q/k/G (..,C,dk)."""
+    *lead, C, dk = k.shape
+    nS = C // sb
+
+    def blocks(a):
+        return a.reshape(*lead, nS, sb, dk)
+
+    Gb, kb = blocks(G), blocks(k)
+    tri = jnp.tril(jnp.ones((sb, sb), bool))[:, :, None]             # (t,s,1)
+    e = jnp.exp(jnp.where(tri, Gb[..., :, None, :] - Gb[..., None, :, :],
+                          -jnp.inf))                          # (..,nS,t,s,dk)
+    ab = jnp.stack([kb, blocks(q)], -4)                       # (..,2,nS,t,dk)
+    d = jnp.sum(ab[..., :, None, :] * e[..., None, :, :, :, :]
+                * kb[..., None, :, None, :, :], -1)           # (..,2,nS,t,s)
+    eye = jnp.eye(nS, dtype=_F32)[:, None, :, None]                # (i,t,j,s)
+
+    def put(A, part):
+        return (A.reshape(*lead, nS, sb, nS, sb)
+                + part[..., :, :, None, :] * eye).reshape(*lead, C, C)
+
+    return put(Akk, d[..., 0, :, :, :]), put(Aqk, d[..., 1, :, :, :])
+
+
+def kda_chunked(q, k, v, g, beta, S0, chunk: int,
+                floor: Optional[float] = None):
     """The recurrence over a whole sequence, chunk by chunk (the WY / UT
     form): inside a chunk every pair's decay is taken relative to the start
-    of the 16-token sub-block of the later one, so no exponent passes 80;
-    between chunks only the state is carried. All float32.
+    of the 16-token sub-block of the later one; between chunks only the
+    state is carried. All float32. ``floor``: the least log-decay one step
+    can take (None: no bound). Where the 15 steps after a sub-block's start
+    stay within e^80 (the bounded gate's -5: e^75) the pairs inside a
+    sub-block take the relative form too, on the MXU. Deeper,
+    that form's exponent has no bound, so those pairs are computed
+    elementwise, ``sum_c k_tc k_sc exp(G_tc - G_sc)`` for s <= t, whose
+    exponent is <= 0 (scope ``kda.diag``); the relative form keeps only the
+    pairs across sub-blocks, <= e^0 there. The elementwise form is exact for
+    any g <= 0 but not free: on one TPU v5e the bounded gate's benchmark
+    cell ran ~10 % fewer explanations a second with it (PERF.md).
     q/k (B,T,H,dk), v (B,T,H,dv), g (B,T,H,dk), beta (B,T,H), S0 (B,H,dk,dv)
     -> o (B,T,H,dv), S_T. A position with g = 0 and beta = 0 leaves the
     state as it was (padding)."""
@@ -1163,13 +1246,25 @@ def kda_chunked(q, k, v, g, beta, S0, chunk: int):
     rel = jnp.exp(G.reshape(n, B, H, nS, sb, dk) - R[..., None, :])   # <= 1
     Lk = k.reshape(n, B, H, nS, sb, dk) * rel
     Lq = q.reshape(n, B, H, nS, sb, dk) * rel
-    # k_s e^(R_i - G_s): <= 1 for s before sub-block i, <= e^75 inside it,
-    # clamped where s lies after it (those pairs are masked below).
-    Kr = k[..., None, :, :] * jnp.exp(jnp.minimum(
-        R[..., :, None, :] - G[..., None, :, :], 80.0))          # (..,nS,C,dk)
+    relative = floor is not None and -floor * (sb - 1) <= 80.0
+    if relative:
+        # k_s e^(R_i - G_s): <= 1 for s before sub-block i, <= e^75 inside
+        # it, clamped where s lies after it (those pairs are masked below).
+        Kr = k[..., None, :, :] * jnp.exp(jnp.minimum(
+            R[..., :, None, :] - G[..., None, :, :], 80.0))      # (..,nS,C,dk)
+    else:
+        # k_s e^(R_i - G_s) for s before sub-block i alone (<= 1), 0 from
+        # its start on: the pairs inside it are added by _diag_pairs.
+        before = jnp.arange(C)[None, :] < sb * jnp.arange(nS)[:, None]
+        Kr = k[..., None, :, :] * jnp.exp(jnp.where(
+            before[:, :, None], R[..., :, None, :] - G[..., None, :, :],
+            -jnp.inf))                                           # (..,nS,C,dk)
     pair = partial(jnp.einsum, "...itc,...isc->...its", precision=_EXACT)
     Akk = pair(Lk, Kr).reshape(n, B, H, C, C)
     Aqk = pair(Lq, Kr).reshape(n, B, H, C, C)
+    if not relative:
+        with jax.named_scope("kda.diag"):
+            Akk, Aqk = _diag_pairs(q, k, G, Akk, Aqk, sb)
     t_, s_ = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
     Ab = jnp.where(s_ < t_, Akk, 0.0) * beta[..., None, :]
     Aq = jnp.where(s_ <= t_, Aqk, 0.0) * beta[..., None, :]
@@ -1229,20 +1324,40 @@ def _last_inputs(window: jax.Array, keep: int,
         w_, e, keep, 0))(window, end)
 
 
+def _count_decays(stats: Dict[str, jax.Array], g: jax.Array,
+                  real: Optional[jax.Array]) -> Dict[str, jax.Array]:
+    """Add a layer's per-channel log-decays of real tokens (``real`` (B,T),
+    None = all) and those below ``DEEP_DECAY`` to ``stats``."""
+    B, T, H, d = g.shape
+    if real is None:
+        n, deep = jnp.int32(B * T), g < DEEP_DECAY
+    else:
+        n = jnp.sum(real.astype(jnp.int32))
+        deep = (g < DEEP_DECAY) & real[:, :, None, None]
+    got = {"kda_decays": n * (H * d),
+           "kda_decays_deep": jnp.sum(deep.astype(jnp.int32))}
+    return {**stats, **{k: stats.get(k, 0) + v for k, v in got.items()}}
+
+
 def _kda_mix(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
              h: jax.Array, S: jax.Array, tail: jax.Array,
-             real: Optional[jax.Array]):
+             real: Optional[jax.Array], stats: Dict[str, jax.Array]):
     """The KDA mixer on its residual. ``S`` (B,H,dk,dv) float32 and ``tail``
     (B,taps-1,3,H,d: the last pre-filter inputs) are the row's state coming
-    in; returns (x', S', tail'). T == 1 is the decode step; longer inputs run
-    chunked, and ``real`` (B,T) marks the tokens that count (padding, left or
-    right, leaves the state and the tail alone)."""
+    in; returns (x', S', tail', stats). T == 1 is the decode step; longer
+    inputs run chunked, and ``real`` (B,T) marks the tokens that count
+    (padding, left or right, leaves the state and the tail alone; at T == 1
+    the rows that decode). The unbounded gate's decays are counted into
+    ``stats`` (``KDA_STATS``)."""
     T = h.shape[1]
     qkv, g, beta = _kda_project(params, cfg, l, h)
     if T > 1 and real is not None:
         qkv = jnp.where(real[:, :, None, None, None], qkv, 0)
         g = jnp.where(real[:, :, None, None], g, 0.0)
         beta = jnp.where(real[:, :, None], beta, 0.0)
+    kimi = cfg.kda.form == "kimi"
+    if kimi:
+        stats = _count_decays(stats, g, real)
     window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
     q, k, v = _kda_filter(params, cfg, l, window)
     keep = cfg.kda.conv_taps - 1
@@ -1252,13 +1367,22 @@ def _kda_mix(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
             o, tail = o[:, None], window[:, 1:]
     else:
         with jax.named_scope("kda.chunk"):
-            o, S = kda_chunked(q, k, v, g, beta, S, cfg.kda.chunk)
+            o, S = kda_chunked(q, k, v, g, beta, S, cfg.kda.chunk,
+                               None if kimi else cfg.kda.lower_bound)
             tail = _last_inputs(window, keep, real)
     with jax.named_scope("attn.out"):
         o = (o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
                                + cfg.rms_eps)).astype(cfg.dtype) \
             * params[f"l{l}.kda_onorm"]
-    return _head_gate_out(params, cfg, l, "kda", x, h, o), S, tail
+    if not kimi:
+        return _head_gate_out(params, cfg, l, "kda", x, h, o), S, tail, stats
+    with jax.named_scope("attn.out"):
+        with jax.named_scope("kda.out_gate"):
+            z = jax.nn.sigmoid(_low_rank(params, cfg, f"l{l}.kda_", "wga",
+                                         "wgb", h).astype(_F32))
+        o = (o.astype(_F32) * z).astype(cfg.dtype)
+        return (x + _mm("bthd,hdD->btD", o, params[f"l{l}.kda_wo"], cfg.dtype),
+                S, tail, stats)
 
 
 # -- the gated short convolution ---------------------------------------------
@@ -1307,6 +1431,15 @@ def moe_stat_names(cfg: TransformerConfig) -> Tuple[str, ...]:
     if not cfg.n_expert_layers:
         return ()
     return MOE_STATS + (("picks_zero",) if cfg.moe.n_zero else ())
+
+
+def stat_names(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """Every counter the slot programs pack, in their order: the expert
+    layers' (``moe_stat_names``), then ``KDA_STATS`` where a KDA layer's gate
+    has no lower bound; () for a model that counts nothing."""
+    unbounded = cfg.kda is not None and cfg.kda.form == "kimi" and any(
+        m == "kda" for m, _ in cfg.kinds)
+    return moe_stat_names(cfg) + (KDA_STATS if unbounded else ())
 
 
 def _dense_mlp(params: Params, cfg: TransformerConfig, l: int, x: jax.Array,
@@ -1586,7 +1719,8 @@ def _ffn(params: Params, cfg: TransformerConfig, l: int, x: jax.Array, act,
         branch, new = _expert_branch(
             params, cfg, l, rms_norm(x, params[f"l{l}.ln2"], cfg.rms_eps), live)
         x = _dense_mlp(params, cfg, l, x, act)
-    return x, {k: stats.get(k, 0) + v for k, v in new.items()}, branch
+    return x, {**stats, **{k: stats.get(k, 0) + v for k, v in new.items()}}, \
+        branch
 
 
 def _act(cfg: TransformerConfig):
@@ -1624,25 +1758,27 @@ def _put_row(arr: jax.Array, row: jax.Array, slot: jax.Array) -> jax.Array:
 
 def _stateful_mix(params: Params, cfg: TransformerConfig, l: int,
                   x: jax.Array, h: jax.Array, state: Dict[str, jax.Array],
-                  real: Optional[jax.Array], slot: Optional[jax.Array] = None):
+                  real: Optional[jax.Array], stats: Dict[str, jax.Array],
+                  slot: Optional[jax.Array] = None):
     """Layer ``l``'s mixer of ``STATEFUL_MIXERS`` on its residual: the one
     seam the three layer loops hand such a mixer its named state arrays
     through (``state``: any dict that holds the layer's ``l{l}.{name}``
     entries) and take them back by. With ``slot`` the arrays are the pool's
     and the mixer runs on row ``slot`` of each, which alone is written.
-    Returns (x', {``l{l}.{name}``: the array going out})."""
+    Returns (x', {``l{l}.{name}``: the array going out}, stats with what the
+    mixer counts added)."""
     mixer = cfg.kinds[l][0]
     names = [f"l{l}.{name}" for name in _state_shapes(cfg, mixer)]
     held = [state[n] for n in names]
     if slot is not None:
         held = [jax.lax.dynamic_index_in_dim(a, slot, 0) for a in held]
     if mixer == "kda":
-        x, *new = _kda_mix(params, cfg, l, x, h, *held, real)
+        x, *new, stats = _kda_mix(params, cfg, l, x, h, *held, real, stats)
     else:
         x, *new = _conv_mix(params, cfg, l, x, h, *held, real)
     if slot is not None:
         new = [_put_row(state[n], a, slot) for n, a in zip(names, new)]
-    return x, dict(zip(names, new))
+    return x, dict(zip(names, new)), stats
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -1690,7 +1826,7 @@ def _hybrid_mixer(params: Params, cfg: TransformerConfig, l: int,
     if cfg.kinds[l][0] in STATEFUL_MIXERS:
         return _stateful_mix(
             params, cfg, l, x, h,
-            init_state(cfg, B) if kv_cache is None else kv_cache, real)
+            init_state(cfg, B) if kv_cache is None else kv_cache, real, {})[:2]
     q, lat = _mla_project(params, cfg, l, h, positions)
     if kv_cache is None:
         o = _mla_expanded(params, cfg, l, q, lat,
@@ -1909,14 +2045,15 @@ def _attn_out(params: Params, cfg: TransformerConfig, l: int,
 
 
 def _zero_stats(cfg: TransformerConfig) -> Dict[str, jax.Array]:
-    """The expert layers' counters at zero; {} for a model without any."""
-    return {k: jnp.int32(0) for k in moe_stat_names(cfg)}
+    """The model's counters at zero (``stat_names``); {} for a model
+    without any."""
+    return {k: jnp.int32(0) for k in stat_names(cfg)}
 
 
 def _pack_stats(stats: Dict[str, jax.Array]) -> Optional[jax.Array]:
-    """The counters as one int32 vector in ``moe_stat_names`` order, so they
+    """The counters as one int32 vector in ``stat_names`` order, so they
     cost the host one small fetch; None where there are none."""
-    names = [k for k in MOE_STATS + ("picks_zero",) if k in stats]
+    names = [k for k in MOE_STATS + ("picks_zero",) + KDA_STATS if k in stats]
     return jnp.stack([stats[k] for k in names]) if stats else None
 
 
@@ -1933,7 +2070,8 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
     (per-slot temperature: greedy rows argmax, sampled rows draw from
     (key, row) — a slot's stream never depends on its neighbors). ``live``
     (B,) marks the rows that decode: an expert layer routes the others
-    nowhere. Returns (tokens, new cache, the expert layers' counters)."""
+    nowhere, and no counter counts them. Returns (tokens, new cache, the
+    counters of ``stat_names``)."""
     B = tokens.shape[0]
     positions = lens[:, None]                                   # (B, 1)
     x = _embed_rows(params["embed"], tokens[:, None], cfg.dtype)
@@ -1948,7 +2086,8 @@ def _slot_step_math(params: Params, cfg: TransformerConfig,
     for l, (mixer, _) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
         if mixer in STATEFUL_MIXERS:
-            x, upd = _stateful_mix(params, cfg, l, x, h, kv_cache, None)
+            x, upd, stats = _stateful_mix(params, cfg, l, x, h, kv_cache, live,
+                                          stats)
             new_cache.update(upd)
             x, stats, branch = _ffn(params, cfg, l, x, act, live, stats,
                                     branch)
@@ -2014,12 +2153,12 @@ def _slot_window_loop(params: Params, tokens: jax.Array, lens: jax.Array,
     active_row_steps, new_view, stats)``; the host appends each row's tokens
     column-by-column under the same freeze rule, so host and device agree
     bit-for-bit, and steps_run/active_row_steps feed the occupancy
-    accounting. ``stats`` are the expert layers' counters summed over the
-    window's steps (one int32 vector in ``MOE_STATS`` order; None without
-    expert layers)."""
+    accounting. ``stats`` are the model's counters summed over the
+    window's steps (one int32 vector in ``stat_names`` order; None for a
+    model that counts nothing)."""
     B = tokens.shape[0]
     out0 = jnp.full((B, steps), cfg.EOS, jnp.int32)
-    routed = bool(cfg.n_expert_layers)
+    counted = bool(stat_names(cfg))
 
     def cond(carry):
         i, _, _, act, _, _, _, _, _ = carry
@@ -2029,7 +2168,7 @@ def _slot_window_loop(params: Params, tokens: jax.Array, lens: jax.Array,
         i, last, lens_c, act_c, rem, cache, out, n_act, stats = carry
         tok, cache, new = _slot_step_math(
             params, cfg, cache, last, lens_c, temperature,
-            jax.random.fold_in(rng, i), act_c if routed else None)
+            jax.random.fold_in(rng, i), act_c if counted else None)
         stats = {k: stats[k] + new[k] for k in stats}
         # Rows active this step wrote their fed token's k/v at lens.
         lens_c = lens_c + act_c.astype(jnp.int32)
@@ -2123,7 +2262,7 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     the preamble's snapshot, or zeros, there: ``restore_slot_state``) and the
     state at the last real token going out. Returns ``(first token, pages,
     state, stats)``: ``state`` is {} for a model none of whose mixers keeps one,
-    ``stats`` None for one without experts."""
+    ``stats`` None for one that counts nothing (``stat_names``)."""
     B, Ts = tokens.shape
     page = next(iter(kv_pages.values())).shape[1]
     positions = jnp.broadcast_to(prefix_len + jnp.arange(Ts), (B, Ts))
@@ -2147,7 +2286,8 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     for l, (mixer, _) in enumerate(cfg.kinds):
         h = rms_norm(x, params[f"l{l}.ln1"], cfg.rms_eps)
         if mixer in STATEFUL_MIXERS:
-            x, upd = _stateful_mix(params, cfg, l, x, h, new_state, real, slot)
+            x, upd, stats = _stateful_mix(params, cfg, l, x, h, new_state,
+                                          real, stats, slot)
             new_state.update(upd)
             x, stats, branch = _ffn(params, cfg, l, x, act, real, stats,
                                     branch)

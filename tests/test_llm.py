@@ -692,7 +692,8 @@ def test_hybrid_quantize_covers_every_new_matrix():
 @pytest.mark.parametrize("chunk,T,case", [
     (16, 40, "random"), (32, 70, "random"), (64, 64, "random"),
     (64, 200, "published"), (64, 150, "correlated"), (32, 70, "correlated"),
-    (64, 150, "correlated-floor"), (64, 150, "beta0")])
+    (64, 150, "correlated-floor"), (64, 150, "beta0"), (64, 150, "deep"),
+    (16, 40, "deep")])
 def test_kda_chunked_equals_stepped(chunk, T, case):
     """The chunked (WY / UT) form of the recurrence is the token-by-token
     step, decays from the strongest allowed (-5 a token: 1/Gamma overflows
@@ -704,7 +705,13 @@ def test_kda_chunked_equals_stepped(chunk, T, case):
     system (every key one unit vector plus 1e-2 of noise, beta in [0.9, 1):
     its strictly lower part sits near beta, which a substitution solves and a
     product of its powers does not), without decay and with every decay at
-    the floor; ``beta0`` makes the system the identity."""
+    the floor; ``beta0`` makes the system the identity. Each case runs both
+    forms of the pairs inside a 16-token sub-block: relative to its start
+    (``floor`` -5, the bounded gate's) and elementwise (``floor`` None).
+    ``deep`` takes log-decays down to -40 a step (a gate with no lower
+    bound), where the relative form's exponent would run to e^several
+    hundred (clamped, a token's own pair would read 0): a ``floor`` that
+    deep selects the elementwise form."""
     rng = np.random.default_rng(chunk + T)
     B, H, d = (1, 2, 128) if case == "published" else (2, 3, 16)
     q, k, v = (rng.standard_normal((B, T, H, d)).astype(np.float32) for _ in range(3))
@@ -721,15 +728,21 @@ def test_kda_chunked_equals_stepped(chunk, T, case):
         beta = (0.9 + 0.1 * beta).astype(np.float32)
     if case == "beta0":
         beta[:] = 0.0
+    if case == "deep":
+        g = (-40.0 * rng.random((B, T, H, d)) ** 2).astype(np.float32)
+        g[-1, 10:14] = 0.0
+        assert (g < -5).mean() > 0.5
     S0 = rng.standard_normal((B, H, d, d)).astype(np.float32)
-    o, S = llm.kda_chunked(*(jnp.asarray(a) for a in (q, k, v, g, beta, S0)), chunk)
     Sw, ow = jnp.asarray(S0), []
     for t in range(T):
         o_t, Sw = llm.kda_step(*(jnp.asarray(a[:, t]) for a in (q, k, v, g, beta)), Sw)
         ow.append(o_t)
-    assert np.isfinite(np.asarray(o)).all()
-    np.testing.assert_allclose(np.asarray(o), np.stack(ow, 1), atol=2e-5)
-    np.testing.assert_allclose(np.asarray(S), np.asarray(Sw), atol=2e-5)
+    for floor in (None, -40.0 if case == "deep" else -5.0):
+        o, S = llm.kda_chunked(*(jnp.asarray(a) for a in (q, k, v, g, beta, S0)),
+                               chunk, floor)
+        assert np.isfinite(np.asarray(o)).all()
+        np.testing.assert_allclose(np.asarray(o), np.stack(ow, 1), atol=2e-5)
+        np.testing.assert_allclose(np.asarray(S), np.asarray(Sw), atol=2e-5)
     if case == "beta0":                   # nothing is written: S0 decayed, read
         decayed = S0[:, None] * np.exp(np.cumsum(g, 1))[..., None]
         np.testing.assert_allclose(

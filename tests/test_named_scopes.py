@@ -178,6 +178,49 @@ def _convmix_prefill_case():
             LAYER_SCOPES | CONV_SCOPES | {"moe.route", "moe.experts"})
 
 
+# What the unbounded KDA gate adds: the decay through a low rank with no
+# lower bound (the chunk's pairs inside a sub-block taken elementwise), a
+# channel-wise low-rank output gate, latent attention without rotary lanes.
+KIMI_SCOPES = {"kda.diag", "kda.out_gate"}
+KIMI = llm.TransformerConfig(
+    vocab_size=300, d_model=32, n_heads=2, n_layers=3, d_ff=64, max_seq=256,
+    tie_embeddings=False, rms_eps=1e-5,
+    layer_kinds=(("kda", "dense"), ("mla", "experts"), ("kda", "experts")),
+    mla=llm.MLAConfig(kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8,
+                      out_gate=False, rotary=False),
+    kda=llm.KDAConfig(n_heads=2, head_dim=16, chunk=16, form="kimi"),
+    moe=llm.MoEConfig(n_experts=8, top_k=2, n_group=1, topk_group=1,
+                      d_expert=16, d_shared=16, routed_scale=2.446,
+                      held_start=0, held_count=4))
+
+
+def _kimi_inputs():
+    params = llm.init_params(jax.random.PRNGKey(0), KIMI)
+    pages = llm.init_kv_pages(KIMI, 12, 16)
+    tables = jnp.asarray(np.arange(12).reshape(3, 4), jnp.int32)
+    return params, pages, tables, jax.random.PRNGKey(1), llm.init_state(KIMI, 3)
+
+
+def _kimi_decode_case():
+    params, pages, tables, key, state = _kimi_inputs()
+    args = (params, jnp.asarray([5, 6, 7], jnp.int32),
+            jnp.asarray([10, 20, 3], jnp.int32),
+            jnp.asarray([True, True, False]), jnp.asarray([8, 8, 0], jnp.int32),
+            KIMI, pages, tables, jnp.zeros(3, jnp.float32), key, 4, state)
+    return (llm.paged_decode_window, (5, 10), args,
+            LAYER_SCOPES - {"attn.scores", "attn.values"} | HYBRID_SCOPES
+            | {"kv.append", "kda.step", "mla.absorb", "kda.out_gate"})
+
+
+def _kimi_prefill_case():
+    params, pages, tables, key, state = _kimi_inputs()
+    tokens = jnp.asarray(np.arange(32).reshape(1, 32) % 250, jnp.int32)
+    args = (params, tokens, jnp.int32(40), KIMI, pages, tables[0],
+            jnp.float32(0.0), key, 16, state, jnp.int32(1))
+    return (llm.paged_slot_prefill, (3, 8), args,
+            LAYER_SCOPES | HYBRID_SCOPES | KIMI_SCOPES | {"kda.chunk"})
+
+
 def _packed_rows():
     rng = np.random.default_rng(0)
     ids = rng.integers(0, 500, (8, 16)).astype(np.int16)
@@ -217,7 +260,8 @@ def _tree_case():
                                   _prefill_case, _lr_case, _tree_case,
                                   _hybrid_decode_case, _hybrid_prefill_case,
                                   _shortcut_decode_case, _shortcut_prefill_case,
-                                  _convmix_decode_case, _convmix_prefill_case])
+                                  _convmix_decode_case, _convmix_prefill_case,
+                                  _kimi_decode_case, _kimi_prefill_case])
 def test_scopes_are_named_and_change_no_number(case, monkeypatch):
     fn, static, args, scopes = case()
     named = fn.lower(*args)
@@ -419,3 +463,44 @@ def test_convmix_programs_keep_filter_tails_and_hold_every_expert():
         assert stats["picks"] > 0 and stats["picks_held"] == stats["picks"]
         assert set(out[-2]) == {"l0.tail", "l2.tail"}
         assert out[-2]["l0.tail"].shape == (3, 2, 32)
+
+
+@pytest.mark.parametrize("case", [_decode_case, _prefill_case,
+                                  _hybrid_decode_case, _hybrid_prefill_case,
+                                  _shortcut_decode_case, _shortcut_prefill_case,
+                                  _convmix_decode_case, _convmix_prefill_case])
+def test_other_programs_carry_none_of_the_unbounded_gates_scopes(case):
+    """The dense decoder, the hybrid of the bounded gate, the
+    shortcut-connected model and the convolution hybrid lower to the
+    programs they were: no elementwise diagonal, no channel-wise output
+    gate, and no decay counters among their counters."""
+    fn, _, args, _ = case()
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert not [s for s in KIMI_SCOPES if f"/{s}" in text]
+    cfg = next(a for a in args if isinstance(a, llm.TransformerConfig))
+    assert llm.stat_names(cfg) == llm.moe_stat_names(cfg)
+
+
+def test_unbounded_gate_programs_count_their_decays():
+    """Both slot programs of the unbounded gate hand back the expert
+    counters and then the two decay counters: every real token's
+    per-channel decays over the KDA layers (2 x 2 x 16 a token), of which
+    those below -5 are a part; the diagonal lies inside the chunk's scope,
+    the channel gate inside the output's."""
+    names = llm.stat_names(KIMI)
+    assert names == llm.MOE_STATS + llm.KDA_STATS
+    for case, tokens in ((_kimi_decode_case, None), (_kimi_prefill_case, 40 - 16)):
+        fn, _, args, _ = case()
+        text = fn.lower(*args).as_text(debug_info=True)
+        assert "/attn.out/kda.out_gate" in text
+        if tokens:
+            assert "/kda.chunk/kda.diag" in text
+        out = fn(*args)
+        stats = dict(zip(names, np.asarray(out[-1])))
+        per_token = 2 * 2 * 16
+        if tokens is None:                  # the live rows' steps alone
+            assert stats["kda_decays"] % per_token == 0
+            assert 0 < stats["kda_decays"] <= 2 * 4 * per_token
+        else:
+            assert stats["kda_decays"] == tokens * per_token
+        assert 0 <= stats["kda_decays_deep"] <= stats["kda_decays"]

@@ -574,6 +574,9 @@ SLOTSERVE_BLOCK_SCHEMA = {
     "moe_prefill_experts_touched": (int,),
     "moe_prefill_picks_held": (int,),
     "state_restores": (int,),
+    # a KDA gate without a lower bound: its decays; zeros otherwise
+    "kda_decays": (int,),
+    "kda_decays_deep": (int,),
 }
 
 
