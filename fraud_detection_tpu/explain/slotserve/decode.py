@@ -4,7 +4,10 @@ One :class:`PagedSlotDecoder` owns ONE persistent KV pool — a flat pool of
 fixed-size pages per layer, allocated once, and a per-slot page table — and
 the two jitted entries that touch it: ``paged_slot_prefill`` (admit one
 prompt into a free slot at an iteration boundary) and
-``paged_decode_window`` (advance every busy slot up to a window of tokens).
+``paged_decode_window`` (advance every busy slot up to a window of tokens),
+or, for a model that generates by diffusion over blocks,
+``block_diffusion.paged_block_window`` (advance every busy slot up to a
+window of block forwards; the block each slot has in flight is kept here).
 Compile count is bounded by construction: exactly one decode program for
 the pool, plus one prefill program per prompt bucket (prompt lengths round
 up to ``prompt_bucket`` multiples — the same padding-ladder idea
@@ -28,7 +31,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from fraud_detection_tpu.models import llm
+from fraud_detection_tpu.models import block_diffusion, llm
 
 
 def _no_span(_stage: str):
@@ -155,9 +158,15 @@ class PagedSlotDecoder:
             raise ValueError(
                 f"page_size must be a power of two, got {page_size}")
         cfg = lm.cfg
+        # A model that generates by diffusion over blocks (None otherwise):
+        # prompts are prefilled and pages written a whole block at a time.
+        self.diffusion = cfg.diffusion
         # Bucket the width itself so the widest prefill is a ladder rung.
         width = prompt_bucket * (-(-prompt_width // prompt_bucket))
         max_len = width + max_new_tokens
+        if self.diffusion is not None:
+            block_diffusion.check_model(cfg)
+            max_len = -(-max_len // self.diffusion.block) * self.diffusion.block
         if max_len > cfg.max_seq:
             raise ValueError(
                 f"slot cache needs {max_len} positions (prompt_width "
@@ -233,6 +242,19 @@ class PagedSlotDecoder:
         # llm.DEEP_DECAY (the least one step of the bounded gate gives)
         self.kda_decays = 0
         self.kda_decays_deep = 0
+        # Diffusion over blocks: row-forwards of live rows (commit forwards
+        # included), positions committed by denoising, blocks committed to
+        # the pages; and each slot's block in flight (its ids, denoising
+        # steps so far, positions that started masked), which rides from
+        # one window to the next here.
+        self.diffusion_row_forwards = 0
+        self.diffusion_tokens_committed = 0
+        self.diffusion_blocks_committed = 0
+        if self.diffusion is not None:
+            self._blocks = np.full((slots, self.diffusion.block),
+                                   self.diffusion.mask_id, np.int32)
+            self._block_step = np.zeros(slots, np.int32)
+            self._block_new = np.zeros(slots, np.int32)
         # One page's bytes across every layer/tensor array; the pool's
         # total (pages, plus the slots' state blocks); and what the pool
         # saves against a worst-case reservation of max_len positions for
@@ -326,6 +348,10 @@ class PagedSlotDecoder:
         if self._prefix_pids:
             raise ValueError("prefix already set")
         toks = np.asarray(self.lm.tokenizer.encode(prefix_text), np.int32)
+        if self.diffusion is not None:
+            # only whole blocks are shared: the preamble's last partial
+            # block attends the transcript after it
+            toks = toks[:self.committed_len(len(toks))]
         lp = len(toks)
         if lp >= self.prompt_width:
             raise ValueError(
@@ -370,9 +396,18 @@ class PagedSlotDecoder:
 
     # -- admission -------------------------------------------------------
 
+    def committed_len(self, n: int) -> int:
+        """Positions of an ``n``-token prompt that the prefill writes to the
+        pages: all of them, or, diffusing over blocks, its whole blocks (the
+        rest seed the first block generated)."""
+        if self.diffusion is None:
+            return n
+        return n // self.diffusion.block * self.diffusion.block
+
     def pages_needed(self, prompt_tokens: np.ndarray) -> int:
         """Fresh pages an admit would ALLOCATE (retained shared pages are
         free-list-neutral; the COW copy is not)."""
+        prompt_tokens = prompt_tokens[:self.committed_len(len(prompt_tokens))]
         lp, suffix = self._split_prompt(prompt_tokens)
         ts = self.prompt_bucket * (-(-len(suffix) // self.prompt_bucket))
         cover = -(-(lp + ts) // self.page_size)
@@ -432,29 +467,43 @@ class PagedSlotDecoder:
 
     def prefill(self, slot: int, prompt_tokens: np.ndarray,
                 temperature: float, seed: int,
-                span: Callable = _no_span) -> int:
+                span: Callable = _no_span) -> Optional[int]:
         """Admit one prompt: build the slot's page table (alloc/retain/COW)
         FIRST, copy the state the suffix starts from into the slot's block
         (the preamble's snapshot, or zeros; under ``span("slot_state_restore")``
         and only where the model keeps such state), then run the suffix-only
         prefill program against both. Returns the FIRST sampled token
-        (already part of the row's output)."""
+        (already part of the row's output); None for a model that diffuses
+        over blocks, whose whole blocks are prefilled and whose tail opens
+        the slot's first block (nothing is served before it commits)."""
         import jax
         import jax.numpy as jnp
 
         if self._cover[slot]:
             raise ValueError(f"slot {slot} admitted without release")
-        n = len(prompt_tokens)
+        n = self.committed_len(len(prompt_tokens))
+        if self.diffusion is not None:
+            tail = prompt_tokens[n:]
+            self._blocks[slot] = self.diffusion.mask_id
+            self._blocks[slot, :len(tail)] = tail
+            self._block_step[slot] = 0
+            self._block_new[slot] = self.diffusion.block - len(tail)
+            prompt_tokens = prompt_tokens[:n]
         lp, suffix = self._split_prompt(prompt_tokens)
         ts = self.prompt_bucket * (-(-len(suffix) // self.prompt_bucket))
         cover = -(-(lp + ts) // self.page_size)
         self._table_for_admit(slot, lp, cover)
+        if lp:
+            self.prefix_hits += 1
+            self.prefix_tokens_saved += lp
         if self.state:
             with span("slot_state_restore"):
                 self.state = llm.restore_slot_state(
                     self.state, self._prefix_state if lp else self._zero_state,
                     jnp.int32(slot))
             self.state_restores += 1
+        if not ts:                      # no whole block past the preamble
+            return None
         padded = np.zeros((1, ts), np.int32)
         padded[0, :len(suffix)] = suffix
         tok, self.pages, self.state, stats = llm.paged_slot_prefill(
@@ -467,12 +516,8 @@ class PagedSlotDecoder:
         self.prefills_flash += llm.prefill_takes_flash(self.cfg, ts)
         self.prefills_expert_kernel += llm.experts_take_kernel(
             self.cfg, self.lm.params)
-        if lp:
-            self.prefix_hits += 1
-            self.prefix_tokens_saved += lp
-        tok = int(tok)
         self._count(stats, prefill=True)
-        return tok
+        return None if self.diffusion is not None else int(tok)
 
     # -- decode-window growth & release ----------------------------------
 
@@ -480,7 +525,10 @@ class PagedSlotDecoder:
         """Extend ``slot``'s table to cover ``length + steps`` positions
         (host side of the iteration boundary — the compiled window program
         never sees a table that can't hold its writes). False on pool
-        exhaustion: the caller preempts a slot and retries."""
+        exhaustion: the caller preempts a slot and retries. Diffusing over
+        blocks, ``steps`` forwards commit ``window_positions`` at most."""
+        if self.diffusion is not None:
+            steps = block_diffusion.window_positions(self.diffusion, steps)
         need = -(-min(length + steps, self.max_len) // self.page_size)
         while self._cover[slot] < need:
             try:
@@ -558,17 +606,60 @@ class PagedSlotDecoder:
             self._count(stats, prefill=False, steps_run=fetched[2])
             return fetched
 
+    def block_step(self, lens: np.ndarray, active: np.ndarray,
+                   remaining: np.ndarray, temperatures: np.ndarray, seed: int,
+                   steps: int, span: Callable = _no_span):
+        """One window of up to ``steps`` block forwards over the whole pool
+        (``block_diffusion.paged_block_window``; the slots' blocks in flight
+        go in and come back here); returns ``(out, counts, new_lens,
+        forwards_run, active_row_forwards)``: row b served ``out[b,
+        :counts[b]]`` this window. One host sync per window, spans as
+        :meth:`step`'s."""
+        import jax
+        import jax.numpy as jnp
+
+        with span("slot_launch"):
+            (out, counts, blocks, new_lens, bstep, bnew, steps_run, n_act,
+             self.pages, stats, dstats) = block_diffusion.paged_block_window(
+                self.lm.params, jnp.asarray(self._blocks),
+                jnp.asarray(lens, jnp.int32), jnp.asarray(self._block_step),
+                jnp.asarray(self._block_new), jnp.asarray(active),
+                jnp.asarray(remaining, jnp.int32), self.cfg, self.pages,
+                jnp.asarray(self._tables),
+                jnp.asarray(temperatures, jnp.float32),
+                jax.random.PRNGKey(seed & 0x7FFFFFFF), int(steps))
+        self.steps += 1
+        self.windows_expert_kernel += llm.experts_take_kernel(
+            self.cfg, self.lm.params)
+        with span("slot_fetch"):
+            fetched = (np.asarray(out), np.asarray(counts), np.array(new_lens),
+                       int(steps_run), int(n_act))
+            self._blocks = np.array(blocks)
+            self._block_step = np.array(bstep)
+            self._block_new = np.array(bnew)
+            self._count(stats, prefill=False, steps_run=fetched[3])
+            rows, tokens, blocks_done = (int(v) for v in np.asarray(dstats))
+            self.diffusion_row_forwards += rows
+            self.diffusion_tokens_committed += tokens
+            self.diffusion_blocks_committed += blocks_done
+            return fetched
+
     def warm(self, steps: int, prompt: Optional[str] = None) -> None:
         """Compile the decode window + the smallest suffix bucket off the
         serving path, then return the pages (no residue)."""
         toks, _ = self.encode_prompt(prompt or "warm")
         self.prefill(0, toks, 0.0, 0)
-        self.grow_for_window(0, len(toks), steps)
+        length = self.committed_len(len(toks))
+        self.grow_for_window(0, length, steps)
         lens = np.zeros(self.slots, np.int32)
-        lens[0] = len(toks)
+        lens[0] = length
         active = np.zeros(self.slots, bool)
         active[0] = True
         remaining = np.ones(self.slots, np.int32)
-        self.step(np.full(self.slots, self.cfg.EOS, np.int32), lens, active,
-                  remaining, np.zeros(self.slots, np.float32), 0, steps)
+        temps = np.zeros(self.slots, np.float32)
+        if self.diffusion is not None:
+            self.block_step(lens, active, remaining, temps, 0, steps)
+        else:
+            self.step(np.full(self.slots, self.cfg.EOS, np.int32), lens,
+                      active, remaining, temps, 0, steps)
         self.release_slot(0)

@@ -465,21 +465,31 @@ class SlotServeService:
                 first = self._decoder.prefill(
                     slot, req.tokens, req.temperature,
                     self._seed + self._seq, span=self._span)
-            now = self._clock()
-            req.first_token_at = now
-            self._first.add(max(0.0, now - req.submitted_at))
-            self._lens[slot] = len(req.tokens)
-            self._last_tok[slot] = first
+            if first is not None:
+                self._first_token(req)
+            self._lens[slot] = self._decoder.committed_len(len(req.tokens))
             self._temps[slot] = req.temperature
             self._active_arr[slot] = True
             with self._cv:
                 self._prefills += 1
-            self._emit(slot, first)
+            # A model that diffuses over blocks serves nothing before its
+            # first block commits.
+            if first is not None:
+                self._last_tok[slot] = first
+                self._emit(slot, first)
+
+    def _first_token(self, req: _SlotRequest) -> None:
+        now = self._clock()
+        req.first_token_at = now
+        self._first.add(max(0.0, now - req.submitted_at))
 
     def _decode_step(self) -> None:
         """prefill/decode → decode: one fused decode window for the whole
         pool. The host replays the device's freeze rule column-by-column,
-        so each row's emission stream is exactly the single-step one."""
+        so each row's emission stream is exactly the single-step one. A
+        model that diffuses over blocks serves a row's tokens a committed
+        block at a time (``out[slot, :counts[slot]]``), and the window's
+        steps are block forwards."""
         import numpy as np
 
         busy_rows = np.flatnonzero(self._active_arr).tolist()
@@ -497,10 +507,16 @@ class SlotServeService:
             req = self._slot_req[slot]
             remaining[slot] = max(0, req.max_new - len(req.out))
         self._seq += 1
-        out, new_lens, steps_run, n_act = self._decoder.step(
-            self._last_tok, self._lens, self._active_arr, remaining,
-            self._temps, self._seed + self._seq, self.decode_window,
-            span=self._span)
+        counts = None
+        if self._decoder.diffusion is not None:
+            out, counts, new_lens, steps_run, n_act = self._decoder.block_step(
+                self._lens, self._active_arr, remaining, self._temps,
+                self._seed + self._seq, self.decode_window, span=self._span)
+        else:
+            out, new_lens, steps_run, n_act = self._decoder.step(
+                self._last_tok, self._lens, self._active_arr, remaining,
+                self._temps, self._seed + self._seq, self.decode_window,
+                span=self._span)
         self._lens = new_lens
         free = steps_run * self.slots - n_act
         with self._cv:
@@ -516,7 +532,10 @@ class SlotServeService:
         with self._span("slot_emit"):
             for slot in busy_rows:
                 req = self._slot_req[slot]
-                for j in range(out.shape[1]):
+                n = out.shape[1] if counts is None else int(counts[slot])
+                if n and req.first_token_at is None:
+                    self._first_token(req)
+                for j in range(n):
                     tok = int(out[slot, j])
                     req.out.append(tok)
                     self._last_tok[slot] = tok
@@ -779,6 +798,15 @@ class SlotServeService:
             # and those below -5 (llm.DEEP_DECAY).
             "kda_decays": self._decoder.kda_decays,
             "kda_decays_deep": self._decoder.kda_decays_deep,
+            # Diffusion over blocks (zeros otherwise): row-forwards of live
+            # rows, commit forwards included (``decode_steps`` counts the
+            # pool's forwards), positions committed by denoising, and blocks
+            # committed to the pages.
+            "diffusion_row_forwards": self._decoder.diffusion_row_forwards,
+            "diffusion_tokens_committed":
+                self._decoder.diffusion_tokens_committed,
+            "diffusion_blocks_committed":
+                self._decoder.diffusion_blocks_committed,
         }
 
 

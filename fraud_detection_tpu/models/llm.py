@@ -185,6 +185,9 @@ class TransformerConfig:
     # ``attention`` layers RMS-norm q and k per head ahead of RoPE (gammas
     # ``q_norm`` / ``k_norm`` of head_dim, eps ``rms_eps``)
     qk_norm: bool = False
+    # generation by diffusion over blocks (models/block_diffusion.py
+    # ``BlockDiffusionConfig``): attention is causal by blocks everywhere
+    diffusion: Optional["BlockDiffusionConfig"] = None  # noqa: F821
 
     def __post_init__(self):
         kinds = self.kinds
@@ -726,7 +729,8 @@ def _flash_diff_bwd(res, g):
 _flash_attention_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 
-def causal_attention(q, k, v, use_flash: Optional[bool] = None) -> jax.Array:
+def causal_attention(q, k, v, use_flash: Optional[bool] = None,
+                     block: int = 1) -> jax.Array:
     """Full-sequence causal attention, dispatched by length and context:
 
     * short sequences — materialized scores (cheapest to compile);
@@ -744,7 +748,15 @@ def causal_attention(q, k, v, use_flash: Optional[bool] = None) -> jax.Array:
 
     k/v may arrive at their narrow GQA/MQA width (fewer heads than q):
     the flash path and the short path (``_attend``) consume them natively;
-    only the chunked path expands here. Every branch sees identical math."""
+    only the chunked path expands here. Every branch sees identical math.
+
+    ``block`` > 1: causal by blocks of that many positions (a query sees its
+    whole block; ``_sees_to``), a serving path only: the flash kernel or
+    materialized scores, as the slot prefill chooses, with no backward."""
+    if block > 1:
+        T = q.shape[1]
+        mask = jnp.arange(T)[None, :] <= _sees_to(jnp.arange(T), block)[:, None]
+        return _suffix_attention(q, k, v, mask, 0, block)
     long_seq = q.shape[1] >= _FLASH_MIN_T
     if use_flash is None:
         use_flash = long_seq
@@ -782,20 +794,33 @@ def prefill_takes_flash(cfg: TransformerConfig, suffix_len: int) -> bool:
                for mixer, _ in cfg.kinds if mixer in widths)
 
 
-def _suffix_attention(q, k, v, mask, prefix_len: int) -> jax.Array:
+def _sees_to(pos: jax.Array, block: int) -> jax.Array:
+    """The last position a query at ``pos`` attends: its own (causal), or
+    the last of its block of ``block`` positions (a power of two; causal by
+    blocks, ``cfg.diffusion``)."""
+    return pos | (block - 1) if block > 1 else pos
+
+
+def _block(cfg: TransformerConfig) -> int:
+    return 1 if cfg.diffusion is None else cfg.diffusion.block
+
+
+def _suffix_attention(q, k, v, mask, prefix_len: int,
+                      block: int = 1) -> jax.Array:
     """A suffix's queries (B,Ts,H,d) at position ``prefix_len`` against the
     row's gathered view k (B,S,Hkv,d) / v (B,S,Hkv,dv): row j attends view
-    positions <= prefix_len + j, which is what ``mask`` (Ts,S) says. A long
-    suffix of wide heads goes blockwise through the flash kernel, which takes
-    the offset and no mask, and no (H, Ts, S) scores exist; any other
-    materializes them under the mask."""
+    positions <= prefix_len + j (to the end of its block where ``block`` >
+    1), which is what ``mask`` (Ts,S) says. A long suffix of wide heads goes
+    blockwise through the flash kernel, which takes the offset and the block
+    and no mask, and no (H, Ts, S) scores exist; any other materializes them
+    under the mask."""
     if _suffix_takes_flash(q.shape[1], q.shape[-1]):
         from fraud_detection_tpu.ops.attention import flash_attention
         from fraud_detection_tpu.utils.device import pallas_interpret
 
         with jax.named_scope("attn.flash"):
             return flash_attention(q, k, v, q_offset=prefix_len,
-                                   interpret=pallas_interpret())
+                                   interpret=pallas_interpret(), block=block)
     return _attend(q, k, v, mask)
 
 
@@ -1970,7 +1995,7 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
             attn = sp(q, k, v, seq_mesh, batch_axis=b_axis)
         else:
             # kv at native GQA width: only the chunked branch expands it
-            attn = causal_attention(q, k, v, use_flash)
+            attn = causal_attention(q, k, v, use_flash, _block(cfg))
 
         x = x + _mm("bthd,hdD->btD", attn, params[f"l{l}.wo"], cfg.dtype)
         if ffn != "dense":
@@ -2318,9 +2343,10 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
     pos = prefix_len + jnp.arange(Ts)
     pids = table_row[pos // page]                        # (Ts,) traced ids
     offs = pos % page
-    # Row j attends every resident position at or below its own.
+    # Row j attends every resident position at or below its own (to the end
+    # of its block, for a model that generates by diffusion over blocks).
     kv_mask = (jnp.arange(table_row.shape[0] * page)[None, :]
-               <= pos[:, None])                          # (Ts, Tkv)
+               <= _sees_to(pos, _block(cfg))[:, None])   # (Ts, Tkv)
     real = (pos < length)[None] if cfg.layer_kinds else None
     new_pages: Dict[str, jax.Array] = dict(kv_pages)
     new_state: Dict[str, jax.Array] = dict(state or {})
@@ -2354,7 +2380,7 @@ def paged_slot_prefill(params: Params, tokens: jax.Array, length: jax.Array,
                             table_row[None])
         if mixer == "attention":
             x = _attn_out(params, cfg, l, x, _suffix_attention(
-                q, view["k"], view["v"], kv_mask, prefix_len))
+                q, view["k"], view["v"], kv_mask, prefix_len, _block(cfg)))
         else:
             x = _mla_out(params, cfg, l, x, h, _mla_expanded(
                 params, cfg, l, q, view["c"], kv_mask, suffix_at=prefix_len))

@@ -10,9 +10,11 @@ on the MXU. The cross-chip analogue (sequence-parallel ring attention,
 ``models/llm.py ring_attention``) uses the same online-softmax algebra with
 K/V blocks arriving over ICI instead of from HBM.
 
-Causal-only by design: the decoder has no non-causal path, and causality is
-what lets sequence padding ride for free (padded key columns sit above the
-diagonal for every real query row, so the mask discards them).
+Causal by design, or causal by blocks (``block``: a query sees the whole of
+its own block of ``block`` positions, the order in which a block-diffusion
+model attends): the decoder has no other path, and causality is what lets
+sequence padding ride for free (padded key columns sit above the diagonal
+for every real query row, so the mask discards them).
 
 Two callers: ``forward()``'s whole-sequence attention (square: the queries
 are the keys' own positions) and the slot lane's suffix prefill
@@ -38,7 +40,7 @@ _NEG = -1e30  # mask value: exp(s - m) underflows to exactly 0, no inf-inf NaNs
 
 def _flash_kernel(q_ref, k_ref, vt_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   scale: float, blk_q: int, chunk: int, n_chunks: int,
-                  n_major: int, q_offset: int):
+                  n_major: int, q_offset: int, block: int = 1):
     """One (batch*head, q-block, major key block) cell; the grid runs the
     major blocks innermost, so the scratch accumulators carry across them.
 
@@ -55,11 +57,18 @@ def _flash_kernel(q_ref, k_ref, vt_ref, o_ref, m_ref, l_ref, acc_ref, *,
     kernel loops over the chunks every row sees (no mask), then over those the
     diagonal crosses (masked), and never touches the ones above it. A major
     block wholly above the diagonal runs no chunk and, its index map holding
-    the block already resident, costs no DMA."""
+    the block already resident, costs no DMA. With ``block`` > 1 (a power
+    of two dividing ``q_offset`` and ``blk_q``) row ``i`` sees the columns
+    up to the end of its block of positions, ``<= q_offset + (i | (block -
+    1))``: the diagonal is a staircase, one step a block."""
     qi = pl.program_id(1)
     mi = pl.program_id(2)
-    first_row_sees = q_offset + qi * blk_q          # last column row 0 sees
-    last_row_sees = first_row_sees + (blk_q - 1)
+    first_row_at = q_offset + qi * blk_q            # row 0's own position
+    if block > 1:
+        first_row_sees = first_row_at + (block - 1)
+    else:
+        first_row_sees = first_row_at               # last column row 0 sees
+    last_row_sees = first_row_at + (blk_q - 1)
 
     @pl.when(mi == 0)
     def _init():
@@ -75,9 +84,11 @@ def _flash_kernel(q_ref, k_ref, vt_ref, o_ref, m_ref, l_ref, acc_ref, *,
             k_ref[0, c], q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale        # (chunk, blk_q)
         if masked:
-            ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                     - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-            s = jnp.where(ahead >= (base + c) * chunk - first_row_sees, s, _NEG)
+            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            if block > 1:                       # the last row of each block
+                rows = rows | (block - 1)
+            ahead = rows - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            s = jnp.where(ahead >= (base + c) * chunk - first_row_at, s, _NEG)
         m_prev = m_ref[0:1]                                    # (1, blk_q)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)                                 # masked -> 0
@@ -107,10 +118,11 @@ def _flash_kernel(q_ref, k_ref, vt_ref, o_ref, m_ref, l_ref, acc_ref, *,
 _MAJOR_KEYS = 2048
 
 
-@partial(jax.jit, static_argnames=("q_offset", "blk_q", "blk_k", "interpret"))
+@partial(jax.jit, static_argnames=("q_offset", "blk_q", "blk_k", "interpret",
+                                   "block"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     q_offset: int = 0, blk_q: int = 0, blk_k: int = 0,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False, block: int = 1) -> jax.Array:
     """Causal flash attention. q: (B, T, H, d) at the static position
     ``q_offset``: query row j attends key columns ``<= q_offset + j`` of
     k (B, S, Hkv, d) / v (B, S, Hkv, dv), ``S >= q_offset + T`` — the whole
@@ -126,7 +138,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     enforced by tests/test_flash_attention.py.
 
     ``blk_q`` queries a block, ``blk_k`` keys a chunk of the kernel's inner
-    loop; 0 picks from the shapes (``_auto_blocks``)."""
+    loop; 0 picks from the shapes (``_auto_blocks``). ``block`` > 1: causal
+    by blocks of that many positions (a power of two dividing ``q_offset``
+    and ``T``): query row j attends key columns ``<= q_offset + (j |
+    (block - 1))``, the whole of its own block."""
     B, T, H, d = q.shape
     S, h_kv, dv = k.shape[1], k.shape[2], v.shape[3]
     if H % h_kv or v.shape[2] != h_kv:
@@ -136,6 +151,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError(f"{T} queries at offset {q_offset} need "
                          f"{q_offset + T} keys and values, got "
                          f"{S}/{v.shape[1]}")
+    if block & (block - 1) or q_offset % block or T % block:
+        raise ValueError(f"blocks of {block} positions must be a power of "
+                         f"two dividing the offset {q_offset} and {T} queries")
     rep = H // h_kv
     auto_q, auto_k = _auto_blocks(T)
     blk_q, chunk = blk_q or auto_q, blk_k or auto_k
@@ -170,7 +188,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     out = pl.pallas_call(
         partial(_flash_kernel, scale=scale, blk_q=blk_q, chunk=chunk,
-                n_chunks=n_chunks, n_major=n_major, q_offset=q_offset),
+                n_chunks=n_chunks, n_major=n_major, q_offset=q_offset,
+                block=block),
         grid=(B * H, n_q, n_major),
         in_specs=[
             pl.BlockSpec((1, blk_q, d_pad), lambda b, qi, mi: (b, qi, 0),

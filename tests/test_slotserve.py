@@ -581,6 +581,11 @@ SLOTSERVE_BLOCK_SCHEMA = {
     # a KDA gate without a lower bound: its decays; zeros otherwise
     "kda_decays": (int,),
     "kda_decays_deep": (int,),
+    # generation by diffusion over blocks: its forwards, the positions it
+    # committed and the blocks it wrote to the pages; zeros otherwise
+    "diffusion_row_forwards": (int,),
+    "diffusion_tokens_committed": (int,),
+    "diffusion_blocks_committed": (int,),
 }
 
 
