@@ -18,10 +18,11 @@ management in the spirit of vLLM, SOSP '23):
   explain preamble prefilled ONCE into refcounted read-only pages
   (copy-on-write on the partial page), and pool exhaustion preempts the
   newest admit as an accounted ``kv_pages_exhausted`` drop;
-* a bounded **admission queue**: newly flagged rows admit into free slots
-  at iteration boundaries — prefill interleaves with decode, no fixed-batch
-  barrier, and overload drops the OLDEST queued request with honest
-  accounting (``admitted == completed + dropped`` is a pinned invariant);
+* a bounded **admission queue**: newly flagged rows admit at iteration
+  boundaries into every free slot the queue and the page pool can fill —
+  prefill interleaves with decode, no fixed-batch barrier, and overload
+  drops the OLDEST queued request with honest accounting (``admitted ==
+  completed + dropped`` is a pinned invariant);
 * per-slot retirement: a row that hits EOS frees its slot THAT iteration
   and the next queued row takes it — wall clock tracks the MEAN emission
   length, not the max, and slots never decode padding rows;
@@ -163,14 +164,17 @@ class SlotServeService:
     thread ("slotserve-lane") owns the decoder; every public surface is
     callable from any thread. ``kv_pages`` caps the page pool (default: a
     worst-case row for every slot); ``shared_prefix`` prefills the explain
-    preamble once where it fits. ``paged`` is kept for the one caller that
-    still states it (benchmark/desk.py): the pool is always paged, and
-    ``paged=False`` raises.
+    preamble once where it fits. ``prefill_per_iter`` caps the rows admitted
+    at one boundary (default: none beyond the free slots and the pages).
+    ``paged`` is kept for the one caller that still states it
+    (benchmark/desk.py): the pool is always paged, and ``paged=False``
+    raises.
     """
 
     def __init__(self, lm, *, slots: int = 8, max_queue: int = 1024,
                  max_new_tokens: int = 128, prompt_width: int = 384,
-                 prompt_bucket: int = 64, prefill_per_iter: int = 2,
+                 prompt_bucket: int = 64,
+                 prefill_per_iter: Optional[int] = None,
                  decode_window: int = 16,
                  temperature: float = 0.0, seed: int = 0,
                  rowtrace=None, wait_timeout: float = 600.0,
@@ -180,7 +184,7 @@ class SlotServeService:
                  clock: Callable[[], float] = time.perf_counter):
         if max_queue < 1:
             raise ValueError(f"max_queue must be >= 1, got {max_queue}")
-        if prefill_per_iter < 1:
+        if prefill_per_iter is not None and prefill_per_iter < 1:
             raise ValueError(
                 f"prefill_per_iter must be >= 1, got {prefill_per_iter}")
         if decode_window < 1:
@@ -257,6 +261,10 @@ class SlotServeService:
         self._truncated = 0
         self._iterations = 0
         self._prefills = 0
+        # Boundaries that admitted a row, and those that stopped with a
+        # slot free and a request queued (the page gate, or a stated cap).
+        self._admit_rounds = 0
+        self._admit_gated = 0
         self._decode_steps = 0
         self._tokens_out = 0
         self._occ_sum = 0
@@ -405,8 +413,8 @@ class SlotServeService:
         moves every busy slot, then finished rows retire and free their
         slots for the next boundary."""
         with self._span("slot_iter"):
-            with self._span("slot_admit"):
-                self._admit_pending()
+            with self._span("slot_admit") as span:
+                self._admit_pending(span)
             self._decode_step()
             with self._span("slot_retire"):
                 self._retire_done()
@@ -422,15 +430,20 @@ class SlotServeService:
             return contextlib.nullcontext()
         return tr.span(cid or f"slot-{self._iterations:x}", stage)
 
-    def _admit_pending(self) -> None:
-        """free → prefill: pop queued requests into free slots (bounded
-        per iteration so admission never starves decode), prefill each
-        prompt into its slot and emit the first sampled token."""
+    def _admit_pending(self, span=None) -> None:
+        """free → prefill: pop queued requests into every free slot the
+        queue and the page pool can fill (or up to ``prefill_per_iter``,
+        where a caller states one), prefill each prompt into its slot and
+        emit the first sampled token. The free slots bound the round by
+        themselves: a prefill only takes a slot that would otherwise spend
+        the whole next window empty. ``span``, the boundary's open
+        ``slot_admit`` span, gets ``rows= free= queued=``."""
         grabbed: List[tuple] = []
         pages_planned = 0
+        cap = self.prefill_per_iter or self.slots
         with self._cv:
-            while (self._free and self._q
-                   and len(grabbed) < self.prefill_per_iter):
+            free, queued = len(self._free), len(self._q)
+            while self._free and self._q and len(grabbed) < cap:
                 # Page-pool gate: stop admitting this boundary once the
                 # free pages can't cover every grabbed prompt's table —
                 # decode retirements free pages for the next boundary, so
@@ -451,6 +464,12 @@ class SlotServeService:
                 self._admits += 1
                 self._admit_seq[slot] = self._admits
                 grabbed.append((slot, req))
+            if grabbed:
+                self._admit_rounds += 1
+            if self._free and self._q:
+                self._admit_gated += 1
+        if span is not None:
+            span.detail = f"rows={len(grabbed)} free={free} queued={queued}"
         tr = self._rowtrace
         for slot, req in grabbed:
             self._seq += 1
@@ -713,6 +732,7 @@ class SlotServeService:
             dropped, errors = self._dropped, self._errors
             truncated = self._truncated
             iterations, prefills = self._iterations, self._prefills
+            admit_rounds, admit_gated = self._admit_rounds, self._admit_gated
             decode_steps, tokens_out = self._decode_steps, self._tokens_out
             occ_sum, started = self._occ_sum, self._started_at
             starved, backlogged = (self._steps_starved,
@@ -745,6 +765,11 @@ class SlotServeService:
                           if decode_steps else None),
             "iterations": iterations,
             "prefills": prefills,
+            # Boundaries that admitted a row (prefills / admit_rounds = rows
+            # a round), and those that stopped with a slot free and a
+            # request queued: the page gate, or a stated prefill_per_iter.
+            "admit_rounds": admit_rounds,
+            "admit_gated": admit_gated,
             # ... whose suffix was long enough for the flash kernel
             # (llm.prefill_takes_flash, the rule the program is traced by).
             "prefills_flash": self._decoder.prefills_flash,

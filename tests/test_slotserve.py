@@ -343,6 +343,134 @@ def test_chaos_every_flagged_row_explained_or_accounted(lm, pipeline):
 
 
 # ---------------------------------------------------------------------------
+# admission: every free slot at a boundary, up to the page gate
+# ---------------------------------------------------------------------------
+
+def queue_at_once(svc, prompts, max_tokens):
+    """Every framed prompt queued before the lane's next boundary: the lane
+    waits on the condition that ``submit`` takes, re-entrantly, under this
+    hold. ``max_tokens``: one budget, or one a prompt. The tickets come
+    back unresolved."""
+    budgets = (max_tokens if isinstance(max_tokens, list)
+               else [max_tokens] * len(prompts))
+    with svc._cv:
+        return [svc.submit(flatten_chat(frame_prompt(p)), max_tokens=n,
+                           temperature=0.0)
+                for p, n in zip(prompts, budgets)]
+
+
+def admit_rounds_seen(tracer):
+    """``(rows, free, queued)`` of every ``slot_admit`` span, in order."""
+    rounds = []
+    for s in tracer.ring.snapshot():
+        if s.stage == "slot_admit":
+            fields = dict(kv.split("=") for kv in s.detail.split())
+            rounds.append(tuple(int(fields[k])
+                                for k in ("rows", "free", "queued")))
+    return rounds
+
+
+def traced_service(lm, **kw):
+    from fraud_detection_tpu.obs.trace import RowTracer
+
+    svc = make_service(lm, **kw)
+    tr = RowTracer(worker="w0", sample=1.0, seed=0, capacity=1 << 14)
+    svc.set_rowtrace(tr)
+    return svc, tr
+
+
+def test_a_boundary_admits_a_row_into_every_free_slot(lm):
+    """With k slots free and at least k rows queued, the boundary admits k:
+    the first fills all 4 slots at once, and no later boundary leaves a
+    slot free while a row waits. ``admit_rounds`` counts the boundaries
+    that admitted, ``prefills`` the rows."""
+    svc, tr = traced_service(lm, slots=4, max_new_tokens=12)
+    try:
+        reqs = queue_at_once(svc, prompts_varied(10), 12)
+        for r in reqs:
+            r.wait(svc.wait_timeout)
+        snap = svc.snapshot()
+    finally:
+        assert svc.close()
+    rounds = admit_rounds_seen(tr)
+    assert rounds[0] == (4, 4, 10)
+    assert all(rows == min(free, queued) for rows, free, queued in rounds)
+    assert snap["prefills"] == sum(r[0] for r in rounds) == 10
+    assert snap["admit_rounds"] == sum(r[0] > 0 for r in rounds)
+    assert snap["admit_rounds"] < snap["prefills"]
+    assert snap["admit_gated"] == 0
+    assert snap["completed"] == 10 and snap["dropped"] == 0
+
+
+def test_a_stated_prefill_per_iter_caps_each_boundary(lm):
+    """A caller who states ``prefill_per_iter`` keeps that cap: one row a
+    boundary, and every boundary it stops with a slot free and a row
+    queued is counted as gated."""
+    svc, tr = traced_service(lm, slots=4, max_new_tokens=12,
+                             prefill_per_iter=1)
+    try:
+        reqs = queue_at_once(svc, prompts_varied(6), 12)
+        for r in reqs:
+            r.wait(svc.wait_timeout)
+        snap = svc.snapshot()
+    finally:
+        assert svc.close()
+    rounds = admit_rounds_seen(tr)
+    assert rounds[0] == (1, 4, 6)
+    assert all(rows <= 1 for rows, _, _ in rounds)
+    assert snap["prefills"] == snap["admit_rounds"] == 6
+    assert snap["admit_gated"] == sum(rows < min(free, queued)
+                                      for rows, free, queued in rounds) > 0
+    with pytest.raises(ValueError, match="prefill_per_iter"):
+        make_service(lm, prefill_per_iter=0)
+
+
+def test_page_gate_stops_admission_and_counts_it(lm):
+    """A pool that holds one worst-case row but not two prompts' tables:
+    the boundary admits one row and stops at the page gate with a slot
+    free and a row queued (``admit_gated``); the gated row is admitted
+    once the first retires, and none is preempted."""
+    prompts = [f"Analyze dialogue {i}: " + "the caller insists on a wire "
+               "transfer today. " * 9 for i in range(3)]
+    svc, tr = traced_service(lm, slots=2, max_new_tokens=24,
+                             shared_prefix=False, kv_pages=8)
+    try:
+        dec = svc._decoder
+        need = dec.pages_needed(dec.encode_prompt(
+            flatten_chat(frame_prompt(prompts[0])))[0])
+        assert dec.total_pages == dec.n_view == 8 < 2 * need
+        reqs = queue_at_once(svc, prompts, 24)
+        for r in reqs:
+            r.wait(svc.wait_timeout)
+        snap = svc.snapshot()
+    finally:
+        assert svc.close()
+    rounds = admit_rounds_seen(tr)
+    assert rounds[0] == (1, 2, 3)
+    assert all(rows <= 1 for rows, _, _ in rounds)
+    assert snap["admit_gated"] == sum(rows < min(free, queued)
+                                      for rows, free, queued in rounds) > 0
+    assert snap["prefills"] == snap["completed"] == 3
+    assert snap["dropped"] == 0
+
+
+def test_a_cohort_admitted_at_one_boundary_matches_fixed_batch_greedy(lm):
+    """Rows prefilled together at one boundary, and the cohorts after them,
+    emit the fixed-batch path's greedy tokens, positionally aligned."""
+    prompts = prompts_varied(12, base=20)
+    svc, tr = traced_service(lm, slots=4, max_new_tokens=24)
+    try:
+        reqs = queue_at_once(svc, prompts, 24)
+        got = [r.wait(svc.wait_timeout) for r in reqs]
+    finally:
+        assert svc.close()
+    assert admit_rounds_seen(tr)[0] == (4, 4, 12)
+    want = OnPodBackend.from_model(lm).generate_batch(
+        prompts, temperature=0.0, max_tokens=24)
+    assert got == list(want)
+
+
+# ---------------------------------------------------------------------------
 # the annotation lane's window over slot tickets (ISSUE 32)
 # ---------------------------------------------------------------------------
 
@@ -473,7 +601,8 @@ def test_backlog_through_the_engine_never_starves_the_slots(lm, pipeline):
     """End to end at test size: 4 slots behind the lane's window of 64 and
     a backlog of flagged rows through the engine. While rows queue on the
     lane, a free slot-step is never a STARVED one (the window keeps the
-    service's own queue fed); every row lands explained."""
+    service's own queue fed, and each boundary fills every free slot from
+    it); every row lands explained."""
     svc = make_service(lm, slots=4, max_new_tokens=6)
     try:
         broker = InProcessBroker(num_partitions=2)
@@ -516,7 +645,10 @@ def test_backlog_through_the_engine_never_starves_the_slots(lm, pipeline):
         assert (snap["slot_steps_occupied"] + snap["slot_steps_starved"]
                 + snap["slot_steps_backlogged"]
                 == snap["decode_steps"] * snap["slots"])
-        assert snap["slot_steps_backlogged"] > 0
+        # The fed queue is what each boundary admits from: several rows a
+        # boundary, none held back with a slot free.
+        assert snap["prefills"] > snap["admit_rounds"] > 0
+        assert snap["admit_gated"] == 0
     finally:
         svc.close()
 
@@ -541,6 +673,10 @@ SLOTSERVE_BLOCK_SCHEMA = {
     "occupancy": (type(None), int, float),
     "iterations": (int,),
     "prefills": (int,),
+    # boundaries that admitted a row / that stopped with a slot free and a
+    # request queued (the page gate, or a stated prefill_per_iter)
+    "admit_rounds": (int,),
+    "admit_gated": (int,),
     # ... whose suffix took the flash kernel (ISSUE 34): 0 in a tiny service.
     "prefills_flash": (int,),
     # ... and the prefills and windows whose expert tiles took the Pallas
@@ -617,14 +753,17 @@ def test_slot_steps_partition_into_occupied_starved_backlogged(lm,
     """Every slot-step of every decode window is counted once: a row
     decoded in it, or the slot was free with the queue empty (starved), or
     free with requests waiting (backlogged). A mixed run has all three:
-    12 rows over 4 slots admit 2 an iteration (free slots, rows queued),
-    then one row decodes alone (free slots, nothing queued). Both
+    12 rows queued at once over 4 slots, budgets of 16 and 12 tokens, so
+    the short rows finish mid-window while rows queue (the steps a row
+    leaves over go with its boundary's class), then one row decodes alone
+    (free slots, nothing queued). Both
     deployments of the pool count alike: the preamble resident in 5 shared
     pages, or every page a slot's own."""
     svc = make_service(lm, slots=4, max_new_tokens=16,
                        shared_prefix=shared_prefix)
     try:
-        svc.generate_batch(prompts_varied(12), temperature=0.0, max_tokens=16)
+        for r in queue_at_once(svc, prompts_varied(12), [16, 12] * 6):
+            r.wait(svc.wait_timeout)
         svc.generate_batch(prompts_varied(1, base=90), temperature=0.0,
                            max_tokens=16)
         snap = svc.snapshot()
